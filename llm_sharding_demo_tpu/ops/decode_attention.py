@@ -54,10 +54,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._pallas_compat import tpu_compiler_params
-from . import _pallas_compat
 
-BLOCK_S = 256          # cache positions per DMA block
+BLOCK_S = 256          # cache positions per DMA block (and the unit the
+                       # engine rounds its cache to)
 _WRITE_ROWS = 8        # RMW window for the column write (HBM tile rows)
 NEG_INF = -1e30        # f32 additive mask for scores
 
@@ -71,6 +70,31 @@ def eligible(max_seq: int, head_dim: int, q_len: int) -> bool:
             and max_seq % BLOCK_S == 0 and max_seq >= BLOCK_S)
 
 
+# What the block stream costs in VMEM, per cache position of a block and
+# per (batch row, kv head): the double buffer (2 copies at the cache
+# dtype) plus the loop body's f32 temporaries. The v5e compiler's own
+# report put those at 4.2 f32 copies of a block (GPT-2 124M, bf16: 105 MB
+# of temporaries beside a 25 MB block at B=16 / 256 positions, the same
+# ratio at B=32 / 128) — and refused both, since a v5e core has 128 MiB.
+_VMEM_BYTES = 128 * 1024 * 1024
+_VMEM_HEADROOM = 12 * 1024 * 1024      # accumulators, masks, windows
+_F32_TEMPORARIES = 4.25
+
+
+def stream_block(bh: int, hd: int, itemsize: int, reserved: int = 0) -> int:
+    """Cache positions per streamed block: ``BLOCK_S``, halved until the
+    stream fits the VMEM that ``reserved`` bytes of other tenants (the
+    megakernel's weight windows) leave. Every result divides ``BLOCK_S``,
+    so the cache is still whole blocks; GPT-2 124M keeps 256 through B=8
+    and streams 128 at B=16, 64 at B=32."""
+    per_position = bh * 2 * hd * (2 * itemsize + 4 * _F32_TEMPORARIES)
+    room = _VMEM_BYTES - _VMEM_HEADROOM - reserved
+    bs = BLOCK_S
+    while bs > _WRITE_ROWS and bs * per_position > room:
+        bs //= 2
+    return bs
+
+
 def _kernel(meta_ref,                      # SMEM  [2] int32 (li, off)
             q_ref, knew_ref, vnew_ref,     # VMEM (full arrays, [BH, ...])
             vf_ref,                        # VMEM [BH, 1, 1] int32 pad mask
@@ -81,7 +105,7 @@ def _kernel(meta_ref,                      # SMEM  [2] int32 (li, off)
             *, batch: int, hkv: int, g: int, hd: int):
     """One grid cell, one DMA per S-block: each fetch carries ALL
     (batch row, kv head) slices of the block and the compute is batched
-    over them, so the loop runs only ``ceil(off/BLOCK_S)`` iterations.
+    over them, so the loop runs only ``ceil(off/block_s)`` iterations.
     (Earlier shapes measured: a (b, h) grid ~2.6x slower and a flattened
     per-(b,h,block) loop ~1.9x slower — both drowned in per-iteration
     DMA/fence overhead at 64 KB blocks; this shape moves ~6 MB per DMA
@@ -89,6 +113,7 @@ def _kernel(meta_ref,                      # SMEM  [2] int32 (li, off)
     li = meta_ref[0]
     off = meta_ref[1]
     bh = batch * hkv
+    block_s = kvbuf.shape[3]               # see stream_block
 
     scale = 1.0 / (hd ** 0.5)
 
@@ -108,11 +133,11 @@ def _kernel(meta_ref,                      # SMEM  [2] int32 (li, off)
                                 preferred_element_type=jnp.float32)
     vf_bh = vf_ref[...]                                    # [BH, 1, 1]
 
-    n_blk = jnp.maximum((off + BLOCK_S - 1) // BLOCK_S, 1)
+    n_blk = jnp.maximum((off + block_s - 1) // block_s, 1)
 
     def fetch(slot, i):
         return pltpu.make_async_copy(
-            kv_in.at[li, :, :, pl.ds(i * BLOCK_S, BLOCK_S), :],
+            kv_in.at[li, :, :, pl.ds(i * block_s, block_s), :],
             kvbuf.at[slot], copy_sems.at[slot])
 
     fetch(0, 0).start()
@@ -135,12 +160,12 @@ def _kernel(meta_ref,                      # SMEM  [2] int32 (li, off)
             fetch(1 - slot, i + 1).start()
 
         fetch(slot, i).wait()
-        kvb = kvbuf[slot].astype(jnp.float32).reshape(bh, BLOCK_S, 2 * hd)
+        kvb = kvbuf[slot].astype(jnp.float32).reshape(bh, block_s, 2 * hd)
         # q_ext's V lanes are zero, so the 2hd contraction is q . K
         s = jax.lax.dot_general(q_ext, kvb, (((2,), (2,)), ((0,), (0,))),
                                 preferred_element_type=jnp.float32)
-        pos = i * BLOCK_S + jax.lax.broadcasted_iota(
-            jnp.int32, (1, 1, BLOCK_S), 2)
+        pos = i * block_s + jax.lax.broadcasted_iota(
+            jnp.int32, (1, 1, block_s), 2)
         # strictly-prior positions stream from the cache; position ``off``
         # itself is the in-register self term (folded in at finalize)
         ok = (pos < off) & (pos >= vf_bh)                  # [BH, 1, BS]
@@ -202,6 +227,7 @@ def _call(q4, k_new, v_new, vf_bh, KV, meta, *, interpret: bool):
     L, B, Hkv, Smax, hd2 = KV.shape
     hd = hd2 // 2
     g = q4.shape[2]
+    block_s = stream_block(B * Hkv, hd, KV.dtype.itemsize)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
@@ -211,17 +237,17 @@ def _call(q4, k_new, v_new, vf_bh, KV, meta, *, interpret: bool):
             pl.BlockSpec(memory_space=pltpu.VMEM),  # k_new [BH, 1, hd]
             pl.BlockSpec(memory_space=pltpu.VMEM),  # v_new
             pl.BlockSpec(memory_space=pltpu.VMEM),  # vf [BH, 1, 1] int32
-            pl.BlockSpec(memory_space=_pallas_compat.HBM),   # fused KV (aliased out)
+            pl.BlockSpec(memory_space=pltpu.HBM),   # fused KV (aliased out)
         ],
         out_specs=[
             pl.BlockSpec(memory_space=pltpu.VMEM),  # out [B, Hkv, g, hd]
-            pl.BlockSpec(memory_space=_pallas_compat.HBM),
+            pl.BlockSpec(memory_space=pltpu.HBM),
         ],
         scratch_shapes=[
             pltpu.VMEM((B * Hkv, g, 2 * hd), jnp.float32),  # acc (fused)
             pltpu.VMEM((B * Hkv, g, 1), jnp.float32),       # m
             pltpu.VMEM((B * Hkv, g, 1), jnp.float32),       # l
-            pltpu.VMEM((2, B, Hkv, BLOCK_S, 2 * hd), KV.dtype),  # dbl buf
+            pltpu.VMEM((2, B, Hkv, block_s, 2 * hd), KV.dtype),  # dbl buf
             pltpu.VMEM((B, Hkv, _WRITE_ROWS, 2 * hd), KV.dtype),  # RMW win
             pltpu.SemaphoreType.DMA((2,)),
             pltpu.SemaphoreType.DMA(()),
@@ -238,10 +264,11 @@ def _call(q4, k_new, v_new, vf_bh, KV, meta, *, interpret: bool):
         # inputs (incl. the scalar operand): meta=0, q=1, k_new=2,
         # v_new=3, vf=4, KV=5 -> outputs (out=0, KV=1)
         input_output_aliases={5: 1},
-        # the double buffer alone is ~2*B*Hkv*BLOCK_S*2hd*2 bytes (12.6 MB
+        # the double buffer alone is ~2*B*Hkv*block_s*2hd*2 bytes (12.6 MB
         # at GPT-2-124M bs=8) — past the default 16 MB scoped-vmem limit
-        # once accumulators join; v5e has 128 MB of VMEM to give
-        compiler_params=tpu_compiler_params(
+        # once accumulators join; v5e has 128 MB of VMEM to give, and
+        # stream_block keeps the stream inside it as the batch widens
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=100 * 1024 * 1024),
         interpret=interpret,
     )(meta, q4.reshape(B * Hkv, g, hd),

@@ -44,6 +44,7 @@ The fp32 BASELINE parity mode never routes here.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional, Tuple
 
 import jax
@@ -51,9 +52,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._pallas_compat import tpu_compiler_params
-from . import _pallas_compat
-from .decode_attention import BLOCK_S, NEG_INF, _WRITE_ROWS
+from .decode_attention import BLOCK_S, NEG_INF, _WRITE_ROWS, stream_block
 
 _LANE = 128
 
@@ -229,6 +228,7 @@ def _attention(l, off, q, k_new, v_new, vf_ref, kv_hbm, kv_out,
     side's tests; apply masking/finalize/write-window changes to BOTH."""
     bh = batch * hkv
     scale = 1.0 / (hd ** 0.5)
+    block_s = kvbuf.shape[3]               # decode_attention.stream_block
 
     row2 = jax.lax.broadcasted_iota(jnp.int32, (hd, 2 * hd), 0)
     col2 = jax.lax.broadcasted_iota(jnp.int32, (hd, 2 * hd), 1)
@@ -242,11 +242,11 @@ def _attention(l, off, q, k_new, v_new, vf_ref, kv_hbm, kv_out,
                                 preferred_element_type=jnp.float32)
     vf_bh = vf_ref[...]                                    # [BH, 1, 1]
 
-    n_blk = jnp.maximum((off + BLOCK_S - 1) // BLOCK_S, 1)
+    n_blk = jnp.maximum((off + block_s - 1) // block_s, 1)
 
     def fetch(slot, i):
         return pltpu.make_async_copy(
-            kv_hbm.at[l, :, :, pl.ds(i * BLOCK_S, BLOCK_S), :],
+            kv_hbm.at[l, :, :, pl.ds(i * block_s, block_s), :],
             kvbuf.at[slot], copy_sems.at[slot])
 
     fetch(0, 0).start()
@@ -266,11 +266,11 @@ def _attention(l, off, q, k_new, v_new, vf_ref, kv_hbm, kv_out,
             fetch(1 - slot, i + 1).start()
 
         fetch(slot, i).wait()
-        kvb = kvbuf[slot].astype(jnp.float32).reshape(bh, BLOCK_S, 2 * hd)
+        kvb = kvbuf[slot].astype(jnp.float32).reshape(bh, block_s, 2 * hd)
         s = jax.lax.dot_general(q_ext, kvb, (((2,), (2,)), ((0,), (0,))),
                                 preferred_element_type=jnp.float32)
-        pos = i * BLOCK_S + jax.lax.broadcasted_iota(
-            jnp.int32, (1, 1, BLOCK_S), 2)
+        pos = i * block_s + jax.lax.broadcasted_iota(
+            jnp.int32, (1, 1, block_s), 2)
         ok = (pos < off) & (pos >= vf_bh)
         s = jnp.where(ok, s, NEG_INF)
         m_new = jnp.maximum(m_ref[...], jnp.max(s, axis=2, keepdims=True))
@@ -427,6 +427,15 @@ def _build_call(kernel, parts, vmem_operands, KV, meta, *, n_head,
     L, B, Hkv, _, hd2 = KV.shape
     hd = hd2 // 2
     h0 = vmem_operands[0]
+    # VMEM the KV stream cannot have: the pipelined weight windows (two
+    # layers' blocks resident at once) and _matmul's f32 working copy of
+    # the largest weight, with the conversion's intermediate beside it
+    # (the v5e compiler refused GPT-2 medium int8 at B=8 without this
+    # term: its temporaries alone overran vmem_limit_bytes)
+    sizes = [math.prod(x.shape[1:]) for x in parts]
+    reserved = (2 * sum(n * x.dtype.itemsize for n, x in zip(sizes, parts))
+                + 2 * 4 * max(sizes))
+    block_s = stream_block(B * Hkv, hd, KV.dtype.itemsize, reserved=reserved)
 
     def layer_block(x):
         # one layer's block of a stacked [L, ...] tensor, pipelined
@@ -440,17 +449,17 @@ def _build_call(kernel, parts, vmem_operands, KV, meta, *, n_head,
         in_specs=([layer_block(x) for x in parts]
                   + [pl.BlockSpec(memory_space=pltpu.VMEM)
                      for _ in vmem_operands]
-                  + [pl.BlockSpec(memory_space=_pallas_compat.HBM)]),  # KV (aliased)
+                  + [pl.BlockSpec(memory_space=pltpu.HBM)]),  # KV (aliased)
         out_specs=[
             pl.BlockSpec(memory_space=pltpu.VMEM),            # h out
-            pl.BlockSpec(memory_space=_pallas_compat.HBM),
+            pl.BlockSpec(memory_space=pltpu.HBM),
         ],
         scratch_shapes=[
             pltpu.VMEM(h0.shape, h0.dtype),                   # h carry
             pltpu.VMEM((B * Hkv, n_head // Hkv, 2 * hd), jnp.float32),
             pltpu.VMEM((B * Hkv, n_head // Hkv, 1), jnp.float32),
             pltpu.VMEM((B * Hkv, n_head // Hkv, 1), jnp.float32),
-            pltpu.VMEM((2, B, Hkv, BLOCK_S, 2 * hd), KV.dtype),
+            pltpu.VMEM((2, B, Hkv, block_s, 2 * hd), KV.dtype),
             pltpu.VMEM((B, Hkv, _WRITE_ROWS, 2 * hd), KV.dtype),
             pltpu.SemaphoreType.DMA((2,)),
             pltpu.SemaphoreType.DMA(()),
@@ -465,7 +474,7 @@ def _build_call(kernel, parts, vmem_operands, KV, meta, *, n_head,
             jax.ShapeDtypeStruct(KV.shape, KV.dtype),
         ],
         input_output_aliases={n_in - 1: 1},
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=110 * 1024 * 1024),
         interpret=interpret,

@@ -40,7 +40,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._pallas_compat import tpu_compiler_params
 
 NEG_INF = -1e9
 
@@ -202,7 +201,7 @@ def _forward_kernel(q, k, v, block_q, block_k, interpret):
             pltpu.VMEM((block_q, 128), jnp.float32),   # running max m
             pltpu.VMEM((block_q, 128), jnp.float32),   # normalizer l
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qf, kf, vf)
@@ -318,7 +317,7 @@ def _backward_kernels(q, k, v, out, lse, g, block_q, block_k, interpret):
         out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct((b * h, s, hd), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, hd), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qf, kf, vf, dof, lse, dd)
@@ -339,7 +338,7 @@ def _backward_kernels(q, k, v, out, lse, g, block_q, block_k, interpret):
                    jax.ShapeDtypeStruct((b * h, s, hd), q.dtype)],
         scratch_shapes=[pltpu.VMEM((block_k, hd), jnp.float32),
                         pltpu.VMEM((block_k, hd), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qf, kf, vf, dof, lse, dd)

@@ -38,8 +38,8 @@ Two consumption patterns:
   attention reading straight from the pool and writing the new K/V
   column into its block in place. The paged sibling of
   ``ops.attention.cached_attention_inplace`` (and the hook a Pallas
-  paged kernel would slot into behind the ``_pallas_compat`` seam, the
-  way ``ops.decode_attention`` does for the contiguous fused cache:
+  paged kernel would slot into, the way ``ops.decode_attention``
+  does for the contiguous fused cache:
   same HBM-resident pool ref, block-table-driven DMAs instead of
   ``jnp.take``). Byte-equal to the contiguous path — pinned by
   tests/test_paged_attention.py.

@@ -30,7 +30,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..parallel._shard_compat import pcast_varying, shard_map
 
 # Placement contract (tools/graftcheck placement pass + utils/
 # graftshard): Q/K/V enter and leave with the sequence dim sharded over
@@ -101,7 +100,7 @@ def ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         # carry becomes axis-varying after the first merge — cast up front
         # so the carry signature is stable; k/v enter already varying
         def vary(x):
-            return pcast_varying(x, axis)
+            return jax.lax.pcast(x, axis, to="varying")
 
         init = (vary(jnp.zeros(q_loc.shape, jnp.float32)),
                 vary(jnp.full(q_loc.shape[:3], NEG_INF, jnp.float32)),
@@ -129,6 +128,6 @@ def ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         return (acc / l[..., None]).astype(q_loc.dtype)
 
     spec = P(None, None, axis, None)
-    return shard_map(per_device, mesh=mesh,
-                     in_specs=(spec, spec, spec), out_specs=spec,
-                     axis_names={axis})(q, k, v)
+    return jax.shard_map(per_device, mesh=mesh,
+                         in_specs=(spec, spec, spec), out_specs=spec,
+                         axis_names={axis})(q, k, v)

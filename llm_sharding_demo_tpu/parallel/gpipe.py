@@ -36,7 +36,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..models.gpt2 import GPT2Config, Params, apply_blocks
-from ._shard_compat import pcast_varying, shard_map
 
 # Placement contract (tools/graftcheck placement pass + utils/
 # graftshard): ``pp`` is the single MANUAL axis here — the compiled
@@ -148,8 +147,9 @@ def _compiled_pipeline(mesh: Mesh, config: GPT2Config, pp_axis: str,
         zeros_state = jnp.zeros(h_all.shape[1:], h_all.dtype)
         # mark the scan carry as pp-varying up front (it becomes varying
         # via ppermute/masked writes; the carry signature must agree)
-        init = (pcast_varying(zeros_state, pp_axis),
-                pcast_varying(jnp.zeros_like(h_all), pp_axis))
+        init = (jax.lax.pcast(zeros_state, pp_axis, to="varying"),
+                jax.lax.pcast(jnp.zeros_like(h_all), pp_axis,
+                              to="varying"))
 
         def tick(carry, t):
             state, outputs = carry
@@ -189,11 +189,11 @@ def _compiled_pipeline(mesh: Mesh, config: GPT2Config, pp_axis: str,
         return jax.lax.psum(outputs, pp_axis)
 
     if not has_valid:
-        return jax.jit(shard_map(
+        return jax.jit(jax.shard_map(
             lambda b, h: per_stage(b, None, h), mesh=mesh,
             in_specs=(P(pp_axis), P()), out_specs=P(),
             axis_names={pp_axis}))
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         per_stage, mesh=mesh,
         in_specs=(P(pp_axis), P(pp_axis), P()), out_specs=P(),
         axis_names={pp_axis}))

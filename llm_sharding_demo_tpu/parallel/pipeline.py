@@ -25,6 +25,7 @@ see ``parallel.spmd`` (shard_map + ppermute over a pipeline mesh axis).
 
 from __future__ import annotations
 
+import logging
 import time
 from typing import List, Optional, Sequence, Tuple
 
@@ -85,6 +86,16 @@ class PipelineRunner:
 
         avail = list(devices) if devices is not None else jax.devices()
         self.devices = [avail[i % len(avail)] for i in range(len(self.specs))]
+        if len(avail) < len(self.specs):
+            # the default serving path on one chip (SPLIT_AT's two stages)
+            # lands here by design; it must not pass for a split across
+            # chips, so say where the stages went
+            logging.getLogger(__name__).warning(
+                "%d stages on %d device(s): stages share devices "
+                "round-robin (%s) — a stage-per-chip pipeline needs "
+                "PP_DECODE=1 and one device per stage",
+                len(self.specs), len(avail),
+                [str(d) for d in self.devices])
 
         # Each stage's param subset moves to its device once, at
         # construction — weights never transfer again (the reference

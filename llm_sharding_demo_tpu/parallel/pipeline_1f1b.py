@@ -48,7 +48,6 @@ import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..models.gpt2 import GPT2Config, Params
-from ._shard_compat import pcast_varying, shard_map
 from .gpipe import microbatch
 
 # Placement contract (tools/graftcheck placement pass + utils/
@@ -199,7 +198,7 @@ def _compiled_1f1b(mesh: Mesh, config: GPT2Config, pp_axis: str,
             # block slice) are already varying — pcast rejects the no-op.
             def f(a):
                 try:
-                    return pcast_varying(a, pp_axis)
+                    return jax.lax.pcast(a, pp_axis, to="varying")
                 except ValueError:
                     return a
             return jax.tree_util.tree_map(f, tree)
@@ -396,7 +395,7 @@ def _compiled_1f1b(mesh: Mesh, config: GPT2Config, pp_axis: str,
             blocks = jax.tree_util.tree_map(lambda x: x[:, None], blocks)
             if valid is not None:
                 valid = valid[:, None]
-        run = shard_map(
+        run = jax.shard_map(
             per_stage if has_valid else
             (lambda b, e, h, i: per_stage(b, None, e, h, i)),
             mesh=mesh,

@@ -49,7 +49,6 @@ from ..ops.attention import KVCache
 from ..runtime.engine import (GenerateResult, SamplingConfig, _split_keys,
                               _step_keys, prepare_generate, select_token)
 from . import partition as Pt
-from ._shard_compat import pcast_varying, shard_map
 
 
 # Static-analysis contract (tools/graftcheck): the scope whose traced
@@ -214,8 +213,8 @@ class PipelinedDecoder:
             if has_pad:
                 pad_b = extra[i]               # [B]
             stage = jax.lax.axis_index(pp)
-            h_var = pcast_varying(h, pp)
-            final0 = pcast_varying(jnp.zeros_like(h), pp)
+            h_var = jax.lax.pcast(h, pp, to="varying")
+            final0 = jax.lax.pcast(jnp.zeros_like(h), pp, to="varying")
 
             def tick(carry, t):
                 h_in, ck, cv, final = carry
@@ -260,7 +259,7 @@ class PipelinedDecoder:
         if has_pad:
             in_specs.append(P())
             args.append(pad)
-        return shard_map(
+        return jax.shard_map(
             per_device, mesh=self.mesh,
             in_specs=tuple(in_specs),
             out_specs=(P(), P(pp), P(pp)),

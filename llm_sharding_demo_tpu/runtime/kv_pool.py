@@ -856,8 +856,17 @@ class KVBlockPool:
                  block_size: int, head_dim: int, max_seq: int,
                  dtype=jnp.float32, watermark: float = 0.9,
                  sanitize: Optional[bool] = None,
-                 block_dtype: Optional[str] = None):
+                 block_dtype: Optional[str] = None,
+                 fused: bool = False):
+        """``fused``: the engine's caches use the FUSED layout of the
+        Pallas decode kernels (``ops.attention.create_fused_cache`` —
+        one ``[L, B, H, S, 2*hd]`` buffer of ``[K | V]`` rows plus an
+        empty placeholder). Block storage is the same either way; the
+        movers join K and V on gather and split them on scatter inside
+        their own programs, so every front end hands the engine's
+        compiled programs the layout they were built for."""
         self.nbm = PA.blocks_per_row(max_seq, block_size)
+        self.fused = fused
         if num_blocks < self.nbm:
             raise ValueError(
                 f"num_blocks={num_blocks} cannot hold even one full "
@@ -928,21 +937,35 @@ class KVBlockPool:
         # full-precision jit population is bit-identical to a build
         # without this feature and the byte-equality pins stay pinned
         # to precisely the programs they always covered.
+
+        def join(k, v):
+            # block movers' separate (K, V) -> the engine's cache leaves
+            if not fused:
+                return k, v
+            return (jnp.concatenate([k, v], axis=-1),
+                    jnp.zeros((0,), dtype=k.dtype))
+
+        def split(k, v):
+            # the engine's cache leaves -> separate (K, V)
+            return (k[..., :head_dim], k[..., head_dim:]) if fused else (k, v)
+
+        self._join, self._split = join, split
         if self.block_dtype is not None:
             self._compile_watches = self._init_quantized_movers()
             return
 
         def _gather_impl(pool, tables):
-            return PA.gather_kv(pool, tables)
+            return join(*PA.gather_kv(pool, tables))
 
         def _scatter_impl(pool, k, v, tables):
-            return PA.scatter_kv(pool, k, v, tables)
+            return PA.scatter_kv(pool, *split(k, v), tables)
 
         def _scatter_one_rolled(pool, k, v, table_row, roll):
             # admission merge: roll a solo-prefilled row's K/V content
             # along the slot axis (engine left-pad convention — wrap
             # garbage lands in masked pad slots), then scatter the full
             # row. roll/table are traced: one program per solo shape.
+            k, v = split(k, v)
             k = jnp.roll(k, roll, axis=-2)
             v = jnp.roll(v, roll, axis=-2)
             return PA.scatter_kv(pool, k, v, table_row[None])
@@ -997,15 +1020,18 @@ class KVBlockPool:
         scatter_fn = (KVQ.scatter_kv_int8 if self.block_dtype == "int8"
                       else KVQ.scatter_kv_fp8)
 
+        join, split = self._join, self._split
+
         def _gather_q_impl(data, scales, tables):
-            return KVQ.gather_kv_q(data, scales, tables, out_dtype)
+            return join(*KVQ.gather_kv_q(data, scales, tables, out_dtype))
 
         def _scatter_q_impl(data, scales, k, v, tables):
-            return scatter_fn(data, scales, k, v, tables)
+            return scatter_fn(data, scales, *split(k, v), tables)
 
         def _scatter_row_q_impl(data, scales, k, v, table_row, roll):
             # admission merge, quantized: same roll-then-scatter as the
             # plain family; the full row re-quantizes on the way in.
+            k, v = split(k, v)
             k = jnp.roll(k, roll, axis=-2)
             v = jnp.roll(v, roll, axis=-2)
             return scatter_fn(data, scales, k, v, table_row[None])
@@ -1102,14 +1128,11 @@ class KVBlockPool:
                    block_dtype: Optional[str] = None) -> "KVBlockPool":
         """Build a pool matching an engine's cache geometry. The paged
         path drives the engine's OWN compiled programs on gathered
-        views, so the engine must run the plain XLA single-device
-        layout: no Pallas decode kernel (fused layout + in-place DMA),
-        no stage partitioning (per-stage cache lists), no mesh."""
-        if engine._decode_kernel is not None:
-            raise NotImplementedError(
-                "KV pool paging drives the XLA cache layout; the Pallas "
-                "decode kernel owns its fused in-place cache "
-                "(decode_kernel='xla' composes)")
+        views, so the engine must be the unstaged single-device one:
+        no stage partitioning (per-stage cache lists), no mesh. With a
+        Pallas decode kernel resolved (what ``decode_kernel="auto"``
+        does on a TPU outside fp32) the engine's caches are FUSED and
+        the pool's movers convert at the block boundary (``fused``)."""
         if engine.specs is not None:
             raise NotImplementedError(
                 "KV pool paging covers the unstaged engine; staged "
@@ -1123,9 +1146,23 @@ class KVBlockPool:
         return cls(cfg.n_layer, num_blocks, heads, block_size,
                    cfg.head_dim, engine._cache_seq, dtype=engine.dtype,
                    watermark=watermark, sanitize=sanitize,
-                   block_dtype=block_dtype)
+                   block_dtype=block_dtype,
+                   fused=engine._decode_kernel is not None)
 
     # -- device ops (all under _dev_lock) ------------------------------------
+
+    @staticmethod
+    def _device_tables(tables) -> jnp.ndarray:
+        """Block tables for a mover, from a PRIVATE host copy. Callers
+        keep their tables as host arrays and rewrite them in place
+        between dispatches (the scheduler's ``state.tables``). A device
+        array made straight from such a buffer may alias it (the CPU
+        backend, when the buffer is suitably aligned) or copy it only
+        when the transfer runs, after this call has returned — either
+        way an asynchronously dispatched mover could read the
+        REWRITTEN table and gather another row's blocks. Nobody holds
+        the copy made here, so nobody can rewrite it."""
+        return jnp.asarray(np.array(tables, dtype=np.int32))
 
     def gather(self, tables: np.ndarray, length: int) -> KVCache:
         """Contiguous working cache for the tabled rows (a FRESH buffer
@@ -1134,7 +1171,7 @@ class KVBlockPool:
         with self._dev_lock:
             if self.allocator.sanitize:
                 self._graftsan_check_tables(tables, "gather")
-            tj = jnp.asarray(tables, jnp.int32)
+            tj = self._device_tables(tables)
             if self.block_dtype is not None:
                 k, v = self._gather_q(self.data, self.scales, tj)
             else:
@@ -1145,7 +1182,7 @@ class KVBlockPool:
         with self._dev_lock:
             if self.allocator.sanitize:
                 self._graftsan_check_tables(tables, "scatter", write=True)
-            tj = jnp.asarray(tables, jnp.int32)
+            tj = self._device_tables(tables)
             if self.block_dtype is not None:
                 self.data, self.scales = self._scatter_q(
                     self.data, self.scales, cache.k, cache.v, tj)
@@ -1163,7 +1200,9 @@ class KVBlockPool:
         bounded by the store's chunk grid."""
         bs = self.block_size
         sub = KVCache(k=cache.k[..., nb_lo * bs:, :],
-                      v=cache.v[..., nb_lo * bs:, :], length=cache.length)
+                      v=(cache.v if self.fused
+                         else cache.v[..., nb_lo * bs:, :]),
+                      length=cache.length)
         self.scatter(sub, tables[:, nb_lo:])
 
     def scatter_row(self, cache: KVCache, table_row: np.ndarray,
@@ -1174,7 +1213,7 @@ class KVBlockPool:
         with self._dev_lock:
             if self.allocator.sanitize:
                 self._graftsan_check_tables(table_row, "scatter_row", write=True)
-            row_j = jnp.asarray(table_row, jnp.int32)
+            row_j = self._device_tables(table_row)
             roll_j = jnp.asarray(roll, jnp.int32)
             if self.block_dtype is not None:
                 self.data, self.scales = self._scatter_row_q(

@@ -12,6 +12,7 @@ import logging
 
 from .app import create_app
 from .http import serve
+from ..utils import compile_cache
 from ..utils.config import from_env
 
 
@@ -25,6 +26,8 @@ def main() -> None:
                         help="default: SHARD_PORT env (5000)")
     args = parser.parse_args()
     cfg = from_env()
+    logging.getLogger(__name__).info(
+        "compile cache at %s", compile_cache.configure())
     app = create_app(cfg)  # create_app joins the multi-host runtime
     port = args.port if args.port is not None else cfg.shard_port
     logging.getLogger(__name__).info(

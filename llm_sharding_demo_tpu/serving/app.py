@@ -1611,6 +1611,10 @@ def create_app(cfg: Optional[ServingConfig] = None,
     # the app object; the wire surface is GET /debug/plan)
     app.plan_switcher = switcher
     app.trend_reducer = trend_reducer
+    # the object answering /generate (None on shard/remote roles): the
+    # on-chip smoke reads the resolved decode kernel and the arrays'
+    # placement from it — no wire surface reports either
+    app.runner = runner
     return app
 
 

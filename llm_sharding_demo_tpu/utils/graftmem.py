@@ -76,7 +76,7 @@ TIMELINE_EVENTS = {
 # THE component vocabulary (tools/graftcheck/memory.py rejects a
 # MEMORY_LEDGER declaration whose component falls outside it — a new
 # residency class is a reviewed vocabulary change, not an ad-hoc
-# string). Keep in sync with the ARCHITECTURE.md taxonomy table.
+# string). Keep in sync with the ARCHITECTURE.md component table.
 MEMORY_COMPONENTS = {
     "params":       "model parameter tree (placed or host-staged)",
     "pool_codes":   "paged KV pool block-storage plane (KVBlockPool"

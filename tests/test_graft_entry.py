@@ -22,8 +22,8 @@ def test_dryrun_multichip(n):
 
 def test_dryrun_multichip_bare_driver_contract():
     """The driver invokes dryrun_multichip(8) in a fresh process with ONE
-    visible device and no conftest bootstrap (round-1 failure mode,
-    MULTICHIP_r01.json rc=1).  Simulate it: clean subprocess, host platform
+    visible device and no conftest bootstrap (the round-1 failure
+    mode).  Simulate it: clean subprocess, host platform
     forced to a single device, no pytest in sight."""
     import os
     import subprocess

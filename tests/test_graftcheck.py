@@ -106,8 +106,7 @@ def test_repo_passes_graftcheck():
     for rel in ("llm_sharding_demo_tpu/serving/app.py",
                 "llm_sharding_demo_tpu/runtime/iterbatch.py",
                 "llm_sharding_demo_tpu/runtime/batcher.py",
-                "llm_sharding_demo_tpu/utils/subproc.py",
-                "llm_sharding_demo_tpu/utils/backend_probe.py"):
+                "llm_sharding_demo_tpu/utils/subproc.py"):
         assert fpol.get(rel, 0) >= 1, (
             f"{rel}: no matched FAULT_POLICY entry — its fault "
             "contract no longer matches any blocking site")
@@ -549,7 +548,7 @@ def test_ppermute_extraction_from_traced_program():
         smap = functools.partial(shard_map, axis_names={"pp"})
     except ImportError:
         from jax.experimental.shard_map import shard_map as smap
-    mesh = AbstractMesh((("pp", 4),))
+    mesh = AbstractMesh((4,), ("pp",))
 
     def traced(perm):
         def per_device(x):

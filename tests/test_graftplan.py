@@ -164,8 +164,7 @@ def test_collective_walker_handles_scan_trip_counts():
     3-trip scan over a 2-wide axis -> 3 x (2 x 16 x 1) = 96 bytes."""
     import jax.numpy as jnp
     from jax.sharding import AbstractMesh
-    from llm_sharding_demo_tpu.parallel._shard_compat import shard_map
-    mesh = AbstractMesh((("tp", 2),))
+    mesh = AbstractMesh((2,), ("tp",))
 
     def per_device(x):
         def body(c, _):
@@ -173,8 +172,8 @@ def test_collective_walker_handles_scan_trip_counts():
         y, _ = jax.lax.scan(body, x, None, length=3)
         return y
 
-    fn = shard_map(per_device, mesh=mesh, in_specs=(P(),), out_specs=P(),
-                   axis_names={"tp"})
+    fn = jax.shard_map(per_device, mesh=mesh, in_specs=(P(),), out_specs=P(),
+                       axis_names={"tp"})
     aval = jax.ShapeDtypeStruct((4,), jnp.float32)
     assert CM.comm_bytes_program(fn, (aval,), {"tp": 2}) == 96
 
@@ -471,8 +470,7 @@ def test_overlap_rule_flags_carry_collective_fed_by_compute():
     compute upstream) must not."""
     import jax.numpy as jnp
     from jax.sharding import AbstractMesh
-    from llm_sharding_demo_tpu.parallel._shard_compat import shard_map
-    mesh = AbstractMesh((("pp", 2),))
+    mesh = AbstractMesh((2,), ("pp",))
 
     def serial(x, w):
         def body(c, _):
@@ -491,15 +489,15 @@ def test_overlap_rule_flags_carry_collective_fed_by_compute():
 
     aval = jax.ShapeDtypeStruct((2, 4, 4), jnp.float32)
     w = jax.ShapeDtypeStruct((4, 4), jnp.float32)
-    fn = shard_map(serial, mesh=mesh, in_specs=(P("pp"), P()),
-                   out_specs=P("pp"), axis_names={"pp"})
+    fn = jax.shard_map(serial, mesh=mesh, in_specs=(P("pp"), P()),
+                       out_specs=P("pp"), axis_names={"pp"})
     jaxpr = jax.make_jaxpr(fn)(aval, w)
     got = semantic.check_overlap_jaxpr(jaxpr, "fix", "p.py", "serial")
     assert len(got) == 1 and got[0].rule == "overlap"
     assert "strictly ordered" in got[0].message
 
-    fn2 = shard_map(forwarding, mesh=mesh, in_specs=(P("pp"),),
-                    out_specs=P("pp"), axis_names={"pp"})
+    fn2 = jax.shard_map(forwarding, mesh=mesh, in_specs=(P("pp"),),
+                        out_specs=P("pp"), axis_names={"pp"})
     jaxpr2 = jax.make_jaxpr(fn2)(aval)
     assert semantic.check_overlap_jaxpr(jaxpr2, "fix", "p.py", "fwd") == []
 

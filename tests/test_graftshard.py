@@ -33,7 +33,6 @@ import pytest
 from jax.sharding import AbstractMesh, Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from llm_sharding_demo_tpu.parallel._shard_compat import shard_map
 from llm_sharding_demo_tpu.utils import graftmem, graftshard
 
 from tools.graftcheck import placement
@@ -149,12 +148,12 @@ def test_fixture_placement_drift_replicated_but_sharded(tmp_path):
         def prog(x):
             ...
         """))
-    mesh = AbstractMesh((("tp", 2),))
+    mesh = AbstractMesh((2,), ("tp",))
 
     def prog(x):
-        return shard_map(lambda v: v * 2.0, mesh=mesh,
-                         in_specs=P("tp"), out_specs=P("tp"),
-                         axis_names={"tp"})(x)
+        return jax.shard_map(lambda v: v * 2.0, mesh=mesh,
+                             in_specs=P("tp"), out_specs=P("tp"),
+                             axis_names={"tp"})(x)
 
     traced = [placement.TracedPlacement("parallel/rep.py", "prog",
                                         lambda: (prog, (jnp.zeros(
@@ -224,12 +223,12 @@ def test_fixture_undeclared_collective_traced(tmp_path):
         def prog(x):
             ...
         """))
-    mesh = AbstractMesh((("tp", 2),))
+    mesh = AbstractMesh((2,), ("tp",))
 
     def prog(x):
-        return shard_map(lambda v: jax.lax.psum(v, "tp"), mesh=mesh,
-                         in_specs=P("tp"), out_specs=P(),
-                         axis_names={"tp"})(x)
+        return jax.shard_map(lambda v: jax.lax.psum(v, "tp"), mesh=mesh,
+                             in_specs=P("tp"), out_specs=P(),
+                             axis_names={"tp"})(x)
 
     traced = [placement.TracedPlacement("ops/tcoll.py", "prog",
                                         lambda: (prog, (jnp.zeros(
@@ -250,12 +249,12 @@ def _pool_trap_trace(tmp_path, relpath, source):
     p = tmp_path / relpath
     p.parent.mkdir(parents=True, exist_ok=True)
     p.write_text(textwrap.dedent(source))
-    mesh = AbstractMesh((("kvp", 2),))
+    mesh = AbstractMesh((2,), ("kvp",))
 
     def lookup(pool, q):
-        return shard_map(lambda pl, v: v + jnp.sum(pl), mesh=mesh,
-                         in_specs=(P(), P("kvp")),
-                         out_specs=P("kvp"), axis_names={"kvp"})(pool, q)
+        return jax.shard_map(lambda pl, v: v + jnp.sum(pl), mesh=mesh,
+                             in_specs=(P(), P("kvp")),
+                             out_specs=P("kvp"), axis_names={"kvp"})(pool, q)
 
     pool = jnp.zeros((2, 64, 4), jnp.float32)  # 2048 bytes, replicated
     q = jnp.zeros((2, 4), jnp.float32)

@@ -423,3 +423,49 @@ def test_iterbatch_admission_load_sheds_on_saturation(setup):
     assert not ok and retry >= 1.0
     ok, _ = ib.admission_load(8, 8)          # 1 block fits the watermark
     assert ok
+
+
+def test_movers_take_a_private_copy_of_their_tables():
+    """The scheduler rewrites its block tables in place between
+    dispatches. A device array made straight from such a buffer aliases
+    it on the CPU backend when the buffer is 64-byte aligned, so an
+    asynchronously dispatched mover could read the REWRITTEN table —
+    another row's blocks (the fault behind the pooled tests that failed
+    under load and passed alone). Pinned on a buffer aligned on
+    purpose."""
+    raw = np.zeros(16 * 64 + 16, np.int32)
+    skip = (-raw.ctypes.data % 64) // 4
+    tables = raw[skip:skip + 16 * 64].reshape(16, 64)
+    assert tables.ctypes.data % 64 == 0
+    tables[:] = 3
+    on_device = KVBlockPool._device_tables(tables)
+    tables[:] = 9                      # the scheduler moves on
+    np.testing.assert_array_equal(np.asarray(on_device), 3)
+
+
+@pytest.mark.parametrize("kernel", ["layer-interpret", "mega-interpret"])
+def test_pool_serves_an_engine_with_a_pallas_decode_kernel(kernel):
+    """What ``decode_kernel="auto"`` resolves to on a TPU outside fp32:
+    the engine's caches are FUSED ([K|V] rows), and the pool's movers
+    convert at the block boundary — paged greedy decode equals the
+    kernel engine's own contiguous decode, solo and as a ragged batch,
+    and every block comes back."""
+    cfg = gpt2.GPT2Config(vocab_size=512, n_positions=256, n_embd=128,
+                          n_layer=2, n_head=2)
+    params = gpt2.init_params(cfg, jax.random.PRNGKey(0))
+    eng = DecodeEngine(params, cfg, max_seq=256, dtype="bfloat16",
+                       decode_kernel=kernel)
+    assert eng._decode_kernel == {"layer-interpret": "interpret"}.get(
+        kernel, kernel)
+    pool = KVBlockPool.for_engine(eng, num_blocks=48, block_size=16)
+    assert pool.fused
+    runner = PagedKVRunner(eng, pool)
+    rng = np.random.default_rng(5)
+    solo = rng.integers(0, 512, size=(1, 21))
+    np.testing.assert_array_equal(runner.generate(solo, 12).tokens,
+                                  eng.generate(solo, 12).tokens)
+    ragged = [list(rng.integers(0, 512, size=(9,))),
+              list(rng.integers(0, 512, size=(17,)))]
+    np.testing.assert_array_equal(runner.generate(ragged, 10).tokens,
+                                  eng.generate(ragged, 10).tokens)
+    assert pool.allocator.stats().blocks_in_use == 0

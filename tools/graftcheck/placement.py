@@ -415,7 +415,7 @@ def traced_placements() -> List[TracedPlacement]:
         from llm_sharding_demo_tpu.parallel import partition as Pt
         from . import registry
         module, config = registry.families()["gpt2-tiny"]
-        mesh = AbstractMesh((("pp", 2),))
+        mesh = AbstractMesh((2,), ("pp",))
         specs = Pt.make_stage_specs(
             config.n_layer, Pt.balanced_boundaries(config.n_layer, 2))
         pavals = jax.eval_shape(
@@ -432,7 +432,7 @@ def traced_placements() -> List[TracedPlacement]:
         from jax.sharding import AbstractMesh
 
         from llm_sharding_demo_tpu.ops import ring_attention as RA
-        mesh = AbstractMesh((("sp", 2),))
+        mesh = AbstractMesh((2,), ("sp",))
         q = jax.ShapeDtypeStruct((1, 2, 4, 4), jnp.float32)
         return (lambda q, k, v: RA.ring_attention(q, k, v, mesh),
                 (q, q, q))

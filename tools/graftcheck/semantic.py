@@ -351,7 +351,7 @@ def check_ring_program(n_stages: int, where: str) -> List[Finding]:
         smap = functools.partial(shard_map, axis_names={"pp"})
     except ImportError:
         from jax.experimental.shard_map import shard_map as smap
-    mesh = AbstractMesh((("pp", n_stages),))
+    mesh = AbstractMesh((n_stages,), ("pp",))
 
     def per_device(x):
         return jax.lax.ppermute(x, "pp", stage_ring_permutation(n_stages))
@@ -373,8 +373,10 @@ def check_ring_program(n_stages: int, where: str) -> List[Finding]:
 
 # comm primitives the overlap rule (and the cost model's byte walker,
 # tools/graftcheck/costmodel.py) recognize in a traced jaxpr
-COMM_PRIMITIVES = ("ppermute", "psum", "all_gather", "all_to_all",
-                   "reduce_scatter", "pmax", "pmin")
+# (``psum_invariant`` is what ``lax.psum`` traces to inside a
+# ``shard_map`` that tracks varying types — the default)
+COMM_PRIMITIVES = ("ppermute", "psum", "psum_invariant", "all_gather",
+                   "all_to_all", "reduce_scatter", "pmax", "pmin")
 
 # primitives that are pure data movement/bookkeeping — never the compute
 # a transfer could overlap with
@@ -415,7 +417,7 @@ def check_overlap_jaxpr(jaxpr, where: str, path: str,
     findings: List[Finding] = []
 
     def analyze_scan_body(body, num_carry: int, where_in: str):
-        from jax.core import Literal
+        from jax.extend.core import Literal
         eqns = list(body.eqns)
         producer = {}
         for i, eqn in enumerate(eqns):
@@ -516,7 +518,7 @@ def build_ppdecode_programs(n_stages: int, batch: int = 1, seq: int = 8,
     dec = PipelinedDecoder.__new__(PipelinedDecoder)
     dec.config = config
     dec.mesh = mesh if mesh is not None \
-        else AbstractMesh((("pp", n_stages),))
+        else AbstractMesh((n_stages,), ("pp",))
     dec.max_seq = max_seq
     dec.pp_axis = "pp"
     dec.n_stages = n_stages
@@ -699,8 +701,9 @@ def run_semantic() -> Tuple[List[Finding], int]:
                 checks += 1
 
     # PartitionSpec trees vs the mesh stand-ins they are meant for
-    mesh_tp = AbstractMesh(tuple(registry.MESHES["tp2"].items()))
-    mesh_ep = AbstractMesh(tuple(registry.MESHES["ep2-tp2"].items()))
+    tp2, ep2_tp2 = registry.MESHES["tp2"], registry.MESHES["ep2-tp2"]
+    mesh_tp = AbstractMesh(tuple(tp2.values()), tuple(tp2))
+    mesh_ep = AbstractMesh(tuple(ep2_tp2.values()), tuple(ep2_tp2))
     gpt2_mod, gpt2_cfg = fams["gpt2-tiny"]
     llama_mod, llama_cfg = fams["llama-tiny"]
     moe_mod, moe_cfg = fams["moe-tiny"]

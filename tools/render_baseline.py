@@ -4,8 +4,8 @@ latest full measurement, never hand-accreted — a reader should know
 every number in it is what the current code produced).
 
 ``bench.py`` calls ``update_file`` automatically after writing
-``BENCH_full.json``; ``python tools/render_baseline.py`` re-renders by
-hand from the committed artifact.
+``BENCH_full.json`` (a run's output, not committed); ``python
+tools/render_baseline.py <artifact>`` re-renders by hand.
 """
 
 from __future__ import annotations
@@ -136,8 +136,10 @@ def update_file(baseline_path: str, payload: dict) -> bool:
 
 def main() -> None:
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    artifact = sys.argv[1] if len(sys.argv) > 1 else os.path.join(
-        repo, "BENCH_full.json")
+    if len(sys.argv) != 2:
+        sys.exit("usage: python tools/render_baseline.py <full matrix "
+                 "written by bench.py>")
+    artifact = sys.argv[1]
     payload = json.load(open(artifact))
     path = os.path.join(repo, "BASELINE.md")
     if update_file(path, payload):
