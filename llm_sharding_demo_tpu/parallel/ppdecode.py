@@ -48,6 +48,7 @@ from ..models.gpt2 import GPT2Config, Params, apply_blocks, embed, final_logits
 from ..ops.attention import KVCache
 from ..runtime.engine import (GenerateResult, SamplingConfig, _split_keys,
                               _step_keys, prepare_generate, select_token)
+from ..utils import tracing
 from . import partition as Pt
 
 
@@ -335,6 +336,10 @@ class PipelinedDecoder:
         first = select_token(last_logits, sampling, prefill_key)
         first.block_until_ready()
         t1 = time.perf_counter()
+        # both windows end in a wait for the device, so each span's
+        # ready instant is its own end
+        tracing.record("prefill", t0, t1, ready=t1, batch=batch,
+                       prompt_len=prompt_len, stages=self.n_stages)
         length0 = jnp.asarray(prompt_len, jnp.int32)
         new, ck, cv = self._decode(self.shared, self.blocks, ck, cv, first,
                                    length0, decode_key, pad_j,
@@ -342,6 +347,8 @@ class PipelinedDecoder:
         del ck, cv  # alias the donated prefill cache
         new = np.asarray(jax.block_until_ready(new))
         t2 = time.perf_counter()
+        tracing.record("decode", t1, t2, ready=t2, batch=batch,
+                       steps=max_new_tokens - 1, stages=self.n_stages)
 
         tokens = np.concatenate([ids, new], axis=1)
         return GenerateResult(tokens=tokens, prompt_len=prompt_len,

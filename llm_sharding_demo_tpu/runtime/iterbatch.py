@@ -112,6 +112,7 @@ full-precision pool keeps every byte-equality pin above.
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 import queue
 import threading
@@ -224,6 +225,7 @@ GUARDED_STATE = {
     "spec_segments_run": "_stats_lock", "eos_retires": "_stats_lock",
     "grows": "_stats_lock", "preemptions": "_stats_lock",
     "resumes": "_stats_lock", "fault_parks": "_stats_lock",
+    "batches_closed": "_stats_lock", "_turned": "_stats_lock",
     "_parked": "_stats_lock",
     "_pending": "_stats_lock",
     "_np": "_lock",
@@ -233,6 +235,14 @@ GUARDED_STATE = {
 # _SegOut fetch lock never nests inside them; the declared order keeps
 # it that way.
 LOCK_ORDER = ("_stats_lock", "_lock")
+
+
+# Why the head of the queue stays where it is (the ``queue_wait`` span's
+# ``<reason>_ms`` labels): the live batch is closed to admission and has
+# to drain; the batch is full at ``max_batch``; the pool has no room;
+# and everything else — the scheduler is inside a dispatch, an admission
+# of someone ahead, or ``_seed``'s wait.
+_WAIT_REASONS = ("closed", "slot", "pool", "boundary")
 
 
 def _rid_of(req) -> Optional[str]:
@@ -413,12 +423,17 @@ class _BatchState:
         self.tables: Optional[np.ndarray] = None   # [B, NBm] (pool mode)
         self.slots: List[Optional[_Slot]] = []
         self.closed = False           # True: no more admissions (FIFO)
+        self.batch = 0                # ``batches_run`` it was seeded under
+        # the newest handover of this batch (tracing.READY.hand): the
+        # seed's first-token array, then each plain segment's output
+        self.ready = None
         # speculative batches only: device token buffer [B, buflen]
         # (prompt + emitted per row, content ending at depth + 1) and
         # the per-row verify key chains [B, 2] (sample mode)
         self.spec_mode = False
         self.buf = None
         self.keys = None
+        self.spec_ready: Optional[float] = None   # its newest segment's sync
         # HBM ledger handles (utils/graftmem): released by _run_batch
         # at batch teardown (the owner finalizer backstops any path
         # that drops the state without reaching it)
@@ -520,6 +535,19 @@ class IterBatchingEngine:
         self.preemptions = 0          # rows parked under pool pressure
         self.resumes = 0              # parked rows recomputed back in
         self.fault_parks = 0          # transient-fault park events
+        self.batches_closed = 0       # batches that ended closed
+        # how often _admit turned the head away, by rule: closed for a
+        # prompt longer than the live depth or a generation past the
+        # cache / for a sampling mismatch; deferred for a slot / for
+        # pool room
+        self._turned = dict.fromkeys(
+            ("closes_depth", "closes_policy", "defers_slot",
+             "defers_pool"), 0)
+        # (instant, reason) transitions of what holds the head of the
+        # queue (worker-thread-only): every admitted request's wait is
+        # cut by it. 4096 transitions span minutes of boundaries; a wait
+        # older than the list counts as "boundary"
+        self._wait_log: "collections.deque" = collections.deque(maxlen=4096)
         self._worker = threading.Thread(target=self._loop, daemon=True)
         self._worker.start()
 
@@ -590,6 +618,10 @@ class IterBatchingEngine:
         # a no-wait read.
         s, eos_at = req.payload
         new = self._row_tokens(s)
+        if req.trace is not None:
+            # the fetch above waited for the same arrays: stamp whatever
+            # ready instant the waiter has not got round to
+            req.trace.settle()
         if eos_at is not None:
             new = new[:eos_at + 1]
         tokens = np.concatenate([req.prompt, new])[None, :]
@@ -612,6 +644,8 @@ class IterBatchingEngine:
                    "preemptions": self.preemptions,
                    "resumes": self.resumes,
                    "fault_parks": self.fault_parks,
+                   "batches_closed": self.batches_closed,
+                   **self._turned,
                    "parked": len(self._parked)}
         return out
 
@@ -680,6 +714,31 @@ class IterBatchingEngine:
     def _set_pending(self, req: Optional[_Req]) -> None:
         with self._stats_lock:
             self._pending = req
+
+    def _mark(self, reason: str) -> None:
+        """From now on the head of the queue waits for ``reason``."""
+        if not self._wait_log or self._wait_log[-1][1] != reason:
+            self._wait_log.append((time.perf_counter(), reason))
+
+    def _turn_away(self, reason: str, counter: str) -> None:
+        self._mark(reason)
+        with self._stats_lock:
+            self._turned[counter] += 1
+
+    def _wait_labels(self, t0: float, t1: float) -> dict:
+        """The wait ``[t0, t1)`` cut by the transitions: milliseconds
+        under each reason, summing to the wait."""
+        took = dict.fromkeys(_WAIT_REASONS, 0.0)
+        end = t1
+        for at, reason in reversed(self._wait_log):
+            if at >= end:
+                continue
+            took[reason] += end - max(at, t0)
+            end = max(at, t0)
+            if at <= t0:
+                break
+        took["boundary"] += end - t0    # older than the oldest transition
+        return {f"{r}_ms": round(v * 1e3, 3) for r, v in took.items()}
 
     def _req_dead(self, req: _Req) -> bool:
         """Cancelled OR past its deadline — either way nobody wants the
@@ -771,6 +830,10 @@ class IterBatchingEngine:
                     self._release_blocks(state, i)
             raise
         finally:
+            self._mark("boundary")
+            if state.closed:
+                with self._stats_lock:
+                    self.batches_closed += 1
             # batch teardown: its device holdings leave the HBM ledger
             # (an idle scheduler must not keep reporting the last
             # batch's cache/buffer bytes)
@@ -898,21 +961,29 @@ class IterBatchingEngine:
             if isinstance(e, _Parked):
                 first = first.at[i].set(int(e.tokens[-1]))
         sp1 = time.perf_counter()
+        covered = []                  # (trace, its prefill span)
         for e in seed:
             r = self._ent_req(e)
             if r.trace is not None:
                 if isinstance(e, _Parked):
                     r.trace.add_span("preempted", e.preempt_t, sp0,
                                      scheduler="iter")
-                    r.trace.add_span("prefill", sp0, sp1, kind="resume",
-                                     width=b, emitted=e.emitted)
+                    pre = r.trace.add_span(
+                        "prefill", sp0, sp1, kind="resume", width=b,
+                        emitted=e.emitted)
                 else:
                     r.trace.add_span("queue_wait", r.t_submit, sp0,
-                                     scheduler="iter")
-                    r.trace.add_span("prefill", sp0, sp1, kind="seed",
-                                     width=b, prompt_len=len(r.prompt))
+                                     scheduler="iter",
+                                     **self._wait_labels(r.t_submit, sp0))
+                    pre = r.trace.add_span(
+                        "prefill", sp0, sp1, kind="seed", width=b,
+                        prompt_len=len(r.prompt))
+                covered.append((r.trace, pre))
 
         state = _BatchState(sampling, first, cache, pad_j, s_max)
+        # the span's window is the dispatch; the shared first-token
+        # array says when the prefill had run
+        state.ready = tracing.READY.hand(first, covered)
         if spec_mode:
             # verify-loop entry state (spec_decode._seg_b invariant): the
             # token buffer holds prompt + the unforwarded first token per
@@ -957,6 +1028,7 @@ class IterBatchingEngine:
         if self.pool is not None:
             self._init_tables(state)
         with self._stats_lock:
+            state.batch = self.batches_run
             self.batches_run += 1
             self.resumes += n_res
         REGISTRY.inc("iter_batches_total")
@@ -1070,16 +1142,22 @@ class IterBatchingEngine:
                 # just waits for the next batch to seed from it
                 if ent.req.sampling != state.sampling:
                     state.closed = True
+                    self._turn_away("closed", "closes_policy")
+                else:
+                    self._mark("boundary")
                 return
             if not self._slot_possible(state):
+                self._turn_away("slot", "defers_slot")
                 return  # full batch: retried at the next boundary
             reserved = self._reserve_blocks(state, ent)
             if reserved is None:
+                self._turn_away("pool", "defers_pool")
                 return  # blocks free up as rows retire; stays parked
             slot = self._free_slot(state)
             if slot is None:
                 if self.pool is not None:
                     self.pool.allocator.free(reserved[1])
+                self._turn_away("slot", "defers_slot")
                 return
             ent = self._pop_parked()
             try:
@@ -1094,6 +1172,7 @@ class IterBatchingEngine:
                 try:
                     req = self._queue.get_nowait()
                 except queue.Empty:
+                    self._mark("boundary")
                     return
                 self._set_pending(req)
             if self._req_dead(req):
@@ -1101,16 +1180,22 @@ class IterBatchingEngine:
                 continue
             if not self._compatible(state, req):
                 state.closed = True  # req stays parked as the FIFO head
+                self._turn_away(
+                    "closed", "closes_policy"
+                    if req.sampling != state.sampling else "closes_depth")
                 return
             if not self._slot_possible(state):
+                self._turn_away("slot", "defers_slot")
                 return  # full batch: req stays the head
             reserved = self._reserve_blocks(state, req)
             if reserved is None:
+                self._turn_away("pool", "defers_pool")
                 return  # req stays the head; retried as rows retire
             slot = self._free_slot(state)
             if slot is None:
                 if self.pool is not None:
                     self.pool.allocator.free(reserved[1])
+                self._turn_away("slot", "defers_slot")
                 return
             self._set_pending(None)
             try:
@@ -1218,13 +1303,19 @@ class IterBatchingEngine:
         plen = resume.plen if resume is not None else plen_eff
         t0 = resume.t0 if resume is not None else time.monotonic()
         p0 = time.perf_counter()
+        # whoever waits behind this one waits for its admission from
+        # here on, not for what held it
+        self._mark("boundary")
+        live = sum(s is not None for s in state.slots)
         if req.trace is not None:
             if resume is not None:
                 req.trace.add_span("preempted", resume.preempt_t, p0,
                                    scheduler="iter")
             else:
                 req.trace.add_span("queue_wait", req.t_submit, p0,
-                                   scheduler="iter")
+                                   scheduler="iter",
+                                   **self._wait_labels(req.t_submit, p0))
+        pre = None
         if self.prefix is not None and resume is None:
             # admission prefill through the prefix store: a joiner whose
             # prompt shares a cached prefix forwards only its suffix (and
@@ -1236,6 +1327,9 @@ class IterBatchingEngine:
             # hit/miss annotations) into the ambient trace.
             with tracing.use_trace(req.trace):
                 logits, solo, sp = self.prefix.prefill_state(stream)
+            if req.trace is not None:
+                pre = req.trace.find_all("prefill")[-1]
+                pre.labels["live"] = live
         else:
             sp = min(_round_up(plen_eff, self.prompt_bucket), state.depth)
             if sp < plen_eff:  # bucket would overshoot current depth:
@@ -1247,10 +1341,10 @@ class IterBatchingEngine:
                     eng._run_params(), jnp.asarray(ids),
                     jnp.asarray([sp - plen_eff], jnp.int32))
             if req.trace is not None:
-                req.trace.add_span(
+                pre = req.trace.add_span(
                     "prefill", p0, time.perf_counter(),
                     kind="resume" if resume is not None else "admit",
-                    depth=state.depth, prompt_len=plen_eff)
+                    depth=state.depth, prompt_len=plen_eff, live=live)
         sampling = state.sampling
         if sampling.mode == "greedy":
             first = jnp.argmax(logits, axis=-1).astype(jnp.int32)[0]
@@ -1262,6 +1356,8 @@ class IterBatchingEngine:
             # the live token is the parked row's last emitted one —
             # known, never re-selected (see _seed_batch)
             first = jnp.asarray(int(resume.tokens[-1]), jnp.int32)
+        if pre is not None:
+            tracing.READY.hand(first, [(req.trace, pre)])
         if self.pool is not None:
             blk_lo, blk_ids = self._place_admitted(
                 state, slot, solo, state.depth - sp, reserved)
@@ -1605,27 +1701,34 @@ class IterBatchingEngine:
         seg = _SegOut(out)
         t1 = time.perf_counter()
         eng._note_compiles()
-        # per-decode-step time, serving-thread DISPATCH view: segments
-        # queue asynchronously on the device, so this is enqueue cost,
-        # not device truth (the engine-component series is; see
-        # utils.metrics METRIC_CATALOG)
-        REGISTRY.observe("decode_step_seconds", (t1 - t0) / n,
-                         component="iter")
         with self._stats_lock:
+            seg_no = self.segments_run
             self.segments_run += 1
         REGISTRY.inc("iter_segments_total")
+        covered = []
         for s in state.slots:
             if s is not None:
                 s.segs.append((seg, n))
                 s.emitted += n
                 if s.req.trace is not None:
-                    # dispatch wall time (segments queue asynchronously
-                    # on the device — the serving-thread view)
-                    s.req.trace.add_span(
-                        "decode", t0, t1, seg=True, steps=n,
-                        width=len(state.slots), depth=state.depth,
-                        step_ms=round((t1 - t0) / n * 1e3, 3),
-                        **({"blocks": len(s.blk_ids)} if pooled else {}))
+                    # the window is the DISPATCH (segments queue
+                    # asynchronously on the device — the serving-thread
+                    # view); the waiter stamps when the segment had run
+                    covered.append((s.req.trace, s.req.trace.add_span(
+                        "decode", t0, t1, seg=seg_no, batch=state.batch,
+                        steps=n, width=len(state.slots), depth=state.depth,
+                        **({"blocks": len(s.blk_ids)} if pooled else {}))))
+        prev = state.ready
+
+        def observe(at):
+            # per-decode-step time, DEVICE view: consecutive ready
+            # instants of one batch over the later segment's steps —
+            # the step itself plus whatever ran between the segments
+            # (joiners' prefills, pool movers, host gaps)
+            if prev.at is not None:
+                REGISTRY.observe("decode_step_seconds", (at - prev.at) / n,
+                                 component="iter")
+        state.ready = tracing.READY.hand(out, covered, then=observe)
         self._retire_finished(state)
         self._set_gauges(state)
 
@@ -1683,6 +1786,7 @@ class IterBatchingEngine:
         state.pad_j, state.keys = pad, keys
         seg = _SegOut(buf)
         emitted_np = np.asarray(emitted)          # THE per-segment sync
+        ready = time.perf_counter()               # ... so it has run
         pad_np = np.asarray(pad)
         steps_i = int(steps)
         state.depth = int(total) - 1
@@ -1715,6 +1819,7 @@ class IterBatchingEngine:
             state.cache = cache
         _ = seg.np  # materialize: the next segment donates ``buf``
         with self._stats_lock:
+            seg_no = self.segments_run
             self.segments_run += 1
             self.spec_segments_run += 1
         # acceptance stats flow through the spec engine's one accounting
@@ -1726,16 +1831,23 @@ class IterBatchingEngine:
         REGISTRY.inc("iter_spec_segments_total")
         self.spec._note_compiles()
         t1 = time.perf_counter()
-        # per-VERIFY-step time (a spec segment's scheduling quantum);
-        # this window includes the segment's one documented host sync,
-        # so it is closer to device truth than the plain-segment view
+        # per-VERIFY-step time (a spec segment's scheduling quantum),
+        # between consecutive ready instants of the batch; its first
+        # segment counts from its own dispatch (the window holds the
+        # segment's one documented host sync)
+        since = t0 if state.spec_ready is None else state.spec_ready
+        state.spec_ready = ready
         REGISTRY.observe("decode_step_seconds",
-                         (t1 - t0) / max(steps_i, 1),
+                         (ready - since) / max(steps_i, 1),
                          component="iter_spec")
         for s in state.slots:
             if s is not None and s.req.trace is not None:
+                # the sync above is this segment's ready instant: it
+                # lies INSIDE the window, which closes after the pool
+                # handoff
                 s.req.trace.add_span(
-                    "decode", t0, t1, seg=True, spec=True,
+                    "decode", t0, t1, ready=ready, seg=seg_no,
+                    batch=state.batch, spec=True,
                     verify_steps=steps_i,
                     emitted=int(emitted_np[s.row]),
                     width=len(state.slots), depth=state.depth,

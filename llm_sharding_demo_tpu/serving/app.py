@@ -1544,19 +1544,33 @@ def create_app(cfg: Optional[ServingConfig] = None,
                     text = tokenizer.decode(ids)
             trace.finish()
             # Latency split derived from the span tree. TTFT counts from
-            # request arrival THROUGH the prefill (queue wait included —
-            # what the caller experiences); runners without span
-            # instrumentation (PipelineRunner, remote dispatch) fall
-            # back to the whole request. TPOT divides the decode spans'
-            # wall time over the inter-token steps actually decoded.
+            # request arrival (queue wait included — what the caller
+            # experiences) to the instant the first token EXISTED: the
+            # prefill span's ready instant, or, where the span's own
+            # window already waited for the device, its end; runners
+            # without span instrumentation (PipelineRunner, remote
+            # dispatch) fall back to the whole request. TPOT is the
+            # time from there to the last decode span's ready instant
+            # over the inter-token steps actually decoded; without
+            # ready instants, the decode spans' wall time (spans that
+            # waited for the device) over the same steps.
             pre = trace.find("prefill")
-            ttft = (pre.t1 - trace.t0) if pre is not None \
-                else trace.duration
+            pre_ready = None if pre is None else pre.ready
+            if pre is None:
+                ttft = trace.duration
+            else:
+                ttft = (pre.t1 if pre_ready is None else pre_ready) \
+                    - trace.t0
             reg.observe("ttft_seconds", ttft, mode=req.mode)
             if n_decoded > 1:
                 decode_spans = trace.find_all("decode")
-                decode_wall = sum(s.duration for s in decode_spans)
-                if not decode_spans:
+                readies = [s.ready for s in decode_spans
+                           if s.ready is not None]
+                if readies and pre_ready is not None:
+                    decode_wall = max(max(readies) - pre_ready, 0.0)
+                elif decode_spans:
+                    decode_wall = sum(s.duration for s in decode_spans)
+                else:
                     decode_wall = max(trace.duration - ttft, 0.0)
                 reg.observe("tpot_seconds", decode_wall / (n_decoded - 1),
                             mode=req.mode)

@@ -48,8 +48,12 @@ METRIC_CATALOG: Dict[str, str] = {
     "generated_tokens_total": "counter",
     "upstream_failures_total": "counter",
     "generate_request_seconds": "histogram",
-    # request-phase latency split (derived from the request trace):
-    # time-to-first-token and per-token (inter-token) time, per mode
+    # request-phase latency split (derived from the request trace), per
+    # mode. ttft_seconds: arrival to the instant the first token EXISTED
+    # (the first prefill span's ready instant; the end of its window
+    # where nobody stamped one). tpot_seconds: (last decode span's ready
+    # instant - prefill ready) / (decoded - 1); without ready instants
+    # the decode spans' wall time over the same gaps
     "ttft_seconds": "histogram",
     "tpot_seconds": "histogram",
     # graftscope device-time attribution (utils/graftscope.py):
@@ -59,7 +63,10 @@ METRIC_CATALOG: Dict[str, str] = {
     # truth model); and the per-decode-step time each decode front end
     # derives from its own timing window, labeled by component
     # (component="engine": device-inclusive, the final fetch syncs;
-    # component="iter"/"iter_spec": serving-thread dispatch view)
+    # component="iter": consecutive READY instants of one batch over the
+    # later segment's steps, so the step plus what ran between the two
+    # segments; "iter_spec": the same over its verify steps, a batch's
+    # first segment counted from its own dispatch)
     "dispatch_seconds": "histogram",
     "decode_step_seconds": "histogram",
     # admission batcher (runtime/batcher.py)

@@ -34,16 +34,25 @@ traces))`` so one measured span lands in every participating request's
 tree.
 
 Span timestamps are ``time.perf_counter`` values; serialized timelines
-are relative to the request's start. Scheduler-side decode spans measure
-dispatch wall time (segments queue asynchronously on the device), which
-is the honest serving-thread view — device-level truth is the profiler
-trace's job.
+are relative to the request's start. Scheduler-side prefill and decode
+spans cover a DISPATCH (segments queue asynchronously on the device),
+so their windows are instants of the host, which runs ahead of the
+device. **Ready instants** put the device's completions on the same
+clock: ``READY.hand(array, [(trace, span), ...])`` gives the array to
+one daemon thread that waits in FIFO order (the device finishes
+programs in the order they were enqueued) and stamps ``span.ready``
+the instant the array exists, serialized as the label ``ready_ms`` on
+the trace's own clock. The dispatching thread never waits; a trace is
+settled (``RequestTrace.settle``: the caller's thread waits on
+whatever is still unstamped) before the flight recorder keeps it.
 """
 
 from __future__ import annotations
 
 import contextlib
 import contextvars
+import logging
+import queue
 import threading
 import time
 import uuid
@@ -52,13 +61,18 @@ from typing import Iterator, List, Optional
 
 from . import graftsched, grafttime
 
+log = logging.getLogger(__name__)
+
 # Lock-discipline contract (tools/graftcheck locks pass): a trace's
 # committed root spans and the flight recorder's ring are the only
 # cross-thread mutable state here (open-span stacks are thread-local by
 # design); both live under their instance's ``_lock`` — including the
 # fanout commit, which appends to OTHER traces' span lists under each
-# target's own lock.
-GUARDED_STATE = {"spans": "_lock", "_traces": "_lock"}
+# target's own lock. So do a trace's handovers still waiting for their
+# ready instant (``_unready``: the scheduler thread appends, the
+# caller's thread drains) and the ready waiter's lazily started thread.
+GUARDED_STATE = {"spans": "_lock", "_traces": "_lock",
+                 "_unready": "_lock", "_thread": "_lock"}
 LOCK_ORDER = ("_lock",)
 
 # Timeline contract (tools/graftcheck timeline pass): every span lands
@@ -150,17 +164,23 @@ def new_request_id() -> str:
 
 class Span:
     """One timed node: name, [t0, t1) perf_counter window, labels,
-    children. Append-only while open; read-only once closed."""
+    children. Append-only while open; read-only once closed — but for
+    ``ready``: the perf_counter instant at which the result of the
+    dispatch this span covers existed on the device, stamped after the
+    span closed (``ReadyWaiter``), or given by a call site that waited
+    itself. None where nobody knows."""
 
-    __slots__ = ("name", "t0", "t1", "labels", "children")
+    __slots__ = ("name", "t0", "t1", "labels", "children", "ready")
 
     def __init__(self, name: str, t0: float, t1: Optional[float] = None,
-                 labels: Optional[dict] = None):
+                 labels: Optional[dict] = None,
+                 ready: Optional[float] = None):
         self.name = name
         self.t0 = t0
         self.t1 = t1
         self.labels = dict(labels) if labels else {}
         self.children: List["Span"] = []
+        self.ready = ready
 
     @property
     def duration(self) -> float:
@@ -170,11 +190,95 @@ class Span:
         d = {"name": self.name,
              "start_ms": round((self.t0 - origin) * 1e3, 3),
              "duration_ms": round(self.duration * 1e3, 3)}
-        if self.labels:
+        if self.labels or self.ready is not None:
             d["labels"] = dict(self.labels)
+            if self.ready is not None:
+                d["labels"]["ready_ms"] = round(
+                    (self.ready - origin) * 1e3, 3)
         if self.children:
             d["spans"] = [c.to_dict(origin) for c in self.children]
         return d
+
+
+class _Handover:
+    """One device array and the spans that cover its enqueue. Whoever
+    waits for the array first (the waiter thread, or a caller's thread
+    settling its trace) stamps ``at``; every span gets that instant."""
+
+    __slots__ = ("array", "covers", "then", "at")
+
+    def __init__(self, array, covers: List[Span], then=None):
+        self.array = array
+        self.covers = covers
+        self.then = then
+        self.at: Optional[float] = None
+
+    def settle(self) -> None:
+        """Wait until the array exists, then stamp. Safe from several
+        threads at once: each waits on the same array, the stamps lie
+        microseconds apart and any of them is true."""
+        array = self.array
+        if self.at is None and array is not None:
+            wait = getattr(array, "block_until_ready", None)
+            if wait is not None:      # a host array exists already
+                try:
+                    wait()
+                except Exception:  # noqa: BLE001 — a deleted or poisoned
+                    pass           # array: its request fails on its own
+                    #                path; the instant it was found out
+                    #                is the only one there is
+        if self.at is None:
+            self.at = time.perf_counter()
+        self.array = None             # the waiter keeps no buffer alive
+        for s in self.covers:
+            if s.ready is None:
+                s.ready = self.at
+        self.covers = ()              # ... and no span
+
+
+class ReadyWaiter:
+    """Puts the device's completions on the host's clock. ``hand`` takes
+    a device array and the spans covering its enqueue and returns at
+    once; one daemon thread waits on the arrays in the order they were
+    handed over — the order the device finishes them in — so each
+    completion is stamped as it happens. The thread starts with the
+    first handover (importing this module starts nothing)."""
+
+    def __init__(self):
+        self._lock = graftsched.lock("tracing.ReadyWaiter._lock")
+        self._fifo: "queue.SimpleQueue[_Handover]" = queue.SimpleQueue()
+        self._thread: Optional[threading.Thread] = None
+
+    def hand(self, array, covered, then=None) -> _Handover:
+        """``covered``: ``(trace, span)`` pairs, each span on that
+        trace; the trace will not be flight-recorded before the span is
+        stamped. ``then(at)`` runs on the waiter thread, once, after
+        every earlier handover's — for series derived from consecutive
+        ready instants."""
+        h = _Handover(array, [s for _, s in covered], then)
+        for tr, _ in covered:
+            tr._await(h)
+        self._fifo.put(h)
+        with self._lock:
+            if self._thread is None:
+                self._thread = threading.Thread(
+                    target=self._run, name="tracing-ready", daemon=True)
+                self._thread.start()
+        return h
+
+    def _run(self) -> None:
+        while True:
+            h = self._fifo.get()
+            h.settle()
+            # run once and let go: a ``then`` that holds the handover
+            # before it must not chain a whole batch's handovers together
+            then, h.then = h.then, None
+            if then is not None:
+                try:
+                    then(h.at)
+                except Exception:  # noqa: BLE001 — a metric's arithmetic
+                    # must not end the stamping
+                    log.exception("ready waiter: then() failed")
 
 
 class _TraceSink:
@@ -190,6 +294,20 @@ class _TraceSink:
         self._lock = graftsched.lock("tracing._TraceSink._lock")
         self._tls = threading.local()
         self.spans: List[Span] = []
+        self._unready: List[_Handover] = []
+
+    def _await(self, handover: _Handover) -> None:
+        with self._lock:
+            self._unready.append(handover)
+
+    def settle(self) -> None:
+        """Wait, on the calling thread, for every handover of this
+        trace that is still unstamped (none, as a rule: the caller
+        fetched the same arrays to assemble its tokens)."""
+        with self._lock:
+            waiting, self._unready = self._unready, []
+        for h in waiting:
+            h.settle()
 
     def _stack(self) -> list:
         st = getattr(self._tls, "stack", None)
@@ -226,10 +344,13 @@ class _TraceSink:
             grafttime.emit("span_close", name=name, rid=self._rid(),
                            t=s.t1, dur_ms=round(s.duration * 1e3, 3))
 
-    def add_span(self, name: str, t0: float, t1: float, **labels) -> Span:
+    def add_span(self, name: str, t0: float, t1: float,
+                 ready: Optional[float] = None, **labels) -> Span:
         """Record an already-timed span (schedulers time phases once and
-        attach them to every participating request)."""
-        s = Span(name, t0, t1, labels=labels)
+        attach them to every participating request). ``ready``: the
+        instant the covered dispatch's result existed, where the call
+        site waited for it itself."""
+        s = Span(name, t0, t1, labels=labels, ready=ready)
         self._commit(s)
         grafttime.emit("span_close", name=name, rid=self._rid(), t=t1,
                        dur_ms=round(s.duration * 1e3, 3))
@@ -332,9 +453,11 @@ def span_from_dict(d: dict, base: float) -> Span:
     timeline becomes spans on THIS process's clock, child shape
     preserved."""
     t0 = base + d.get("start_ms", 0.0) / 1e3
+    labels = dict(d.get("labels") or {})
+    ready_ms = labels.pop("ready_ms", None)
     s = Span(d.get("name", "?"), t0,
-             t0 + d.get("duration_ms", 0.0) / 1e3,
-             labels=d.get("labels"))
+             t0 + d.get("duration_ms", 0.0) / 1e3, labels=labels,
+             ready=None if ready_ms is None else base + ready_ms / 1e3)
     s.children = [span_from_dict(c, base) for c in d.get("spans", ())]
     return s
 
@@ -397,12 +520,13 @@ def span(name: str, **labels) -> Iterator[Optional[Span]]:
         yield s
 
 
-def record(name: str, t0: float, t1: float, **labels) -> None:
+def record(name: str, t0: float, t1: float,
+           ready: Optional[float] = None, **labels) -> None:
     """Attach an already-timed span to the ambient trace (no-op without
     one) — for call sites that measured the window themselves."""
     tr = _current.get()
     if tr is not None:
-        tr.add_span(name, t0, t1, **labels)
+        tr.add_span(name, t0, t1, ready=ready, **labels)
 
 
 def annotate_span(**labels) -> None:
@@ -431,6 +555,8 @@ class FlightRecorder:
 
     def record(self, trace_obj: RequestTrace) -> None:
         trace_obj.finish()
+        # never keep a trace with a handed-over span unstamped
+        trace_obj.settle()
         with self._lock:
             self._traces.append(trace_obj)
 
@@ -503,3 +629,6 @@ def debug_requests_payload(recorder: FlightRecorder, query: dict,
 
 # process-wide default recorder (what serving.app uses; injectable there)
 RECORDER = FlightRecorder()
+
+# process-wide ready waiter: one device queue, one FIFO
+READY = ReadyWaiter()

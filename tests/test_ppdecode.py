@@ -49,6 +49,29 @@ def test_ppdecode_matches_host_driven_runner(model, want):
     np.testing.assert_array_equal(runner.generate(prompt, 12).tokens, expected)
 
 
+def test_ppdecode_records_prefill_and_decode_spans_with_ready(model, want):
+    """The pipelined path waits for ``first`` and for the tokens, so its
+    spans carry their own ends as ready instants: serving's TTFT ends at
+    the first token instead of falling back to the whole request."""
+    from llm_sharding_demo_tpu.utils import tracing
+    cfg, params = model
+    prompt, expected = want
+    dec = PipelinedDecoder(params, cfg, make_mesh({"pp": 2}, jax.devices()[:2]),
+                           max_seq=64)
+    tr = tracing.RequestTrace("pp")
+    with tracing.use_trace(tr):
+        np.testing.assert_array_equal(dec.generate(prompt, 12).tokens,
+                                      expected)
+    pre, dcd = tr.find("prefill"), tr.find("decode")
+    assert pre.t0 < pre.t1 == pre.ready == dcd.t0 < dcd.t1 == dcd.ready
+    assert pre.labels == {"batch": 2, "prompt_len": 7, "stages": 2}
+    assert dcd.labels == {"batch": 2, "steps": 11, "stages": 2}
+    spans = tr.finish().to_dict()["spans"]
+    assert [s["name"] for s in spans] == ["prefill", "decode"]
+    assert all(s["labels"]["ready_ms"] == pytest.approx(
+        s["start_ms"] + s["duration_ms"], abs=0.002) for s in spans)
+
+
 def test_ppdecode_sampling_deterministic(model):
     cfg, params = model
     mesh = make_mesh({"pp": 2}, jax.devices()[:2])
