@@ -17,14 +17,29 @@ def family_module(config):
     standalone. Plain GPT2Config is the only family the dense pipeline
     partitioner (parallel.partition) can stage.
     """
-    from . import gpt2, llama, moe
+    from . import gpt2, latent_moe, llama, moe
     if isinstance(config, moe.MoEConfig):
         return moe
+    if isinstance(config, latent_moe.LatentMoEConfig):
+        return latent_moe
     if isinstance(config, llama.LlamaConfig):
         return llama
     if isinstance(config, gpt2.GPT2Config):
         return gpt2
     raise TypeError(f"unknown model config type {type(config).__name__}")
+
+
+def cache_entry(config) -> tuple:
+    """``(planes, heads, width)``: what ONE position holds in ONE layer's
+    cache, as the family declares it. The dense families keep two planes
+    (keys, values) of ``n_kv_head x head_dim``; a family whose cache is
+    something else (``latent_moe``: one plane of one latent vector) says
+    so in its own ``cache_entry``. The paged pool, its movers, the
+    prefix store and the byte accounting size themselves from this."""
+    declared = getattr(family_module(config), "cache_entry", None)
+    if declared is not None:
+        return declared(config)
+    return (2, getattr(config, "n_kv_head", config.n_head), config.head_dim)
 
 
 def is_partitionable(config) -> bool:
@@ -52,6 +67,7 @@ def is_window_independent(config) -> bool:
     shapes (speculative verify windows, chunked prefill, prefix-cache
     continuations). MoE capacity-factor routing makes tokens compete for
     expert slots within a window, so it is window-DEPENDENT; the dense
-    families are independent."""
+    families are independent, and so is ``latent_moe``, whose routing has
+    no capacity and drops no token."""
     from . import moe
     return not isinstance(config, moe.MoEConfig)

@@ -151,6 +151,26 @@ def init_params(config: LlamaConfig, key: jax.Array,
     }
 
 
+def swiglu(mlp: Params, m: jnp.ndarray) -> jnp.ndarray:
+    """``down(silu(gate(m)) * up(m))`` over ``{gate, up, down}`` kernels."""
+    return linear(jax.nn.silu(linear(m, mlp["gate"]["kernel"]))
+                  * linear(m, mlp["up"]["kernel"]), mlp["down"]["kernel"])
+
+
+def pre_norm_block(block_params: Params, h: jnp.ndarray, eps: float,
+                   mixer, ffn):
+    """One pre-norm residual block assembled from its two halves:
+    ``h += mixer(norm(h))`` then ``h += ffn(norm(h))``. ``mixer(a)``
+    returns ``(out, state)`` (its updated cache, whatever form that
+    takes), ``ffn(m)`` returns ``out``; both read their own weights from
+    a closure. Every RMSNorm family's block is this with another mixer
+    or feed-forward (``models.latent_moe`` runs two kinds of layer in
+    one stack through it). Returns ``(h, state)``."""
+    out, state = mixer(rms_norm(h, block_params["ln_attn"]["scale"], eps))
+    h = h + out
+    return h + ffn(rms_norm(h, block_params["ln_mlp"]["scale"], eps)), state
+
+
 def _block(block_params: Params, h: jnp.ndarray, config: LlamaConfig,
            cos: jnp.ndarray, sin: jnp.ndarray,
            cache_k: Optional[jnp.ndarray], cache_v: Optional[jnp.ndarray],
@@ -164,97 +184,99 @@ def _block(block_params: Params, h: jnp.ndarray, config: LlamaConfig,
     ``cache_k``/``cache_v`` are the FULL stacked ``[L, B, Hkv, max_seq,
     hd]`` buffers with ``layer_idx`` selecting this block's slice — the
     in-place carry pattern (see ``ops.attention.write_kv_layer``)."""
-    a = rms_norm(h, block_params["ln_attn"]["scale"], config.rms_norm_eps)
     attn = block_params["attn"]
-    q = split_heads(linear(a, attn["wq"]["kernel"]), config.n_head)
-    k = split_heads(linear(a, attn["wk"]["kernel"]), config.n_kv_head)
-    v = split_heads(linear(a, attn["wv"]["kernel"]), config.n_kv_head)
-    q = apply_rope(q, cos, sin)
-    k = apply_rope(k, cos, sin)
-    if cache_k is None:
-        impl = config.attention_impl
 
-        def repeat_kv(k, v):
-            # the pallas/ring kernels want equal q/kv head counts; repeat
-            # (HF repeat_kv ordering) — a training-path materialization,
-            # the cached decode path below never repeats, and neither do
-            # the XLA fallbacks (grouped einsum handles GQA natively)
-            g = config.n_head // config.n_kv_head
-            return ((jnp.repeat(k, g, axis=1), jnp.repeat(v, g, axis=1))
-                    if g > 1 else (k, v))
+    def mixer(a):
+        q = split_heads(linear(a, attn["wq"]["kernel"]), config.n_head)
+        k = split_heads(linear(a, attn["wk"]["kernel"]), config.n_kv_head)
+        v = split_heads(linear(a, attn["wv"]["kernel"]), config.n_kv_head)
+        q = apply_rope(q, cos, sin)
+        k = apply_rope(k, cos, sin)
+        if cache_k is None:
+            impl = config.attention_impl
 
-        if impl == "pallas":
-            from ..ops.flash_attention import (flash_attention,
-                                               flash_profitable)
-            if flash_profitable(q.shape[2]):
+            def repeat_kv(k, v):
+                # the pallas/ring kernels want equal q/kv head counts; repeat
+                # (HF repeat_kv ordering) — a training-path materialization,
+                # the cached decode path below never repeats, and neither do
+                # the XLA fallbacks (grouped einsum handles GQA natively)
+                g = config.n_head // config.n_kv_head
+                return ((jnp.repeat(k, g, axis=1), jnp.repeat(v, g, axis=1))
+                        if g > 1 else (k, v))
+
+            if impl == "pallas":
+                from ..ops.flash_attention import (flash_attention,
+                                                   flash_profitable)
+                if flash_profitable(q.shape[2]):
+                    kf, vf = repeat_kv(k, v)
+                    attn_out = flash_attention(
+                        q, kf, vf, interpret=jax.default_backend() != "tpu")
+                else:
+                    # below the measured crossover the XLA einsum wins
+                    attn_out = causal_attention(q, k, v, q_offset=offset,
+                                                k_valid_from=k_valid_from)
+            elif impl == "ring":
+                from ..ops.ring_attention import ring_attention
+                if mesh is None:
+                    raise ValueError("attention_impl='ring' needs a mesh with "
+                                     "an 'sp' axis: pass forward(..., mesh=mesh)")
+                if k_valid_from is not None:
+                    raise NotImplementedError(
+                        "ring attention does not support ragged batches")
                 kf, vf = repeat_kv(k, v)
-                attn_out = flash_attention(
-                    q, kf, vf, interpret=jax.default_backend() != "tpu")
+                attn_out = ring_attention(q, kf, vf, mesh, axis="sp")
             else:
-                # below the measured crossover the XLA einsum wins
                 attn_out = causal_attention(q, k, v, q_offset=offset,
                                             k_valid_from=k_valid_from)
-        elif impl == "ring":
-            from ..ops.ring_attention import ring_attention
-            if mesh is None:
-                raise ValueError("attention_impl='ring' needs a mesh with "
-                                 "an 'sp' axis: pass forward(..., mesh=mesh)")
-            if k_valid_from is not None:
-                raise NotImplementedError(
-                    "ring attention does not support ragged batches")
-            kf, vf = repeat_kv(k, v)
-            attn_out = ring_attention(q, kf, vf, mesh, axis="sp")
-        else:
-            attn_out = causal_attention(q, k, v, q_offset=offset,
-                                        k_valid_from=k_valid_from)
-        new_ck = new_cv = None
-    elif decode_kernel is not None:
-        # FUSED cache mode (ops.attention.create_fused_cache): cache_k is
-        # the fused [L, B, Hkv, Smax, 2*hd] buffer, cache_v a placeholder
-        from ..ops.attention import (cached_attention_fused,
-                                     write_kv_layer_fused)
-        if flash_prefill:
+            new_ck = new_cv = None
+        elif decode_kernel is not None:
+            # FUSED cache mode (ops.attention.create_fused_cache): cache_k is
+            # the fused [L, B, Hkv, Smax, 2*hd] buffer, cache_v a placeholder
+            from ..ops.attention import (cached_attention_fused,
+                                         write_kv_layer_fused)
+            if flash_prefill:
+                from ..ops.flash_attention import flash_attention
+                new_ck = write_kv_layer_fused(cache_k, k, v, layer_idx, offset)
+                g = config.n_head // config.n_kv_head
+                kf = jnp.repeat(k, g, axis=1) if g > 1 else k
+                vf = jnp.repeat(v, g, axis=1) if g > 1 else v
+                attn_out = flash_attention(
+                    q, kf, vf, interpret=jax.default_backend() != "tpu")
+            elif q.shape[2] == 1:
+                # GQA-native flash-decode kernel: g = n_head/n_kv_head query
+                # heads ride each kv head's block stream, K/V never repeat
+                from ..ops.decode_attention import decode_attention
+                attn_out, new_ck = decode_attention(
+                    q, k, v, cache_k, layer_idx, offset, k_valid_from,
+                    interpret=decode_kernel == "interpret")
+            else:
+                attn_out, new_ck = cached_attention_fused(
+                    q, k, v, cache_k, layer_idx, offset, k_valid_from)
+            new_cv = cache_v
+        elif flash_prefill:
+            # fresh-cache prefill (offset 0, no pad): cached attention is
+            # plain causal attention over the new K/V — write the cache at
+            # kv-head width, run the flash kernel on repeated heads (the
+            # kernel wants equal q/kv head counts; a one-off prefill
+            # materialization, decode still reads the narrow cache)
             from ..ops.flash_attention import flash_attention
-            new_ck = write_kv_layer_fused(cache_k, k, v, layer_idx, offset)
+            new_ck, new_cv = write_kv_layer(cache_k, cache_v, k, v, layer_idx,
+                                            offset)
             g = config.n_head // config.n_kv_head
             kf = jnp.repeat(k, g, axis=1) if g > 1 else k
             vf = jnp.repeat(v, g, axis=1) if g > 1 else v
             attn_out = flash_attention(
                 q, kf, vf, interpret=jax.default_backend() != "tpu")
-        elif q.shape[2] == 1:
-            # GQA-native flash-decode kernel: g = n_head/n_kv_head query
-            # heads ride each kv head's block stream, K/V never repeat
-            from ..ops.decode_attention import decode_attention
-            attn_out, new_ck = decode_attention(
-                q, k, v, cache_k, layer_idx, offset, k_valid_from,
-                interpret=decode_kernel == "interpret")
         else:
-            attn_out, new_ck = cached_attention_fused(
-                q, k, v, cache_k, layer_idx, offset, k_valid_from)
-        new_cv = cache_v
-    elif flash_prefill:
-        # fresh-cache prefill (offset 0, no pad): cached attention is
-        # plain causal attention over the new K/V — write the cache at
-        # kv-head width, run the flash kernel on repeated heads (the
-        # kernel wants equal q/kv head counts; a one-off prefill
-        # materialization, decode still reads the narrow cache)
-        from ..ops.flash_attention import flash_attention
-        new_ck, new_cv = write_kv_layer(cache_k, cache_v, k, v, layer_idx,
-                                        offset)
-        g = config.n_head // config.n_kv_head
-        kf = jnp.repeat(k, g, axis=1) if g > 1 else k
-        vf = jnp.repeat(v, g, axis=1) if g > 1 else v
-        attn_out = flash_attention(
-            q, kf, vf, interpret=jax.default_backend() != "tpu")
-    else:
-        attn_out, new_ck, new_cv = cached_attention_inplace(
-            q, k, v, cache_k, cache_v, layer_idx, offset, k_valid_from)
-    h = h + linear(merge_heads(attn_out), attn["wo"]["kernel"])
-    m = rms_norm(h, block_params["ln_mlp"]["scale"], config.rms_norm_eps)
-    mlp = block_params["mlp"]
-    m = linear(jax.nn.silu(linear(m, mlp["gate"]["kernel"]))
-               * linear(m, mlp["up"]["kernel"]), mlp["down"]["kernel"])
-    return h + m, new_ck, new_cv
+            attn_out, new_ck, new_cv = cached_attention_inplace(
+                q, k, v, cache_k, cache_v, layer_idx, offset, k_valid_from)
+        return (linear(merge_heads(attn_out), attn["wo"]["kernel"]),
+                (new_ck, new_cv))
+
+    h, (new_ck, new_cv) = pre_norm_block(
+        block_params, h, config.rms_norm_eps, mixer,
+        lambda m: swiglu(block_params["mlp"], m))
+    return h, new_ck, new_cv
 
 
 def _embed(params: Params, input_ids: jnp.ndarray) -> jnp.ndarray:

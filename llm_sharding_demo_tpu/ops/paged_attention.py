@@ -19,6 +19,11 @@ in ``runtime.kv_pool``): attention and data movement over a POOLED cache,
   trailing ``+1`` block is the shared TRASH block: ghost rows and
   masked pad-prefix slots point at it, so every scatter target is a
   real block and no per-row liveness branching enters any program.
+  The ``(2, n_kv_head, head_dim)`` of it is the family's cache entry
+  (``models.cache_entry``): a family that caches ONE vector a position
+  has a pool of ``[L, num_blocks+1, 1, 1, bs, width]``, moved by
+  ``gather_rows`` / ``scatter_rows`` below (``copy_blocks`` serves
+  both).
 - **block tables**: ``[B, blocks_per_row]`` int32, TRACED operands —
   logical cache slot ``p`` of row ``b`` lives in pool block
   ``table[b, p // bs]`` at offset ``p % bs``. Tables never key
@@ -82,11 +87,14 @@ JIT_ENTRY_POINTS = ("paged_decode_attention",)
 
 
 def pool_shape(n_layer: int, num_blocks: int, n_kv_head: int,
-               block_size: int, head_dim: int) -> Tuple[int, ...]:
+               block_size: int, head_dim: int,
+               planes: int = 2) -> Tuple[int, ...]:
     """THE pool aval contract (one extra physical block: the trash
     block at index ``num_blocks``). graftcheck's paged contract family
-    checks gather/scatter round-trips against this shape."""
-    return (n_layer, num_blocks + 1, 2, n_kv_head, block_size, head_dim)
+    checks gather/scatter round-trips against this shape. ``planes``,
+    ``n_kv_head`` and ``head_dim`` are the family's cache entry."""
+    return (n_layer, num_blocks + 1, planes, n_kv_head, block_size,
+            head_dim)
 
 
 def blocks_per_row(max_seq: int, block_size: int) -> int:
@@ -149,6 +157,51 @@ def scatter_kv(pool: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                  jnp.zeros((), jnp.int32), jnp.zeros((), jnp.int32),
                  jnp.zeros((), jnp.int32), jnp.zeros((), jnp.int32)))
     return pool
+
+
+def gather_rows(pool: jnp.ndarray, tables: jnp.ndarray) -> jnp.ndarray:
+    """``gather_kv`` for a one-plane, one-head pool ``[L, NBp, 1, 1, bs,
+    width]``: a block is ``bs`` whole rows of the row-major cache, so
+    the view ``[L, B, 1, NBm*bs, width]`` is assembled by one block copy
+    per table entry, in a loop, straight into the output. (The general
+    form's take-then-transpose needs two more copies of the view as
+    temporaries, which a chip full of weights does not have.)"""
+    b, nbm = tables.shape
+    l, _, _, _, bs, w = pool.shape
+    flat = tables.reshape(-1)
+    zero = jnp.zeros((), jnp.int32)
+
+    def one(i, out):
+        blk = jax.lax.dynamic_slice(
+            pool, (zero, flat[i], zero, zero, zero, zero),
+            (l, 1, 1, 1, bs, w))
+        return jax.lax.dynamic_update_slice(
+            out, blk.reshape(l, 1, 1, bs, w),
+            (zero, i // nbm, zero, (i % nbm) * bs, zero))
+
+    return jax.lax.fori_loop(
+        0, b * nbm, one, jnp.zeros((l, b, 1, nbm * bs, w), pool.dtype))
+
+
+def scatter_rows(pool: jnp.ndarray, k: jnp.ndarray,
+                 tables: jnp.ndarray) -> jnp.ndarray:
+    """Inverse of ``gather_rows``: the same block copies the other way,
+    in table order, so duplicate targets (the trash block) resolve as
+    in ``scatter_kv``: the last write wins."""
+    b, nbm = tables.shape
+    l, _, _, _, bs, w = pool.shape
+    flat = tables.reshape(-1)
+    zero = jnp.zeros((), jnp.int32)
+
+    def one(i, pool):
+        blk = jax.lax.dynamic_slice(
+            k, (zero, i // nbm, zero, (i % nbm) * bs, zero),
+            (l, 1, 1, bs, w))
+        return jax.lax.dynamic_update_slice(
+            pool, blk.reshape(l, 1, 1, 1, bs, w).astype(pool.dtype),
+            (zero, flat[i], zero, zero, zero, zero))
+
+    return jax.lax.fori_loop(0, b * nbm, one, pool)
 
 
 def copy_blocks(pool: jnp.ndarray, src: jnp.ndarray,

@@ -55,3 +55,14 @@ def apply_rope(x: jnp.ndarray, cos: jnp.ndarray,
     x32 = x.astype(jnp.float32)
     out = x32 * cos + _rotate_half(x32) * sin
     return out.astype(x.dtype)
+
+
+def pairs_to_halves(x: jnp.ndarray) -> jnp.ndarray:
+    """Interleaved rotary layout -> the rotate-half layout: component
+    ``2i`` goes to ``i`` and ``2i+1`` to ``hd/2 + i``. Rotating the pair
+    ``(x[2i], x[2i+1])`` by ``pos * inv_freq_i`` (what a config's
+    ``rope_interleave`` asks for) is ``apply_rope`` on this permutation;
+    queries and keys permuted alike keep every dot product."""
+    half = x.shape[-1] // 2
+    x = x.reshape(*x.shape[:-1], half, 2)
+    return jnp.concatenate([x[..., 0], x[..., 1]], axis=-1)

@@ -536,6 +536,11 @@ class DecodeEngine:
         from ..utils.graftnum import engine_regime_of
         self.regime = engine_regime_of(dtype)
         quantize = self.regime == "int8"
+        from ..models import family_module as _family
+        if quantize and not getattr(_family(config), "INT8_WEIGHTS", True):
+            raise NotImplementedError(
+                f"{type(config).__name__} indexes its experts' plain "
+                "weight stacks; it serves float32 or bfloat16, not int8")
         if quantize and mesh is not None and not hasattr(config, "n_experts"):
             # refuse BEFORE any weight work (quantizing a real checkpoint
             # takes seconds — same convention as the prefill_chunk guard)
@@ -637,6 +642,15 @@ class DecodeEngine:
                 "'mega*' force the per-layer / whole-stack kernel)")
         self._cache_seq = max_seq
         self._decode_kernel: Optional[str] = None
+        if getattr(self._model, "BOUNDS_OWN_READS", False):
+            # the family's attention bounds its cache reads by the live
+            # depth inside the program (as the decode kernels' block
+            # loops do): a window bucket as wide as the cache means the
+            # engine cuts no windows for it
+            self.WINDOW_BUCKET = max_seq
+        # names of the counters a family's cache carries in its second,
+        # one-dimensional leaf (models.latent_moe), or ()
+        self.cache_counters = getattr(self._model, "CACHE_COUNTERS", ())
         # "auto" engages only for non-fp32 dtypes (fp32 is BASELINE.json's
         # byte-pinned greedy-parity mode; the kernel's online softmax is
         # allclose-not-bitwise vs the einsum path) and only without an ep
@@ -647,6 +661,10 @@ class DecodeEngine:
         explicit_interp = decode_kernel in ("interpret", "layer-interpret",
                                             "mega-interpret")
         explicit_kernel = decode_kernel not in ("auto", "xla")
+        # a family with a cache of its own (models.cache_entry) brings
+        # its own per-layer kernel and geometry rule, keeps its own
+        # cache layout under it, and has no whole-stack kernel
+        own_rule = getattr(self._model, "decode_kernel_eligible", None)
         if mesh is not None and explicit_kernel:
             raise ValueError(
                 f"decode_kernel={decode_kernel!r} does not compose with a "
@@ -660,7 +678,8 @@ class DecodeEngine:
         if want:
             rounded = min(-(-max_seq // _DA.BLOCK_S) * _DA.BLOCK_S,
                           config.n_positions)
-            base_ok = _DA.eligible(rounded, config.head_dim, 1)
+            base_ok = (own_rule(config, rounded) if own_rule is not None
+                       else _DA.eligible(rounded, config.head_dim, 1))
             # whole-stack megakernel (ops.decode_layer): one launch per
             # decode step instead of one per op — plain (unstaged)
             # GPT-2/llama engines with lane-aligned dims inside the VMEM
@@ -674,7 +693,7 @@ class DecodeEngine:
             # stage_apply's mega route) — n_stages launches per step
             # instead of one per op
             isize = jnp.dtype(dtype).itemsize
-            mega_ok = base_ok and (
+            mega_ok = base_ok and own_rule is None and (
                 (self._model is _g and _DL.eligible(config, rounded, isize))
                 or (self._model is _ll
                     and _DL.llama_eligible(config, rounded, isize)))
@@ -760,7 +779,8 @@ class DecodeEngine:
         # ops.attention.create_fused_cache) the kernel's aligned DMAs
         # require; the XLA mode keeps the family's separate buffers.
         heads = getattr(self.config, "n_kv_head", self.config.n_head)
-        if self._decode_kernel is not None:
+        if (self._decode_kernel is not None
+                and not hasattr(self._model, "decode_kernel_eligible")):
             from ..ops.attention import create_fused_cache
             if self.specs is None:
                 return create_fused_cache(self.config.n_layer, batch, heads,
@@ -837,6 +857,10 @@ class DecodeEngine:
                  and self._mesh is None
                  and flash_eligible(ids.shape[1])
                  and flash_profitable(ids.shape[1]))
+        # a family with its own fresh-cache attention form (latent:
+        # expanded, reading no cache; it masks pad itself) takes the
+        # same static word
+        flash = flash or getattr(self._model, "FRESH_PREFILL_FLAG", False)
         logits, cache = self._forward_cached(params, ids, cache, pad,
                                              flash_prefill=flash)
         return logits[:, -1], cache
@@ -982,6 +1006,10 @@ class DecodeEngine:
         (sliced out statically; the updated slice merges back into the
         donated full buffer on exit). Returns ``(tokens [B, n], cache)``."""
         sub = self._slice_cache(cache, window) if window else cache
+        if self.cache_counters:
+            # what comes back beside this segment's tokens is this
+            # segment's sums, however long the cache has lived
+            sub = sub._replace(v=jnp.zeros_like(sub.v))
 
         def body(carry, step_key):
             token, c = carry

@@ -226,6 +226,7 @@ GUARDED_STATE = {
     "grows": "_stats_lock", "preemptions": "_stats_lock",
     "resumes": "_stats_lock", "fault_parks": "_stats_lock",
     "batches_closed": "_stats_lock", "_turned": "_stats_lock",
+    "_moe": "_stats_lock",
     "_parked": "_stats_lock",
     "_pending": "_stats_lock",
     "_np": "_lock",
@@ -543,6 +544,13 @@ class IterBatchingEngine:
         self._turned = dict.fromkeys(
             ("closes_depth", "closes_policy", "defers_slot",
              "defers_pool"), 0)
+        # routing sums of a family whose cache carries counters
+        # (engine.cache_counters; models.latent_moe): what the decode
+        # segments and the prefills handed back, added up as each
+        # becomes ready. Empty for the dense families.
+        self._moe = dict.fromkeys(
+            [f"moe.{k}" for k in engine.cache_counters]
+            + [f"moe.prefill_{k}" for k in engine.cache_counters], 0)
         # (instant, reason) transitions of what holds the head of the
         # queue (worker-thread-only): every admitted request's wait is
         # cut by it. 4096 transitions span minutes of boundaries; a wait
@@ -645,7 +653,7 @@ class IterBatchingEngine:
                    "resumes": self.resumes,
                    "fault_parks": self.fault_parks,
                    "batches_closed": self.batches_closed,
-                   **self._turned,
+                   **self._turned, **self._moe,
                    "parked": len(self._parked)}
         return out
 
@@ -983,7 +991,8 @@ class IterBatchingEngine:
         state = _BatchState(sampling, first, cache, pad_j, s_max)
         # the span's window is the dispatch; the shared first-token
         # array says when the prefill had run
-        state.ready = tracing.READY.hand(first, covered)
+        state.ready = tracing.READY.hand(
+            first, covered, counters=self._routing_counters(cache, True))
         if spec_mode:
             # verify-loop entry state (spec_decode._seg_b invariant): the
             # token buffer holds prompt + the unforwarded first token per
@@ -1357,7 +1366,8 @@ class IterBatchingEngine:
             # known, never re-selected (see _seed_batch)
             first = jnp.asarray(int(resume.tokens[-1]), jnp.int32)
         if pre is not None:
-            tracing.READY.hand(first, [(req.trace, pre)])
+            tracing.READY.hand(first, [(req.trace, pre)],
+                               counters=self._routing_counters(solo, True))
         if self.pool is not None:
             blk_lo, blk_ids = self._place_admitted(
                 state, slot, solo, state.depth - sp, reserved)
@@ -1626,6 +1636,26 @@ class IterBatchingEngine:
 
     # -- the segment step ----------------------------------------------------
 
+    def _routing_counters(self, cache, prefill: bool):
+        """The ``counters`` of a ready handover (``tracing.READY.hand``)
+        for a cache that carries routing counters, or ``None``: a copy
+        of its counter leaf (the cache itself is donated onward) and the
+        function that, once the numbers exist, adds them to ``stats()``
+        and has the family name them as span labels."""
+        names = self.engine.cache_counters
+        if not names:
+            return None
+        model, config = self.engine._model, self.engine.config
+
+        def label(values):
+            got = dict(zip(names, values))
+            with self._stats_lock:
+                for k, v in got.items():
+                    self._moe[f"moe.prefill_{k}" if prefill
+                              else f"moe.{k}"] += v
+            return model.span_labels(got, config, prefill)
+        return jnp.copy(cache.v), label
+
     def _set_gauges(self, state: _BatchState) -> None:
         """Live-state gauges, refreshed at every scheduling decision
         point (seed, segment boundary): what the batch looks like NOW."""
@@ -1691,6 +1721,7 @@ class IterBatchingEngine:
         out, cache = eng._decode_seg(
             eng._run_params(), state.token, cache, state.pad_j,
             step_keys, sampling=state.sampling, window=window)
+        routing = self._routing_counters(cache, False)
         if pooled:
             self.pool.scatter(cache, state.tables)
             self.pool.note_compiles()
@@ -1728,7 +1759,8 @@ class IterBatchingEngine:
             if prev.at is not None:
                 REGISTRY.observe("decode_step_seconds", (at - prev.at) / n,
                                  component="iter")
-        state.ready = tracing.READY.hand(out, covered, then=observe)
+        state.ready = tracing.READY.hand(out, covered, then=observe,
+                                         counters=routing)
         self._retire_finished(state)
         self._set_gauges(state)
 

@@ -136,6 +136,10 @@ TIMELINE_EVENTS = {
 MEMORY_LEDGER = {
     "data": "pool_codes",
     "scales": "pool_scales",
+    # a one-plane pool (a latent cache: one vector a position) is the
+    # same buffer under its own holding and component, so a byte table
+    # tells the two kinds of pool apart
+    "latent": "pool_latent",
 }
 
 # Placement contract (tools/graftcheck placement pass + utils/
@@ -273,14 +277,18 @@ _REGIME_LABELS = {"float32": "f32", "bfloat16": "bf16",
 
 def bytes_per_block(n_layer: int, n_kv_head: int, block_size: int,
                     head_dim: int, dtype=jnp.float32,
-                    block_dtype: Optional[str] = None) -> int:
+                    block_dtype: Optional[str] = None,
+                    planes: int = 2) -> int:
     """HBM bytes one physical block costs, scales included: the unit
     the capacity bench (`kv_quant_capacity`) uses to size an int8 and
     an f32 pool to the SAME byte budget, and the number the
     ``kv_pool_bytes_per_block`` gauge publishes. Quantized blocks pay
     ``2 * n_kv_head`` f32 scales per layer on top of the narrow codes
-    (1/(block_size*head_dim) of the data — negligible, but counted)."""
-    slots = n_layer * 2 * n_kv_head * block_size * head_dim
+    (1/(block_size*head_dim) of the data — negligible, but counted).
+    ``planes``/``n_kv_head``/``head_dim`` are the family's cache entry
+    (``models.cache_entry``): a one-plane latent pool's block holds
+    ``block_size x width`` values a layer."""
+    slots = n_layer * planes * n_kv_head * block_size * head_dim
     if block_dtype is None:
         return slots * np.dtype(dtype).itemsize
     storage = KVQ.STORAGE_DTYPES[block_dtype]
@@ -857,8 +865,15 @@ class KVBlockPool:
                  dtype=jnp.float32, watermark: float = 0.9,
                  sanitize: Optional[bool] = None,
                  block_dtype: Optional[str] = None,
-                 fused: bool = False):
-        """``fused``: the engine's caches use the FUSED layout of the
+                 fused: bool = False, planes: int = 2, aux=None):
+        """``planes``, ``n_kv_head`` and ``head_dim`` are the family's
+        cache entry (``models.cache_entry``). A ONE-plane pool serves a
+        cache whose first leaf is the only storage (``models.
+        latent_moe``); ``aux`` is the shape and dtype of that cache's
+        second, one-dimensional leaf, made anew (zeroed) by every
+        gather and dropped by every scatter.
+
+        ``fused``: the engine's caches use the FUSED layout of the
         Pallas decode kernels (``ops.attention.create_fused_cache`` —
         one ``[L, B, H, S, 2*hd]`` buffer of ``[K | V]`` rows plus an
         empty placeholder). Block storage is the same either way; the
@@ -867,6 +882,13 @@ class KVBlockPool:
         compiled programs the layout they were built for."""
         self.nbm = PA.blocks_per_row(max_seq, block_size)
         self.fused = fused
+        self.planes = planes
+        self.entry_width = planes * n_kv_head * head_dim
+        if planes not in (1, 2) or (planes == 1 and (fused or block_dtype)):
+            raise NotImplementedError(
+                f"a pool of {planes} plane(s) with fused={fused}, "
+                f"block_dtype={block_dtype!r}: one-plane (latent) pools "
+                "store full-precision blocks for the unfused layout only")
         if num_blocks < self.nbm:
             raise ValueError(
                 f"num_blocks={num_blocks} cannot hold even one full "
@@ -905,7 +927,7 @@ class KVBlockPool:
                                         watermark=watermark,
                                         sanitize=sanitize)
         shape = PA.pool_shape(n_layer, num_blocks, n_kv_head, block_size,
-                              head_dim)
+                              head_dim, planes)
         if self.block_dtype is not None:
             self.data = jnp.zeros(shape,
                                   dtype=KVQ.STORAGE_DTYPES[self.block_dtype])
@@ -919,7 +941,10 @@ class KVBlockPool:
             0 if self.scales is None
             else self.scales.nbytes // shape[1])
         self._dev_lock = graftsched.rlock("kv_pool.KVBlockPool._dev_lock")
-        graftmem.track(self, "data", "pool_codes", self.data)
+        if planes == 1:
+            graftmem.track(self, "latent", "pool_latent", self.data)
+        else:
+            graftmem.track(self, "data", "pool_codes", self.data)
         if self.scales is not None:
             graftmem.track(self, "scales", "pool_scales", self.scales)
         # grafttier host spill tier (runtime/kv_tier.py), attached via
@@ -955,9 +980,14 @@ class KVBlockPool:
             return
 
         def _gather_impl(pool, tables):
+            if planes == 1:
+                return (PA.gather_rows(pool, tables),
+                        jnp.zeros(aux.shape, aux.dtype))
             return join(*PA.gather_kv(pool, tables))
 
         def _scatter_impl(pool, k, v, tables):
+            if planes == 1:
+                return PA.scatter_rows(pool, k, tables)
             return PA.scatter_kv(pool, *split(k, v), tables)
 
         def _scatter_one_rolled(pool, k, v, table_row, roll):
@@ -967,6 +997,8 @@ class KVBlockPool:
             # row. roll/table are traced: one program per solo shape.
             k, v = split(k, v)
             k = jnp.roll(k, roll, axis=-2)
+            if planes == 1:
+                return PA.scatter_rows(pool, k, table_row[None])
             v = jnp.roll(v, roll, axis=-2)
             return PA.scatter_kv(pool, k, v, table_row[None])
 
@@ -1141,13 +1173,18 @@ class KVBlockPool:
             raise NotImplementedError(
                 "KV pool paging is single-device; mesh decode (tp/ep) "
                 "keeps contiguous caches")
+        from ..models import cache_entry
         cfg = engine.config
-        heads = getattr(cfg, "n_kv_head", cfg.n_head)
+        planes, heads, width = cache_entry(cfg)
+        aux = (jax.eval_shape(lambda: engine._fresh_cache(1)).v
+               if planes == 1 else None)
         return cls(cfg.n_layer, num_blocks, heads, block_size,
-                   cfg.head_dim, engine._cache_seq, dtype=engine.dtype,
+                   width, engine._cache_seq, dtype=engine.dtype,
                    watermark=watermark, sanitize=sanitize,
                    block_dtype=block_dtype,
-                   fused=engine._decode_kernel is not None)
+                   fused=(engine._decode_kernel is not None
+                          and planes == 2),
+                   planes=planes, aux=aux)
 
     # -- device ops (all under _dev_lock) ------------------------------------
 
@@ -1200,7 +1237,7 @@ class KVBlockPool:
         bounded by the store's chunk grid."""
         bs = self.block_size
         sub = KVCache(k=cache.k[..., nb_lo * bs:, :],
-                      v=(cache.v if self.fused
+                      v=(cache.v if self.fused or self.planes == 1
                          else cache.v[..., nb_lo * bs:, :]),
                       length=cache.length)
         self.scatter(sub, tables[:, nb_lo:])
@@ -1327,6 +1364,9 @@ class KVBlockPool:
                "blocks_per_row": self.nbm,
                "block_dtype": self.block_regime,
                "bytes_per_block": self._bytes_per_block,
+               # values one position holds in one layer, as the family
+               # declares them: 2 x n_kv_head x head_dim, or one latent
+               "entry_width": self.entry_width,
                "graftsan": self.allocator.sanitize}
         if self.tier is not None:
             out["tier"] = self.tier.stats()

@@ -376,6 +376,10 @@ class PrefixCachingEngine:
                     raise
             else:
                 cache = entry
+                if self._eng.cache_counters:
+                    # the stored state's counters are those of the
+                    # request that made it; this one counts its own
+                    cache = cache._replace(v=jnp.zeros_like(cache.v))
         else:
             with self._store_lock:
                 self.misses += 1

@@ -324,6 +324,37 @@ def create_app(cfg: Optional[ServingConfig] = None,
         # prefills in iter mode), and prefix+speculation composes
         # single-stream AND batched (spec-flagged rounds/batches decode
         # through the batched verify loop).
+    from ..models import latent_moe as _latent
+    if isinstance(config, _latent.LatentMoEConfig):
+        # what the latent-attention / sparse-expert family refuses, one
+        # message each, instead of a wrong answer further down: it
+        # serves through the single-device engine (solo, either batcher,
+        # the paged pool, the prefix store) in float32 or bfloat16
+        name = type(config).__name__
+        refused = (
+            (cfg.kv_pool_dtype,
+             f"KV_POOL_DTYPE={cfg.kv_pool_dtype}: {name}'s pool holds one "
+             "latent vector a position; the quantized movers scale per "
+             "kv-head and have not been fitted to it"),
+            (cfg.kv_host_blocks > 0,
+             f"KV_HOST_BLOCKS: the host tier has not been run over "
+             f"{name}'s one-plane pool"),
+            (cfg.spec_decode > 0,
+             f"SPEC_DECODE: the verify loop's rewind leaves {name}'s "
+             "routing counters and cached latents of rejected drafts "
+             "untested; serve it without speculation"),
+            (cfg.pp_decode or cfg.tp_decode or cfg.ep_decode,
+             f"PP/TP/EP_DECODE: no multi-chip decoder stages or shards "
+             f"{name} (two stacks of unlike layers, experts indexed in "
+             "place); it serves on one chip, told which experts it "
+             "holds"),
+            (cfg.inference_dtype == "int8",
+             f"INFERENCE_DTYPE=int8: {name} indexes its experts' plain "
+             "weight stacks; it serves float32 or bfloat16"),
+        )
+        for on, why in refused:
+            if on:
+                raise ValueError(f"{why} (refused for this family)")
     if cfg.ep_decode:
         if not (cfg.shard_role == "coordinator" and cfg.dispatch == "local"):
             raise ValueError("EP_DECODE applies to the coordinator's local "
@@ -842,7 +873,8 @@ def create_app(cfg: Optional[ServingConfig] = None,
             # applied to bytes)
             st["pool_bytes"] = (
                 graftmem.holding_bytes(kv_pool, "data")
-                + graftmem.holding_bytes(kv_pool, "scales"))
+                + graftmem.holding_bytes(kv_pool, "scales")
+                + graftmem.holding_bytes(kv_pool, "latent"))
             if kv_pool.tier is not None:
                 # Per-tier conservation (the grafttier analog of the
                 # block assert above): entries, occupancy, and the
@@ -966,7 +998,8 @@ def create_app(cfg: Optional[ServingConfig] = None,
             st = kv_pool.stats()
             st["pool_bytes"] = (
                 graftmem.holding_bytes(kv_pool, "data")
-                + graftmem.holding_bytes(kv_pool, "scales"))
+                + graftmem.holding_bytes(kv_pool, "scales")
+                + graftmem.holding_bytes(kv_pool, "latent"))
             body["pool"] = st
         return body
 

@@ -83,6 +83,8 @@ MEMORY_COMPONENTS = {
                     ".data — full-precision or quantized codes)",
     "pool_scales":  "quantized pool per-block f32 scales plane "
                     "(KVBlockPool.scales)",
+    "pool_latent":  "paged pool of a one-plane (latent) cache: one "
+                    "vector a position a layer (KVBlockPool.data)",
     "engine_cache": "contiguous KV caches and in-flight decode "
                     "working views (engine / iterbatch batch state)",
     "spec_buffers": "speculative-decode device token buffers",
@@ -389,7 +391,8 @@ class MemoryLedger:
         comp = self.component_bytes()
         measured_params = comp.get("params", 0)
         measured_pool = (comp.get("pool_codes", 0)
-                         + comp.get("pool_scales", 0))
+                         + comp.get("pool_scales", 0)
+                         + comp.get("pool_latent", 0))
         measured_cache = comp.get("engine_cache", 0)
 
         def _cmp(measured: int, predicted) -> dict:

@@ -72,7 +72,8 @@ log = logging.getLogger(__name__)
 # ready instant (``_unready``: the scheduler thread appends, the
 # caller's thread drains) and the ready waiter's lazily started thread.
 GUARDED_STATE = {"spans": "_lock", "_traces": "_lock",
-                 "_unready": "_lock", "_thread": "_lock"}
+                 "_unready": "_lock", "_thread": "_lock",
+                 "counters": "_once"}
 LOCK_ORDER = ("_lock",)
 
 # Timeline contract (tools/graftcheck timeline pass): every span lands
@@ -205,19 +206,26 @@ class _Handover:
     waits for the array first (the waiter thread, or a caller's thread
     settling its trace) stamps ``at``; every span gets that instant."""
 
-    __slots__ = ("array", "covers", "then", "at")
+    __slots__ = ("array", "covers", "then", "at", "counters", "values")
+    _once = threading.Lock()      # one settler reads a handover's counters
 
-    def __init__(self, array, covers: List[Span], then=None):
+    def __init__(self, array, covers: List[Span], then=None,
+                 counters=None):
         self.array = array
         self.covers = covers
         self.then = then
         self.at: Optional[float] = None
+        # ``(device vector, label(values) -> dict)``: numbers the device
+        # computed beside ``array`` (a segment's routing counters), read
+        # once the array exists and written onto the covered spans
+        self.counters = counters
+        self.values = None
 
     def settle(self) -> None:
         """Wait until the array exists, then stamp. Safe from several
         threads at once: each waits on the same array, the stamps lie
         microseconds apart and any of them is true."""
-        array = self.array
+        array, covers = self.array, self.covers
         if self.at is None and array is not None:
             wait = getattr(array, "block_until_ready", None)
             if wait is not None:      # a host array exists already
@@ -230,7 +238,20 @@ class _Handover:
         if self.at is None:
             self.at = time.perf_counter()
         self.array = None             # the waiter keeps no buffer alive
-        for s in self.covers:
+        with self._once:
+            # the first settler reads the counters and labels the spans;
+            # whoever else settles waits here until the labels are on
+            counters, self.counters = self.counters, None
+            if counters is not None:
+                try:
+                    # computed by the program that made ``array``
+                    self.values = counters[0].tolist()
+                    labels = counters[1](self.values)
+                    for s in covers:
+                        s.labels.update(labels)
+                except Exception:  # noqa: BLE001 — as above: the
+                    pass           # request fails on its own path
+        for s in covers:
             if s.ready is None:
                 s.ready = self.at
         self.covers = ()              # ... and no span
@@ -249,13 +270,15 @@ class ReadyWaiter:
         self._fifo: "queue.SimpleQueue[_Handover]" = queue.SimpleQueue()
         self._thread: Optional[threading.Thread] = None
 
-    def hand(self, array, covered, then=None) -> _Handover:
+    def hand(self, array, covered, then=None,
+             counters=None) -> _Handover:
         """``covered``: ``(trace, span)`` pairs, each span on that
         trace; the trace will not be flight-recorded before the span is
         stamped. ``then(at)`` runs on the waiter thread, once, after
         every earlier handover's — for series derived from consecutive
-        ready instants."""
-        h = _Handover(array, [s for _, s in covered], then)
+        ready instants. ``counters``: see ``_Handover``; ``then`` may
+        read the handover's ``values`` (it runs after the stamp)."""
+        h = _Handover(array, [s for _, s in covered], then, counters)
         for tr, _ in covered:
             tr._await(h)
         self._fifo.put(h)

@@ -90,6 +90,20 @@ def test_decode_attention_compiles(one_chip, batch):
         _shape((cfg.n_layer, batch, h, SMAX, 2 * hd)))
 
 
+@pytest.mark.parametrize("batch,lanes", [(1, 640), (16, 640)])
+def test_latent_decode_attention_compiles(one_chip, batch, lanes):
+    """The absorbed decode step over a latent cache at the published
+    widths: 32 heads, rows of 640 lanes (576 values), a 3,072-slot cache
+    of 40 layers. (At 576 lanes Mosaic refused a hand-made DMA slice.)"""
+    from llm_sharding_demo_tpu.ops.latent_decode import (
+        latent_decode_attention)
+    _compile(lambda q, cache, pad, li, off: latent_decode_attention(
+        q, cache, li, off, 192 ** -0.5, pad), one_chip,
+        _shape((batch, 32, lanes)), _shape((40, batch, 1, 3072, lanes)),
+        _shape((batch,), jnp.int32), _shape((), jnp.int32),
+        _shape((), jnp.int32))
+
+
 @pytest.mark.parametrize("dtype", ["bfloat16", "int8"])
 @pytest.mark.parametrize("batch", [1, 8, decode_layer.MAX_BATCH])
 @pytest.mark.parametrize("name", ["gpt2", "gpt2-medium"])
