@@ -14,12 +14,18 @@ Design — right-aligned chunking, unlike the engine's left-padded
   prefix's KV state is identical no matter what follows it — exactly the
   property left-alignment destroys (its pad width depends on total
   length) and the reason this module does its own chunking.
-- Compile count stays bounded: one program for the chunk width + at most
-  ``chunk - 1`` tail widths, regardless of prompt length diversity.
+- The walk from the hit depth to the deepest whole chunk takes strides
+  of whole chunks from a fixed ladder (``STRIDE_LADDER``: 4, 2, 1),
+  largest first: every program call streams every weight once, so 12
+  chunks left are 3 calls, not 12. The stride is a function of the
+  chunks left alone; ``chunk`` stays the store's alignment (keys, hit
+  depths, shared blocks).
+- Compile count stays bounded: one program per ladder width (3) + at
+  most ``chunk - 1`` tail widths, regardless of prompt length diversity.
 - Cache entries are keyed by the token *content* of the first ``m``
   chunks and stored in LRU order. A lookup walks from the longest
   possible prefix down, so a request reuses the deepest cached state
-  available, then extends it chunk by chunk.
+  available, then extends it stride by stride.
 - Exactness: a hit replays the same ``forward_with_cache`` math the cold
   path runs, on a device-side COPY of the stored buffers (the decode
   scan donates its cache input, and stored entries must survive), so
@@ -63,12 +69,27 @@ JIT_ENTRY_POINTS = ("_extend", "_extend_keep")
 # Observability contract (tools/graftcheck scope pass + utils/graftscope):
 # both continuation programs' dispatches are timed into the graftscope
 # ring (graftscope.instrument at the jit sites), keyed by operand shape
-# — the ids width IS the program key (one program per chunk/tail width).
+# — the ids width IS the program key (one program per stride/tail width).
 PROFILED_SCOPES = ("_extend", "_extend_keep")
 
 
 def _extend_scope_key(params, cache, ids):
     return (int(ids.shape[0]), int(ids.shape[1]))
+
+
+# Whole chunks one walk call forwards, largest first. A call streams
+# every weight whatever it carries, and a v5e bf16 pass carries about
+# 256 tokens before compute overtakes the bytes; each entry is one more
+# program per donation variant, so the ladder is short.
+STRIDE_LADDER = (4, 2, 1)
+
+
+def _strides(chunks: int):
+    """``chunks`` whole chunks as ladder strides, largest first."""
+    for s in STRIDE_LADDER:
+        while chunks >= s:
+            yield s
+            chunks -= s
 
 # Donation contract (tools/graftcheck sanitize pass): ``_extend``
 # consumes its cache input (arg 1 — fresh caches and intermediate walk
@@ -123,7 +144,9 @@ MEMORY_BOUNDS = {
 # (the /healthz read) must never wait out an in-flight generation's
 # seconds of device time behind the big lock.
 GUARDED_STATE = {"_store": "_store_lock", "hits": "_store_lock",
-                 "misses": "_store_lock", "_mem_handles": "_store_lock"}
+                 "misses": "_store_lock", "_mem_handles": "_store_lock",
+                 "extend_calls": "_store_lock",
+                 "extend_tokens": "_store_lock"}
 
 # The device lock is always the OUTER of the pair (generate/prefill
 # take ``_lock``, then the walk touches the store under
@@ -214,8 +237,14 @@ class PrefixCachingEngine:
             "prefix_cache.PrefixCachingEngine._store_lock")
         self.hits = 0
         self.misses = 0
-        # One continuation program per ids width (the chunk width plus the
-        # ragged tail widths < chunk): forward `ids` at cache.length.
+        # program calls the walks made (strides and tails) and the
+        # tokens they forwarded: tokens a call is how wide the walk's
+        # weight passes ran
+        self.extend_calls = 0
+        self.extend_tokens = 0
+        # One continuation program per ids width (the ladder's stride
+        # widths plus the ragged tail widths < chunk): forward `ids` at
+        # cache.length.
         # Two donation variants: ``_extend`` consumes its cache input
         # (fresh caches and intermediate states), while ``_extend_keep``
         # leaves it intact — used for the FIRST step off a stored entry,
@@ -387,8 +416,8 @@ class PrefixCachingEngine:
             tracing.annotate_span(prefix_hit=False)
             cache = self._eng._fresh_cache(1)
 
-        # extend chunk by chunk (one shared program), snapshotting the
-        # deepest full-chunk state for the store before the ragged
+        # extend in ladder strides (one program a width), snapshotting
+        # the deepest full-chunk state for the store before the ragged
         # tail consumes the buffers. The first step off a stored
         # entry must not donate it (see _extend_keep) — unless the
         # entry came from the pool, where the gather already produced
@@ -402,12 +431,13 @@ class PrefixCachingEngine:
             from_store = False
             return fn(run_params, cache, ids)
 
+        strides = list(_strides(m_total - m_hit))
         try:
-            logits = None
-            for m in range(m_hit, m_total):
-                piece = jnp.asarray(
-                    prompt[None, m * self.chunk:(m + 1) * self.chunk])
-                logits, cache = step(cache, piece)
+            m = m_hit
+            for s in strides:
+                _, cache = step(cache, jnp.asarray(
+                    prompt[None, m * self.chunk:(m + s) * self.chunk]))
+                m += s
             if m_total > m_hit:
                 if self._pool is not None:
                     self._insert_pool(prompt, m_total, cache, hit_ids,
@@ -422,6 +452,11 @@ class PrefixCachingEngine:
                 self._pool.allocator.free(hit_ids)
         tail = jnp.asarray(prompt[None, m_total * self.chunk:])
         logits, cache = step(cache, tail)
+        calls = len(strides) + 1                # the tail's
+        with self._store_lock:
+            self.extend_calls += calls
+            self.extend_tokens += prompt_len - m_hit * self.chunk
+        tracing.annotate_span(extend_calls=calls)
         return logits, cache
 
     def prefill_state(self, prompt: np.ndarray):
@@ -504,7 +539,8 @@ class PrefixCachingEngine:
                        if self._pool is not None else len(self._store))
             out = {"entries": entries, "hits": self.hits,
                    "misses": self.misses, "capacity": self.capacity,
-                   "chunk": self.chunk}
+                   "chunk": self.chunk, "extend_calls": self.extend_calls,
+                   "extend_tokens": self.extend_tokens}
             if self._pool is not None:
                 out["pooled"] = True
             return out

@@ -868,3 +868,39 @@ def test_spec_rows_preempt_and_resume_byte_identical():
     np.testing.assert_array_equal(res[1].tokens[0], wantB)
     assert st["preemptions"] >= 1 and st["resumes"] >= 1
     assert pool.allocator.stats().blocks_in_use == 0
+
+
+def test_joiner_walks_all_three_strides_through_the_pooled_store():
+    """A joiner seven whole chunks long (strides of 4, 2 and 1, then
+    its tail) joins a live batch through the pool-backed store, llama
+    widths (grouped-query heads): both streams equal the solo engine's,
+    and the store's counters show four calls, not eight."""
+    from llm_sharding_demo_tpu.models import llama
+    from llm_sharding_demo_tpu.runtime.kv_pool import KVBlockPool
+    from llm_sharding_demo_tpu.runtime.prefix_cache import (
+        PrefixCachingEngine)
+    cfg = llama.LlamaConfig(vocab_size=211, n_positions=256, n_embd=64,
+                            n_layer=2, n_head=4, n_kv_head=2,
+                            intermediate_size=96)
+    params = jax.tree.map(lambda x: x * 4.0,
+                          llama.init_params(cfg, jax.random.PRNGKey(3)))
+    engine = DecodeEngine(params, cfg, max_seq=200)
+    pool = KVBlockPool.for_engine(engine, num_blocks=96, block_size=8)
+    prefix = PrefixCachingEngine(engine, capacity=4, chunk=8, pool=pool)
+    ib = IterBatchingEngine(engine, max_batch=4, seg_steps=8,
+                            max_wait_ms=50.0, prefix=prefix, pool=pool)
+    rng = np.random.default_rng(28)
+    pA = rng.integers(0, 211, size=(64,))   # seeds: deep enough to admit pB
+    pB = rng.integers(0, 211, size=(61,))   # 7 chunks of 8 + a tail of 5
+    wantA = engine.generate(pA[None, :], 60).tokens[0]
+    wantB = engine.generate(pB[None, :], 20).tokens[0]
+    before, h0 = ib.stats(), prefix.stats()
+    resA, resB = _staggered(ib, [
+        (pA, 60, 0.0, {}),
+        (pB, 20, _after_segments(ib, before["segments"], 1), {})])
+    h1 = prefix.stats()
+    np.testing.assert_array_equal(resA.tokens[0], wantA)
+    np.testing.assert_array_equal(resB.tokens[0], wantB)
+    assert ib.stats()["joins"] - before["joins"] >= 1
+    assert h1["extend_calls"] - h0["extend_calls"] == 4
+    assert h1["extend_tokens"] - h0["extend_tokens"] == 61

@@ -323,6 +323,50 @@ def test_iter_batcher_pool_and_prefix_store_serve_the_solo_streams(
     assert np.all(ref.max(-1) - ref[np.arange(len(served)), served] < TOL)
 
 
+def test_a_joiner_on_all_three_strides_serves_the_solo_stream(whole):
+    """A joiner seven whole chunks long walks the pool-backed store in
+    strides of 4, 2 and 1 chunks (64, 32 and 16 tokens through the
+    router and the grouped matmul at once) beside a live row: its stream
+    is the solo engine's, and each served token is the float32
+    reference's own choice or within noise of it."""
+    sizes, cfg, params = whole
+    eng = DecodeEngine(params, cfg, max_seq=256)
+    pool = KVBlockPool.for_engine(eng, 96, block_size=16)
+    prefix = PrefixCachingEngine(eng, capacity=4, chunk=16, pool=pool)
+    it = IterBatchingEngine(eng, max_batch=4, seg_steps=8, prefix=prefix,
+                            pool=pool)
+    rs = np.random.RandomState(28)
+    prompts = [rs.randint(0, 256, (124,)),  # seeds: deep enough to admit
+               rs.randint(0, 256, (119,))]  # 7 chunks of 16 + a tail of 7
+    news = [48, 12]
+    got = {}
+
+    def go(i):
+        got[i] = it.generate(prompts[i], news[i])
+
+    threads = [threading.Thread(target=go, args=(i,)) for i in range(2)]
+    threads[0].start()
+    deadline = time.monotonic() + 120
+    while it.stats()["segments"] < 1 and time.monotonic() < deadline:
+        time.sleep(0.001)
+    h0 = prefix.stats()
+    threads[1].start()
+    for t in threads:
+        t.join(timeout=300)
+    assert it.stats()["joins"] >= 1
+    h1 = prefix.stats()
+    assert h1["extend_calls"] - h0["extend_calls"] == 4
+    assert h1["extend_tokens"] - h0["extend_tokens"] == 119
+    solo = DecodeEngine(params, cfg, max_seq=256)
+    for i in range(2):
+        want = solo.generate(prompts[i], news[i]).tokens
+        assert np.array_equal(got[i].tokens, want), i
+    seq = got[1].tokens[0]
+    ref = reference_logits(params, sizes, seq[:-1])[len(prompts[1]) - 1:]
+    served = seq[len(prompts[1]):]
+    assert np.all(ref.max(-1) - ref[np.arange(len(served)), served] < TOL)
+
+
 def test_what_the_family_refuses():
     from llm_sharding_demo_tpu.serving.app import create_app
     from llm_sharding_demo_tpu.utils.config import ServingConfig

@@ -248,3 +248,93 @@ def test_prefix_composes_with_batching_mixed_hit_miss():
     st = prefix.stats()
     assert st["hits"] >= 2          # the two shared-prefix rows hit
     assert batcher.rows_served == 3
+
+
+# -- the walk's stride ladder -------------------------------------------------
+
+STRIDE_CHUNK = 8
+
+
+def _ladder_widths(chunks_left):
+    """The reference decomposition: 4, 2, 1 whole chunks, largest first."""
+    out = []
+    for s in (4, 2, 1):
+        while chunks_left >= s:
+            out.append(s * STRIDE_CHUNK)
+            chunks_left -= s
+    return out
+
+
+def _recording(pce):
+    """Record the ids width of every continuation-program call."""
+    widths = []
+
+    def wrap(fn):
+        def call(params, cache, ids):
+            widths.append(int(ids.shape[1]))
+            return fn(params, cache, ids)
+        return call
+
+    pce._extend = wrap(pce._extend)
+    pce._extend_keep = wrap(pce._extend_keep)
+    return widths
+
+
+def _prefill_labels(pce, prompt):
+    from llm_sharding_demo_tpu.utils import tracing
+    tr = tracing.RequestTrace("walk")
+    with tracing.use_trace(tr):
+        pce.prefill_state(prompt)
+    return tr.find("prefill").labels
+
+
+@pytest.mark.parametrize("pooled", [False, True], ids=["store", "pool"])
+@pytest.mark.parametrize("hit_chunks", [0, 2], ids=["miss", "hit"])
+@pytest.mark.parametrize("chunks_left", range(1, 10))
+def test_strided_walk_exact_and_on_the_ladder(params, plain, chunks_left,
+                                              hit_chunks, pooled):
+    """A walk of ``chunks_left`` whole chunks past the hit depth runs on
+    the ladder's widths alone, gives the plain engine's greedy stream,
+    leaves the store with the chunk-by-chunk walk's one key (the deepest
+    whole chunk), and counts its calls and tokens."""
+    from llm_sharding_demo_tpu.runtime.kv_pool import KVBlockPool
+    eng = DecodeEngine(params, CFG, max_seq=192)
+    pool = (KVBlockPool.for_engine(eng, num_blocks=64, block_size=8)
+            if pooled else None)
+    pce = PrefixCachingEngine(eng, capacity=4, chunk=STRIDE_CHUNK, pool=pool)
+    rng = np.random.default_rng(100 * chunks_left + 10 * hit_chunks + pooled)
+    m_total = hit_chunks + chunks_left
+    tail = 1 + chunks_left % 5
+    prompt = rng.integers(
+        0, CFG.vocab_size,
+        size=(m_total * STRIDE_CHUNK + tail,)).astype(np.int32)
+    if hit_chunks:
+        seed = np.concatenate([prompt[:hit_chunks * STRIDE_CHUNK],
+                               [3]]).astype(np.int32)
+        pce.generate(seed, 2)
+    before = pce.stats()
+    widths = _recording(pce)
+
+    got = pce.generate(prompt, max_new_tokens=8)
+    np.testing.assert_array_equal(
+        got.tokens, plain.generate(prompt, max_new_tokens=8).tokens)
+    assert widths == _ladder_widths(chunks_left) + [tail]
+
+    after = pce.stats()
+    assert after["extend_calls"] - before["extend_calls"] == len(widths)
+    assert (after["extend_tokens"] - before["extend_tokens"]
+            == chunks_left * STRIDE_CHUNK + tail == sum(widths))
+    assert after["hits"] - before["hits"] == (1 if hit_chunks else 0)
+    assert after["entries"] == (2 if hit_chunks else 1)
+    key = pce._key(prompt, m_total, STRIDE_CHUNK)
+    assert (pool.allocator.has_prefix(key) if pooled
+            else key in pce._store)
+
+    # the next request behind the same whole chunks reuses all of them
+    # in one tail call, as it did behind the chunk-by-chunk walk
+    del widths[:]
+    follow = np.concatenate([prompt[:m_total * STRIDE_CHUNK],
+                             [5, 6, 7]]).astype(np.int32)
+    labels = _prefill_labels(pce, follow)
+    assert labels["reused_tokens"] == m_total * STRIDE_CHUNK
+    assert labels["extend_calls"] == 1 and widths == [3]
