@@ -220,8 +220,7 @@ def measure_engine(config, prompt_len: int, batch: int,
 
     ``dtype_name="int8"`` is the weight-only quantized fast path
     (ops.quant): int8 kernels/embedding, bf16 activations + KV cache.
-    ``decode_kernel`` forces a specific attention/stack kernel (the
-    crossover rows pin "mega" vs "layer"); "auto" is the production
+    ``decode_kernel`` is ``DecodeEngine``'s; "auto" is the production
     dispatch."""
     import jax
     import jax.numpy as jnp
@@ -2088,41 +2087,6 @@ def main() -> None:
                     "runs (measured-crossover dispatch, never < 1.0x XLA)",
         }
 
-    def cfg12():
-        # Megakernel batch ceiling (VERDICT r4 #6): ops.decode_layer
-        # MAX_BATCH=16 silently downgrades wider batches to the
-        # per-layer kernel. Pin the boundary with forced kernels:
-        # bs=1 layer (the megakernel's headline win is vs this), bs=16
-        # mega vs layer (is the ceiling right?), bs=32 layer (what the
-        # auto fallback actually delivers past the ceiling).
-        import jax as _jax
-        rows = []
-        for bs, kern in ((1, "layer"), (16, "mega"), (16, "layer"),
-                         (32, "layer")):
-            try:
-                r = measure_engine(g124, PROMPT_LEN, bs, "bfloat16",
-                                   decode_kernel=kern)
-                rows.append({"batch": bs, "kernel": kern,
-                             "tokens_per_sec":
-                                 round(r["tokens_per_sec"], 1)})
-            except Exception as e:  # noqa: BLE001 — e.g. a VMEM ceiling
-                rows.append({"batch": bs, "kernel": kern,  # at bs=32
-                             "error": f"{type(e).__name__}: {e}"[:200]})
-        by = {(r["batch"], r["kernel"]): r.get("tokens_per_sec")
-              for r in rows}  # error rows carry no rate
-        mega16, layer16 = by.get((16, "mega")), by.get((16, "layer"))
-        verdict = (None if not (mega16 and layer16)
-                   else "mega" if mega16 >= layer16 else "layer")
-        return {
-            "rows": rows,
-            "bs16_winner": verdict,
-            "note": "auto dispatch uses mega for bs<=16 (MAX_BATCH) and "
-                    "the per-layer kernel above; bs16_winner validates "
-                    "the ceiling from measurement (cfg2/cfg3 carry the "
-                    "auto-path bs=1/bs=8 rates to compare against the "
-                    "bs=1 layer row here)",
-        }
-
     def cfg10():
         tr = measure_training(g124)
         gp = measure_gpipe_overhead()
@@ -2319,10 +2283,6 @@ def main() -> None:
     safe("cfg9_llama_124m_gqa", cfg9)
     safe("cfg7_flash_attention_vs_xla", cfg7)
     safe("cfg10_training_gpt2_124m", cfg10)
-    # last: the 4-engine crossover sweep is the longest single row — if
-    # an external timeout cuts the run short, the classic matrix rows
-    # above are already journaled
-    safe("cfg12_megakernel_batch_crossover", cfg12)
 
     def cfg_timeline_overhead():
         """grafttime event-bus cost row (ISSUE 14): emit throughput

@@ -395,25 +395,6 @@ def forward(params: Params, input_ids: jnp.ndarray,
     return final_logits(params, h, config.layer_norm_epsilon)
 
 
-def mega_step(blocks: Params, h: jnp.ndarray, config: GPT2Config, cache,
-              pad, decode_kernel: str):
-    """One whole-stack megakernel decode step over an embedded
-    ``[B, 1, D]`` hidden state — all the stacked blocks in one launch
-    (ops.decode_layer, the dispatch-overhead fix). THE single gpt2-family
-    mega route, shared by ``forward_with_cache`` and the stage runner
-    (parallel.partition). Returns ``(h, cache)``, or ``None`` when the
-    batch exceeds the kernel's VMEM budget — the caller downgrades to
-    the per-layer kernel (``ops.decode_layer.mega_downgrade``)."""
-    from ..ops.decode_layer import MAX_BATCH, decode_layers
-    if h.shape[0] > MAX_BATCH:
-        return None
-    h, KV = decode_layers(blocks, h, cache.k, cache.length,
-                          k_valid_from=pad, n_head=config.n_head,
-                          eps=config.layer_norm_epsilon,
-                          interpret=decode_kernel == "mega-interpret")
-    return h, KVCache(k=KV, v=cache.v, length=cache.length + 1)
-
-
 def forward_with_cache(params: Params, input_ids: jnp.ndarray,
                        config: GPT2Config, cache: KVCache,
                        pad: Optional[jnp.ndarray] = None,
@@ -432,18 +413,6 @@ def forward_with_cache(params: Params, input_ids: jnp.ndarray,
     Cache indices stay uniform across rows (the point of left-padding: one
     ``dynamic_update_slice`` serves the whole batch).
     """
-    from ..ops.decode_layer import mega_downgrade, mega_requested
-    if mega_requested(decode_kernel, input_ids.shape[1]):
-        offset = (cache.length if pad is None
-                  else cache.length - pad[:, None])
-        h = embed(params, input_ids, offset)
-        step = mega_step(params["blocks"], h, config, cache, pad,
-                         decode_kernel)
-        if step is not None:
-            h, cache = step
-            return final_logits(params, h,
-                                config.layer_norm_epsilon), cache
-        decode_kernel = mega_downgrade(decode_kernel)
     if pad is None:
         h = embed(params, input_ids, cache.length)
         h, cache = apply_blocks(params["blocks"], h, config, cache,

@@ -385,30 +385,6 @@ def forward(params: Params, input_ids: jnp.ndarray, config: LlamaConfig,
     return _final(params, h, config)
 
 
-def mega_step(blocks: Params, h: jnp.ndarray, config: LlamaConfig, cache,
-              pad, cos, sin, decode_kernel: str):
-    """One whole-stack megakernel decode step — the llama-family twin of
-    ``gpt2.mega_step`` (shared by ``forward_with_cache`` and the stage
-    runner). ``cos``/``sin`` are the step's rotary angles in any of
-    ``_angles``' single-position layouts; they normalize to the
-    ``[B, hd]`` the kernel wants. Returns ``(h, cache)`` or ``None``
-    past the kernel's batch budget."""
-    from ..ops.decode_layer import MAX_BATCH, decode_layers_llama
-    b = h.shape[0]
-    if b > MAX_BATCH:
-        return None
-    cos1 = jnp.broadcast_to(cos.reshape(-1, config.head_dim),
-                            (b, config.head_dim))
-    sin1 = jnp.broadcast_to(sin.reshape(-1, config.head_dim),
-                            (b, config.head_dim))
-    h, KV = decode_layers_llama(blocks, h, cache.k, cache.length, cos1,
-                                sin1, k_valid_from=pad,
-                                n_head=config.n_head,
-                                eps=config.rms_norm_eps,
-                                interpret=decode_kernel == "mega-interpret")
-    return h, KVCache(KV, cache.v, cache.length + 1)
-
-
 def forward_with_cache(params: Params, input_ids: jnp.ndarray,
                        config: LlamaConfig, cache: KVCache,
                        pad: Optional[jnp.ndarray] = None,
@@ -424,14 +400,6 @@ def forward_with_cache(params: Params, input_ids: jnp.ndarray,
     h = _embed(params, input_ids)
     offset = cache.length
     cos, sin = _angles(config, input_ids.shape[1], offset, pad)
-    from ..ops.decode_layer import mega_downgrade, mega_requested
-    if mega_requested(decode_kernel, input_ids.shape[1]):
-        step = mega_step(params["blocks"], h, config, cache, pad, cos, sin,
-                         decode_kernel)
-        if step is not None:
-            h, cache = step
-            return _final(params, h, config), cache
-        decode_kernel = mega_downgrade(decode_kernel)
     # structural guard (mirrors gpt2): the flash branch has no pad mask,
     # so ragged batches always take the masked cached-attention path
     flash_prefill = flash_prefill and pad is None

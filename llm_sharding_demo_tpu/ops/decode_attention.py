@@ -81,14 +81,13 @@ _VMEM_HEADROOM = 12 * 1024 * 1024      # accumulators, masks, windows
 _F32_TEMPORARIES = 4.25
 
 
-def stream_block(bh: int, hd: int, itemsize: int, reserved: int = 0) -> int:
+def stream_block(bh: int, hd: int, itemsize: int) -> int:
     """Cache positions per streamed block: ``BLOCK_S``, halved until the
-    stream fits the VMEM that ``reserved`` bytes of other tenants (the
-    megakernel's weight windows) leave. Every result divides ``BLOCK_S``,
-    so the cache is still whole blocks; GPT-2 124M keeps 256 through B=8
-    and streams 128 at B=16, 64 at B=32."""
+    stream fits a core's VMEM. Every result divides ``BLOCK_S``, so the
+    cache is still whole blocks; GPT-2 124M keeps 256 through B=8 and
+    streams 128 at B=16, 64 at B=32."""
     per_position = bh * 2 * hd * (2 * itemsize + 4 * _F32_TEMPORARIES)
-    room = _VMEM_BYTES - _VMEM_HEADROOM - reserved
+    room = _VMEM_BYTES - _VMEM_HEADROOM
     bs = BLOCK_S
     while bs > _WRITE_ROWS and bs * per_position > room:
         bs //= 2

@@ -184,29 +184,12 @@ def stage_apply(stage_params: Params, spec: StageSpec, config: GPT2Config,
     if pad is not None:
         position_offset = position_offset - pad[:, None]
     h = embed(stage_params, x, position_offset) if spec.is_first else x
-    h, cache = _stage_blocks_gpt2(stage_params, h, config, cache, pad,
-                                  decode_kernel)
+    h, cache = apply_blocks(stage_params["blocks"], h, config, cache,
+                            k_valid_from=pad, decode_kernel=decode_kernel)
     if spec.is_last:
         head_params = {"ln_f": stage_params["ln_f"], "wte": stage_params["wte_out"]}
         h = final_logits(head_params, h, config.layer_norm_epsilon)
     return h, cache
-
-
-def _stage_blocks_gpt2(stage_params, h, config, cache, pad, decode_kernel):
-    """A stage's block stack: the whole-stack megakernel when the engine
-    selected it (one launch for the stage's L_s layers instead of one
-    per op — ``gpt2.mega_step``, THE shared family route), else the
-    scanned per-layer path."""
-    from ..models.gpt2 import mega_step
-    from ..ops.decode_layer import mega_downgrade, mega_requested
-    if mega_requested(decode_kernel, h.shape[1]) and cache is not None:
-        step = mega_step(stage_params["blocks"], h, config, cache, pad,
-                         decode_kernel)
-        if step is not None:
-            return step
-        decode_kernel = mega_downgrade(decode_kernel)
-    return apply_blocks(stage_params["blocks"], h, config, cache,
-                        k_valid_from=pad, decode_kernel=decode_kernel)
 
 
 def _stage_apply_llama(stage_params: Params, spec: StageSpec, config,
@@ -217,24 +200,12 @@ def _stage_apply_llama(stage_params: Params, spec: StageSpec, config,
     same same-for-all-stages offset the dense path derives), embedding on
     the first stage, RMSNorm + untied head on the last."""
     from ..models import llama
-    from ..ops.decode_layer import mega_downgrade, mega_requested
     offset = cache.length if cache is not None else 0
     cos, sin = llama._angles(config, x.shape[1], offset, pad)
     h = llama._embed(stage_params, x) if spec.is_first else x
-    done = None
-    if mega_requested(decode_kernel, h.shape[1]) and cache is not None:
-        # the shared llama-family mega route (llama.mega_step): one
-        # launch for this stage's blocks
-        done = llama.mega_step(stage_params["blocks"], h, config, cache,
-                               pad, cos, sin, decode_kernel)
-        if done is None:
-            decode_kernel = mega_downgrade(decode_kernel)
-    if done is not None:
-        h, cache = done
-    else:
-        h, cache = llama.apply_blocks(stage_params["blocks"], h, config,
-                                      cos, sin, cache, k_valid_from=pad,
-                                      decode_kernel=decode_kernel)
+    h, cache = llama.apply_blocks(stage_params["blocks"], h, config, cos, sin,
+                                  cache, k_valid_from=pad,
+                                  decode_kernel=decode_kernel)
     if spec.is_last:
         h = llama._final(stage_params, h, config)
     return h, cache

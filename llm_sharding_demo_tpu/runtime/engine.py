@@ -106,8 +106,8 @@ MEMORY_LEDGER = {
 # logits once — the "softmax and logits stay f32" half of the bf16/
 # int8 prose, now traced). All entries exact: the f32 regime is the
 # byte-pinned parity mode; approximate REGIMES are declared at their
-# source modules (ops/quant.py -> decode.int8, ops/decode_layer.py ->
-# decode.bf16) and measured by graftnum's oracle at the engine level.
+# source modules (ops/quant.py -> decode.int8, ops/latent_decode.py
+# -> decode.bf16) and measured by graftnum's oracle at the engine level.
 PRECISION_CONTRACT = {
     "_prefill_impl": {"regime": "carried", "exact": True, "casts": ()},
     "_prefill_chunked_impl": {"regime": "carried", "exact": True,
@@ -621,25 +621,27 @@ class DecodeEngine:
                        self.params if self.params is not None
                        else self.stage_params)
         self.prefill_chunk = prefill_chunk
-        # Decode-attention dispatch (``decode_kernel``): "auto" routes
-        # single-token decode steps through the Pallas flash-decode kernel
-        # on TPU (in-place cache write + depth-adaptive block reads —
-        # ops.decode_attention has the measurements), "xla" keeps the
-        # einsum path (the byte-pinned parity mode), "interpret" forces
-        # the kernel in interpret mode for CPU tests. The kernel needs the
-        # cache allocated in whole blocks, so the PHYSICAL cache rounds up
-        # to a BLOCK_S multiple (capped at n_positions). On ineligible
-        # shapes "auto" falls back to "xla" with the exact ``max_seq``
-        # allocation; an EXPLICIT "interpret" request refuses instead
-        # (see the raise below).
+        # Decode-attention dispatch (``decode_kernel``), four modes over
+        # one Pallas path, the per-layer flash-decode kernel (in-place
+        # cache write + depth-adaptive block reads; ops.decode_attention,
+        # or the family's own under ``decode_kernel_eligible``):
+        #   "auto"      the served default: the kernel on a TPU outside
+        #               float32, else XLA; quietly XLA (with the exact
+        #               ``max_seq`` allocation) on an ineligible geometry
+        #               or under a mesh
+        #   "xla"       the einsum path, the byte-pinned parity oracle
+        #   "layer"     the compiled kernel asked for by name: refuses
+        #               where "auto" would fall back
+        #   "interpret" the same kernel interpreted, for CPU tests;
+        #               refuses like "layer"
+        # The kernel needs the cache allocated in whole blocks, so the
+        # PHYSICAL cache rounds up to a BLOCK_S multiple (capped at
+        # n_positions).
         from ..ops import decode_attention as _DA
-        _KERNEL_MODES = ("auto", "xla", "interpret", "layer",
-                         "layer-interpret", "mega", "mega-interpret")
+        _KERNEL_MODES = ("auto", "xla", "layer", "interpret")
         if decode_kernel not in _KERNEL_MODES:
             raise ValueError(
-                f"decode_kernel={decode_kernel!r} not one of {_KERNEL_MODES}"
-                " ('auto'/'interpret' pick the best kernel; 'layer*' and "
-                "'mega*' force the per-layer / whole-stack kernel)")
+                f"decode_kernel={decode_kernel!r} not one of {_KERNEL_MODES}")
         self._cache_seq = max_seq
         self._decode_kernel: Optional[str] = None
         if getattr(self._model, "BOUNDS_OWN_READS", False):
@@ -651,19 +653,17 @@ class DecodeEngine:
         # names of the counters a family's cache carries in its second,
         # one-dimensional leaf (models.latent_moe), or ()
         self.cache_counters = getattr(self._model, "CACHE_COUNTERS", ())
-        # "auto" engages only for non-fp32 dtypes (fp32 is BASELINE.json's
-        # byte-pinned greedy-parity mode; the kernel's online softmax is
-        # allclose-not-bitwise vs the einsum path) and only without an ep
-        # mesh (the kernel's manual DMAs don't compose with GSPMD
-        # partitioning — "auto" quietly resolves to XLA there, while the
-        # EXPLICIT kernel request refuses rather than silently running
-        # something else).
-        explicit_interp = decode_kernel in ("interpret", "layer-interpret",
-                                            "mega-interpret")
-        explicit_kernel = decode_kernel not in ("auto", "xla")
+        # "auto" engages only outside the f32 regime, however the dtype
+        # was spelled (fp32 is BASELINE.json's byte-pinned greedy-parity
+        # mode; the kernel's online softmax is allclose-not-bitwise vs
+        # the einsum path) and only without a mesh (the kernel's manual
+        # DMAs don't compose with GSPMD partitioning — "auto" quietly
+        # resolves to XLA there, while the EXPLICIT kernel request
+        # refuses rather than silently running something else).
+        explicit_kernel = decode_kernel in ("layer", "interpret")
         # a family with a cache of its own (models.cache_entry) brings
-        # its own per-layer kernel and geometry rule, keeps its own
-        # cache layout under it, and has no whole-stack kernel
+        # its own kernel and geometry rule and keeps its own cache
+        # layout under it
         own_rule = getattr(self._model, "decode_kernel_eligible", None)
         if mesh is not None and explicit_kernel:
             raise ValueError(
@@ -674,48 +674,16 @@ class DecodeEngine:
             explicit_kernel
             or (decode_kernel == "auto"
                 and jax.default_backend() == "tpu"
-                and dtype != jnp.float32))
+                and self.regime != "f32"))
         if want:
             rounded = min(-(-max_seq // _DA.BLOCK_S) * _DA.BLOCK_S,
                           config.n_positions)
-            base_ok = (own_rule(config, rounded) if own_rule is not None
-                       else _DA.eligible(rounded, config.head_dim, 1))
-            # whole-stack megakernel (ops.decode_layer): one launch per
-            # decode step instead of one per op — plain (unstaged)
-            # GPT-2/llama engines with lane-aligned dims inside the VMEM
-            # budget. The model falls back to the per-layer kernel at
-            # trace time for batches past MAX_BATCH.
-            from ..models import gpt2 as _g
-            from ..models import llama as _ll
-            from ..ops import decode_layer as _DL
-            # staged engines compose: each stage's stacked blocks run as
-            # their own whole-stack launch (parallel.partition.
-            # stage_apply's mega route) — n_stages launches per step
-            # instead of one per op
-            isize = jnp.dtype(dtype).itemsize
-            mega_ok = base_ok and own_rule is None and (
-                (self._model is _g and _DL.eligible(config, rounded, isize))
-                or (self._model is _ll
-                    and _DL.llama_eligible(config, rounded, isize)))
-            if decode_kernel in ("mega", "mega-interpret") and not mega_ok:
-                raise ValueError(
-                    f"decode_kernel={decode_kernel!r} requested but the "
-                    "megakernel is ineligible here (needs a GPT-2/llama "
-                    "engine with lane-aligned dims within the VMEM "
-                    "budget and a whole-block cache). Note: even an "
-                    "eligible mega engine falls back to the per-layer "
-                    f"kernel at trace time past {_DL.MAX_BATCH} batch "
-                    "rows (its VMEM batch budget)")
-            if base_ok:
+            if (own_rule(config, rounded) if own_rule is not None
+                    else _DA.eligible(rounded, config.head_dim, 1)):
                 self._cache_seq = rounded
-                use_mega = (mega_ok and decode_kernel
-                            not in ("layer", "layer-interpret"))
-                if use_mega:
-                    self._decode_kernel = ("mega-interpret"
-                                           if explicit_interp else "mega")
-                else:
-                    self._decode_kernel = ("interpret" if explicit_interp
-                                           else "device")
+                self._decode_kernel = ("interpret"
+                                       if decode_kernel == "interpret"
+                                       else "device")
             elif explicit_kernel:
                 # An EXPLICIT kernel request must never silently run
                 # something else (mirrors the mesh refusal above): a

@@ -198,3 +198,59 @@ def _clear_jax_caches_between_modules():
     cross-module recompiles cheap loads."""
     yield
     jax.clear_caches()
+
+
+class DescribedChip:
+    """One chip of a described ``v5e:2x2`` (section 2 of the
+    on-chip-measurement guide): the TPU compiler builds for it from
+    shapes alone, and refuses what interpret mode lets through."""
+
+    HBM_BYTES = 16e9
+
+    def __init__(self, sharding):
+        self.sharding = sharding
+
+    def shape(self, shape, dtype=jax.numpy.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=self.sharding)
+
+    def placed(self, tree):
+        """Shapes (from ``jax.eval_shape``) pinned to the chip."""
+        return jax.tree.map(lambda x: self.shape(x.shape, x.dtype), tree)
+
+    def check(self, compiled):
+        """A Mosaic kernel inside, and a program that fits the chip."""
+        assert "tpu_custom_call" in compiled.as_text(), \
+            "no Mosaic kernel inside"
+        mem = compiled.memory_analysis()
+        total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+                 + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+        assert total < self.HBM_BYTES, \
+            f"{total / 1e9:.1f} GB does not fit a v5e chip"
+        return mem
+
+    def compile(self, fn, *args):
+        return self.check(
+            jax.jit(fn).lower(*self.placed(args)).compile())
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """The described chip for the ``test_tpu_compile*`` files. Described
+    inside a fixture, never at import: the call loads the TPU's library
+    into the worker that runs the file (the tier-1 command sets
+    ``ALLOW_MULTIPLE_LIBTPU_LOAD`` for the second such worker)."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — whatever keeps it undescribed
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # such a compile is written to the persistent cache but cannot be
+    # read back without a chip; keep the cache out of these tests
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield DescribedChip(jax.sharding.SingleDeviceSharding(topo.devices[0]))
+    jax.config.update("jax_enable_compilation_cache", True)
+    compilation_cache.reset_cache()

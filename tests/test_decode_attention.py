@@ -108,20 +108,28 @@ def test_kernel_mode_composes_with_spec_and_chunked_prefill():
     assert list(sp.tokens[0]) == want
 
 
-def test_staged_engine_with_kernel_matches_xla():
+@pytest.mark.parametrize("family", ["gpt2", "llama-gqa"])
+def test_staged_engine_with_kernel_matches_xla(family):
     """DecodeEngine(boundaries=...) + the decode kernel: per-stage fused
-    caches, kernel invoked per stage — streams match the XLA engine."""
-    cfg = gpt2.GPT2Config(vocab_size=211, n_positions=1024, n_embd=64,
-                          n_layer=4, n_head=1)
-    params = gpt2.init_params(cfg, jax.random.PRNGKey(5))
-    p = np.asarray([[5, 9, 2, 77, 30]])
-    a = DecodeEngine(params, cfg, max_seq=300,
-                     decode_kernel="xla").generate(p, 24)
+    caches, kernel invoked per stage (``parallel.partition``'s two stage
+    runners) — streams match the XLA engine, solo and ragged."""
+    if family == "gpt2":
+        cfg = gpt2.GPT2Config(vocab_size=211, n_positions=1024, n_embd=64,
+                              n_layer=4, n_head=1)
+        params = gpt2.init_params(cfg, jax.random.PRNGKey(5))
+    else:
+        cfg = llama.LlamaConfig(vocab_size=211, n_positions=1024,
+                                n_embd=128, n_layer=4, n_head=2,
+                                n_kv_head=1, intermediate_size=64)
+        params = llama.init_params(cfg, jax.random.PRNGKey(6))
+    xla = DecodeEngine(params, cfg, max_seq=300, decode_kernel="xla")
     staged = DecodeEngine(params, cfg, max_seq=300, boundaries=[1, 3],
                           decode_kernel="interpret")
+    assert staged._decode_kernel == "interpret"
     assert is_fused_cache(staged._fresh_cache(1)[0])
-    b = staged.generate(p, 24)
-    assert list(a.tokens[0]) == list(b.tokens[0])
+    for prompts, n in (([[5, 9, 2, 77, 30]], 24), ([[5, 9, 2], [42]], 16)):
+        assert np.array_equal(xla.generate(prompts, n).tokens,
+                              staged.generate(prompts, n).tokens)
 
 
 def test_eligibility_gates():
@@ -156,3 +164,143 @@ def test_fp32_parity_mode_never_takes_the_kernel(monkeypatch):
     assert fp32._decode_kernel is None          # parity mode -> XLA
     bf16 = DecodeEngine(params, cfg, max_seq=300, dtype=jnp.bfloat16)
     assert bf16._decode_kernel == "device"      # fast path -> kernel
+
+
+# -- the one place the choice lives: DecodeEngine.__init__ --------------------
+
+def _gpt2(n_embd=64, n_head=1):
+    cfg = gpt2.GPT2Config(vocab_size=97, n_positions=1024, n_embd=n_embd,
+                          n_layer=1, n_head=n_head)
+    return cfg, gpt2.init_params(cfg, jax.random.PRNGKey(0))
+
+
+def _latent():
+    from llm_sharding_demo_tpu.models import latent_moe
+    cfg = latent_moe.LatentMoEConfig(
+        vocab_size=97, n_positions=512, n_embd=32, n_layer=2, n_head=2,
+        q_lora_rank=16, kv_lora_rank=32, qk_nope_head_dim=16,
+        qk_rope_head_dim=8, v_head_dim=16, intermediate_size=32,
+        moe_intermediate_size=8, n_routed_total=4, n_routed_experts=4,
+        n_experts_per_tok=2)
+    return cfg, latent_moe.init_params(cfg, jax.random.PRNGKey(0))
+
+
+def _tp_mesh():
+    from llm_sharding_demo_tpu.parallel.spmd import make_mesh
+    return make_mesh({"tp": 2}, jax.devices()[:2])
+
+
+# (mode, model, dtype, backend, mesh) -> (_decode_kernel, _cache_seq) at
+# max_seq 300, or the refusal's words. The dtype is spelled as the server
+# passes it, a string.
+RESOLUTION = {
+    "auto-float32-on-a-tpu": (
+        ("auto", _gpt2, "float32", "tpu", None), (None, 300)),
+    "auto-bfloat16-off-the-tpu": (
+        ("auto", _gpt2, "bfloat16", "cpu", None), (None, 300)),
+    "xla-bfloat16-on-a-tpu": (
+        ("xla", _gpt2, "bfloat16", "tpu", None), (None, 300)),
+    "layer-eligible": (
+        ("layer", _gpt2, "bfloat16", "cpu", None), ("device", 2 * BLOCK_S)),
+    "interpret": (
+        ("interpret", _gpt2, "float32", "cpu", None),
+        ("interpret", 2 * BLOCK_S)),
+    "layer-narrow-heads": (
+        ("layer", lambda: _gpt2(n_embd=32, n_head=2), "bfloat16", "cpu",
+         None), "geometry is ineligible"),
+    "layer-under-a-mesh": (
+        ("layer", lambda: _gpt2(n_head=2), "bfloat16", "cpu", _tp_mesh),
+        "does not compose with a mesh"),
+    "layer-latent-family": (
+        ("layer", _latent, "bfloat16", "cpu", None), ("device", 2 * BLOCK_S)),
+}
+
+
+@pytest.mark.parametrize("case", list(RESOLUTION))
+def test_decode_kernel_resolution(monkeypatch, case):
+    """The four modes, what each resolves to, and what the explicit ones
+    refuse: ``_decode_kernel`` is ``None`` (XLA, the cache as long as
+    asked), ``"device"`` or ``"interpret"`` (the cache in whole blocks)."""
+    import llm_sharding_demo_tpu.runtime.engine as eng_mod
+    (mode, model, dtype, backend, mesh), want = RESOLUTION[case]
+    monkeypatch.setattr(eng_mod.jax, "default_backend", lambda: backend)
+    cfg, params = model()
+
+    def build():
+        return DecodeEngine(params, cfg, max_seq=300, dtype=dtype,
+                            decode_kernel=mode,
+                            mesh=mesh() if mesh else None)
+    if isinstance(want, str):
+        with pytest.raises(ValueError, match=want):
+            build()
+        return
+    eng = build()
+    assert (eng._decode_kernel, eng._cache_seq) == want
+    cache = eng._fresh_cache(1)
+    if model is _latent:
+        # the family's own rule (its head width fails the two-plane
+        # one), and its own cache layout under the kernel
+        assert not eligible(eng._cache_seq, cfg.head_dim, 1)
+        assert not is_fused_cache(cache)
+        assert cache.k.shape == (cfg.n_layer, 1, 1, 2 * BLOCK_S,
+                                 cfg.cache_lanes)
+    else:
+        assert is_fused_cache(cache) == (want[0] is not None)
+
+
+@pytest.mark.parametrize("name", ["mega", "mega-interpret",
+                                  "layer-interpret"])
+def test_removed_kernel_modes_are_unknown_names(name):
+    """Names of modes that no longer exist are refused like any unknown
+    name, with the modes there are."""
+    cfg, params = _gpt2()
+    with pytest.raises(ValueError) as e:
+        DecodeEngine(params, cfg, max_seq=300, decode_kernel=name)
+    assert repr(name) in str(e.value)
+    assert "('auto', 'xla', 'layer', 'interpret')" in str(e.value)
+
+
+# -- what the kernel path owes every composition, on "interpret" --------------
+
+def _scaled(n_embd=128, n_head=2):
+    cfg = gpt2.GPT2Config(vocab_size=211, n_positions=1024, n_embd=n_embd,
+                          n_layer=2, n_head=n_head)
+    return cfg, jax.tree.map(lambda x: x * 4.0,
+                             gpt2.init_params(cfg, jax.random.PRNGKey(1)))
+
+
+def test_int8_weights_logits_allclose_across_xla_and_kernel():
+    """Weight-only int8 under the kernel: a decode step's logits agree
+    with the XLA path's (token equality across paths is not promised
+    for int8; the engine's documented contract)."""
+    cfg, params = _scaled()
+    p = jnp.asarray([[5, 9, 2, 77, 30]])
+    logits = {}
+    for mode in ("xla", "interpret"):
+        eng = DecodeEngine(params, cfg, max_seq=300, dtype="int8",
+                           decode_kernel=mode)
+        _, cache = eng._prefill(eng._run_params(), p, None)
+        step, _ = eng._forward_cached(
+            eng._run_params(), jnp.asarray([[100]], jnp.int32), cache, None)
+        logits[mode] = np.asarray(step[0, -1], np.float32)
+    np.testing.assert_allclose(logits["interpret"], logits["xla"],
+                               rtol=0.08, atol=0.35)
+
+
+def test_kernel_mode_samples_the_xla_stream_after_chunked_prefill():
+    """Seeded sampling rides the same per-row keys whichever path
+    computes the logits, a chunked prefill and a 1-token prompt (whose
+    prefill is itself a kernel step, at depth 0) included."""
+    from llm_sharding_demo_tpu.runtime.engine import SamplingConfig
+    cfg, params = _scaled()
+    prompt = np.arange(23).reshape(1, 23) % cfg.vocab_size
+    s = SamplingConfig(mode="sample", temperature=0.7, top_k=30)
+    k = jax.random.PRNGKey(5)
+    xla = DecodeEngine(params, cfg, max_seq=300, decode_kernel="xla")
+    ker = DecodeEngine(params, cfg, max_seq=300, prefill_chunk=8,
+                       decode_kernel="interpret")
+    assert ker._decode_kernel == "interpret"
+    for p in (prompt, np.asarray([[7]])):
+        want = xla.generate(p, 16, sampling=s, key=k)
+        got = ker.generate(p, 16, sampling=s, key=k)
+        assert list(want.tokens[0]) == list(got.row_tokens(0))

@@ -228,7 +228,6 @@ def test_repo_passes_graftcheck():
     npc = payload["numerics_contracts"]
     for rel in ("llm_sharding_demo_tpu/ops/quant.py",
                 "llm_sharding_demo_tpu/ops/layers.py",
-                "llm_sharding_demo_tpu/ops/decode_layer.py",
                 "llm_sharding_demo_tpu/ops/kv_quant.py",
                 "llm_sharding_demo_tpu/runtime/engine.py",
                 "llm_sharding_demo_tpu/runtime/kv_pool.py",

@@ -68,7 +68,6 @@ def test_repo_numerics_clean_and_nonvacuous():
     assert len(live) >= 3, summary["numerics_contracts"]
     for rel in ("llm_sharding_demo_tpu/ops/quant.py",
                 "llm_sharding_demo_tpu/ops/layers.py",
-                "llm_sharding_demo_tpu/ops/decode_layer.py",
                 "llm_sharding_demo_tpu/runtime/engine.py"):
         assert summary["numerics_contracts"].get(rel, 0) >= 1, (
             f"{rel}: PRECISION_CONTRACT resolves to no live entries")

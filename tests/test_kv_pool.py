@@ -443,8 +443,7 @@ def test_movers_take_a_private_copy_of_their_tables():
     np.testing.assert_array_equal(np.asarray(on_device), 3)
 
 
-@pytest.mark.parametrize("kernel", ["layer-interpret", "mega-interpret"])
-def test_pool_serves_an_engine_with_a_pallas_decode_kernel(kernel):
+def test_pool_serves_an_engine_with_a_pallas_decode_kernel():
     """What ``decode_kernel="auto"`` resolves to on a TPU outside fp32:
     the engine's caches are FUSED ([K|V] rows), and the pool's movers
     convert at the block boundary — paged greedy decode equals the
@@ -454,9 +453,8 @@ def test_pool_serves_an_engine_with_a_pallas_decode_kernel(kernel):
                           n_layer=2, n_head=2)
     params = gpt2.init_params(cfg, jax.random.PRNGKey(0))
     eng = DecodeEngine(params, cfg, max_seq=256, dtype="bfloat16",
-                       decode_kernel=kernel)
-    assert eng._decode_kernel == {"layer-interpret": "interpret"}.get(
-        kernel, kernel)
+                       decode_kernel="interpret")
+    assert eng._decode_kernel == "interpret"
     pool = KVBlockPool.for_engine(eng, num_blocks=48, block_size=16)
     assert pool.fused
     runner = PagedKVRunner(eng, pool)

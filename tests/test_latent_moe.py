@@ -345,12 +345,24 @@ def test_a_joiner_on_all_three_strides_serves_the_solo_stream(whole):
         got[i] = it.generate(prompts[i], news[i])
 
     threads = [threading.Thread(target=go, args=(i,)) for i in range(2)]
+    # Row 0's first decode call does not return to the scheduler before
+    # the joiner is in its queue, so the boundary after it admits the
+    # joiner whatever the machine's load: row 0's remaining segments are
+    # compiled and quick, and a thread started beside them can lose.
+    seg, h0 = eng._decode_seg, {}
+
+    def first_segment_waits_for_the_joiner(*a, **kw):
+        out = seg(*a, **kw)
+        if not h0:
+            h0.update(prefix.stats())
+            threads[1].start()
+            deadline = time.monotonic() + 120
+            while it._queue.qsize() < 1 and time.monotonic() < deadline:
+                time.sleep(0.001)
+        return out
+
+    eng._decode_seg = first_segment_waits_for_the_joiner
     threads[0].start()
-    deadline = time.monotonic() + 120
-    while it.stats()["segments"] < 1 and time.monotonic() < deadline:
-        time.sleep(0.001)
-    h0 = prefix.stats()
-    threads[1].start()
     for t in threads:
         t.join(timeout=300)
     assert it.stats()["joins"] >= 1

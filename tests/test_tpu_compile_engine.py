@@ -1,0 +1,140 @@
+"""The serving path's whole programs, compiled for a TPU v5e on the
+decode kernel the cells run (``decode_kernel="layer"`` -> ``"device"``).
+
+The twin of ``tests/test_tpu_compile.py`` one level up: not a kernel
+alone but the engine's decode segment and the prefix store's ``_extend``
+around it, from shapes, for the described chip (``tests/conftest.py``'s
+``one_chip``). The two benchmark configurations keep their published
+widths, read from ``benchmark/configs/*.json`` the way the harness reads
+them; only the depth is cut, for compile time. A compile that passes is
+not a chip run.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from benchmark.harness import server
+from benchmark.harness.spec import Spec, resolve
+from benchmark.rehearse import _Abstract
+from llm_sharding_demo_tpu.models import gpt2
+from llm_sharding_demo_tpu.runtime.engine import DecodeEngine, SamplingConfig
+from llm_sharding_demo_tpu.runtime.prefix_cache import PrefixCachingEngine
+
+# the depth cut: two layers of mistral-7b-l16; one dense and one expert
+# layer (the 16 experts this chip holds) of joyai-llm-flash-ep16
+LAYERS = 2
+# the decode widths the iteration scheduler compiles (PERF.md 5)
+WIDTHS = (1, 2, 4, 8, 16)
+SEG_STEPS = 32
+# GB a decode segment may hold beside its arguments and results (the
+# compiler's own report here: 0.0004, 0.069 and 0.042 at 16 rows)
+TEMPORARIES = {"gpt2-124m": 0.01, "mistral-7b-l16": 0.1,
+               "joyai-llm-flash-ep16": 0.06}
+# the same for the store's widest stride, 256 ids (0.068 and 0.113: the
+# figure PR 28's builder read by hand, PERF.md 6) and for a seed's
+# longest prompt (0.83 at 1,536 ids and 1.70 at 2,560)
+EXTEND_TEMPORARIES = {"mistral-7b-l16": 0.1, "joyai-llm-flash-ep16": 0.15}
+PREFILL_TEMPORARIES = {"mistral-7b-l16": 1.0, "joyai-llm-flash-ep16": 2.0}
+
+
+def _engine(chip, shapes, model_config, max_seq, dtype):
+    """A ``decode_kernel="layer"`` engine over shapes alone, with the
+    parameter tree its programs take."""
+    def is_leaf(x):
+        return isinstance(x, _Abstract)
+    abstract = jax.tree.map(
+        lambda s: _Abstract(s.shape, s.dtype, chip.sharding), shapes)
+    eng = DecodeEngine(abstract, model_config, max_seq=max_seq, dtype=dtype,
+                       decode_kernel="layer")
+    assert eng._decode_kernel == "device"
+    return eng, jax.tree.map(lambda a: a.sds, eng.params, is_leaf=is_leaf)
+
+
+def _built(chip, name):
+    if name == "gpt2-124m":
+        cfg = gpt2.CONFIGS["gpt2"]
+        shapes = jax.eval_shape(
+            lambda: gpt2.init_params(cfg, jax.random.PRNGKey(0)))
+        return _engine(chip, shapes, cfg, 1024, jnp.bfloat16)
+    config = dict(Spec().config(name), num_hidden_layers=LAYERS)
+    env = config["serving_env"]
+    shapes = jax.eval_shape(
+        lambda: resolve(config["reference"]).init(config, 0))
+    return _engine(chip, shapes, server.family_config(config),
+                   int(env["MAX_SEQ"]), env["INFERENCE_DTYPE"])
+
+
+@pytest.fixture(scope="module")
+def built(one_chip):
+    """name -> (engine, parameter shapes), each built once."""
+    cache = {}
+
+    def get(name):
+        if name not in cache:
+            cache[name] = _built(one_chip, name)
+        return cache[name]
+    return get
+
+
+@pytest.mark.parametrize("name,batch", [
+    ("gpt2-124m", 8),
+    *[("mistral-7b-l16", b) for b in WIDTHS],
+    *[("joyai-llm-flash-ep16", b) for b in WIDTHS]])
+def test_engine_decode_segment_compiles(one_chip, built, name, batch):
+    """The program a decode call of the scheduler runs: ``SEG_STEPS``
+    greedy steps over ``batch`` rows on the engine's own cache, which it
+    donates."""
+    eng, params = built(name)
+    shape = one_chip.shape
+    cache = one_chip.placed(jax.eval_shape(lambda: eng._fresh_cache(batch)))
+    compiled = jax.jit(
+        eng._decode_seg_impl, donate_argnums=(2,),
+        static_argnames=("sampling", "window")).lower(
+            params, shape((batch,), jnp.int32), cache,
+            shape((batch,), jnp.int32),
+            shape((SEG_STEPS, batch, 2), jnp.uint32),
+            sampling=SamplingConfig(mode="greedy"), window=None).compile()
+    mem = one_chip.check(compiled)
+    # the cache is updated in place: what the program holds beside its
+    # arguments is activations and logits, never a second cache
+    cache_bytes = sum(x.size * x.dtype.itemsize
+                      for x in jax.tree.leaves(cache))
+    assert mem.alias_size_in_bytes >= cache_bytes
+    assert mem.temp_size_in_bytes < TEMPORARIES[name] * 1e9, (
+        f"{mem.temp_size_in_bytes / 1e9:.3f} GB of temporaries")
+
+
+@pytest.mark.parametrize("ids", [64, 128, 256])
+@pytest.mark.parametrize("name", ["mistral-7b-l16", "joyai-llm-flash-ep16"])
+def test_prefix_store_extend_compiles(one_chip, built, name, ids):
+    """The store's ``_extend`` at the strides a walk takes (one, two and
+    four 64-token chunks; PERF.md 6, PR 28): a multi-token step over the
+    kernel engine's cache, which for the latent family is the expanded
+    attention form and the grouped matmul over the held experts."""
+    eng, params = built(name)
+    store = PrefixCachingEngine(eng, capacity=8, chunk=64)
+    cache = one_chip.placed(jax.eval_shape(lambda: eng._fresh_cache(1)))
+    compiled = store._extend.lower(
+        params, cache, one_chip.shape((1, ids), jnp.int32)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < EXTEND_TEMPORARIES[name] * 1e9, (
+        f"{mem.temp_size_in_bytes / 1e9:.3f} GB of temporaries")
+
+
+@pytest.mark.parametrize("workload", ["mistral-7b-l16.chat",
+                                      "joyai-llm-flash-ep16.assist"])
+def test_engine_prefill_compiles_at_the_longest_prompt(one_chip, built,
+                                                       workload):
+    """A seed's whole prompt in one call, at the longest the cell's
+    traffic draws (what ``benchmark/rehearse.py`` compiles by hand)."""
+    spec = Spec()
+    entry = spec.workload(workload)
+    longest = spec.traffic(entry["traffic"])["prompt"]["max"]
+    eng, params = built(entry["config"])
+    compiled = jax.jit(eng._prefill_impl).lower(
+        params, one_chip.shape((1, longest), jnp.int32),
+        one_chip.shape((1,), jnp.int32)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < PREFILL_TEMPORARIES[entry["config"]] * 1e9, (
+        f"{mem.temp_size_in_bytes / 1e9:.3f} GB of temporaries")

@@ -48,10 +48,6 @@ _ROWS = {
     "cfg9_llama_124m_gqa": (
         "llama family (RoPE/GQA/SwiGLU), bf16 + int8 + 3k-depth decode",
         "dense 124M CPU loop (reference caps at 1024 positions)"),
-    "cfg12_megakernel_batch_crossover": (
-        "Whole-stack megakernel vs per-layer kernel across batch sizes "
-        "(MAX_BATCH ceiling check)",
-        "per-layer Pallas kernel, same chip"),
     "cfg7_flash_attention_vs_xla": (
         "Pallas flash attention vs XLA attention (S=1k/2k/4k)",
         "XLA fused attention, same chip"),
