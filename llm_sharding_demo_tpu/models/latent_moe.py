@@ -18,14 +18,27 @@ JAX, with the family surface every runtime module dispatches on
   absorbed form, whose reads are bounded by the live depth inside the
   program (``BOUNDS_OWN_READS``: the engine cuts no windows for it); a
   decode step on a TPU runs it as a Pallas kernel (``ops.
-  latent_decode``) that streams the row's vectors block by block.
+  latent_decode``) that streams the row's vectors block by block. The
+  rotary parts are turned pair by pair where they lie (``ops.rope.
+  rotate_pairs``: the published layout is interleaved), queries and
+  keys alike in every form, so ``k_pe`` is stored in the order the
+  weights give it.
+- **A single position has its own forms** where a cheaper one exists,
+  chosen by the shapes a call brings (a decode step is ``[B, 1]``):
+  the folds into and out of the latent as one matmul each against the
+  leaves as they lie (``ops.latent_attention``), the router's top k by
+  rank and one tile an expert over all the rows (``ops.expert_ffn``).
+  Same arithmetic, a third fewer device operations a layer: a decode
+  step is forty layers of nine weight streams and a kernel, and what
+  it paid beside them was launches and copies (PERF.md 6, PR 31).
 - **Two kinds of layer in one stack**: ``first_k_dense`` leading layers
   with a dense SwiGLU, then expert layers: sigmoid-scored top-k routing
   over ALL ``n_routed_total`` experts with a selection bias, plus a
   shared expert every token takes. Each kind is one ``lax.scan`` over
-  its own stacked leaves; both blocks are ``llama.pre_norm_block`` with
-  another mixer and feed-forward, and the norm, RoPE, SwiGLU, embedding
-  and head are llama's.
+  its own stacked leaves (the expert layers' counts come out of the
+  scan stacked and are summed into the counters once a forward); both
+  blocks are ``llama.pre_norm_block`` with another mixer and
+  feed-forward, and the norm, SwiGLU, embedding and head are llama's.
 - **The layer is told which experts it holds**: ``n_routed_experts``
   consecutive ids from ``first_expert`` of the ``n_routed_total`` the
   router scores. It computes its own experts' terms and leaves the
@@ -61,7 +74,7 @@ import jax.numpy as jnp
 from ..ops import expert_ffn, latent_attention
 from ..ops.attention import KVCache
 from ..ops.layers import linear, rms_norm
-from ..ops.rope import apply_rope, pairs_to_halves, rope_angles
+from ..ops.rope import pair_angles, rotate_pairs
 from .llama import _embed, _final, pre_norm_block, swiglu
 
 Params = Dict[str, Any]
@@ -291,21 +304,19 @@ def _attention(attn: Params, a: jnp.ndarray, config: LatentMoEConfig,
         q = linear(c_q, attn["wuq"]["kernel"]).reshape(
             b, s, c.n_head, c.head_dim).transpose(0, 2, 1, 3)
         q_nope = q[..., :c.qk_nope_head_dim]
-        q_pe = apply_rope(pairs_to_halves(q[..., c.qk_nope_head_dim:]),
-                          cos, sin)
+        q_pe = rotate_pairs(q[..., c.qk_nope_head_dim:], cos, sin)
         down = linear(a, attn["wdkv"]["kernel"])
         c_kv = rms_norm(down[..., :c.kv_lora_rank],
                         attn["kv_norm"]["scale"], c.rms_norm_eps)
-        k_pe = apply_rope(
-            pairs_to_halves(down[..., c.kv_lora_rank:])[:, None],
-            cos, sin)[:, 0]
+        k_pe = rotate_pairs(down[:, None, :, c.kv_lora_rank:], cos, sin)[:, 0]
         wuk, wuv = attn["wuk"]["kernel"], attn["wuv"]["kernel"]
         if cache is not None:
-            fill = jnp.zeros((b, s, c.cache_lanes - c.cache_width),
-                             c_kv.dtype)
+            # the row's lanes past ``cache_width`` are zeros since
+            # ``make_cache`` (and the pool's planes) and stay so: this
+            # is the only writer
             cache = latent_attention.write_latent(
-                cache, jnp.concatenate([c_kv, k_pe, fill], axis=-1),
-                layer_idx, offset)
+                cache, jnp.concatenate([c_kv, k_pe], axis=-1), layer_idx,
+                offset)
         if cache is None or fresh:
             o = latent_attention.expanded(q_nope, q_pe, c_kv, k_pe, wuk,
                                           wuv, pad)
@@ -342,9 +353,13 @@ def expert_layer(moe: Params, experts: Params, m: jnp.ndarray,
 
 
 def _count(counters: jnp.ndarray, counts: jnp.ndarray, pairs: int):
+    """``counts`` [layers, held]: what one forward's expert layers gave
+    their held experts, added to ``CACHE_COUNTERS`` once a forward."""
     return counters + jnp.stack([
-        jnp.sum(counts > 0), jnp.sum(counts), jnp.asarray(pairs),
-        jnp.max(counts), jnp.asarray(1)]).astype(counters.dtype)
+        jnp.sum(counts > 0), jnp.sum(counts),
+        jnp.asarray(pairs * counts.shape[0]),
+        jnp.sum(jnp.max(counts, axis=1)),
+        jnp.asarray(counts.shape[0])]).astype(counters.dtype)
 
 
 def apply_blocks(params: Params, h: jnp.ndarray, config: LatentMoEConfig,
@@ -367,7 +382,7 @@ def apply_blocks(params: Params, h: jnp.ndarray, config: LatentMoEConfig,
     pairs = h.shape[0] * h.shape[1] * c.n_experts_per_tok
 
     def layer(carry, xs, first_layer, ffn):
-        h, latent, counters = carry
+        h, latent = carry
         p, li = xs
         seen = []
 
@@ -382,9 +397,7 @@ def apply_blocks(params: Params, h: jnp.ndarray, config: LatentMoEConfig,
             return out
 
         h, latent = pre_norm_block(p, h, c.rms_norm_eps, mixer, feed)
-        if seen[0] is not None:
-            counters = _count(counters, seen[0], pairs)
-        return (h, latent, counters), None
+        return (h, latent), seen[0]
 
     def dense_ffn(p, m, li):
         return swiglu(p["mlp"], m), None
@@ -392,15 +405,17 @@ def apply_blocks(params: Params, h: jnp.ndarray, config: LatentMoEConfig,
     def expert_ffn_(p, m, li):
         return expert_layer(p["moe"], experts, m, c, li)
 
-    carry = (h, latent, counters)
+    carry = (h, latent)
     for stack, first_layer, ffn in (
             (params["dense"], 0, dense_ffn),
             (moe_blocks, c.first_k_dense, expert_ffn_)):
         n = jax.tree_util.tree_leaves(stack)[0].shape[0]
-        carry, _ = jax.lax.scan(
+        carry, counts = jax.lax.scan(
             lambda cr, xs, fl=first_layer, f=ffn: layer(cr, xs, fl, f),
             carry, (stack, jnp.arange(n)))
-    h, latent, counters = carry
+        if counts is not None:
+            counters = _count(counters, counts, pairs)
+    h, latent = carry
     if cache is None:
         return h, None
     new_len = cache.length + jnp.asarray(h.shape[1], dtype=jnp.int32)
@@ -412,7 +427,7 @@ def _angles(config: LatentMoEConfig, seq_len: int, offset,
     pos = offset + jnp.arange(seq_len)
     if pad is not None:
         pos = jnp.maximum(pos[None, :] - pad[:, None], 0)
-    return rope_angles(pos, config.qk_rope_head_dim, config.rope_theta)
+    return pair_angles(pos, config.qk_rope_head_dim, config.rope_theta)
 
 
 def forward(params: Params, input_ids: jnp.ndarray, config: LatentMoEConfig,

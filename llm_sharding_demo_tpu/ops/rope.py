@@ -22,6 +22,7 @@ buffers so the parity oracle stays exact.
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 
@@ -57,12 +58,40 @@ def apply_rope(x: jnp.ndarray, cos: jnp.ndarray,
     return out.astype(x.dtype)
 
 
-def pairs_to_halves(x: jnp.ndarray) -> jnp.ndarray:
-    """Interleaved rotary layout -> the rotate-half layout: component
-    ``2i`` goes to ``i`` and ``2i+1`` to ``hd/2 + i``. Rotating the pair
-    ``(x[2i], x[2i+1])`` by ``pos * inv_freq_i`` (what a config's
-    ``rope_interleave`` asks for) is ``apply_rope`` on this permutation;
-    queries and keys permuted alike keep every dot product."""
-    half = x.shape[-1] // 2
-    x = x.reshape(*x.shape[:-1], half, 2)
-    return jnp.concatenate([x[..., 0], x[..., 1]], axis=-1)
+def pair_angles(positions: jnp.ndarray, head_dim: int,
+                theta: float = 10000.0) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """``rope_angles`` for the interleaved layout (a config's
+    ``rope_interleave``), where components ``2i`` and ``2i+1`` are one
+    pair turned by ``pos * inv_freq_i``: (cos, sin) each ``[...,
+    head_dim]`` with the pair's angle on both of its lanes and the sign
+    of the rotation folded into the sine (minus on the even lane), so
+    that ``rotate_pairs`` is two multiplies and an add."""
+    lane = jnp.arange(head_dim)
+    inv_freq = theta ** (-(lane // 2 * 2).astype(jnp.float32) / head_dim)
+    freqs = positions.astype(jnp.float32)[..., None] * inv_freq
+    return jnp.cos(freqs), jnp.sin(freqs) * jnp.where(lane % 2 == 0, -1.0, 1.0)
+
+
+def rotate_pairs(x: jnp.ndarray, cos: jnp.ndarray,
+                 sin: jnp.ndarray) -> jnp.ndarray:
+    """Rotate ``x`` [B, H, S, hd] pair by pair where the pairs lie:
+    ``(x[2i], x[2i+1])`` by the angles of ``pair_angles`` (shaped as
+    ``apply_rope`` takes them). No lane leaves its place: each takes its
+    partner from the lane beside it (a matmul with the 0/1 matrix that
+    swaps neighbours), so a query and a key rotated here
+    keep the layout their weights give them, and their dot product is
+    the one ``apply_rope`` gives on the half-split permutation of both.
+    Float32 inside, like ``apply_rope``."""
+    if cos.ndim == 2:                        # [S, hd] -> [1, 1, S, hd]
+        cos, sin = cos[None, None], sin[None, None]
+    else:                                    # [B, S, hd] -> [B, 1, S, hd]
+        cos, sin = cos[:, None], sin[:, None]
+    hd = x.shape[-1]
+    lane = jnp.arange(hd)
+    swap = (lane[:, None] == (lane ^ 1)[None, :]).astype(x.dtype)
+    # one term a lane, so exact in any dtype at this precision; a lane
+    # shuffle stated as slices or a roll costs the chip several copies
+    partner = jnp.matmul(x, swap, precision=jax.lax.Precision.HIGHEST)
+    out = (x.astype(jnp.float32) * cos
+           + partner.astype(jnp.float32) * sin)
+    return out.astype(x.dtype)

@@ -183,38 +183,155 @@ def test_routing_bias_chooses_and_scores_weigh():
     np.testing.assert_allclose(np.asarray(raw), picked, rtol=1e-6)
 
 
-def test_a_tokens_output_is_bit_equal_alone_in_a_batch_and_in_a_chunk(whole):
+@pytest.mark.parametrize("b,s", [(4, 40), (8, 1), (16, 1)],
+                         ids=["general", "one-tile-an-expert", "widest-step"])
+def test_a_tokens_output_is_bit_equal_alone_in_a_batch_and_in_a_chunk(
+        whole, b, s):
     """Window independence: no capacity, no dropped token, tiles of one
     shape summed in expert order. Bit-equal wherever the token sits and
     whatever shares its forward: alone among zeros, among other mates,
     as part of a chunk laid elsewhere. (The forwards compared have one
     shape, so the CPU's matmuls are the same programs; across shapes a
     row's dense matmuls already differ in the last bit on this backend,
-    with or without experts, so those are held to 1e-5.)"""
+    with or without experts, so those are held to 1e-5.) Through the
+    general grouped matmul (160 tokens) and through the form for at most
+    ``TILE`` pairs that a decode step takes (8 and 16 rows of one
+    position), whose routed part is one ``[TILE, d]`` program at every
+    width: THAT is bit-equal across widths too."""
     _, cfg, params = whole
     moe = dict(jax.tree.map(lambda a: a, params["blocks"]["moe"]))
     experts = moe.pop("experts")
     layer = jax.tree.map(lambda a: a[2], moe)
     rs = np.random.RandomState(3)
-    x = rs.randn(4, 40, 64).astype(np.float32)
+    t = b * s
+    assert (t * cfg.n_experts_per_tok <= expert_ffn.TILE) == (s == 1)
+    x = rs.randn(t, 64).astype(np.float32)
 
-    def run(m):
-        out, counts = latent_moe.expert_layer(layer, experts,
-                                              jnp.asarray(m), cfg, 2)
-        assert int(counts.sum()) == m.shape[0] * m.shape[1] * 4  # none dropped
-        return np.asarray(out)
+    def run(tokens, shape=(b, s)):
+        out, counts = latent_moe.expert_layer(
+            layer, experts, jnp.asarray(tokens.reshape(*shape, 64)), cfg, 2)
+        assert int(counts.sum()) == len(tokens) * 4              # none dropped
+        return np.asarray(out).reshape(len(tokens), 64)
 
     batch = run(x)
     alone = np.zeros_like(x)
-    alone[3, 20] = x[1, 7]
-    assert np.array_equal(run(alone)[3, 20], batch[1, 7])
-    mates = rs.randn(4, 40, 64).astype(np.float32)
-    mates[0, 3:19] = x[2, 8:24]                                  # a chunk
-    assert np.array_equal(run(mates)[0, 3:19], batch[2, 8:24])
+    alone[t - 3] = x[1]
+    assert np.array_equal(run(alone)[t - 3], batch[1])
+    n = max(t // 10, 3)                                          # a chunk
+    mates = rs.randn(t, 64).astype(np.float32)
+    mates[1:1 + n] = x[t - n - 1:t - 1]
+    assert np.array_equal(run(mates)[1:1 + n], batch[t - n - 1:t - 1])
     assert np.array_equal(run(x[::-1])[::-1], batch)
-    np.testing.assert_allclose(run(x[1:2, 7:8]), batch[1:2, 7:8], atol=1e-5)
-    np.testing.assert_allclose(run(x[2:3, 8:24]), batch[2:3, 8:24],
+    np.testing.assert_allclose(run(x[1:2], (1, 1)), batch[1:2], atol=1e-5)
+    np.testing.assert_allclose(run(x[2:2 + n], (1, n)), batch[2:2 + n],
                                atol=1e-5)
+    if s == 1:
+        ids, w = expert_ffn.route(
+            jnp.asarray(x), layer["router"]["kernel"],
+            layer["router"]["bias"], 4, cfg.routed_scaling_factor)
+
+        def routed(rows):
+            y, _ = expert_ffn.held_experts_ffn(
+                jnp.asarray(x[rows]), ids[rows], w[rows],
+                experts["gate"]["kernel"], experts["up"]["kernel"],
+                experts["down"]["kernel"], 2, 0)
+            return np.asarray(y)
+
+        every = routed(slice(0, t))
+        for rows in (slice(1, 2), slice(2, 6), slice(0, t // 2)):
+            assert np.array_equal(routed(rows), every[rows])
+
+
+@pytest.mark.parametrize("rows", [1, 16])
+def test_a_decode_steps_routing_is_the_general_forms(whole, monkeypatch,
+                                                     rows):
+    """The forms for at most ``TILE`` pairs (top k by rank, one tile an
+    expert over all the rows) against the general ones (``lax.top_k``,
+    pairs sorted into tiles) on the same rows: ids, weights, the counts
+    and the five counters byte-equal; the routed sum within float32
+    noise (the general form's tiles hold other rows)."""
+    _, cfg, params = whole
+    moe = dict(jax.tree.map(lambda a: a, params["blocks"]["moe"]))
+    experts = moe.pop("experts")
+    layer = jax.tree.map(lambda a: a[1], moe)
+    x = jnp.asarray(np.random.RandomState(31).randn(rows, 1, 64), jnp.float32)
+    # ties, which both must give to the lower id
+    tied = jnp.asarray(np.round(np.random.RandomState(32).randn(rows, 16), 1),
+                       jnp.float32)
+
+    def both():
+        ids, w = expert_ffn.route(
+            x[:, 0], layer["router"]["kernel"], layer["router"]["bias"], 4,
+            cfg.routed_scaling_factor)
+        out, counts = latent_moe.expert_layer(layer, experts, x, cfg, 1)
+        counters = latent_moe._count(jnp.zeros((5,), jnp.int32),
+                                     counts[None], rows * 4)
+        _, cache = latent_moe.forward_with_cache(
+            params, jnp.arange(rows)[:, None], cfg,
+            latent_moe.make_cache(cfg, rows, 8))
+        pick = expert_ffn.route(tied, jnp.eye(16), jnp.zeros((16,)), 4, 1.0)
+        return [np.asarray(a) for a in
+                (ids, w, counts, counters, cache.v, *pick, out)]
+
+    with jax.default_matmul_precision("highest"):
+        few = both()
+        # no call has few enough pairs for the forms a decode step takes
+        monkeypatch.setattr(expert_ffn, "TILE", 1)
+        general = both()
+    for a, g in zip(few[:-1], general[:-1]):
+        assert np.array_equal(a, g)
+    assert few[2].sum() == rows * 4 and few[4][4] == 3
+    assert np.abs(few[-1] - general[-1]).max() < TOL
+
+
+@pytest.mark.parametrize("kernel", [None, "interpret"],
+                         ids=["xla", "interpret"])
+def test_one_position_alone_and_as_the_last_of_two(whole, kernel):
+    """The single-position form of the attention front (the folds into
+    and out of the latent as one matmul each against the leaves as they
+    lie) against the einsum form: position 20 run alone and as the last
+    token of a two-token call writes the same cache row and gives the
+    same logits within float32 noise."""
+    _, cfg, params = whole
+    ids = jnp.asarray(np.random.RandomState(33).randint(0, 256, (1, 21)))
+    with jax.default_matmul_precision("highest"):
+        def prefilled(n):
+            _, cache = latent_moe.forward_with_cache(
+                params, ids[:, :n], cfg, latent_moe.make_cache(cfg, 1, 256),
+                flash_prefill=True)
+            return cache
+        one, c1 = latent_moe.forward_with_cache(
+            params, ids[:, 20:], cfg, prefilled(20), decode_kernel=kernel)
+        two, c2 = latent_moe.forward_with_cache(
+            params, ids[:, 19:], cfg, prefilled(19))
+    assert np.abs(np.asarray(one[0, -1]) - np.asarray(two[0, -1])).max() < TOL
+    assert np.abs(np.asarray(c1.k) - np.asarray(c2.k)).max() < TOL
+    assert int(c1.length) == int(c2.length) == 21
+
+
+@pytest.mark.parametrize("kernel", ["xla", "interpret"])
+def test_three_writers_one_cache_agree_with_the_whole_forward(whole, kernel):
+    """One convention for the rotary lanes across every writer and
+    reader of the cache: a fresh prefill (expanded form) writes 24
+    positions, the prefix store's ``_extend`` (einsum absorbed form) 16
+    more, then eight decode steps (single-position form) one each; every
+    logit agrees with ``forward`` over the whole sequence."""
+    _, cfg, params = whole
+    ids = jnp.asarray(np.random.RandomState(34).randint(0, 256, (1, 48)))
+    with jax.default_matmul_precision("highest"):
+        ref = np.asarray(latent_moe.forward(params, ids, cfg))[0]
+        eng = DecodeEngine(params, cfg, max_seq=256, decode_kernel=kernel)
+        store = PrefixCachingEngine(eng, capacity=2, chunk=8)
+        last, cache = eng._prefill_impl(eng.params, ids[:, :24], None)
+        got = [np.asarray(last)]
+        chunk, cache = store._extend(eng.params, cache, ids[:, 24:40])
+        got.append(np.asarray(chunk[0]))
+        for t in range(40, 48):
+            step, cache = eng._forward_cached(eng.params, ids[:, t:t + 1],
+                                              cache, None)
+            got.append(np.asarray(step[0]))
+    assert np.abs(np.concatenate(got) - ref[23:]).max() < TOL
+    assert not np.asarray(cache.k[..., cfg.cache_width:]).any()
 
 
 def test_pool_blocks_hold_the_familys_entry_and_llama_is_unchanged(whole):

@@ -10,6 +10,8 @@ them; only the depth is cut, for compile time. A compile that passes is
 not a chip run.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -38,6 +40,32 @@ EXTEND_TEMPORARIES = {"mistral-7b-l16": 0.1, "joyai-llm-flash-ep16": 0.15}
 PREFILL_TEMPORARIES = {"mistral-7b-l16": 1.0, "joyai-llm-flash-ep16": 2.0}
 
 
+# Device operations in ONE iteration of a layer loop of the compiled
+# decode segment at the cells' whole depth (the loop's body, and for the
+# latent family the body of the loop over the experts that were hit
+# inside it): every instruction the compiler schedules there but the
+# ones below, which move or name data without a launch. A budget is the
+# number reached, not looser, so that a change which grows a layer
+# back shows here; Mistral's is pinned, so that a shared function's
+# change that reaches it shows whichever way it goes.
+# Before the single-position forms (ISSUE 31) the expert layer stood at
+# 103 + 24 a tile at width 1 and 88, 96, 96, 92 + 24 at widths 2 to 16.
+NOT_OPERATIONS = {"parameter", "tuple", "get-tuple-element", "bitcast",
+                  "reshape", "constant", "copy-done"}
+LAYER_OPERATIONS = {
+    ("mistral-7b-l16", 8): (33, None),
+    ("joyai-llm-flash-ep16", 1): (67, 7),
+    ("joyai-llm-flash-ep16", 2): (61, 7),
+    ("joyai-llm-flash-ep16", 4): (65, 7),
+    ("joyai-llm-flash-ep16", 8): (65, 7),
+    ("joyai-llm-flash-ep16", 16): (60, 7),
+}
+# what the single-position forms took out of a latent layer and must
+# not come back: the up-projections' copy into a head-major layout and
+# any sort (the router's top 8 of 256, the 8 pairs by expert)
+GONE = (r"= bf16\[64,8,32,128\]\S* copy\(", r" sort\(")
+
+
 def _engine(chip, shapes, model_config, max_seq, dtype):
     """A ``decode_kernel="layer"`` engine over shapes alone, with the
     parameter tree its programs take."""
@@ -51,13 +79,15 @@ def _engine(chip, shapes, model_config, max_seq, dtype):
     return eng, jax.tree.map(lambda a: a.sds, eng.params, is_leaf=is_leaf)
 
 
-def _built(chip, name):
+def _built(chip, name, layers=LAYERS):
     if name == "gpt2-124m":
         cfg = gpt2.CONFIGS["gpt2"]
         shapes = jax.eval_shape(
             lambda: gpt2.init_params(cfg, jax.random.PRNGKey(0)))
         return _engine(chip, shapes, cfg, 1024, jnp.bfloat16)
-    config = dict(Spec().config(name), num_hidden_layers=LAYERS)
+    config = Spec().config(name)
+    config = dict(config, num_hidden_layers=layers or
+                  config["num_hidden_layers"])
     env = config["serving_env"]
     shapes = jax.eval_shape(
         lambda: resolve(config["reference"]).init(config, 0))
@@ -67,14 +97,59 @@ def _built(chip, name):
 
 @pytest.fixture(scope="module")
 def built(one_chip):
-    """name -> (engine, parameter shapes), each built once."""
+    """(name, layers) -> (engine, parameter shapes), each built once;
+    ``layers=None`` is the configuration's whole depth."""
     cache = {}
 
-    def get(name):
-        if name not in cache:
-            cache[name] = _built(one_chip, name)
-        return cache[name]
+    def get(name, layers=LAYERS):
+        if (name, layers) not in cache:
+            cache[name, layers] = _built(one_chip, name, layers)
+        return cache[name, layers]
     return get
+
+
+def _decode_segment(chip, eng, params, batch):
+    """The program a decode call of the scheduler runs, compiled, and
+    the cache it donates."""
+    shape = chip.shape
+    cache = chip.placed(jax.eval_shape(lambda: eng._fresh_cache(batch)))
+    return jax.jit(
+        eng._decode_seg_impl, donate_argnums=(2,),
+        static_argnames=("sampling", "window")).lower(
+            params, shape((batch,), jnp.int32), cache,
+            shape((batch,), jnp.int32),
+            shape((SEG_STEPS, batch, 2), jnp.uint32),
+            sampling=SamplingConfig(mode="greedy"), window=None
+    ).compile(), cache
+
+
+def _loops(text):
+    """``{body: (computation that holds the loop, its instructions)}``
+    of every ``while`` in a compiled module's text."""
+    bodies, lines, name = {}, {}, None
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .*\{$", line)
+        if head:
+            name = head.group(1)
+            lines[name] = []
+        elif line.startswith("}"):
+            name = None
+        elif name is not None and " = " in line:
+            lines[name].append(line)
+            loop = re.search(r" while\(.*body=%?([\w.\-]+)", line)
+            if loop:
+                bodies[loop.group(1)] = name
+    return {b: (holder, lines[b]) for b, holder in bodies.items()}
+
+
+def _operations(lines):
+    found = []
+    for line in lines:
+        op = re.search(r" = .*?(?:^|[\s)])([a-z][\w\-]*)\(",
+                       line.split(", metadata=")[0])
+        if op.group(1) not in NOT_OPERATIONS:
+            found.append(line.strip()[:160])
+    return found
 
 
 @pytest.mark.parametrize("name,batch", [
@@ -86,15 +161,7 @@ def test_engine_decode_segment_compiles(one_chip, built, name, batch):
     greedy steps over ``batch`` rows on the engine's own cache, which it
     donates."""
     eng, params = built(name)
-    shape = one_chip.shape
-    cache = one_chip.placed(jax.eval_shape(lambda: eng._fresh_cache(batch)))
-    compiled = jax.jit(
-        eng._decode_seg_impl, donate_argnums=(2,),
-        static_argnames=("sampling", "window")).lower(
-            params, shape((batch,), jnp.int32), cache,
-            shape((batch,), jnp.int32),
-            shape((SEG_STEPS, batch, 2), jnp.uint32),
-            sampling=SamplingConfig(mode="greedy"), window=None).compile()
+    compiled, cache = _decode_segment(one_chip, eng, params, batch)
     mem = one_chip.check(compiled)
     # the cache is updated in place: what the program holds beside its
     # arguments is activations and logits, never a second cache
@@ -103,6 +170,39 @@ def test_engine_decode_segment_compiles(one_chip, built, name, batch):
     assert mem.alias_size_in_bytes >= cache_bytes
     assert mem.temp_size_in_bytes < TEMPORARIES[name] * 1e9, (
         f"{mem.temp_size_in_bytes / 1e9:.3f} GB of temporaries")
+
+
+@pytest.mark.parametrize("name,batch", sorted(LAYER_OPERATIONS))
+def test_decode_segment_layer_operations(one_chip, built, name, batch):
+    """The census of ISSUE 31 (PERF.md 3): the decode segment at the
+    cell's WHOLE depth (at two layers the compiler unrolls the layer
+    loops into the step's body and there is nothing to count), its
+    steps' loop holding the layer loop and that, for the latent family,
+    the loop over the experts that were hit."""
+    eng, params = built(name, None)
+    compiled, _ = _decode_segment(one_chip, eng, params, batch)
+    loops = _loops(compiled.as_text())
+    steps = [b for b, (holder, _) in loops.items() if holder not in loops]
+    assert len(steps) == 1, sorted(loops)
+    layers = [b for b, (holder, _) in loops.items() if holder == steps[0]]
+    assert len(layers) == 1, sorted(loops)
+    layer = _operations(loops[layers[0]][1])
+    tiles = [_operations(lines) for holder, lines in loops.values()
+             if holder == layers[0]]
+    want_layer, want_tile = LAYER_OPERATIONS[name, batch]
+    listed = "\n".join([f"{len(layer)} operations in a layer:"] + layer)
+    if want_tile is None:           # pinned, not a budget
+        assert len(layer) == want_layer and not tiles, listed
+        return
+    assert len(layer) <= want_layer, listed
+    assert len(tiles) == 1 and len(tiles[0]) <= want_tile, "\n".join(
+        [f"{[len(t) for t in tiles]} operations a tile:"]
+        + [x for t in tiles for x in t])
+    # at 512 rows the fold's own [512, 4096] product has W_uv's shape
+    own_product = batch * eng.config.n_head == 512
+    for gone in (GONE[1:] if own_product else GONE):
+        back = [x for x in layer + tiles[0] if re.search(gone, x)]
+        assert not back, back
 
 
 @pytest.mark.parametrize("ids", [64, 128, 256])
