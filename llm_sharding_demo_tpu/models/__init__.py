@@ -17,9 +17,11 @@ def family_module(config):
     standalone. Plain GPT2Config is the only family the dense pipeline
     partitioner (parallel.partition) can stage.
     """
-    from . import gpt2, latent_moe, llama, moe
+    from . import gdn_moe, gpt2, latent_moe, llama, moe
     if isinstance(config, moe.MoEConfig):
         return moe
+    if isinstance(config, gdn_moe.GDNMoEConfig):
+        return gdn_moe
     if isinstance(config, latent_moe.LatentMoEConfig):
         return latent_moe
     if isinstance(config, llama.LlamaConfig):
@@ -30,9 +32,10 @@ def family_module(config):
 
 
 def cache_entry(config) -> tuple:
-    """``(planes, heads, width)``: what ONE position holds in ONE layer's
-    cache, as the family declares it. The dense families keep two planes
-    (keys, values) of ``n_kv_head x head_dim``; a family whose cache is
+    """``(planes, heads, width)``: what ONE position holds in ONE
+    CACHED layer, as the family declares it (``cache_layers`` says how
+    many layers those are). The dense families keep two planes (keys,
+    values) of ``n_kv_head x head_dim``; a family whose cache is
     something else (``latent_moe``: one plane of one latent vector) says
     so in its own ``cache_entry``. The paged pool, its movers, the
     prefix store and the byte accounting size themselves from this."""
@@ -40,6 +43,23 @@ def cache_entry(config) -> tuple:
     if declared is not None:
         return declared(config)
     return (2, getattr(config, "n_kv_head", config.n_head), config.head_dim)
+
+
+def cache_layers(config) -> int:
+    """How many of a model's layers cache positions: all of them unless
+    the family says otherwise (``gdn_moe``: the softmax layers, one in
+    ``full_attention_interval``; its other layers hold ``row_state``)."""
+    declared = getattr(family_module(config), "cache_layers", None)
+    return config.n_layer if declared is None else declared(config)
+
+
+def row_state(config, dtype) -> tuple:
+    """What one ROW holds beside its cached positions: ``(shape,
+    dtype)`` of each leaf of ``KVCache.state``, batch axis left out, or
+    ``()`` for the families whose every layer caches positions. The
+    state slab (``runtime.kv_pool.StateSlab``) sizes itself from this."""
+    declared = getattr(family_module(config), "row_state", None)
+    return () if declared is None else declared(config, dtype)
 
 
 def is_partitionable(config) -> bool:
@@ -67,7 +87,7 @@ def is_window_independent(config) -> bool:
     shapes (speculative verify windows, chunked prefill, prefix-cache
     continuations). MoE capacity-factor routing makes tokens compete for
     expert slots within a window, so it is window-DEPENDENT; the dense
-    families are independent, and so is ``latent_moe``, whose routing has
-    no capacity and drops no token."""
+    families are independent, and so are ``latent_moe`` and ``gdn_moe``,
+    whose routing has no capacity and drops no token."""
     from . import moe
     return not isinstance(config, moe.MoEConfig)

@@ -158,17 +158,18 @@ def swiglu(mlp: Params, m: jnp.ndarray) -> jnp.ndarray:
 
 
 def pre_norm_block(block_params: Params, h: jnp.ndarray, eps: float,
-                   mixer, ffn):
+                   mixer, ffn, norm=rms_norm):
     """One pre-norm residual block assembled from its two halves:
     ``h += mixer(norm(h))`` then ``h += ffn(norm(h))``. ``mixer(a)``
     returns ``(out, state)`` (its updated cache, whatever form that
     takes), ``ffn(m)`` returns ``out``; both read their own weights from
     a closure. Every RMSNorm family's block is this with another mixer
     or feed-forward (``models.latent_moe`` runs two kinds of layer in
-    one stack through it). Returns ``(h, state)``."""
-    out, state = mixer(rms_norm(h, block_params["ln_attn"]["scale"], eps))
+    one stack through it; ``models.gdn_moe`` brings its own ``norm``,
+    the ``(1 + w)`` form). Returns ``(h, state)``."""
+    out, state = mixer(norm(h, block_params["ln_attn"]["scale"], eps))
     h = h + out
-    return h + ffn(rms_norm(h, block_params["ln_mlp"]["scale"], eps)), state
+    return h + ffn(norm(h, block_params["ln_mlp"]["scale"], eps)), state
 
 
 def _block(block_params: Params, h: jnp.ndarray, config: LlamaConfig,

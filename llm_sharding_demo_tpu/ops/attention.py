@@ -19,7 +19,7 @@ does with ``attn_weights`` and keeping the logit-parity oracle tight.
 
 from __future__ import annotations
 
-from typing import NamedTuple, Optional, Tuple
+from typing import Any, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -34,11 +34,20 @@ class KVCache(NamedTuple):
     (the leading layer axis lets a ``lax.scan`` over stacked block params
     carry its cache slice). ``length`` is the number of valid positions
     already written, shared across layers.
+
+    ``state`` is what a family keeps per ROW beside the positions (a
+    tuple of arrays with the batch on axis 1, as ``k`` has it; ``models.
+    gdn_moe``: the linear-attention layers' matrices and convolution
+    tails) or ``None``: it has no position axis, so whoever slices,
+    rolls or windows ``k``/``v`` passes it through (``_replace``), and
+    whoever moves ROWS (a joiner's merge, a grown batch, the state slab
+    of ``runtime.kv_pool``) moves it with them.
     """
 
     k: jnp.ndarray
     v: jnp.ndarray
     length: jnp.ndarray  # scalar int32
+    state: Any = None
 
     @staticmethod
     def create(n_layer: int, batch: int, n_head: int, max_seq: int,

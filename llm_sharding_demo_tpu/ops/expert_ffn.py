@@ -1,4 +1,5 @@
-"""Sigmoid top-k routing and a dropless grouped matmul over held experts.
+"""Top-k routing (sigmoid or softmax scores) and a dropless grouped matmul
+over held experts.
 
 An expert layer here is TOLD which experts it holds: ``E`` consecutive
 ids starting at ``first`` out of the router's ``n_total``. It routes
@@ -12,6 +13,9 @@ experts of a token are the top ``k`` of ``s + b`` (``b``: a selection
 bias that moves the choice and not the weight); weights are
 ``s[chosen]``, normalised over the chosen and scaled. No capacity, no
 dropped token: a token's choice depends on that token alone.
+``route_softmax`` is the other published scoring: ``p = softmax(x W_g)``
+over all ``n_total``, the ``k`` largest, weights ``p[chosen]``
+normalised over the chosen; the same shorter form for a few tokens.
 
 The grouped matmul (``held_experts_ffn``): the (token, choice) pairs
 that landed on held experts are sorted by expert and cut into tiles of
@@ -45,6 +49,7 @@ import jax.numpy as jnp
 # weighted terms are summed in float32.
 PRECISION_CONTRACT = {
     "route": {"regime": "f32", "exact": True, "casts": ("f32",)},
+    "route_softmax": {"regime": "f32", "exact": True, "casts": ("f32",)},
     "held_experts_ffn": {"regime": "carried", "exact": True,
                          "casts": ("f32", "carried")},
 }
@@ -69,6 +74,24 @@ def route(x: jnp.ndarray, wg: jnp.ndarray, bias: jnp.ndarray, top_k: int,
     if normalise:
         w = w / (w.sum(-1, keepdims=True) + 1e-20)
     return ids.astype(jnp.int32), w * scale
+
+
+def route_softmax(x: jnp.ndarray, wg: jnp.ndarray, top_k: int,
+                  normalise: bool = True,
+                  ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """x [T, d], wg [d, n_total] -> (ids [T, k] int32, weights [T, k]
+    float32): the ``k`` largest of ``softmax(x wg)`` and their
+    probabilities, normalised over the chosen."""
+    p = jax.nn.softmax(jnp.matmul(x.astype(jnp.float32),
+                                  wg.astype(jnp.float32), precision=_HI),
+                       axis=-1)
+    if x.shape[0] * top_k <= TILE:
+        ids, w = _top_k_by_rank(p, p, top_k)
+    else:
+        w, ids = jax.lax.top_k(p, top_k)
+    if normalise:
+        w = w / w.sum(-1, keepdims=True)
+    return ids.astype(jnp.int32), w
 
 
 def _top_k_by_rank(keys: jnp.ndarray, values: jnp.ndarray, k: int):

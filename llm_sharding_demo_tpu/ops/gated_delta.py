@@ -1,0 +1,264 @@
+"""The gated delta rule: linear attention over a state a row carries.
+
+A linear-attention layer keeps, for every value head of every row, one
+matrix ``S`` (``K`` key dimensions by ``V`` value dimensions, float32)
+in place of keys and values a position. One position does
+
+    S <- exp(g) S;  d = beta (v - S^T k);  S <- S + k d^T;  o = S^T q
+
+with ``g <= 0`` (a decay) and ``0 < beta < 1`` (how much of the error
+is written back), ``q`` and ``k`` unit length (``q`` also over
+``sqrt(K)``). The state belongs to the ROW: it has no position axis, so
+nothing here is paged, rolled or windowed; what a caller carries beside
+it is the last ``width - 1`` inputs of the depthwise causal convolution
+in front of the rule (``causal_conv``'s tail).
+
+Three forms of the same sums:
+
+- ``recurrence``: the line above, position by position (``lax.scan``):
+  the definition, what the tests hold the others to, and the XLA path
+  of a single position;
+- ``chunked``: a call of several positions in chunks of ``CHUNK``,
+  counted from the call's first position (the published
+  ``torch_chunk_gated_delta_rule``). Inside a chunk, with ``G`` the
+  running sum of ``g``: ``L_ij = beta_i (k_i . k_j) e^{G_i - G_j}`` for
+  ``i > j``, ``T = (I + L)^-1`` by forward substitution, ``W = T (beta
+  e^G K)``, ``U = T (beta V)``; with the incoming ``S0``: ``V' = U - W
+  S0``, ``O = (Q e^G) S0 + ((Q K^T) e^{G_i - G_j} [i >= j]) V'``, ``S1 =
+  e^{G_C} S0 + (K e^{G_C - G})^T V'``. Every exponent is <= 0. What does
+  not depend on ``S0`` is computed for all chunks at once; one scan
+  carries the state through them. A caller whose calls start at
+  multiples of ``CHUNK`` (the prefix store's walk does, at its default
+  chunk) therefore computes the same sums whether it walks a prompt in
+  one call or in several: the grid is then absolute;
+- ``step_kernel``: one position as a Pallas kernel that streams a row's
+  state through VMEM once, a block of heads at a time (read, decay,
+  correct, write back in place, read out): 2 x ``H K V`` x 4 bytes a row
+  a layer and nothing else of size.
+
+Positions a caller masks (``valid`` false: the left pad of a prompt
+bucket, the right pad up to a whole chunk) get ``beta = 0`` and ``g =
+0``: they change nothing.
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+# Numerics contract (tools/graftcheck numerics pass): the rule runs in
+# float32 whatever the regime (the state is a running sum over the whole
+# row; the published code carries it so), at full matmul precision; the
+# convolution sums in float32 and hands on float32.
+PRECISION_CONTRACT = {
+    "recurrence": {"regime": "f32", "exact": True, "casts": ("f32",)},
+    "chunked": {"regime": "f32", "exact": True, "casts": ("f32",)},
+    "step_kernel": {"regime": "f32", "exact": True, "casts": ("f32",)},
+    "causal_conv": {"regime": "f32", "exact": True,
+                    "casts": ("f32", "carried")},
+}
+
+CHUNK = 64
+HEAD_BLOCK = 16        # value heads a grid step of the kernel streams
+_HI = jax.lax.Precision.HIGHEST
+
+
+def l2norm(x: jnp.ndarray, eps: float = 1e-6) -> jnp.ndarray:
+    x = x.astype(jnp.float32)
+    return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + eps)
+
+
+def gates(a: jnp.ndarray, b: jnp.ndarray, a_log: jnp.ndarray,
+          dt_bias: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """``a``, ``b`` [..., H] -> ``(g, beta)`` float32: ``g = -exp(A_log)
+    softplus(a + dt_bias)``, ``beta = sigmoid(b)``."""
+    g = -jnp.exp(a_log.astype(jnp.float32)) * jax.nn.softplus(
+        a.astype(jnp.float32) + dt_bias.astype(jnp.float32))
+    return g, jax.nn.sigmoid(b.astype(jnp.float32))
+
+
+def causal_conv(u: jnp.ndarray, tail: jnp.ndarray, w: jnp.ndarray,
+                ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Depthwise causal convolution with a carried tail, then SiLU.
+
+    ``u`` [B, T, C] this call's inputs, ``tail`` [B, W-1, C] the inputs
+    of the ``W - 1`` positions before it (zeros before position 0),
+    ``w`` [C, W]: ``c_t = silu(sum_j w[:, j] u_{t - (W-1) + j})``.
+    Returns ``(c [B, T, C] float32, the new tail)`` in ``tail``'s type."""
+    t, width = u.shape[1], w.shape[1]
+    full = jnp.concatenate([tail.astype(u.dtype), u], axis=1)
+    w32 = w.astype(jnp.float32)
+    c = sum(full[:, j:j + t].astype(jnp.float32) * w32[:, j]
+            for j in range(width))
+    return jax.nn.silu(c), full[:, t:].astype(tail.dtype)
+
+
+def recurrence(q, k, v, g, beta, state):
+    """The rule position by position. ``q``, ``k`` [B, H, T, K] (already
+    normalised), ``v`` [B, H, T, V], ``g``, ``beta`` [B, H, T], ``state``
+    [B, H, K, V]; all float32. Returns ``(o [B, H, T, V], state)``."""
+    def one(s, xs):
+        q_t, k_t, v_t, g_t, b_t = xs
+        s = s * jnp.exp(g_t)[..., None, None]
+        kv = jnp.einsum("bhk,bhkv->bhv", k_t, s, precision=_HI)
+        d = b_t[..., None] * (v_t - kv)
+        s = s + k_t[..., :, None] * d[..., None, :]
+        return s, jnp.einsum("bhk,bhkv->bhv", q_t, s, precision=_HI)
+
+    xs = tuple(jnp.moveaxis(x, 2, 0) for x in (q, k, v, g, beta))
+    state, o = jax.lax.scan(one, state.astype(jnp.float32), xs)
+    return jnp.moveaxis(o, 0, 2), state
+
+
+def chunked(q, k, v, g, beta, state, chunk: int = CHUNK):
+    """The rule in chunks of ``chunk`` positions from the call's first
+    (module docstring). Shapes as ``recurrence``; ``T`` is padded on the
+    right to whole chunks with positions that change nothing."""
+    b, h, t, dk = q.shape
+    dv = v.shape[-1]
+    n = -(-t // chunk)
+    extra = n * chunk - t
+    if extra:
+        def fill(x):
+            return jnp.pad(x, [(0, 0), (0, 0), (0, extra)]
+                           + [(0, 0)] * (x.ndim - 3))
+        q, k, v, g, beta = map(fill, (q, k, v, g, beta))
+
+    def cut(x):                        # [B,H,n*C,...] -> [n,B,H,C,...]
+        return jnp.moveaxis(x.reshape((b, h, n, chunk) + x.shape[3:]), 2, 0)
+
+    q, k, v, g, beta = map(cut, (q, k, v, g, beta))
+    big = jnp.cumsum(g, axis=-1)                               # G
+    i = jnp.arange(chunk)
+    seen = i[:, None] >= i[None, :]
+    decay = jnp.exp(jnp.where(seen, big[..., :, None] - big[..., None, :],
+                              -jnp.inf))
+    kb = k * beta[..., None]
+    lower = jnp.einsum("...ik,...jk->...ij", kb, k, precision=_HI) * decay
+    lower = jnp.where(i[:, None] > i[None, :], lower, 0.0)
+    rhs = jnp.concatenate([kb * jnp.exp(big)[..., None],
+                           v * beta[..., None]], axis=-1)
+    solved = jax.lax.linalg.triangular_solve(
+        lower + jnp.eye(chunk, dtype=jnp.float32), rhs, left_side=True,
+        lower=True, unit_diagonal=True)
+    w_, u_ = solved[..., :dk], solved[..., dk:]
+    qk = jnp.einsum("...ik,...jk->...ij", q, k, precision=_HI) * decay
+    qg = q * jnp.exp(big)[..., None]
+    kg = k * jnp.exp(big[..., -1:] - big)[..., None]
+    g_end = jnp.exp(big[..., -1])
+
+    def one(s, xs):
+        w_c, u_c, qk_c, qg_c, kg_c, ge_c = xs
+        vp = u_c - jnp.einsum("bhck,bhkv->bhcv", w_c, s, precision=_HI)
+        o = (jnp.einsum("bhck,bhkv->bhcv", qg_c, s, precision=_HI)
+             + jnp.einsum("bhij,bhjv->bhiv", qk_c, vp, precision=_HI))
+        s = (s * ge_c[..., None, None]
+             + jnp.einsum("bhck,bhcv->bhkv", kg_c, vp, precision=_HI))
+        return s, o
+
+    state, o = jax.lax.scan(one, state.astype(jnp.float32),
+                            (w_, u_, qk, qg, kg, g_end))
+    o = jnp.moveaxis(o, 0, 2).reshape(b, h, n * chunk, dv)
+    return o[:, :, :t], state
+
+
+# -- one position, on the chip ------------------------------------------------
+
+
+def kernel_eligible(dk: int, dv: int, heads: int) -> bool:
+    """Whether the compiled kernel takes these sizes: whole lane tiles
+    of keys and values, heads in whole blocks. (Interpreted, any size.)"""
+    hb = min(HEAD_BLOCK, heads)
+    return dk % 128 == 0 and dv % 128 == 0 and heads % hb == 0
+
+
+def _step_kernel(li_ref, qk_ref, vdb_ref, s_ref, o_ref, s_out_ref):
+    del li_ref                          # used by the index maps
+    qk = qk_ref[...]                    # [hb, 2, K]   q, k
+    vdb = vdb_ref[...]                  # [hb, 3, V]   v, exp(g), beta
+    dk = qk.shape[-1]
+    # a vector that lies along the lanes, stood up along the sublanes:
+    # the diagonal of its broadcast, summed over the lanes
+    eye = (jax.lax.broadcasted_iota(jnp.int32, (dk, dk), 0)
+           == jax.lax.broadcasted_iota(jnp.int32, (dk, dk), 1))
+
+    def column(row):                    # [hb, 1, K] -> [hb, K, 1]
+        return jnp.sum(jnp.where(eye, row, 0.0), axis=-1, keepdims=True)
+
+    q_col, k_col = column(qk[:, 0:1]), column(qk[:, 1:2])
+    v, decay, beta = vdb[:, 0:1], vdb[:, 1:2], vdb[:, 2:3]
+    s = s_ref[...] * decay                                   # [hb, K, V]
+    d = beta * (v - jnp.sum(s * k_col, axis=1, keepdims=True))
+    s = s + k_col * d
+    s_out_ref[...] = s
+    o_ref[...] = jnp.sum(s * q_col, axis=1, keepdims=True)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _step_call(qk, vdb, states, layer_idx, *, interpret: bool):
+    _, b, h, dk, dv = states.shape
+    hb = min(HEAD_BLOCK, h)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(b, h // hb),
+        in_specs=[
+            pl.BlockSpec((None, hb, 2, dk), lambda i, j, li: (i, j, 0, 0)),
+            pl.BlockSpec((None, hb, 3, dv), lambda i, j, li: (i, j, 0, 0)),
+            pl.BlockSpec((None, None, hb, dk, dv),
+                         lambda i, j, li: (li[0], i, j, 0, 0)),
+        ],
+        out_specs=[
+            pl.BlockSpec((None, hb, 1, dv), lambda i, j, li: (i, j, 0, 0)),
+            pl.BlockSpec((None, None, hb, dk, dv),
+                         lambda i, j, li: (li[0], i, j, 0, 0)),
+        ],
+    )
+    return pl.pallas_call(
+        _step_kernel,
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct((b, h, 1, dv), jnp.float32),
+                   jax.ShapeDtypeStruct(states.shape, states.dtype)],
+        # inputs with the scalar operand: li=0, qk=1, vdb=2, states=3
+        input_output_aliases={3: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+            vmem_limit_bytes=64 * 1024 * 1024),
+        interpret=interpret,
+        name="gdn_state_update",
+    )(jnp.asarray(layer_idx, jnp.int32).reshape(1), qk, vdb, states)
+
+
+def step_kernel(q, k, v, g, beta, states, layer_idx,
+                interpret: bool = False):
+    """One position of every row through the kernel. ``q``, ``k``
+    [B, H, K], ``v`` [B, H, V], ``g``, ``beta`` [B, H] (float32);
+    ``states`` the WHOLE ``[layers, B, H, K, V]`` float32 stack, of
+    which layer ``layer_idx`` is read and written in place (the input
+    aliases the output: treat the passed buffer as consumed). Returns
+    ``(o [B, H, V], states)``."""
+    dv = v.shape[-1]
+    qk = jnp.stack([q, k], axis=2).astype(jnp.float32)
+    vdb = jnp.stack([v.astype(jnp.float32),
+                     jnp.broadcast_to(jnp.exp(g)[..., None], v.shape),
+                     jnp.broadcast_to(beta[..., None], v.shape)], axis=2)
+    o, states = _step_call(qk, vdb, states, layer_idx, interpret=interpret)
+    return o.reshape(o.shape[0], o.shape[1], dv), states
+
+
+def step(q, k, v, g, beta, states, layer_idx,
+         kernel: Optional[str] = None):
+    """One position, by the kernel (``kernel``: ``"device"`` or
+    ``"interpret"``) or by the recurrence on the layer's slice."""
+    if kernel is not None:
+        return step_kernel(q, k, v, g, beta, states, layer_idx,
+                           interpret=kernel == "interpret")
+    s = jax.lax.dynamic_index_in_dim(states, layer_idx, 0, keepdims=False)
+    o, s = recurrence(q[:, :, None], k[:, :, None], v[:, :, None],
+                      g[:, :, None], beta[:, :, None], s)
+    return o[:, :, 0], jax.lax.dynamic_update_index_in_dim(
+        states, s.astype(states.dtype), layer_idx, 0)

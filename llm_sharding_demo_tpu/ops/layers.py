@@ -29,6 +29,8 @@ PRECISION_CONTRACT = {
                    "casts": ("f32", "carried")},
     "rms_norm": {"regime": "carried", "exact": True,
                  "casts": ("f32", "carried")},
+    "rms_norm_offset": {"regime": "carried", "exact": True,
+                        "casts": ("f32", "carried")},
     "gelu_new": {"regime": "carried", "exact": True, "casts": ()},
     "linear": {"regime": "carried", "exact": True, "casts": ()},
 }
@@ -63,6 +65,17 @@ def rms_norm(x: jnp.ndarray, scale: jnp.ndarray,
     x32 = x.astype(jnp.float32)
     y = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
     return y.astype(x.dtype) * scale.astype(x.dtype)
+
+
+def rms_norm_offset(x: jnp.ndarray, w: jnp.ndarray,
+                    eps: float = 1e-6) -> jnp.ndarray:
+    """RMSNorm in the ``(1 + w)`` form (weights stored as an offset from
+    one, zero at initialisation): statistics AND the scale in float32,
+    one cast at the end, as the published ``Qwen3NextRMSNorm`` does:
+    ``1 + w`` in bfloat16 would round the scale to eight bits."""
+    x32 = x.astype(jnp.float32)
+    y = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
+    return (y * (1.0 + w.astype(jnp.float32))).astype(x.dtype)
 
 
 def gelu_new(x: jnp.ndarray) -> jnp.ndarray:

@@ -160,27 +160,29 @@ def scatter_kv(pool: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
 
 
 def gather_rows(pool: jnp.ndarray, tables: jnp.ndarray) -> jnp.ndarray:
-    """``gather_kv`` for a one-plane, one-head pool ``[L, NBp, 1, 1, bs,
-    width]``: a block is ``bs`` whole rows of the row-major cache, so
-    the view ``[L, B, 1, NBm*bs, width]`` is assembled by one block copy
-    per table entry, in a loop, straight into the output. (The general
-    form's take-then-transpose needs two more copies of the view as
-    temporaries, which a chip full of weights does not have.)"""
+    """``gather_kv`` for a one-plane pool ``[L, NBp, 1, H, bs, width]``
+    (a latent cache: one head; a cache that keeps ``[K | V]`` in one
+    fused row a kv head): a block is ``bs`` whole rows a head of the
+    row-major cache, so the view ``[L, B, H, NBm*bs, width]`` is
+    assembled by one block copy per table entry, in a loop, straight
+    into the output. (The general form's take-then-transpose needs two
+    more copies of the view as temporaries, which a chip full of weights
+    does not have.)"""
     b, nbm = tables.shape
-    l, _, _, _, bs, w = pool.shape
+    l, _, _, h, bs, w = pool.shape
     flat = tables.reshape(-1)
     zero = jnp.zeros((), jnp.int32)
 
     def one(i, out):
         blk = jax.lax.dynamic_slice(
             pool, (zero, flat[i], zero, zero, zero, zero),
-            (l, 1, 1, 1, bs, w))
+            (l, 1, 1, h, bs, w))
         return jax.lax.dynamic_update_slice(
-            out, blk.reshape(l, 1, 1, bs, w),
+            out, blk.reshape(l, 1, h, bs, w),
             (zero, i // nbm, zero, (i % nbm) * bs, zero))
 
     return jax.lax.fori_loop(
-        0, b * nbm, one, jnp.zeros((l, b, 1, nbm * bs, w), pool.dtype))
+        0, b * nbm, one, jnp.zeros((l, b, h, nbm * bs, w), pool.dtype))
 
 
 def scatter_rows(pool: jnp.ndarray, k: jnp.ndarray,
@@ -189,16 +191,16 @@ def scatter_rows(pool: jnp.ndarray, k: jnp.ndarray,
     in table order, so duplicate targets (the trash block) resolve as
     in ``scatter_kv``: the last write wins."""
     b, nbm = tables.shape
-    l, _, _, _, bs, w = pool.shape
+    l, _, _, h, bs, w = pool.shape
     flat = tables.reshape(-1)
     zero = jnp.zeros((), jnp.int32)
 
     def one(i, pool):
         blk = jax.lax.dynamic_slice(
             k, (zero, i // nbm, zero, (i % nbm) * bs, zero),
-            (l, 1, 1, bs, w))
+            (l, 1, h, bs, w))
         return jax.lax.dynamic_update_slice(
-            pool, blk.reshape(l, 1, 1, 1, bs, w).astype(pool.dtype),
+            pool, blk.reshape(l, 1, 1, h, bs, w).astype(pool.dtype),
             (zero, flat[i], zero, zero, zero, zero))
 
     return jax.lax.fori_loop(0, b * nbm, one, pool)

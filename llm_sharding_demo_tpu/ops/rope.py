@@ -58,6 +58,19 @@ def apply_rope(x: jnp.ndarray, cos: jnp.ndarray,
     return out.astype(x.dtype)
 
 
+def apply_rope_leading(x: jnp.ndarray, cos: jnp.ndarray,
+                       sin: jnp.ndarray) -> jnp.ndarray:
+    """``apply_rope`` on the leading ``cos.shape[-1]`` dimensions of
+    ``x`` [B, H, S, hd] alone (a config's ``partial_rotary_factor``):
+    the rotate-half pairs lie inside that leading part, the rest of a
+    head passes through unturned."""
+    r = cos.shape[-1]
+    if r == x.shape[-1]:
+        return apply_rope(x, cos, sin)
+    return jnp.concatenate([apply_rope(x[..., :r], cos, sin), x[..., r:]],
+                           axis=-1)
+
+
 def pair_angles(positions: jnp.ndarray, head_dim: int,
                 theta: float = 10000.0) -> tuple[jnp.ndarray, jnp.ndarray]:
     """``rope_angles`` for the interleaved layout (a config's

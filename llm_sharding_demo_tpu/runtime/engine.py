@@ -884,16 +884,16 @@ class DecodeEngine:
 
     def _slice_cache(self, cache, window: int):
         def cut(c: KVCache) -> KVCache:
-            return KVCache(k=c.k[..., :window, :], v=c.v[..., :window, :],
-                           length=c.length)
+            return c._replace(k=c.k[..., :window, :],
+                              v=c.v[..., :window, :])
         return [cut(c) for c in cache] if isinstance(cache, list) else cut(cache)
 
     def _merge_window(self, full, sub):
         def merge(f: KVCache, s: KVCache) -> KVCache:
             zeros = (0,) * f.k.ndim
-            return KVCache(k=jax.lax.dynamic_update_slice(f.k, s.k, zeros),
-                           v=jax.lax.dynamic_update_slice(f.v, s.v, zeros),
-                           length=s.length)
+            return s._replace(
+                k=jax.lax.dynamic_update_slice(f.k, s.k, zeros),
+                v=jax.lax.dynamic_update_slice(f.v, s.v, zeros))
         if isinstance(full, list):
             return [merge(f, s) for f, s in zip(full, sub)]
         return merge(full, sub)

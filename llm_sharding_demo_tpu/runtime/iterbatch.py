@@ -342,6 +342,10 @@ class _Slot:
     # the trash block
     blk_lo: int = 0
     blk_ids: List[int] = dataclasses.field(default_factory=list)
+    # pool mode, a family whose rows hold a state beside their
+    # positions: this row's slot in the state slab (held exactly as
+    # long as blk_ids; None otherwise)
+    state_slot: Optional[int] = None
     # tokens emitted before a preemption (host copy); delivery prepends
     # them in place of first_ref
     resumed_prefix: Optional[np.ndarray] = None
@@ -374,7 +378,13 @@ def _admit_cache_impl(cache, solo, slot, roll):
         else:
             v = jax.lax.dynamic_update_slice_in_dim(
                 c.v, jnp.roll(s.v, roll, axis=-2), slot, axis=1)
-        return KVCache(k=k, v=v, length=c.length)
+        # what a row holds beside its positions has no slot axis to
+        # roll: the joiner's goes into its batch row as it is
+        state = (None if c.state is None else tuple(
+            jax.lax.dynamic_update_slice_in_dim(x, y.astype(x.dtype), slot,
+                                                axis=1)
+            for x, y in zip(c.state, s.state)))
+        return KVCache(k=k, v=v, length=c.length, state=state)
 
     if isinstance(cache, list):
         return [one(c, s) for c, s in zip(cache, solo)]
@@ -513,6 +523,16 @@ class IterBatchingEngine:
         self.spec = spec
         self.prefix = prefix
         self.pool = pool
+        # the state slab beside the pool (runtime.state_slab), for a
+        # family whose rows hold a state beside their positions
+        self._slab = getattr(pool, "slab", None)
+        from ..models import row_state
+        if (pool is not None and self._slab is None
+                and row_state(engine.config, engine.dtype)):
+            raise ValueError(
+                f"{type(engine.config).__name__}'s rows hold a state "
+                "beside their positions: build the pool with "
+                "KVBlockPool.for_engine(..., state_slots=)")
         self.queue_limit = max_batch if queue_limit is None else queue_limit
         self.replica = replica
         self.max_batch = max_batch
@@ -655,6 +675,8 @@ class IterBatchingEngine:
                    "batches_closed": self.batches_closed,
                    **self._turned, **self._moe,
                    "parked": len(self._parked)}
+        if self._slab is not None:
+            out.update(self._slab.stats())
         return out
 
     def admission_load(self, prompt_len: int,
@@ -750,18 +772,24 @@ class IterBatchingEngine:
 
     def _req_dead(self, req: _Req) -> bool:
         """Cancelled OR past its deadline — either way nobody wants the
-        work. A past-deadline request is failed typed here (idempotent:
-        the caller usually raised at its own wait expiry already) and
-        marked cancelled so every later checkpoint skips it."""
-        if req.cancelled.is_set():
-            return True
+        work. A past-deadline request is failed typed here (once: the
+        caller usually raised at its own wait expiry already, and marked
+        the request cancelled) and marked cancelled so every later
+        checkpoint skips it. The flight recorder gets the same
+        ``deadline_exceeded`` span as on the mid-decode path
+        (``_retire_finished``): on a loaded host a short budget runs out
+        before the row is admitted."""
         if req.deadline is not None and req.deadline.expired():
+            if req.trace is not None and not req.done.is_set():
+                t = time.perf_counter()
+                req.trace.add_span("deadline_exceeded", t, t,
+                                   scheduler="iter", emitted=0)
             req.fail(graftfault.DeadlineExceeded(
                 "deadline budget exhausted before the scheduler could "
                 "run this request"))
             req.cancelled.set()
             return True
-        return False
+        return req.cancelled.is_set()
 
     def _loop(self):
         if self.replica is not None:
@@ -1063,7 +1091,8 @@ class IterBatchingEngine:
                 alloc.blocks_for(s_max)
                 - (s_max - len(self._ent_ids(e))) // self.pool.block_size
                 for e in ents)
-            ok = need <= alloc.available()
+            ok = need <= alloc.available() and (
+                self._slab is None or len(ents) <= self._slab.available())
         return ok
 
     def _reserve(self, ent) -> int:
@@ -1110,10 +1139,10 @@ class IterBatchingEngine:
         a deferrable admission into a ``PoolExhausted`` request failure
         — or, raced the other way, an over-watermark grant (the
         graftsched check-then-act fixture pins both shapes). Returns
-        ``(p_lo, granted ids)`` or None to defer (blocks free up as
-        rows retire)."""
+        ``(p_lo, granted ids, state slot or None)`` or None to defer
+        (blocks free up as rows retire)."""
         if self.pool is None:
-            return 0, []
+            return 0, [], None
         alloc = self.pool.allocator
         plen_eff = len(self._ent_ids(ent))
         p_lo = (state.depth - plen_eff) // self.pool.block_size
@@ -1121,7 +1150,38 @@ class IterBatchingEngine:
         ids = alloc.admit_alloc(p_hi - p_lo)
         if ids is None:
             return None
-        return p_lo, ids
+        if self._slab is None:
+            return p_lo, ids, None
+        # a row that has blocks but no state slot does not fit
+        slot = self._take_state_slot()
+        if slot is None:
+            alloc.free(ids)
+            return None
+        return p_lo, ids, slot
+
+    def _take_state_slot(self) -> Optional[int]:
+        """One slab slot for a live row, evicting prefix entries (their
+        snapshots hold slots) oldest first if none is free."""
+        alloc = self.pool.allocator
+        slot = self._slab.alloc()
+        while slot is None and alloc.prefix_len():
+            alloc.evict_lru()
+            slot = self._slab.alloc()
+        return slot
+
+    def _free_reserved(self, reserved) -> None:
+        """Hand back what ``_reserve_blocks`` granted."""
+        if self.pool is not None and reserved is not None:
+            self.pool.allocator.free(reserved[1])
+            if reserved[2] is not None:
+                self._slab.free(reserved[2])
+
+    def _state_ids(self, state: _BatchState) -> np.ndarray:
+        """The slab slot of every lane: a live row's own, the trash slot
+        for free and ghost lanes."""
+        return np.asarray(
+            [self._slab.trash if s is None or s.state_slot is None
+             else s.state_slot for s in state.slots], dtype=np.int32)
 
     def _admit(self, state: _BatchState):
         """Drain parked rows (oldest first — they outrank the queue),
@@ -1164,8 +1224,7 @@ class IterBatchingEngine:
                 return  # blocks free up as rows retire; stays parked
             slot = self._free_slot(state)
             if slot is None:
-                if self.pool is not None:
-                    self.pool.allocator.free(reserved[1])
+                self._free_reserved(reserved)
                 self._turn_away("slot", "defers_slot")
                 return
             ent = self._pop_parked()
@@ -1202,8 +1261,7 @@ class IterBatchingEngine:
                 return  # req stays the head; retried as rows retire
             slot = self._free_slot(state)
             if slot is None:
-                if self.pool is not None:
-                    self.pool.allocator.free(reserved[1])
+                self._free_reserved(reserved)
                 self._turn_away("slot", "defers_slot")
                 return
             self._set_pending(None)
@@ -1250,7 +1308,10 @@ class IterBatchingEngine:
         def grow_cache(c):
             def one(kc: KVCache) -> KVCache:
                 v = kc.v if getattr(kc.v, "ndim", 0) <= 1 else rep(kc.v, 1)
-                return KVCache(k=rep(kc.k, 1), v=v, length=kc.length)
+                state = (None if kc.state is None
+                         else tuple(rep(x, 1) for x in kc.state))
+                return KVCache(k=rep(kc.k, 1), v=v, length=kc.length,
+                               state=state)
             if isinstance(c, list):
                 return [one(x) for x in c]
             return one(c)
@@ -1261,7 +1322,8 @@ class IterBatchingEngine:
             state.cache = grow_cache(state.cache)
             graftmem.update(state.mem_cache, state.cache)
         if state.tables is not None:
-            # ghost lanes read (and scatter) the trash block only
+            # ghost lanes read (and scatter) the trash block only (and
+            # the slab's trash slot: ``_state_ids``)
             state.tables = np.concatenate(
                 [state.tables,
                  np.full((pad_rows, self.pool.nbm), self.pool.trash,
@@ -1289,7 +1351,7 @@ class IterBatchingEngine:
                                          reserved)
         except BaseException:
             if self.pool is not None and reserved is not None:
-                self.pool.allocator.free(reserved[1])
+                self._free_reserved(reserved)
                 if state.tables is not None:
                     state.tables[slot, :] = self.pool.trash
             raise
@@ -1411,6 +1473,7 @@ class IterBatchingEngine:
         if self.pool is not None:
             state.slots[slot].blk_lo = blk_lo
             state.slots[slot].blk_ids = blk_ids
+            state.slots[slot].state_slot = reserved[2]
         with self._stats_lock:
             if resume is not None:
                 self.resumes += 1
@@ -1444,7 +1507,16 @@ class IterBatchingEngine:
                 s.blk_lo = p_lo
                 s.blk_ids = self.pool.allocator.alloc(p_hi - p_lo)
                 state.tables[i, p_lo:p_hi] = s.blk_ids
+                if self._slab is not None:
+                    s.state_slot = self._take_state_slot()
+                    if s.state_slot is None:
+                        raise RuntimeError(
+                            "no state slot for a seeded row (the slab "
+                            "is smaller than the batch)")
             self.pool.scatter(state.cache, state.tables)
+            if self._slab is not None:
+                self._slab.scatter(state.cache.state,
+                                   self._state_ids(state))
         except BaseException:
             # all-or-nothing: rows placed before the failure must not
             # leak their refs (the seed delivers the error to every
@@ -1468,11 +1540,14 @@ class IterBatchingEngine:
         and scatter of the rolled row (the paged form of
         ``_admit_cache``'s roll merge). ``_admit_one`` owns freeing the
         reservation on failure; this only resets the table row."""
-        p_lo, ids = reserved
+        p_lo, ids, state_slot = reserved
         try:
             state.tables[slot, :] = self.pool.trash
             state.tables[slot, p_lo:p_lo + len(ids)] = ids
             self.pool.scatter_row(solo, state.tables[slot], roll)
+            if state_slot is not None:
+                # the joiner's state goes into its slot with no roll
+                self._slab.scatter(solo.state, [state_slot])
         except BaseException:
             state.tables[slot, :] = self.pool.trash
             raise
@@ -1480,7 +1555,12 @@ class IterBatchingEngine:
 
     def _release_blocks(self, state: _BatchState, i: int) -> None:
         s = state.slots[i]
-        if self.pool is None or s is None or not s.blk_ids:
+        if self.pool is None or s is None:
+            return
+        if s.state_slot is not None:
+            self._slab.free(s.state_slot)
+            s.state_slot = None
+        if not s.blk_ids:
             return
         self.pool.allocator.free(s.blk_ids)
         s.blk_ids = []
@@ -1714,6 +1794,10 @@ class IterBatchingEngine:
             if not state.active():
                 return  # everyone preempted (single-row pool squeeze)
             cache = self.pool.gather(state.tables, d)
+            if self._slab is not None:
+                # the segment program carries the slab rows it runs
+                slots_j = self._state_ids(state)
+                cache = cache._replace(state=self._slab.gather(slots_j))
         else:
             cache = state.cache
         step_keys = self._segment_keys(state, n)
@@ -1725,6 +1809,9 @@ class IterBatchingEngine:
         if pooled:
             self.pool.scatter(cache, state.tables)
             self.pool.note_compiles()
+            if self._slab is not None:
+                self._slab.scatter(cache.state, slots_j)
+                self._slab.note_compiles()
         else:
             state.cache = cache
         state.token = out[:, -1]

@@ -230,7 +230,7 @@ SPILL_SCOPES = ("KVBlockPool.attach_tier",)
 GUARDED_STATE = {
     "_free": "_lock", "_ref": "_lock", "_prefix": "_lock",
     "_prefix_ref": "_lock", "_san_*": "_lock",
-    "evictions": "_lock", "cow_copies": "_lock",
+    "evictions": "_lock", "cow_copies": "_lock", "_dropped": "_lock",
     "data": "_dev_lock", "scales": "_dev_lock",
 }
 
@@ -412,6 +412,14 @@ class BlockAllocator:
         # returns True when it moved one entry down a tier. None means
         # no tier — plain eviction is the only relief valve.
         self._tier_demote: Optional[Callable[[], bool]] = None
+        # state-slab hook (runtime/state_slab.py, wired by
+        # KVBlockPool.attach_slab): the content keys of prefix entries
+        # dropped since the last flush (eviction, the capacity trim,
+        # pool pressure, a demotion), handed over OUTSIDE ``_lock`` by
+        # ``_notify_freed`` so that each entry's state snapshot goes
+        # with its blocks. None: nothing is recorded.
+        self._on_prefix_drop: Optional[Callable[[List[bytes]], None]] = None
+        self._dropped: List[bytes] = []
         if self.sanitize:
             _SAN_ALLOCATORS.add(self)
 
@@ -542,6 +550,15 @@ class BlockAllocator:
         firing inside would invert the lock order)."""
         if freed and self._on_free is not None:
             self._on_free(freed)
+        if self._on_prefix_drop is not None:
+            with self._lock:
+                keys, self._dropped = self._dropped, []
+            if keys:
+                self._on_prefix_drop(keys)
+
+    def _note_dropped_locked(self, key: bytes) -> None:
+        if self._on_prefix_drop is not None:
+            self._dropped.append(key)
 
     def _alloc_locked(self, n: int, site: str) -> Tuple[List[int],
                                                         List[int]]:
@@ -739,6 +756,7 @@ class BlockAllocator:
             ids = self._prefix.pop(key, None)
             if ids is None:
                 return False
+            self._note_dropped_locked(key)
             freed = self._deref_prefix_locked(ids)
             if self.sanitize:
                 self._san_check_locked("drop_prefix")
@@ -787,6 +805,7 @@ class BlockAllocator:
             if self._prefix.get(key) != expect:
                 return False
             del self._prefix[key]
+            self._note_dropped_locked(key)
             freed = self._deref_prefix_locked(expect)
             if self.sanitize:
                 self._san_check_locked("tier_demote")
@@ -814,6 +833,7 @@ class BlockAllocator:
 
     def _evict_lru_locked(self) -> List[int]:
         key, ids = self._prefix.popitem(last=False)
+        self._note_dropped_locked(key)
         freed = self._deref_prefix_locked(ids)
         self.evictions += 1
         REGISTRY.inc("kv_pool_evictions_total")
@@ -869,9 +889,10 @@ class KVBlockPool:
         """``planes``, ``n_kv_head`` and ``head_dim`` are the family's
         cache entry (``models.cache_entry``). A ONE-plane pool serves a
         cache whose first leaf is the only storage (``models.
-        latent_moe``); ``aux`` is the shape and dtype of that cache's
-        second, one-dimensional leaf, made anew (zeroed) by every
-        gather and dropped by every scatter.
+        latent_moe``: one latent vector a position; ``models.gdn_moe``:
+        keys and values in one fused row a kv head); ``aux`` is the
+        shape and dtype of that cache's second, one-dimensional leaf,
+        made anew (zeroed) by every gather and dropped by every scatter.
 
         ``fused``: the engine's caches use the FUSED layout of the
         Pallas decode kernels (``ops.attention.create_fused_cache`` —
@@ -951,6 +972,10 @@ class KVBlockPool:
         # attach_tier — None means cold prefix entries LRU-evict to
         # oblivion exactly as before
         self.tier = None
+        # the per-row state slab (runtime/state_slab.py) of a family
+        # whose rows hold a state beside their positions, attached via
+        # attach_slab — None for every other family
+        self.slab = None
 
         # per-instance defs (not the module-level ops directly): each
         # pool owns its jitted-program caches, so ``_cache_size()`` is
@@ -1157,8 +1182,16 @@ class KVBlockPool:
                    block_size: int = DEFAULT_KV_BLOCK_SIZE,
                    watermark: float = 0.9,
                    sanitize: Optional[bool] = None,
-                   block_dtype: Optional[str] = None) -> "KVBlockPool":
-        """Build a pool matching an engine's cache geometry. The paged
+                   block_dtype: Optional[str] = None,
+                   state_slots: int = 0) -> "KVBlockPool":
+        """Build a pool matching an engine's cache geometry: as many
+        layers as the family says cache positions (``models.
+        cache_layers``: not every layer of every model does), each of
+        the family's ``cache_entry``; and, for a family whose rows hold
+        a state beside their positions (``models.row_state``), a state
+        slab of ``state_slots`` records beside it (live rows and the
+        prefix store's snapshots: ``MAX_BATCH + PREFIX_CACHE`` as
+        served). The paged
         path drives the engine's OWN compiled programs on gathered
         views, so the engine must be the unstaged single-device one:
         no stage partitioning (per-stage cache lists), no mesh. With a
@@ -1173,18 +1206,28 @@ class KVBlockPool:
             raise NotImplementedError(
                 "KV pool paging is single-device; mesh decode (tp/ep) "
                 "keeps contiguous caches")
-        from ..models import cache_entry
+        from ..models import cache_entry, cache_layers, row_state
         cfg = engine.config
         planes, heads, width = cache_entry(cfg)
         aux = (jax.eval_shape(lambda: engine._fresh_cache(1)).v
                if planes == 1 else None)
-        return cls(cfg.n_layer, num_blocks, heads, block_size,
+        leaves = row_state(cfg, engine.dtype)
+        pool = cls(cache_layers(cfg), num_blocks, heads, block_size,
                    width, engine._cache_seq, dtype=engine.dtype,
                    watermark=watermark, sanitize=sanitize,
                    block_dtype=block_dtype,
                    fused=(engine._decode_kernel is not None
                           and planes == 2),
                    planes=planes, aux=aux)
+        if leaves:
+            if state_slots < 1:
+                raise ValueError(
+                    f"{type(cfg).__name__}'s rows hold a state beside "
+                    "their positions: give the pool state_slots (a slot "
+                    "a live row and one a stored prefix)")
+            from .state_slab import StateSlab
+            pool.attach_slab(StateSlab(leaves, state_slots))
+        return pool
 
     # -- device ops (all under _dev_lock) ------------------------------------
 
@@ -1239,7 +1282,7 @@ class KVBlockPool:
         sub = KVCache(k=cache.k[..., nb_lo * bs:, :],
                       v=(cache.v if self.fused or self.planes == 1
                          else cache.v[..., nb_lo * bs:, :]),
-                      length=cache.length)
+                      length=cache.length)   # rows' state: not the pool's
         self.scatter(sub, tables[:, nb_lo:])
 
     def scatter_row(self, cache: KVCache, table_row: np.ndarray,
@@ -1259,6 +1302,14 @@ class KVBlockPool:
             else:
                 self.data = self._scatter_row(
                     self.data, cache.k, cache.v, row_j, roll_j)
+
+    def attach_slab(self, slab) -> None:
+        """Wire a state slab (runtime/state_slab.py) beside this pool:
+        a prefix entry dropped from the registry, however (LRU eviction,
+        the capacity trim, pool pressure), takes its state snapshot
+        with it."""
+        self.slab = slab
+        self.allocator._on_prefix_drop = slab.drop
 
     def attach_tier(self, tier) -> None:
         """Wire a grafttier host tier (runtime/kv_tier.py) below this
@@ -1409,6 +1460,7 @@ class PagedKVRunner:
         self.engine = engine
         self.pool = pool
         self.prefix = prefix
+        self._row_state = None     # under _gen_lock, one generation's
         # one generation at a time: the pool buffer is donated through
         # every scatter, and the allocator's alloc/free pairs must not
         # interleave between concurrent generates. A declared DEVICE
@@ -1520,6 +1572,9 @@ class PagedKVRunner:
                 alloc.free(row_ids)
             alloc.free(frontier)
             raise
+        # what the rows hold beside their positions (``KVCache.state``,
+        # or None) has no blocks to go to: this generation carries it
+        self._row_state = cache.state
         return logits, tables, owned, shared
 
     # -- decode --------------------------------------------------------------
@@ -1542,12 +1597,15 @@ class PagedKVRunner:
         if steps > 1 and not (done is not None and done.all()):
             step_keys = _step_keys(decode_key, steps - 1)
             used = 0
+            state = self._row_state
             for n, window in segs:
-                working = self.pool.gather(tables, depth)
+                working = self.pool.gather(tables, depth)._replace(
+                    state=state)
                 out, working = eng._decode_seg(
                     run_params, token, working, pad_j,
                     step_keys[used:used + n], sampling=sampling,
                     window=window)
+                state = working.state
                 self.pool.scatter_columns(working, tables, nb_lo)
                 token = out[:, -1]
                 parts.append(np.asarray(out))

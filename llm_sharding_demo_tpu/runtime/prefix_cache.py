@@ -318,17 +318,33 @@ class PrefixCachingEngine:
         return self._pool.gather(table, depth)
 
     def _insert_pool(self, prompt: np.ndarray, m_total: int, cache,
-                     hit_ids, m_hit: int) -> None:
+                     hit_ids, m_hit: int) -> bool:
         """Pool-mode insert: the new entry SHARES the hit entry's full
         blocks and allocates fresh ones only for the new chunks (the
         frontier region is re-scattered from the walk cache into a
         fresh block — registry blocks stay immutable). A full pool
-        skips the insert instead of failing the request."""
+        skips the insert instead of failing the request.
+
+        With a state slab beside the pool the entry is its blocks AND a
+        snapshot of the row's state at this boundary (``cache.state``):
+        blocks are shared with a shallower entry, a state cannot be, so
+        the entry's cost is a slab slot on top of its new blocks. No
+        slot (live rows hold them all, even after evicting every other
+        entry) skips the insert. Returns whether an entry was made."""
         from .kv_pool import PoolExhausted
         alloc = self._pool.allocator
+        slab = self._pool.slab
         key = self._key(prompt, m_total, self.chunk)
         if alloc.has_prefix(key):
-            return
+            return False
+        slot = None
+        if slab is not None:
+            slot = slab.alloc()
+            while slot is None and alloc.prefix_len():
+                alloc.evict_lru()       # its snapshot comes back
+                slot = slab.alloc()
+            if slot is None:
+                return False
         bs = self._pool.block_size
         nb_new = alloc.blocks_for(m_total * self.chunk)
         n_share = (m_hit * self.chunk) // bs if hit_ids else 0
@@ -336,7 +352,9 @@ class PrefixCachingEngine:
         try:
             fresh = alloc.alloc(nb_new - n_share)
         except PoolExhausted:
-            return
+            if slab is not None:
+                slab.free(slot)
+            return False
         try:
             table = np.full((1, self._pool.nbm), self._pool.trash,
                             dtype=np.int32)
@@ -344,9 +362,14 @@ class PrefixCachingEngine:
             table[0, n_share:nb_new] = fresh
             self._pool.scatter_columns(cache, table, n_share)
             alloc.register_prefix(key, share + fresh)
+            if slab is not None:
+                slab.snapshot(key, slot, cache.state)
+                slot = None
         finally:
             alloc.free(fresh)  # entry refs (if registered) keep them;
             # on a scatter/register failure this is the leak guard
+            if slot is not None:
+                slab.free(slot)
         while alloc.prefix_len() > self.capacity:
             # capacity trim prefers the tier ladder: demote the LRU
             # entry to host RAM when a grafttier is attached, and only
@@ -355,6 +378,7 @@ class PrefixCachingEngine:
             tier = self._pool.tier
             if tier is None or not tier.demote_lru(self._pool):
                 alloc.evict_lru()
+        return True
 
     def _insert(self, prompt: np.ndarray, m_chunks: int, cache) -> None:
         """Store a COPY of ``cache`` as the state after ``m_chunks`` full
@@ -385,6 +409,15 @@ class PrefixCachingEngine:
         run_params = self._eng._run_params()
         m_hit, entry = self._lookup(prompt)
         hit_ids = None
+        slab = self._pool.slab if self._pool is not None else None
+        restored = None
+        if entry is not None and slab is not None:
+            # the entry is its blocks AND its state snapshot: one that
+            # lost the snapshot between the lookup and here is no hit
+            restored = slab.restore(self._key(prompt, m_hit, self.chunk))
+            if restored is None:
+                self._pool.allocator.free(entry)
+                m_hit, entry = 0, None
         if entry is not None:
             with self._store_lock:
                 self.hits += 1
@@ -403,6 +436,8 @@ class PrefixCachingEngine:
                 except BaseException:
                     self._pool.allocator.free(hit_ids)
                     raise
+                if restored is not None:
+                    cache = cache._replace(state=restored)
             else:
                 cache = entry
                 if self._eng.cache_counters:
@@ -432,6 +467,7 @@ class PrefixCachingEngine:
             return fn(run_params, cache, ids)
 
         strides = list(_strides(m_total - m_hit))
+        made = False
         try:
             m = m_hit
             for s in strides:
@@ -440,10 +476,11 @@ class PrefixCachingEngine:
                 m += s
             if m_total > m_hit:
                 if self._pool is not None:
-                    self._insert_pool(prompt, m_total, cache, hit_ids,
-                                      m_hit)
+                    made = self._insert_pool(prompt, m_total, cache,
+                                             hit_ids, m_hit)
                 else:
                     self._insert(prompt, m_total, cache)
+                    made = True
         finally:
             # the caller refs taken by the pool lookup must not outlive
             # the walk even when an extend step raises — a phantom ref
@@ -457,6 +494,12 @@ class PrefixCachingEngine:
             self.extend_calls += calls
             self.extend_tokens += prompt_len - m_hit * self.chunk
         tracing.annotate_span(extend_calls=calls)
+        if getattr(cache, "state", None) is not None:
+            # the depth a state snapshot gave this walk (0: none) and
+            # the snapshots it took (an entry of this family is one)
+            tracing.annotate_span(
+                state_restored=m_hit * self.chunk if entry is not None
+                else 0, state_snapshots=int(made))
         return logits, cache
 
     def prefill_state(self, prompt: np.ndarray):

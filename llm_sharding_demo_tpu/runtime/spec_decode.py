@@ -161,7 +161,14 @@ class SpecDecodeEngine:
     def __init__(self, params: Params, config: GPT2Config, max_seq: int,
                  dtype=jnp.float32, draft_len: int = 6, ngram: int = 2,
                  prefill_chunk: Optional[int] = None):
-        from ..models import is_window_independent
+        from ..models import is_window_independent, row_state
+        if row_state(config, dtype):
+            raise NotImplementedError(
+                f"{type(config).__name__}'s rows hold a state beside "
+                "their positions: a rejected draft is rewound out of a "
+                "cache by position, and a state has none to rewind to "
+                "without a snapshot a verify; it decodes without "
+                "speculation")
         if not is_window_independent(config):
             # Not an implementation gap — a semantic one: a (K+1)-token
             # verify forward must route identically to the plain engine's

@@ -355,6 +355,39 @@ def create_app(cfg: Optional[ServingConfig] = None,
         for on, why in refused:
             if on:
                 raise ValueError(f"{why} (refused for this family)")
+    from ..models import gdn_moe as _gdn
+    if isinstance(config, _gdn.GDNMoEConfig):
+        # what the linear-attention / sparse-expert family refuses, one
+        # message each: it serves through the single-device engine
+        # (solo, the iteration scheduler, the paged pool with its state
+        # slab, the prefix store) in float32 or bfloat16
+        name = type(config).__name__
+        refused = (
+            (cfg.spec_decode > 0,
+             f"SPEC_DECODE: a rejected draft cannot be rewound out of "
+             f"{name}'s per-row state (it has no position axis) without "
+             "a snapshot a verify; serve it without speculation"),
+            (cfg.kv_pool_dtype,
+             f"KV_POOL_DTYPE={cfg.kv_pool_dtype}: {name}'s pool is fused "
+             "with counters in its second leaf and its rows' state is "
+             "float32 by contract; the quantized movers have not been "
+             "fitted to it"),
+            (cfg.kv_host_blocks > 0,
+             f"KV_HOST_BLOCKS: a demoted entry of {name} would need its "
+             "state snapshot demoted with its blocks; the host tier "
+             "moves blocks only"),
+            (cfg.pp_decode or cfg.tp_decode or cfg.ep_decode,
+             f"PP/TP/EP_DECODE: no multi-chip decoder stages or shards "
+             f"{name} (periods of unlike layers, a state slab beside "
+             "the pool, experts indexed in place); it serves on one "
+             "chip, told which experts it holds"),
+            (cfg.inference_dtype == "int8",
+             f"INFERENCE_DTYPE=int8: {name} indexes its experts' plain "
+             "weight stacks; it serves float32 or bfloat16"),
+        )
+        for on, why in refused:
+            if on:
+                raise ValueError(f"{why} (refused for this family)")
     if cfg.ep_decode:
         if not (cfg.shard_role == "coordinator" and cfg.dispatch == "local"):
             raise ValueError("EP_DECODE applies to the coordinator's local "
@@ -702,7 +735,11 @@ def create_app(cfg: Optional[ServingConfig] = None,
                     else runner,
                     num_blocks=cfg.kv_pool_blocks,
                     block_size=cfg.kv_block_size,
-                    block_dtype=cfg.kv_pool_dtype or None)
+                    block_dtype=cfg.kv_pool_dtype or None,
+                    # a family whose rows hold a state beside their
+                    # positions: a slab slot a live row and one a
+                    # stored prefix (ignored by every other family)
+                    state_slots=cfg.max_batch + cfg.prefix_cache)
         elif kv_pool is not None:
             raise ValueError("kv_pool injected but KV_POOL_BLOCKS=0 — "
                              "a silently unused pool would misreport "

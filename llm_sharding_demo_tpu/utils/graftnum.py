@@ -107,6 +107,27 @@ TOLERANCE_POLICY = {
     "kv.fp8": {"logit_mse": 3e-5, "top1_agreement": 0.90},
 }
 
+# Equivalence budgets the oracle above does not measure: paths whose two
+# sides are BOTH the exact engine at one precision, but which sum in
+# another order, so that what other families pin byte for byte holds
+# here to a bound. Pinned by the named tests, not by ``oracle_rows``.
+EQUIVALENCE_BUDGETS = {
+    # a preempted row of a family with per-row state (models.gdn_moe):
+    # resume-by-recompute rebuilds the linear-attention state through
+    # the CHUNKED delta rule over prompt + emitted tokens (and on a
+    # bucket's grid, not the uninterrupted prompt's), where the
+    # uninterrupted row reached it through the one-position RECURRENCE:
+    # the same sums in another order. In float32 the resumed row's
+    # logits lie within ``logit_abs`` of the uninterrupted row's, for
+    # logits whose spread is about 1 (measured 4e-6 on the test sizes;
+    # bfloat16 arithmetic anywhere moves them by 1e-2). Every served
+    # token stays the float32 reference's choice or within that noise
+    # of it (tests/test_gdn_moe.py). The dense families and latent_moe
+    # keep their byte pins: their recomputed cache entries are
+    # per-position projections, equal bit for bit.
+    "resume.row_state": {"logit_abs": 5e-5},
+}
+
 
 class GraftnumError(Exception):
     """Typed numerics-contract violation.

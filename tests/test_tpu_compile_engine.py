@@ -4,7 +4,7 @@ decode kernel the cells run (``decode_kernel="layer"`` -> ``"device"``).
 The twin of ``tests/test_tpu_compile.py`` one level up: not a kernel
 alone but the engine's decode segment and the prefix store's ``_extend``
 around it, from shapes, for the described chip (``tests/conftest.py``'s
-``one_chip``). The two benchmark configurations keep their published
+``one_chip``). The benchmark's configurations keep their published
 widths, read from ``benchmark/configs/*.json`` the way the harness reads
 them; only the depth is cut, for compile time. A compile that passes is
 not a chip run.
@@ -24,20 +24,26 @@ from llm_sharding_demo_tpu.runtime.engine import DecodeEngine, SamplingConfig
 from llm_sharding_demo_tpu.runtime.prefix_cache import PrefixCachingEngine
 
 # the depth cut: two layers of mistral-7b-l16; one dense and one expert
-# layer (the 16 experts this chip holds) of joyai-llm-flash-ep16
+# layer (the 16 experts this chip holds) of joyai-llm-flash-ep16; one
+# period of qwen3-next-80b-ep32 (three linear-attention layers and the
+# softmax one: its depth comes in whole periods)
 LAYERS = 2
+DEPTH = {"qwen3-next-80b-ep32": 4}
+GDN = "qwen3-next-80b-ep32"
 # the decode widths the iteration scheduler compiles (PERF.md 5)
 WIDTHS = (1, 2, 4, 8, 16)
 SEG_STEPS = 32
 # GB a decode segment may hold beside its arguments and results (the
 # compiler's own report here: 0.0004, 0.069 and 0.042 at 16 rows)
 TEMPORARIES = {"gpt2-124m": 0.01, "mistral-7b-l16": 0.1,
-               "joyai-llm-flash-ep16": 0.06}
+               "joyai-llm-flash-ep16": 0.06, GDN: 0.25}
 # the same for the store's widest stride, 256 ids (0.068 and 0.113: the
 # figure PR 28's builder read by hand, PERF.md 6) and for a seed's
 # longest prompt (0.83 at 1,536 ids and 1.70 at 2,560)
-EXTEND_TEMPORARIES = {"mistral-7b-l16": 0.1, "joyai-llm-flash-ep16": 0.15}
-PREFILL_TEMPORARIES = {"mistral-7b-l16": 1.0, "joyai-llm-flash-ep16": 2.0}
+EXTEND_TEMPORARIES = {"mistral-7b-l16": 0.1, "joyai-llm-flash-ep16": 0.15,
+                      GDN: 0.3}
+PREFILL_TEMPORARIES = {"mistral-7b-l16": 1.0, "joyai-llm-flash-ep16": 2.0,
+                       GDN: 2.0}
 
 
 # Device operations in ONE iteration of a layer loop of the compiled
@@ -86,8 +92,9 @@ def _built(chip, name, layers=LAYERS):
             lambda: gpt2.init_params(cfg, jax.random.PRNGKey(0)))
         return _engine(chip, shapes, cfg, 1024, jnp.bfloat16)
     config = Spec().config(name)
-    config = dict(config, num_hidden_layers=layers or
-                  config["num_hidden_layers"])
+    config = dict(config, num_hidden_layers=(DEPTH.get(name, layers)
+                                             if layers else
+                                             config["num_hidden_layers"]))
     env = config["serving_env"]
     shapes = jax.eval_shape(
         lambda: resolve(config["reference"]).init(config, 0))
@@ -155,7 +162,8 @@ def _operations(lines):
 @pytest.mark.parametrize("name,batch", [
     ("gpt2-124m", 8),
     *[("mistral-7b-l16", b) for b in WIDTHS],
-    *[("joyai-llm-flash-ep16", b) for b in WIDTHS]])
+    *[("joyai-llm-flash-ep16", b) for b in WIDTHS],
+    *[(GDN, b) for b in WIDTHS]])
 def test_engine_decode_segment_compiles(one_chip, built, name, batch):
     """The program a decode call of the scheduler runs: ``SEG_STEPS``
     greedy steps over ``batch`` rows on the engine's own cache, which it
@@ -205,8 +213,51 @@ def test_decode_segment_layer_operations(one_chip, built, name, batch):
         assert not back, back
 
 
+# The same census for the linear-attention / expert family, whose layer
+# loop runs over PERIODS: one iteration is three linear-attention layers
+# (two projections, the convolution, the state kernel, the gated norm,
+# the output projection) and one gated softmax layer, each with its
+# router, shared expert and loop over the held experts that were hit:
+# (operations in a period outside those loops, in one expert loop's
+# body), the numbers reached (ISSUE 35): 74 and 79 a layer beside the
+# latent layer's 67 and 60. At 16 rows the 160 (row, choice) pairs are
+# more than a tile, so routing and the experts take the general forms
+# (a sort, 29 operations a tile); up to 12 rows the single-position
+# ones (7 a tile, no sort).
+PERIOD_OPERATIONS = {
+    (GDN, 1): (297, 7),
+    (GDN, 16): (314, 29),
+}
+
+
+@pytest.mark.parametrize("name,batch", sorted(PERIOD_OPERATIONS))
+def test_decode_segment_period_operations(one_chip, built, name, batch):
+    eng, params = built(name, None)
+    compiled, _ = _decode_segment(one_chip, eng, params, batch)
+    loops = _loops(compiled.as_text())
+    steps = [b for b, (holder, _) in loops.items() if holder not in loops]
+    assert len(steps) == 1, sorted(loops)
+    periods = [b for b, (holder, _) in loops.items() if holder == steps[0]]
+    assert len(periods) == 1, sorted(loops)
+    period = _operations(loops[periods[0]][1])
+    tiles = [_operations(lines) for holder, lines in loops.values()
+             if holder == periods[0]]
+    want_period, want_tile = PERIOD_OPERATIONS[name, batch]
+    kernels = [x for x in period if "custom-call" in x]
+    # three state kernels and one two-plane decode kernel a period
+    assert len(kernels) == 4, kernels
+    assert len(period) <= want_period, "\n".join(
+        [f"{len(period)} operations in a period:"] + period)
+    # one loop over the experts that were hit a layer
+    assert len(tiles) == 4 and max(map(len, tiles)) <= want_tile, "\n".join(
+        [f"{[len(t) for t in tiles]} operations a tile:"]
+        + [x for t in tiles for x in t])
+    assert not [x for x in period if re.search(r" sort\(", x)] or batch > 12
+
+
 @pytest.mark.parametrize("ids", [64, 128, 256])
-@pytest.mark.parametrize("name", ["mistral-7b-l16", "joyai-llm-flash-ep16"])
+@pytest.mark.parametrize("name", ["mistral-7b-l16", "joyai-llm-flash-ep16",
+                                  GDN])
 def test_prefix_store_extend_compiles(one_chip, built, name, ids):
     """The store's ``_extend`` at the strides a walk takes (one, two and
     four 64-token chunks; PERF.md 6, PR 28): a multi-token step over the
@@ -223,7 +274,8 @@ def test_prefix_store_extend_compiles(one_chip, built, name, ids):
 
 
 @pytest.mark.parametrize("workload", ["mistral-7b-l16.chat",
-                                      "joyai-llm-flash-ep16.assist"])
+                                      "joyai-llm-flash-ep16.assist",
+                                      GDN + ".threads"])
 def test_engine_prefill_compiles_at_the_longest_prompt(one_chip, built,
                                                        workload):
     """A seed's whole prompt in one call, at the longest the cell's

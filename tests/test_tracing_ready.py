@@ -187,8 +187,10 @@ def _wait_span(tr):
 
 def test_a_prompt_longer_than_the_live_depth_waits_closed(sched):
     before = sched.stats()
+    # 150 tokens: the first row is still live when a loaded machine gets
+    # round to the second request's thread
     a, b = _drive(sched, [
-        (_prompt(5), 60, None, {}),
+        (_prompt(5), 150, None, {}),
         (_prompt(70, 3), 4, _after(sched, before["segments"], 1), {})])
     after = sched.stats()
     assert after["closes_depth"] == before["closes_depth"] + 1
