@@ -22,12 +22,27 @@ Three forms of the same sums:
   counted from the call's first position (the published
   ``torch_chunk_gated_delta_rule``). Inside a chunk, with ``G`` the
   running sum of ``g``: ``L_ij = beta_i (k_i . k_j) e^{G_i - G_j}`` for
-  ``i > j``, ``T = (I + L)^-1`` by forward substitution, ``W = T (beta
-  e^G K)``, ``U = T (beta V)``; with the incoming ``S0``: ``V' = U - W
-  S0``, ``O = (Q e^G) S0 + ((Q K^T) e^{G_i - G_j} [i >= j]) V'``, ``S1 =
-  e^{G_C} S0 + (K e^{G_C - G})^T V'``. Every exponent is <= 0. What does
-  not depend on ``S0`` is computed for all chunks at once; one scan
-  carries the state through them. A caller whose calls start at
+  ``i > j``, ``T = (I + L)^-1``, ``[W | U] = T [beta e^G K | beta V]``
+  in one matmul; with the incoming ``S0``: ``V' = U - W S0``, ``O = (Q
+  e^G) S0 + ((Q K^T) e^{G_i - G_j} [i >= j]) V'``, ``S1 = e^{G_C} S0 +
+  (K e^{G_C - G})^T V'``. Every exponent is <= 0. What does not depend
+  on ``S0`` is computed for all chunks at once; one scan carries the
+  state through them. ``T`` is built by ten whole-matrix matmuls for
+  all chunks and heads together (``unit_lower_solve``), not by forward
+  substitution, which the chip runs a row of the chunk at a time: on
+  diagonal blocks of two rows ``T = I - L`` exactly, and two blocks
+  ``T11``, ``T22`` with the ``L21`` between them make the block of twice
+  the size, ``T21 = -T22 L21 T11``, five times over (as ``T <- T - T
+  L' T`` with ``L'`` all the ``L21`` of a level; the levels are one
+  scan over their masks, because unrolled they made every prefill and
+  store program a tenth larger and a warm set-up 8% longer). The
+  shorter product
+  ``(I + N)(I + N^2)...(I + N^32)``, ``N = -L``, is NOT used: where
+  keys are nearly parallel and ``beta`` is near 1, ``L`` is near 1
+  under the whole diagonal and ``N^32`` holds binomials near 1e18, which
+  cancel to NaN in float32; the merges never form a power of ``L`` and
+  stay as close to the recurrence there as the serial solve does
+  (``tests/test_gdn_moe.py``). A caller whose calls start at
   multiples of ``CHUNK`` (the prefix store's walk does, at its default
   chunk) therefore computes the same sums whether it walks a prompt in
   one call or in several: the grid is then absolute;
@@ -48,6 +63,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -115,6 +131,32 @@ def recurrence(q, k, v, g, beta, state):
     return jnp.moveaxis(o, 0, 2), state
 
 
+def unit_lower_solve(lower: jnp.ndarray, rhs: jnp.ndarray) -> jnp.ndarray:
+    """``(I + L)^-1 R`` for strictly lower ``L`` [..., C, C] and ``R``
+    [..., C, N], the inverse built by whole-matrix matmuls (module
+    docstring): exact on diagonal blocks of two rows, then blocks of
+    twice the size from pairs of them until one block is left, the
+    levels as one scan over their masks."""
+    c = lower.shape[-1]
+
+    def within(size):                  # [C, C]: inside a diagonal block
+        return (np.arange(c)[:, None] // size) == (np.arange(c) // size)
+
+    def mm(a, b):
+        return jnp.matmul(a, b, precision=_HI)
+
+    def merge(inv, pair):              # T21 = -T22 L21 T11, every pair
+        below = jnp.where(pair, lower, 0.0)
+        return inv - mm(mm(inv, below), inv), None
+
+    sizes = [2 ** j for j in range(1, (c - 1).bit_length())]
+    pairs = np.array([within(2 * s) & ~within(s) for s in sizes],
+                     bool).reshape(-1, c, c)
+    inv = jnp.eye(c, dtype=lower.dtype) - jnp.where(within(2), lower, 0.0)
+    inv, _ = jax.lax.scan(merge, inv, pairs)
+    return mm(inv, rhs)
+
+
 def chunked(q, k, v, g, beta, state, chunk: int = CHUNK):
     """The rule in chunks of ``chunk`` positions from the call's first
     (module docstring). Shapes as ``recurrence``; ``T`` is padded on the
@@ -143,9 +185,7 @@ def chunked(q, k, v, g, beta, state, chunk: int = CHUNK):
     lower = jnp.where(i[:, None] > i[None, :], lower, 0.0)
     rhs = jnp.concatenate([kb * jnp.exp(big)[..., None],
                            v * beta[..., None]], axis=-1)
-    solved = jax.lax.linalg.triangular_solve(
-        lower + jnp.eye(chunk, dtype=jnp.float32), rhs, left_side=True,
-        lower=True, unit_diagonal=True)
+    solved = unit_lower_solve(lower, rhs)
     w_, u_ = solved[..., :dk], solved[..., dk:]
     qk = jnp.einsum("...ik,...jk->...ij", q, k, precision=_HI) * decay
     qg = q * jnp.exp(big)[..., None]

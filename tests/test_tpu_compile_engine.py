@@ -10,6 +10,7 @@ them; only the depth is cut, for compile time. A compile that passes is
 not a chip run.
 """
 
+import functools
 import re
 
 import jax
@@ -20,6 +21,7 @@ from benchmark.harness import server
 from benchmark.harness.spec import Spec, resolve
 from benchmark.rehearse import _Abstract
 from llm_sharding_demo_tpu.models import gpt2
+from llm_sharding_demo_tpu.ops import gated_delta
 from llm_sharding_demo_tpu.runtime.engine import DecodeEngine, SamplingConfig
 from llm_sharding_demo_tpu.runtime.prefix_cache import PrefixCachingEngine
 
@@ -70,6 +72,11 @@ LAYER_OPERATIONS = {
 # not come back: the up-projections' copy into a head-major layout and
 # any sort (the router's top 8 of 256, the 8 pairs by expert)
 GONE = (r"= bf16\[64,8,32,128\]\S* copy\(", r" sort\(")
+# what the chunked delta rule's inversion by matmuls took out of a walk
+# and a prefill: ``lax.linalg.triangular_solve``, which this compiler
+# spells as the custom call ``InvertDiagBlocksLowerTriangular`` (a loop
+# over a chunk's 64 rows inside it) under the operation's name
+SERIAL_SOLVE = (r"InvertDiagBlocks", r"triangular[-_]solve")
 
 
 def _engine(chip, shapes, model_config, max_seq, dtype):
@@ -130,10 +137,9 @@ def _decode_segment(chip, eng, params, batch):
     ).compile(), cache
 
 
-def _loops(text):
-    """``{body: (computation that holds the loop, its instructions)}``
-    of every ``while`` in a compiled module's text."""
-    bodies, lines, name = {}, {}, None
+def _computations(text):
+    """``{name: its instructions}`` of a compiled module's text."""
+    lines, name = {}, None
     for line in text.splitlines():
         head = re.match(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .*\{$", line)
         if head:
@@ -143,10 +149,29 @@ def _loops(text):
             name = None
         elif name is not None and " = " in line:
             lines[name].append(line)
+    return lines
+
+
+def _loops(text):
+    """``{body: (computation that holds the loop, its instructions)}``
+    of every ``while`` in a compiled module's text."""
+    lines, found = _computations(text), {}
+    for holder, held in lines.items():
+        for line in held:
             loop = re.search(r" while\(.*body=%?([\w.\-]+)", line)
             if loop:
-                bodies[loop.group(1)] = name
-    return {b: (holder, lines[b]) for b, holder in bodies.items()}
+                found[loop.group(1)] = holder, lines[loop.group(1)]
+    return found
+
+
+def _trip_bounds(text):
+    """The whole-number constants that the conditions of a compiled
+    module's ``while`` loops compare with: a counted loop's trips (the
+    chunk scan of a 2,560-id prefill reads 40)."""
+    lines = _computations(text)
+    return {int(n)
+            for cond in re.findall(r" while\(.*?condition=%?([\w.\-]+)", text)
+            for n in re.findall(r"constant\((\d+)\)", "\n".join(lines[cond]))}
 
 
 def _operations(lines):
@@ -255,20 +280,44 @@ def test_decode_segment_period_operations(one_chip, built, name, batch):
     assert not [x for x in period if re.search(r" sort\(", x)] or batch > 12
 
 
+@pytest.fixture(scope="module")
+def walks(one_chip, built):
+    """(name, ids) -> the store's compiled ``_extend``, each once."""
+    @functools.cache
+    def get(name, ids):
+        eng, params = built(name)
+        store = PrefixCachingEngine(eng, capacity=8, chunk=64)
+        row = one_chip.placed(jax.eval_shape(lambda: eng._fresh_cache(1)))
+        return store._extend.lower(
+            params, row, one_chip.shape((1, ids), jnp.int32)).compile()
+    return get
+
+
+@pytest.fixture(scope="module")
+def prefills(one_chip, built):
+    """workload -> (its configuration's name, the compiled prefill of
+    the longest prompt its traffic draws), each once."""
+    @functools.cache
+    def get(workload):
+        spec = Spec()
+        entry = spec.workload(workload)
+        longest = spec.traffic(entry["traffic"])["prompt"]["max"]
+        eng, params = built(entry["config"])
+        return entry["config"], jax.jit(eng._prefill_impl).lower(
+            params, one_chip.shape((1, longest), jnp.int32),
+            one_chip.shape((1,), jnp.int32)).compile()
+    return get
+
+
 @pytest.mark.parametrize("ids", [64, 128, 256])
 @pytest.mark.parametrize("name", ["mistral-7b-l16", "joyai-llm-flash-ep16",
                                   GDN])
-def test_prefix_store_extend_compiles(one_chip, built, name, ids):
+def test_prefix_store_extend_compiles(walks, name, ids):
     """The store's ``_extend`` at the strides a walk takes (one, two and
     four 64-token chunks; PERF.md 6, PR 28): a multi-token step over the
     kernel engine's cache, which for the latent family is the expanded
     attention form and the grouped matmul over the held experts."""
-    eng, params = built(name)
-    store = PrefixCachingEngine(eng, capacity=8, chunk=64)
-    cache = one_chip.placed(jax.eval_shape(lambda: eng._fresh_cache(1)))
-    compiled = store._extend.lower(
-        params, cache, one_chip.shape((1, ids), jnp.int32)).compile()
-    mem = compiled.memory_analysis()
+    mem = walks(name, ids).memory_analysis()
     assert mem.temp_size_in_bytes < EXTEND_TEMPORARIES[name] * 1e9, (
         f"{mem.temp_size_in_bytes / 1e9:.3f} GB of temporaries")
 
@@ -276,17 +325,23 @@ def test_prefix_store_extend_compiles(one_chip, built, name, ids):
 @pytest.mark.parametrize("workload", ["mistral-7b-l16.chat",
                                       "joyai-llm-flash-ep16.assist",
                                       GDN + ".threads"])
-def test_engine_prefill_compiles_at_the_longest_prompt(one_chip, built,
-                                                       workload):
+def test_engine_prefill_compiles_at_the_longest_prompt(prefills, workload):
     """A seed's whole prompt in one call, at the longest the cell's
     traffic draws (what ``benchmark/rehearse.py`` compiles by hand)."""
-    spec = Spec()
-    entry = spec.workload(workload)
-    longest = spec.traffic(entry["traffic"])["prompt"]["max"]
-    eng, params = built(entry["config"])
-    compiled = jax.jit(eng._prefill_impl).lower(
-        params, one_chip.shape((1, longest), jnp.int32),
-        one_chip.shape((1,), jnp.int32)).compile()
+    name, compiled = prefills(workload)
     mem = compiled.memory_analysis()
-    assert mem.temp_size_in_bytes < PREFILL_TEMPORARIES[entry["config"]] * 1e9, (
+    assert mem.temp_size_in_bytes < PREFILL_TEMPORARIES[name] * 1e9, (
         f"{mem.temp_size_in_bytes / 1e9:.3f} GB of temporaries")
+
+
+@pytest.mark.parametrize("ids", [64, 128, 256, "longest prompt"])
+def test_the_chunked_rule_holds_no_serial_solve(walks, prefills, ids):
+    """A walk's strides and a seed's prefill of the linear-attention
+    family invert a chunk's triangle by matmuls (ISSUE 36): no serial
+    solve and no loop over a chunk's rows."""
+    compiled = (prefills(GDN + ".threads")[1] if isinstance(ids, str)
+                else walks(GDN, ids))
+    text = compiled.as_text()
+    for gone in SERIAL_SOLVE:
+        assert not re.search(gone, text), gone
+    assert gated_delta.CHUNK not in _trip_bounds(text)
