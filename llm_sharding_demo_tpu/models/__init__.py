@@ -17,9 +17,11 @@ def family_module(config):
     standalone. Plain GPT2Config is the only family the dense pipeline
     partitioner (parallel.partition) can stage.
     """
-    from . import gdn_moe, gpt2, latent_moe, llama, moe
+    from . import gdn_moe, gpt2, latent_moe, llama, moe, window_moe
     if isinstance(config, moe.MoEConfig):
         return moe
+    if isinstance(config, window_moe.WindowMoEConfig):
+        return window_moe
     if isinstance(config, gdn_moe.GDNMoEConfig):
         return gdn_moe
     if isinstance(config, latent_moe.LatentMoEConfig):
@@ -48,7 +50,8 @@ def cache_entry(config) -> tuple:
 def cache_layers(config) -> int:
     """How many of a model's layers cache positions: all of them unless
     the family says otherwise (``gdn_moe``: the softmax layers, one in
-    ``full_attention_interval``; its other layers hold ``row_state``)."""
+    ``full_attention_interval``; ``window_moe``: the full-attention
+    layers, likewise; their other layers hold ``row_state``)."""
     declared = getattr(family_module(config), "cache_layers", None)
     return config.n_layer if declared is None else declared(config)
 
@@ -56,8 +59,11 @@ def cache_layers(config) -> int:
 def row_state(config, dtype) -> tuple:
     """What one ROW holds beside its cached positions: ``(shape,
     dtype)`` of each leaf of ``KVCache.state``, batch axis left out, or
-    ``()`` for the families whose every layer caches positions. The
-    state slab (``runtime.kv_pool.StateSlab``) sizes itself from this."""
+    ``()`` for the families whose every layer caches positions
+    (``gdn_moe``: the linear-attention matrices and convolution tails;
+    ``window_moe``: the sliding layers' rings of their last window of
+    positions). The state slab (``runtime.state_slab.StateSlab``) sizes
+    itself from this."""
     declared = getattr(family_module(config), "row_state", None)
     return () if declared is None else declared(config, dtype)
 
@@ -87,7 +93,7 @@ def is_window_independent(config) -> bool:
     shapes (speculative verify windows, chunked prefill, prefix-cache
     continuations). MoE capacity-factor routing makes tokens compete for
     expert slots within a window, so it is window-DEPENDENT; the dense
-    families are independent, and so are ``latent_moe`` and ``gdn_moe``,
-    whose routing has no capacity and drops no token."""
+    families are independent, and so are ``latent_moe``, ``gdn_moe`` and
+    ``window_moe``, whose routing has no capacity and drops no token."""
     from . import moe
     return not isinstance(config, moe.MoEConfig)

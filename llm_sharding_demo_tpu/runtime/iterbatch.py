@@ -42,7 +42,8 @@ of paying ``max_batch`` x ghost-row FLOPs. Ghost rows (width minus live
 rows) replicate a real row; per-row independence keeps them inert.
 
 Compiled-program inventory (bounded): the engine's prefill programs
-(prompt-bucketed), ONE decode-segment program per (window bucket,
+(prompt-bucketed: multiples of ``prompt_bucket``, or the ladder a family
+of long prompts declares), ONE decode-segment program per (window bucket,
 sampling, power-of-two batch width up to ``max_batch``) and segment
 length (plus cache-tail remainders, quantized by construction), one
 admit program per width, and one tiny grow program per adjacent width
@@ -226,7 +227,7 @@ GUARDED_STATE = {
     "grows": "_stats_lock", "preemptions": "_stats_lock",
     "resumes": "_stats_lock", "fault_parks": "_stats_lock",
     "batches_closed": "_stats_lock", "_turned": "_stats_lock",
-    "_moe": "_stats_lock",
+    "_moe": "_stats_lock", "_window": "_stats_lock",
     "_parked": "_stats_lock",
     "_pending": "_stats_lock",
     "_np": "_lock",
@@ -571,6 +572,18 @@ class IterBatchingEngine:
         self._moe = dict.fromkeys(
             [f"moe.{k}" for k in engine.cache_counters]
             + [f"moe.prefill_{k}" for k in engine.cache_counters], 0)
+        # a family whose sliding-window layers hold a window of a row
+        # and not its depth (``window_positions``; models.window_moe):
+        # what the records allocated for the live rows hold and the
+        # depths those rows have reached, as of the last scheduling
+        # decision
+        self._window: dict = {}
+        self._window_positions = getattr(engine._model, "window_positions",
+                                         None)
+        # a family that serves prompts of thousands of positions says
+        # how coarsely a lone prompt is bucketed (``prompt_bucket``;
+        # models.window_moe); the others take multiples of the argument
+        self._family_bucket = getattr(engine._model, "prompt_bucket", None)
         # (instant, reason) transitions of what holds the head of the
         # queue (worker-thread-only): every admitted request's wait is
         # cut by it. 4096 transitions span minutes of boundaries; a wait
@@ -673,7 +686,7 @@ class IterBatchingEngine:
                    "resumes": self.resumes,
                    "fault_parks": self.fault_parks,
                    "batches_closed": self.batches_closed,
-                   **self._turned, **self._moe,
+                   **self._turned, **self._moe, **self._window,
                    "parked": len(self._parked)}
         if self._slab is not None:
             out.update(self._slab.stats())
@@ -1103,10 +1116,16 @@ class IterBatchingEngine:
         return (self.spec.draft_len
                 if self._ent_req(ent).sampling.spec else 0)
 
+    def _bucketed(self, length: int) -> int:
+        """The width a lone prefill of ``length`` positions compiles at."""
+        if self._family_bucket is not None:
+            return self._family_bucket(self.engine.config, length)
+        return _round_up(length, self.prompt_bucket)
+
     def _seed_smax(self, ents: List) -> int:
         raw = max(len(self._ent_ids(e)) for e in ents)
         need = max(self._ent_need(e) for e in ents)
-        return min(_round_up(raw, self.prompt_bucket),
+        return min(self._bucketed(raw),
                    self.engine.max_seq - need - self._reserve(ents[0]))
 
     def _first_tokens(self, last_logits, sampling, keys, b):
@@ -1402,7 +1421,7 @@ class IterBatchingEngine:
                 pre = req.trace.find_all("prefill")[-1]
                 pre.labels["live"] = live
         else:
-            sp = min(_round_up(plen_eff, self.prompt_bucket), state.depth)
+            sp = min(self._bucketed(plen_eff), state.depth)
             if sp < plen_eff:  # bucket would overshoot current depth:
                 sp = plen_eff  # exact length (rare; one extra program)
             ids = np.zeros((1, sp), dtype=np.int32)
@@ -1752,6 +1771,17 @@ class IterBatchingEngine:
             kv_block_gauges("iter", state.depth * live,
                             width * self.engine._cache_seq)
         REGISTRY.gauge("queue_depth", depth, scheduler="iter")
+        if self._window_positions is not None:
+            # a live row's cache holds its prompt and all it emitted but
+            # the token in flight; its record lies in the slab, or
+            # without a pool in the batch's own cache
+            held, seen = self._window_positions(
+                state.cache.state if self._slab is None else self._slab.data,
+                [s.plen + s.emitted - 1 for s in state.slots
+                 if s is not None])
+            with self._stats_lock:
+                self._window = {"window.positions_held": held,
+                                "window.positions_seen": seen}
         # graftscope occupancy time series: the trajectory behind the
         # instantaneous gauges above, served at /debug/profile
         graftscope.sample("iter_live_rows", live)

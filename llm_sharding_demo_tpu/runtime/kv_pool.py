@@ -905,6 +905,7 @@ class KVBlockPool:
         self.fused = fused
         self.planes = planes
         self.entry_width = planes * n_kv_head * head_dim
+        self.layers = n_layer
         if planes not in (1, 2) or (planes == 1 and (fused or block_dtype)):
             raise NotImplementedError(
                 f"a pool of {planes} plane(s) with fused={fused}, "
@@ -1418,6 +1419,9 @@ class KVBlockPool:
                # values one position holds in one layer, as the family
                # declares them: 2 x n_kv_head x head_dim, or one latent
                "entry_width": self.entry_width,
+               # the layers that cache every position (models.
+               # cache_layers: not every layer of every model does)
+               "layers": self.layers,
                "graftsan": self.allocator.sanitize}
         if self.tier is not None:
             out["tier"] = self.tier.stats()

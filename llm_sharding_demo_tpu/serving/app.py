@@ -324,6 +324,14 @@ def create_app(cfg: Optional[ServingConfig] = None,
         # prefills in iter mode), and prefix+speculation composes
         # single-stream AND batched (spec-flagged rounds/batches decode
         # through the batched verify loop).
+
+    def _refuse(refused):
+        """``(asked for, why not)`` pairs of what a family refuses: the
+        first one asked for is raised, one message each."""
+        for on, why in refused:
+            if on:
+                raise ValueError(f"{why} (refused for this family)")
+
     from ..models import latent_moe as _latent
     if isinstance(config, _latent.LatentMoEConfig):
         # what the latent-attention / sparse-expert family refuses, one
@@ -352,9 +360,7 @@ def create_app(cfg: Optional[ServingConfig] = None,
              f"INFERENCE_DTYPE=int8: {name} indexes its experts' plain "
              "weight stacks; it serves float32 or bfloat16"),
         )
-        for on, why in refused:
-            if on:
-                raise ValueError(f"{why} (refused for this family)")
+        _refuse(refused)
     from ..models import gdn_moe as _gdn
     if isinstance(config, _gdn.GDNMoEConfig):
         # what the linear-attention / sparse-expert family refuses, one
@@ -385,9 +391,39 @@ def create_app(cfg: Optional[ServingConfig] = None,
              f"INFERENCE_DTYPE=int8: {name} indexes its experts' plain "
              "weight stacks; it serves float32 or bfloat16"),
         )
-        for on, why in refused:
-            if on:
-                raise ValueError(f"{why} (refused for this family)")
+        _refuse(refused)
+    from ..models import window_moe as _window
+    if isinstance(config, _window.WindowMoEConfig):
+        # what the sliding-window / sparse-expert family refuses, one
+        # message each: it serves through the single-device engine
+        # (solo, the iteration scheduler, the paged pool of its full
+        # layers with the window records in the state slab, the prefix
+        # store) in float32 or bfloat16
+        name = type(config).__name__
+        refused = (
+            (cfg.spec_decode > 0,
+             f"SPEC_DECODE: a rejected draft cannot be taken back out of "
+             f"{name}'s window records (a ring has overwritten what the "
+             "draft displaced); serve it without speculation"),
+            (cfg.kv_pool_dtype,
+             f"KV_POOL_DTYPE={cfg.kv_pool_dtype}: {name}'s pool is fused "
+             "with counters in its second leaf and its window records "
+             "carry the served type; the quantized movers have not been "
+             "fitted to either"),
+            (cfg.kv_host_blocks > 0,
+             f"KV_HOST_BLOCKS: a demoted entry of {name} would need its "
+             "window records demoted with its blocks; the host tier "
+             "moves blocks only"),
+            (cfg.pp_decode or cfg.tp_decode or cfg.ep_decode,
+             f"PP/TP/EP_DECODE: no multi-chip decoder stages or shards "
+             f"{name} (a first period unlike the others, a state slab "
+             "beside the pool, experts indexed in place); it serves on "
+             "one chip, told which experts it holds"),
+            (cfg.inference_dtype == "int8",
+             f"INFERENCE_DTYPE=int8: {name} indexes its experts' plain "
+             "weight stacks; it serves float32 or bfloat16"),
+        )
+        _refuse(refused)
     if cfg.ep_decode:
         if not (cfg.shard_role == "coordinator" and cfg.dispatch == "local"):
             raise ValueError("EP_DECODE applies to the coordinator's local "

@@ -124,7 +124,11 @@ EQUIVALENCE_BUDGETS = {
     # token stays the float32 reference's choice or within that noise
     # of it (tests/test_gdn_moe.py). The dense families and latent_moe
     # keep their byte pins: their recomputed cache entries are
-    # per-position projections, equal bit for bit.
+    # per-position projections, equal bit for bit. models.window_moe's
+    # window records take the same budget: a resumed row's rings are
+    # rebuilt by the banded call (blocks of a window) where the
+    # uninterrupted row stepped over its ring, and every layer above
+    # the first sees that difference (tests/test_window_moe.py).
     "resume.row_state": {"logit_abs": 5e-5},
 }
 

@@ -28,24 +28,35 @@ from llm_sharding_demo_tpu.runtime.prefix_cache import PrefixCachingEngine
 # the depth cut: two layers of mistral-7b-l16; one dense and one expert
 # layer (the 16 experts this chip holds) of joyai-llm-flash-ep16; one
 # period of qwen3-next-80b-ep32 (three linear-attention layers and the
-# softmax one: its depth comes in whole periods)
+# softmax one: its depth comes in whole periods); the first period of
+# k-exaone-236b-ep8 (the dense layer and two expert layers with a
+# window each, then the full-attention one: whole periods too)
 LAYERS = 2
-DEPTH = {"qwen3-next-80b-ep32": 4}
+DEPTH = {"qwen3-next-80b-ep32": 4, "k-exaone-236b-ep8": 4}
 GDN = "qwen3-next-80b-ep32"
+SWA = "k-exaone-236b-ep8"
 # the decode widths the iteration scheduler compiles (PERF.md 5)
 WIDTHS = (1, 2, 4, 8, 16)
 SEG_STEPS = 32
 # GB a decode segment may hold beside its arguments and results (the
-# compiler's own report here: 0.0004, 0.069 and 0.042 at 16 rows)
+# compiler's own report here: 0.0004, 0.069 and 0.042 at 16 rows; the
+# window family's 0.50 a period from two rows on is the compiler's own
+# copies of the four query projections, which it keeps in fast memory
+# over a call's 32 steps: 0.01 at one row)
 TEMPORARIES = {"gpt2-124m": 0.01, "mistral-7b-l16": 0.1,
-               "joyai-llm-flash-ep16": 0.06, GDN: 0.25}
+               "joyai-llm-flash-ep16": 0.06, GDN: 0.25, SWA: 0.6}
 # the same for the store's widest stride, 256 ids (0.068 and 0.113: the
 # figure PR 28's builder read by hand, PERF.md 6) and for a seed's
-# longest prompt (0.83 at 1,536 ids and 1.70 at 2,560)
+# longest prompt (0.83 at 1,536 ids and 1.70 at 2,560). The window
+# family's cache is 8,704 positions deep and its longest prompt 8,192:
+# a stride's 0.30 is one block of 128 queries' float32 scores over the
+# cache in a full layer (0.29), a prefill's 1.68 the dense layer's
+# [8192, 18432] products, every position's logits (0.63) and a block of
+# 256 queries' scores (0.54): [64, 8192, 8192] float32 would be 17 GB
 EXTEND_TEMPORARIES = {"mistral-7b-l16": 0.1, "joyai-llm-flash-ep16": 0.15,
-                      GDN: 0.3}
+                      GDN: 0.3, SWA: 0.35}
 PREFILL_TEMPORARIES = {"mistral-7b-l16": 1.0, "joyai-llm-flash-ep16": 2.0,
-                       GDN: 2.0}
+                       GDN: 2.0, SWA: 2.0}
 
 
 # Device operations in ONE iteration of a layer loop of the compiled
@@ -188,7 +199,8 @@ def _operations(lines):
     ("gpt2-124m", 8),
     *[("mistral-7b-l16", b) for b in WIDTHS],
     *[("joyai-llm-flash-ep16", b) for b in WIDTHS],
-    *[(GDN, b) for b in WIDTHS]])
+    *[(GDN, b) for b in WIDTHS],
+    *[(SWA, b) for b in WIDTHS]])
 def test_engine_decode_segment_compiles(one_chip, built, name, batch):
     """The program a decode call of the scheduler runs: ``SEG_STEPS``
     greedy steps over ``batch`` rows on the engine's own cache, which it
@@ -253,6 +265,18 @@ PERIOD_OPERATIONS = {
     (GDN, 1): (297, 7),
     (GDN, 16): (314, 29),
 }
+# The window / expert family at its whole depth is two periods: the
+# first on its own leaves in the step's body, the second the one
+# iteration of its loop over periods, which the compiler unrolls into
+# the step's body too. So a STEP is counted: (operations in a step
+# outside the loops over the experts that were hit, in one such loop's
+# body), the numbers reached (ISSUE 37). Eight layers, of which six read
+# a ring (a select, two dots and a softmax in XLA) and two run the
+# two-plane decode kernel; seven loops over experts.
+STEP_OPERATIONS = {
+    (SWA, 1): (610, 7),
+    (SWA, 16): (547, 7),
+}
 
 
 @pytest.mark.parametrize("name,batch", sorted(PERIOD_OPERATIONS))
@@ -278,6 +302,29 @@ def test_decode_segment_period_operations(one_chip, built, name, batch):
         [f"{[len(t) for t in tiles]} operations a tile:"]
         + [x for t in tiles for x in t])
     assert not [x for x in period if re.search(r" sort\(", x)] or batch > 12
+
+
+@pytest.mark.parametrize("name,batch", sorted(STEP_OPERATIONS))
+def test_decode_segment_step_operations(one_chip, built, name, batch):
+    eng, params = built(name, None)
+    compiled, _ = _decode_segment(one_chip, eng, params, batch)
+    loops = _loops(compiled.as_text())
+    steps = [b for b, (holder, _) in loops.items() if holder not in loops]
+    assert len(steps) == 1, sorted(loops)
+    step = _operations(loops[steps[0]][1])
+    tiles = [_operations(lines) for holder, lines in loops.values()
+             if holder == steps[0]]
+    want_step, want_tile = STEP_OPERATIONS[name, batch]
+    kernels = [x for x in loops[steps[0]][1] if "tpu_custom_call" in x]
+    # one two-plane decode kernel a full-attention layer, none for a ring
+    assert len(kernels) == eng.config.n_periods, kernels
+    assert len(step) <= want_step, "\n".join(
+        [f"{len(step)} operations in a step:"] + step)
+    # one loop over the experts that were hit an expert layer
+    assert len(tiles) == 7 and max(map(len, tiles)) <= want_tile, "\n".join(
+        [f"{[len(t) for t in tiles]} operations a tile:"]
+        + [x for t in tiles for x in t])
+    assert not [x for x in step if re.search(r" sort\(", x)]
 
 
 @pytest.fixture(scope="module")
@@ -311,7 +358,7 @@ def prefills(one_chip, built):
 
 @pytest.mark.parametrize("ids", [64, 128, 256])
 @pytest.mark.parametrize("name", ["mistral-7b-l16", "joyai-llm-flash-ep16",
-                                  GDN])
+                                  GDN, SWA])
 def test_prefix_store_extend_compiles(walks, name, ids):
     """The store's ``_extend`` at the strides a walk takes (one, two and
     four 64-token chunks; PERF.md 6, PR 28): a multi-token step over the
@@ -324,7 +371,8 @@ def test_prefix_store_extend_compiles(walks, name, ids):
 
 @pytest.mark.parametrize("workload", ["mistral-7b-l16.chat",
                                       "joyai-llm-flash-ep16.assist",
-                                      GDN + ".threads"])
+                                      GDN + ".threads",
+                                      SWA + ".shortlong"])
 def test_engine_prefill_compiles_at_the_longest_prompt(prefills, workload):
     """A seed's whole prompt in one call, at the longest the cell's
     traffic draws (what ``benchmark/rehearse.py`` compiles by hand)."""
