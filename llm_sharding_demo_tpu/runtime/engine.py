@@ -57,6 +57,11 @@ EOS_SEGMENT = 32
 # holding the jitted callable — the recompile-budget certifier
 # enumerates these, and an undeclared jit site is a lint finding (a
 # compiled-program population the budget would silently miss).
+# ``_decode_seg`` holds two forms of one body: without a ``steps``
+# operand a ``lax.scan`` whose length (``len(step_keys)``) is part of
+# the program's key; with it a counted loop whose length is an operand
+# and NOT a key — one program serves every length up to
+# ``len(step_keys)`` (the iteration scheduler's calls).
 JIT_ENTRY_POINTS = ("_prefill", "_prefill_chunked", "_decode_seg")
 
 # Observability contract (tools/graftcheck scope pass + utils/graftscope):
@@ -159,9 +164,13 @@ def _prefill_chunked_scope_key(params, chunks, pad):
     return (int(chunks.shape[1]), int(chunks.shape[0]))
 
 
-def _decode_seg_scope_key(params, token, cache, pad, step_keys, *,
-                          sampling, window):
-    return (int(token.shape[0]), int(step_keys.shape[0]), window, sampling,
+def _decode_seg_scope_key(params, token, cache, pad, step_keys, steps=None,
+                          *, sampling, window):
+    # a counted call's length is an operand and no key: its program is
+    # keyed by the longest call it serves
+    n = int(step_keys.shape[0])
+    return (int(token.shape[0]), n if steps is None else f"<={n}", window,
+            sampling,
             "per-row" if getattr(step_keys, "ndim", 2) == 3 else "one",
             pad is not None)
 
@@ -711,7 +720,7 @@ class DecodeEngine:
             key_fn=_prefill_chunked_scope_key)
         # static args: the sampling policy and the attention window (both
         # change the traced program; the step count rides the step_keys
-        # shape).
+        # shape, or the ``steps`` operand where a caller passes one).
         self._decode_seg = graftscope.instrument(
             jax.jit(self._decode_seg_impl, donate_argnums=(2,),
                     static_argnames=("sampling", "window")),
@@ -966,13 +975,24 @@ class DecodeEngine:
 
     def _decode_seg_impl(self, params: Params, token: jnp.ndarray,
                          cache, pad: Optional[jnp.ndarray],
-                         step_keys: jax.Array, *,
+                         step_keys: jax.Array,
+                         steps: Optional[jnp.ndarray] = None, *,
                          sampling: SamplingConfig,
                          window: Optional[int]):
-        """Forward ``len(step_keys)`` cached single-token steps from
-        ``token``; attention reads only the first ``window`` cache slots
-        (sliced out statically; the updated slice merges back into the
-        donated full buffer on exit). Returns ``(tokens [B, n], cache)``."""
+        """Forward cached single-token steps from ``token``; attention
+        reads only the first ``window`` cache slots (sliced out
+        statically; the updated slice merges back into the donated full
+        buffer on exit).
+
+        Without ``steps`` the call runs ``len(step_keys)`` steps as one
+        ``lax.scan`` and returns ``(tokens [B, n], cache)``: the form of
+        a caller that knows its length ahead. With ``steps``, an int32
+        scalar OPERAND, the same body runs ``steps`` times (at most
+        ``len(step_keys)``) and the call returns ``(tokens [B,
+        len(step_keys)], cache, last [B])``: columns from ``steps`` on
+        are never written and never read, ``last`` is the token of the
+        last step run. The length is then no part of the program's key:
+        one program serves every length up to ``len(step_keys)``."""
         sub = self._slice_cache(cache, window) if window else cache
         if self.cache_counters:
             # what comes back beside this segment's tokens is this
@@ -985,9 +1005,25 @@ class DecodeEngine:
             nxt = select_token(logits[:, -1], sampling, step_key)
             return (nxt, c), nxt
 
-        (_, sub), out = jax.lax.scan(body, (token, sub), step_keys)
+        if steps is None:
+            (_, sub), out = jax.lax.scan(body, (token, sub), step_keys)
+            out = out.T                                  # [n, B] -> [B, n]
+        else:
+            def counted(i, carry):
+                token, c, out = carry
+                (nxt, c), _ = body((token, c), step_keys[i])
+                # an unsigned row: not tested for being negative, as a
+                # scan's is not
+                return nxt, c, jax.lax.dynamic_update_index_in_dim(
+                    out, nxt, i.astype(jnp.uint32), 0)
+
+            token, sub, out = jax.lax.fori_loop(
+                0, steps, counted,
+                (token, sub, jnp.zeros((step_keys.shape[0], token.shape[0]),
+                                       jnp.int32)))
+            out = out.T
         cache = self._merge_window(cache, sub) if window else sub
-        return out.T, cache  # [n, B] -> [B, n]
+        return (out, cache) if steps is None else (out, cache, token)
 
     # -- public API ----------------------------------------------------------
 

@@ -44,10 +44,10 @@ rows) replicate a real row; per-row independence keeps them inert.
 Compiled-program inventory (bounded): the engine's prefill programs
 (prompt-bucketed: multiples of ``prompt_bucket``, or the ladder a family
 of long prompts declares), ONE decode-segment program per (window bucket,
-sampling, power-of-two batch width up to ``max_batch``) and segment
-length (plus cache-tail remainders, quantized by construction), one
-admit program per width, and one tiny grow program per adjacent width
-pair.
+sampling, power-of-two batch width up to ``max_batch``) whatever a
+call's length (the step count is an operand of the program: a call cut
+at a row's budget or at the cache's end runs the same one), one admit
+program per width, and one tiny grow program per adjacent width pair.
 
 Speculative segments (``spec=``): a batch whose policy carries the
 ``SamplingConfig.spec`` flag advances through the speculative engine's
@@ -159,7 +159,10 @@ POOL_MOVER_SCOPES = ("IterBatchingEngine._init_tables",
 
 # Decode hot-loop scopes (tools/graftcheck host-sync rule): the segment
 # dispatch loop is the zero-sync fast path; the spec variant's syncs are
-# the documented per-segment price and are baselined.
+# the documented per-segment price and are baselined. The one wait a
+# plain batch's loop makes is BETWEEN dispatches and on purpose
+# (``_hold_lead``: the host stays one call ahead of the device, no
+# more); it fetches nothing.
 GRAFTCHECK_HOT_LOOPS = ("IterBatchingEngine._advance",
                         "IterBatchingEngine._advance_spec")
 
@@ -224,6 +227,8 @@ GUARDED_STATE = {
     "batches_run": "_stats_lock", "rows_served": "_stats_lock",
     "joins": "_stats_lock", "segments_run": "_stats_lock",
     "spec_segments_run": "_stats_lock", "eos_retires": "_stats_lock",
+    "segments_cut": "_stats_lock", "steps_paid": "_stats_lock",
+    "gaps_answered": "_stats_lock",
     "grows": "_stats_lock", "preemptions": "_stats_lock",
     "resumes": "_stats_lock", "fault_parks": "_stats_lock",
     "batches_closed": "_stats_lock", "_turned": "_stats_lock",
@@ -462,10 +467,12 @@ class IterBatchingEngine:
     """Thread-safe iteration-level batching front end over a
     ``DecodeEngine`` (same calling convention as ``BatchingEngine``).
 
-    ``seg_steps`` is the scheduling granularity: admissions and
-    retirements happen every ``seg_steps`` decode steps. Smaller = lower
-    join latency, more scheduler work; larger = better dispatch
-    pipelining. A request's worst-case join delay is one segment.
+    ``seg_steps`` is the LONGEST decode call: a call ends where the
+    first live row's budget ends (or the cache does) and after
+    ``seg_steps`` steps otherwise, and admissions and retirements happen
+    at every call's end, so a row is answered at its last token.
+    Smaller = lower join latency, more scheduler work; larger = better
+    dispatch pipelining. A request's worst-case join delay is one call.
     """
 
     def __init__(self, engine: DecodeEngine, max_batch: int = 8,
@@ -553,6 +560,13 @@ class IterBatchingEngine:
         self.segments_run = 0
         self.spec_segments_run = 0    # draft-verify segments (spec mode)
         self.eos_retires = 0
+        # calls that ran fewer than seg_steps steps because a row's
+        # budget ended there; the decode steps the rows delivered were
+        # live for, and the gaps between the tokens they were answered
+        # with (steps paid per gap: 1.0 when every row ends by budget)
+        self.segments_cut = 0
+        self.steps_paid = 0
+        self.gaps_answered = 0
         self.grows = 0                # width upgrades of a live batch
         self.preemptions = 0          # rows parked under pool pressure
         self.resumes = 0              # parked rows recomputed back in
@@ -589,6 +603,10 @@ class IterBatchingEngine:
         # cut by it. 4096 transitions span minutes of boundaries; a wait
         # older than the list counts as "boundary"
         self._wait_log: "collections.deque" = collections.deque(maxlen=4096)
+        # token arrays of the plain decode calls dispatched and not yet
+        # known to have run, oldest first, whichever batch they served
+        # (worker-thread-only; ``_hold_lead``)
+        self._in_flight: "collections.deque" = collections.deque()
         self._worker = threading.Thread(target=self._loop, daemon=True)
         self._worker.start()
 
@@ -681,7 +699,10 @@ class IterBatchingEngine:
             out = {"batches": self.batches_run, "rows": self.rows_served,
                    "joins": self.joins, "segments": self.segments_run,
                    "spec_segments": self.spec_segments_run,
-                   "eos_retires": self.eos_retires, "grows": self.grows,
+                   "eos_retires": self.eos_retires,
+                   "segments_cut": self.segments_cut,
+                   "steps_paid": self.steps_paid,
+                   "gaps_answered": self.gaps_answered, "grows": self.grows,
                    "preemptions": self.preemptions,
                    "resumes": self.resumes,
                    "fault_parks": self.fault_parks,
@@ -848,9 +869,11 @@ class IterBatchingEngine:
                 <= self.engine.max_seq)
 
     def _run_batch(self, head: _Req):
+        self._hold_lead()
         state = self._seed(head)
         try:
             while state.active():
+                self._hold_lead()
                 if not state.closed:
                     self._admit(state)
                 try:
@@ -888,6 +911,21 @@ class IterBatchingEngine:
             # batch's cache/buffer bytes)
             graftmem.release(state.mem_cache)
             graftmem.release(state.mem_buf)
+
+    def _hold_lead(self) -> None:
+        """Wait until at most ONE decode call is in flight: the one the
+        device runs. The next is then dispatched behind it, so the
+        device is never left without work, and what a boundary decides
+        (who retires, who is admitted, whether the batch ends and the
+        next arrival seeds another) is decided a call ahead of the
+        device and no further. Without it the bound
+        is the runtime's own cap on programs in flight, which counts
+        programs and not their time: calls cut short at a row's budget
+        let the host run five calls (1.6 s) ahead at widths 1 and 2, a
+        joiner's prefill queued behind all of them (PERF.md 6, PR 39).
+        Fetches nothing: the wait is for the tokens to exist."""
+        while len(self._in_flight) > 1:
+            jax.block_until_ready(self._in_flight.popleft())
 
     # -- seeding -------------------------------------------------------------
 
@@ -1812,8 +1850,14 @@ class IterBatchingEngine:
             return self._advance_spec(state)
         eng = self.engine
         d = state.depth
-        n = min(self.seg_steps, eng.max_seq - d)
-        assert n >= 1, "active rows past max_seq (admission bug)"
+        # the call ends where the first live row's budget ends (known
+        # on the host a call ahead of the device, with no fetch): that
+        # row is answered at its last token and its slot and blocks
+        # come back at this call's end
+        longest = min(self.seg_steps, eng.max_seq - d)
+        n = min(longest, *(s.req.max_new_tokens - s.emitted
+                           for s in state.slots if s is not None))
+        assert n >= 1, "active rows past max_seq or budget (admission bug)"
         window = eng._decode_window(d + n)   # shared bucket policy
         pooled = self.pool is not None
         if pooled:
@@ -1830,11 +1874,13 @@ class IterBatchingEngine:
                 cache = cache._replace(state=self._slab.gather(slots_j))
         else:
             cache = state.cache
-        step_keys = self._segment_keys(state, n)
+        # keys for ``seg_steps`` steps whatever ``n`` (a row's keys are
+        # prefix-stable) and ``n`` an operand: one program a width
+        step_keys = self._segment_keys(state, self.seg_steps)
         t0 = time.perf_counter()
-        out, cache = eng._decode_seg(
+        out, cache, state.token = eng._decode_seg(
             eng._run_params(), state.token, cache, state.pad_j,
-            step_keys, sampling=state.sampling, window=window)
+            step_keys, np.int32(n), sampling=state.sampling, window=window)
         routing = self._routing_counters(cache, False)
         if pooled:
             self.pool.scatter(cache, state.tables)
@@ -1844,15 +1890,19 @@ class IterBatchingEngine:
                 self._slab.note_compiles()
         else:
             state.cache = cache
-        state.token = out[:, -1]
         state.depth = d + n
+        self._in_flight.append(out)
         seg = _SegOut(out)
         t1 = time.perf_counter()
         eng._note_compiles()
+        cut = n < longest
         with self._stats_lock:
             seg_no = self.segments_run
             self.segments_run += 1
+            self.segments_cut += cut
         REGISTRY.inc("iter_segments_total")
+        if cut:
+            REGISTRY.inc("iter_segments_cut_total")
         covered = []
         for s in state.slots:
             if s is not None:
@@ -2008,7 +2058,10 @@ class IterBatchingEngine:
         """[n, B, 2] per-step keys. Sample rows consume THEIR OWN step
         indices (emitted-1 ... emitted-1+n of split(dk, .) — prefix-
         stable, so a late joiner's stream matches its solo run); greedy
-        segments pass zeros (the program's key operand is never read)."""
+        segments pass zeros (the program's key operand is never read).
+        A call may start at any step of a row, so the split runs to the
+        next multiple of ``n`` and the start is an operand of the slice:
+        the tiny programs here stay one a multiple, not one a step."""
         b = len(state.slots)
         if state.sampling.mode == "greedy":
             return jnp.zeros((n, b, 2), jnp.uint32)
@@ -2018,7 +2071,9 @@ class IterBatchingEngine:
                 cols.append(jnp.zeros((n, 2), jnp.uint32))
             else:
                 t0 = s.emitted - 1
-                cols.append(jax.random.split(s.dk, t0 + n)[t0:])
+                cols.append(jax.lax.dynamic_slice_in_dim(
+                    jax.random.split(s.dk, -(-(t0 + n) // n) * n),
+                    np.int32(t0), n))
         return jnp.stack(cols, axis=1)              # [n, B, 2]
 
     # -- retirement ----------------------------------------------------------
@@ -2091,7 +2146,7 @@ class IterBatchingEngine:
             parts = [s.resumed_prefix]
         else:
             parts = [s.first_ref.np[s.first_idx:s.first_idx + 1]]
-        parts += [seg.np[s.row] for seg, _ in s.segs]
+        parts += [seg.np[s.row][:n] for seg, n in s.segs]
         return np.concatenate(parts)[:s.req.max_new_tokens]
 
     def _deliver(self, state: _BatchState, i: int, s: _Slot, eos_at):
@@ -2107,8 +2162,14 @@ class IterBatchingEngine:
         s.req.done.set()
         self._release_blocks(state, i)
         state.slots[i] = None
+        gaps = (min(s.emitted, s.req.max_new_tokens) - 1 if eos_at is None
+                else eos_at)
         with self._stats_lock:
             self.rows_served += 1
+            self.steps_paid += s.emitted - 1
+            self.gaps_answered += gaps
+        REGISTRY.inc("iter_steps_paid_total", value=s.emitted - 1)
+        REGISTRY.inc("iter_gaps_answered_total", value=gaps)
         if state.spec_mode:
             with self.spec._stats_lock:
                 self.spec._requests += 1

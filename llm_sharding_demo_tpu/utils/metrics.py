@@ -80,6 +80,9 @@ METRIC_CATALOG: Dict[str, str] = {
     "iter_spec_segments_total": "counter",
     "iter_grows_total": "counter",
     "iter_eos_retires_total": "counter",
+    "iter_segments_cut_total": "counter",
+    "iter_steps_paid_total": "counter",
+    "iter_gaps_answered_total": "counter",
     "iter_rows_total": "counter",
     # speculation (runtime/spec_decode.py)
     "spec_verify_steps_total": "counter",

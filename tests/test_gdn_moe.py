@@ -481,7 +481,12 @@ def test_rows_that_join_and_retire_serve_their_solo_streams(wide, kernel,
         dec = [s for s in tr.spans if s.name == "decode"]
         assert dec and all({"experts_hit", "pairs_here", "pairs_routed"}
                            <= set(s.labels) for s in dec)
-    assert st["moe.layer_forwards"] == 8 * 8 * st["segments"]
+    # a call runs to the first live row's budget, eight steps at most:
+    # its sums are those of the steps it ran
+    steps = {s.labels["seg"]: s.labels["steps"] for _, tr in got.values()
+             for s in tr.spans if s.name == "decode"}
+    assert len(steps) == st["segments"] and max(steps.values()) == 8
+    assert st["moe.layer_forwards"] == 8 * sum(steps.values())
     if pooled:
         pre = [s for _, tr in got.values() for s in tr.spans
                if s.name == "prefill" and "state_restored" in s.labels]

@@ -429,7 +429,12 @@ def test_iter_batcher_pool_and_prefix_store_serve_the_solo_streams(
         assert pre and all(s.labels["expert_load_max"]
                            >= s.labels["expert_load_mean"] > 0 for s in pre)
     assert st["moe.pairs_routed"] == st["moe.pairs_here"] > 0
-    assert st["moe.layer_forwards"] == 3 * 8 * st["segments"]
+    # a call runs to the first live row's budget, eight steps at most:
+    # its sums are those of the steps it ran
+    steps = {s.labels["seg"]: s.labels["steps"] for _, tr in got.values()
+             for s in tr.spans if s.name == "decode"}
+    assert len(steps) == st["segments"] and max(steps.values()) == 8
+    assert st["moe.layer_forwards"] == 3 * sum(steps.values())
     assert 0 < st["moe.experts_hit"] <= 16 * st["moe.layer_forwards"]
     assert st["moe.prefill_layer_forwards"] > 0
     # the longest stream, teacher-forced through the reference: each

@@ -426,7 +426,12 @@ def test_rows_that_join_and_retire_serve_their_solo_streams(wide, kernel,
         dec = [s for s in tr.spans if s.name == "decode"]
         assert dec and all({"experts_hit", "pairs_here", "pairs_routed"}
                            <= set(s.labels) for s in dec)
-    assert st["moe.layer_forwards"] == 7 * 8 * st["segments"]
+    # a call runs to the first live row's budget, eight steps at most:
+    # its sums are those of the steps it ran
+    steps = {s.labels["seg"]: s.labels["steps"] for _, tr in got.values()
+             for s in tr.spans if s.name == "decode"}
+    assert len(steps) == st["segments"] and max(steps.values()) == 8
+    assert st["moe.layer_forwards"] == 7 * sum(steps.values())
     # the first row alone at depth 150: six layers hold 8 of them each
     assert sampled[0]["window.positions_held"] == W
     assert sampled[0]["window.positions_seen"] == 150

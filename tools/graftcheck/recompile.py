@@ -30,7 +30,12 @@ Program-key model per entry point:
 - ``_prefill``          (batch, padded prompt_len, pad operand present)
 - ``_prefill_chunked``  (batch, n_chunks)
 - ``_decode_seg``       (batch, segment len, window, sampling,
-                         key form [one|per-row], pad operand present)
+                         key form [one|per-row], pad operand present);
+                        a COUNTED call (the ``steps`` operand present:
+                        the iteration scheduler's) keys on the longest
+                        length its key buffer allows, ``"<=n"``, never
+                        on the length it runs: a call cut at a row's
+                        budget or at the cache's end mints no program
 - ``_loop``   [spec]    (max_new, normalized sampling, pad present)
 - ``_loop_b`` [spec]    (batch, max_new, normalized sampling)
 - ``_seg_b``  [spec]    (width, max_verify, normalized sampling)
