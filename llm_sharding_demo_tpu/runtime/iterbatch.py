@@ -252,6 +252,22 @@ LOCK_ORDER = ("_stats_lock", "_lock")
 _WAIT_REASONS = ("closed", "slot", "pool", "boundary")
 
 
+# What the scheduler thread is doing, one state at every instant of its
+# life (``tracing.StateLog``; ``IterBatchingEngine.states``): ``idle``
+# in ``_loop``'s wait for a request with nothing parked, pending or
+# live; ``hold`` in ``_hold_lead``'s wait for the device; ``seed``,
+# ``admit`` and ``advance`` in ``_seed``, ``_admit`` and ``_advance``
+# with everything under them; ``other`` for what is left (the loops' own
+# lines, a batch's teardown). ``stats()`` gives their seconds as
+# ``t_<state>_s``. On the profiler's clock each is a span under the name
+# the benchmark's wrapper has used for that function, so a gap's name
+# reads the same with the wrapper or without; ``other`` carries none.
+_STATES = ("idle", "hold", "seed", "admit", "advance", "other")
+_STATE_SPANS = {"idle": "sched.idle", "hold": "sched.hold_lead",
+                "seed": "sched.seed", "admit": "sched.admit",
+                "advance": "sched.segment_dispatch"}
+
+
 def _rid_of(req) -> Optional[str]:
     """The request's timeline correlator (its trace's X-Request-ID);
     None for untraced engine-level calls."""
@@ -607,6 +623,9 @@ class IterBatchingEngine:
         # known to have run, oldest first, whichever batch they served
         # (worker-thread-only; ``_hold_lead``)
         self._in_flight: "collections.deque" = collections.deque()
+        # the worker's own time by state (``_STATES``), from its start
+        self.states = tracing.StateLog(_STATES, "other", _STATE_SPANS,
+                                       label=replica)
         self._worker = threading.Thread(target=self._loop, daemon=True)
         self._worker.start()
 
@@ -711,6 +730,7 @@ class IterBatchingEngine:
                    "parked": len(self._parked)}
         if self._slab is not None:
             out.update(self._slab.stats())
+        out.update({f"t_{k}_s": v for k, v in self.states.totals().items()})
         return out
 
     def admission_load(self, prompt_len: int,
@@ -779,6 +799,13 @@ class IterBatchingEngine:
         with self._stats_lock:
             self._pending = req
 
+    def _enter(self, state: str) -> None:
+        """From now on the scheduler thread is in ``state``."""
+        was, took = self.states.enter(state)
+        if took:
+            REGISTRY.inc("iter_scheduler_state_seconds_total", value=took,
+                         state=was)
+
     def _mark(self, reason: str) -> None:
         """From now on the head of the queue waits for ``reason``."""
         if not self._wait_log or self._wait_log[-1][1] != reason:
@@ -842,7 +869,10 @@ class IterBatchingEngine:
             else:
                 head = self._take_pending()
                 if head is None:
+                    # nothing parked, pending or live: there is no request
+                    self._enter("idle")
                     head = self._queue.get()
+                    self._enter("other")
                 if self._req_dead(head):
                     continue
             try:
@@ -870,12 +900,18 @@ class IterBatchingEngine:
 
     def _run_batch(self, head: _Req):
         self._hold_lead()
-        state = self._seed(head)
+        self._enter("seed")
+        try:
+            state = self._seed(head)
+        finally:
+            self._enter("other")
         try:
             while state.active():
                 self._hold_lead()
                 if not state.closed:
+                    self._enter("admit")
                     self._admit(state)
+                self._enter("advance")
                 try:
                     # the segment dispatch serves every live row: its
                     # instrumented dispatches (and any fault injected
@@ -891,6 +927,7 @@ class IterBatchingEngine:
                     # past its park budget fails typed (503) instead of
                     # cycling forever
                     self._fault_park_all(state, e)
+                self._enter("other")
         except Exception as e:  # noqa: BLE001
             for i, s in enumerate(state.slots):
                 if s is not None:
@@ -902,6 +939,7 @@ class IterBatchingEngine:
                     self._release_blocks(state, i)
             raise
         finally:
+            self._enter("other")
             self._mark("boundary")
             if state.closed:
                 with self._stats_lock:
@@ -924,8 +962,11 @@ class IterBatchingEngine:
         let the host run five calls (1.6 s) ahead at widths 1 and 2, a
         joiner's prefill queued behind all of them (PERF.md 6, PR 39).
         Fetches nothing: the wait is for the tokens to exist."""
-        while len(self._in_flight) > 1:
-            jax.block_until_ready(self._in_flight.popleft())
+        if len(self._in_flight) > 1:
+            self._enter("hold")
+            while len(self._in_flight) > 1:
+                jax.block_until_ready(self._in_flight.popleft())
+            self._enter("other")
 
     # -- seeding -------------------------------------------------------------
 

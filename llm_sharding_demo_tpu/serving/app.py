@@ -1007,8 +1007,15 @@ def create_app(cfg: Optional[ServingConfig] = None,
         ``?profile=<label>`` keeps only requests carrying that
         X-Workload-Profile label — the view that triages ONE graftload
         workload profile's slow/failed requests out of a mixed run
-        (composes with ``errors``/``slowest``)."""
-        return tracing.debug_requests_payload(rec, query, _topology())
+        (composes with ``errors``/``slowest``). Under the iter
+        scheduler the payload also carries ``scheduler``: the scheduler
+        thread's seconds by state and its newest intervals
+        (``utils.tracing.StateLog``), the time in which there was no
+        request or the host held one up, which no request's tree
+        holds."""
+        return tracing.debug_requests_payload(
+            rec, query, _topology(),
+            scheduler=getattr(runner, "states", None))
 
     @app.get("/debug/profile")
     def debug_profile(query: dict):

@@ -84,6 +84,11 @@ METRIC_CATALOG: Dict[str, str] = {
     "iter_steps_paid_total": "counter",
     "iter_gaps_answered_total": "counter",
     "iter_rows_total": "counter",
+    # seconds of the scheduler thread's life by what it was doing,
+    # labeled state (idle / hold / seed / admit / advance / other;
+    # iterbatch._STATES), added as each state closes: an idle device is
+    # "no request" (idle) or the host's (the rest)
+    "iter_scheduler_state_seconds_total": "counter",
     # speculation (runtime/spec_decode.py)
     "spec_verify_steps_total": "counter",
     "spec_emitted_tokens_total": "counter",

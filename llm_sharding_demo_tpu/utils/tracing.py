@@ -45,6 +45,15 @@ the instant the array exists, serialized as the label ``ready_ms`` on
 the trace's own clock. The dispatching thread never waits; a trace is
 settled (``RequestTrace.settle``: the caller's thread waits on
 whatever is still unstamped) before the flight recorder keeps it.
+
+**State logs** (always on): what a span tree cannot hold is time in
+which there is NO request. A thread that serves many requests (the iter
+scheduler's) keeps a ``StateLog``: at every instant it is in exactly one
+named state, ``enter(state)`` closes the state before it, and the log
+keeps cumulative seconds by state, the newest closed intervals on the
+spans' clock, and a ``TraceAnnotation`` a state on the profiler's, so a
+profile taken with ``trace(dir)`` carries the thread's states beside the
+device's operations. ``state_logs()`` hands out the process's logs.
 """
 
 from __future__ import annotations
@@ -56,6 +65,7 @@ import queue
 import threading
 import time
 import uuid
+import weakref
 from collections import deque
 from typing import Iterator, List, Optional
 
@@ -71,9 +81,14 @@ log = logging.getLogger(__name__)
 # target's own lock. So do a trace's handovers still waiting for their
 # ready instant (``_unready``: the scheduler thread appends, the
 # caller's thread drains) and the ready waiter's lazily started thread.
+# A state log's totals, open state and ring are written by the thread
+# that owns the log and read by others (a sampler, a debug handler), and
+# the process-wide set of logs by whoever builds or lists one.
 GUARDED_STATE = {"spans": "_lock", "_traces": "_lock",
                  "_unready": "_lock", "_thread": "_lock",
-                 "counters": "_once"}
+                 "counters": "_once",
+                 "_totals": "_lock", "_open": "_lock", "_ring": "_lock",
+                 "_logs": "_lock"}
 LOCK_ORDER = ("_lock",)
 
 # Timeline contract (tools/graftcheck timeline pass): every span lands
@@ -379,10 +394,6 @@ class _TraceSink:
                        dur_ms=round(s.duration * 1e3, 3))
         return s
 
-    def event(self, name: str, **labels) -> Span:
-        now = time.perf_counter()
-        return self.add_span(name, now, now, **labels)
-
 
 class RequestTrace(_TraceSink):
     """The span tree of one request, plus identity and summary fields."""
@@ -624,14 +635,132 @@ class FlightRecorder:
         return [t.to_dict() for t in traces]
 
 
+class StateLog:
+    """The time of ONE thread, cut into named states: at every instant
+    the thread is in exactly one, ``enter(state)`` closes the state
+    before it, and the states' seconds sum to the log's lifetime.
+
+    Kept three ways: cumulative seconds by state (``totals``: the open
+    state counts up to the instant of the read, so a delta between two
+    reads is exact whatever state they fall in); the newest ``capacity``
+    closed intervals ``(state, t0, t1)`` on the spans' clock
+    (``intervals``; ``unix_offset`` puts them on the wall clock, taken
+    once, as ``RequestTrace.started_unix`` is); and, for the states
+    ``annotations`` names, a ``TraceAnnotation`` on the profiler's
+    clock, opened and closed by ``enter``. Only the owning thread calls
+    ``enter``; any thread reads."""
+
+    def __init__(self, states, initial: str, annotations=None,
+                 label: Optional[str] = None, capacity: int = 65536,
+                 clock=time.perf_counter):
+        if capacity < 1:
+            raise ValueError("capacity must be >= 1")
+        self.label = label
+        self._clock = clock
+        self._annotations = dict(annotations or {})
+        self._span = None             # the open annotation (owner only)
+        self._lock = graftsched.lock("tracing.StateLog._lock")
+        self._totals = dict.fromkeys(states, 0.0)
+        if initial not in self._totals:
+            raise ValueError(f"unknown state {initial!r}")
+        self.t_start = clock()
+        self.unix_offset = time.time() - self.t_start
+        self._open = (initial, self.t_start)
+        self._ring: "deque[tuple]" = deque(maxlen=capacity)
+        STATE_LOGS.add(self)
+
+    def enter(self, state: str) -> tuple:
+        """The thread is in ``state`` from now on. Returns the state it
+        left and the seconds it had been there (0.0 where it stays)."""
+        with self._lock:
+            if state not in self._totals:
+                raise ValueError(f"unknown state {state!r}")
+            now = self._clock()
+            was, since = self._open
+            if was == state:
+                return was, 0.0
+            self._totals[was] += now - since
+            self._ring.append((was, since, now))
+            self._open = (state, now)
+        span, self._span = self._span, None
+        if span is not None:
+            span.__exit__(None, None, None)
+        name = self._annotations.get(state)
+        if name is not None:
+            self._span = annotate(name)
+            self._span.__enter__()
+        return was, now - since
+
+    def totals(self) -> dict:
+        """Seconds by state so far, the open state up to now."""
+        with self._lock:
+            now = self._clock()
+            out = dict(self._totals)
+            state, since = self._open
+            out[state] += now - since
+        return out
+
+    def intervals(self, n: Optional[int] = None) -> List[tuple]:
+        """The newest ``n`` intervals ``(state, t0, t1)``, oldest first,
+        the last one the open state up to now."""
+        with self._lock:
+            now = self._clock()
+            out = list(self._ring)
+            state, since = self._open
+        out.append((state, since, now))
+        return out if n is None else out[len(out) - max(n, 1):]
+
+    def snapshot(self, n: Optional[int] = None) -> dict:
+        """What ``/debug/requests`` serves: seconds by state and the
+        newest ``n`` intervals on the wall clock."""
+        off = self.unix_offset
+        return {
+            "label": self.label,
+            "started_unix": round(self.t_start + off, 3),
+            "seconds": {k: round(v, 6) for k, v in self.totals().items()},
+            "intervals": [{"state": s, "start_unix": round(t0 + off, 6),
+                           "duration_ms": round((t1 - t0) * 1e3, 3)}
+                          for s, t0, t1 in self.intervals(n)],
+        }
+
+
+class _StateLogs:
+    """The process's live state logs (held weakly: a log lives as long
+    as the thread's owner does)."""
+
+    def __init__(self):
+        self._lock = graftsched.lock("tracing._StateLogs._lock")
+        self._logs: "weakref.WeakSet[StateLog]" = weakref.WeakSet()
+
+    def add(self, log: StateLog) -> None:
+        with self._lock:
+            self._logs.add(log)
+
+    def all(self) -> List[StateLog]:
+        with self._lock:
+            logs = list(self._logs)
+        return sorted(logs, key=lambda log: log.t_start)
+
+
+def state_logs() -> List[StateLog]:
+    """Every live state log of the process, oldest first: one a
+    scheduler thread, told apart by ``label`` (the replica) where a
+    process holds several."""
+    return STATE_LOGS.all()
+
+
 def debug_requests_payload(recorder: FlightRecorder, query: dict,
-                           serving: dict):
+                           serving: dict,
+                           scheduler: Optional[StateLog] = None):
     """The ``/debug/requests`` response body (?n/?slowest/?errors/
     ?profile) — ONE implementation shared by the replica surface
     (serving/app.py) and the fleet router (serving/router.py), so a
     new query filter cannot land on one debug surface and silently
     desynchronize the other. ``serving`` is the per-app identity
-    block. Returns ``(422, detail)`` on an unparseable ``n``."""
+    block; ``scheduler`` the state log of the app's scheduler thread,
+    where it has one: its seconds by state and newest ``n`` intervals,
+    the time no request's tree holds. Returns ``(422, detail)`` on an
+    unparseable ``n``."""
     try:
         n = int(query.get("n", "32"))
     except ValueError:
@@ -647,11 +776,16 @@ def debug_requests_payload(recorder: FlightRecorder, query: dict,
         **({"profile": prof} if prof else {}),
         "requests": recorder.snapshot(n=n, slowest=slowest,
                                       errors_only=errs, profile=prof),
+        **({"scheduler": scheduler.snapshot(n)}
+           if scheduler is not None else {}),
     }
 
 
 # process-wide default recorder (what serving.app uses; injectable there)
 RECORDER = FlightRecorder()
+
+# process-wide set of state logs (``state_logs()``)
+STATE_LOGS = _StateLogs()
 
 # process-wide ready waiter: one device queue, one FIFO
 READY = ReadyWaiter()

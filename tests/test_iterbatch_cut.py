@@ -208,10 +208,11 @@ def test_an_armed_row_beside_a_budget_row_retires_at_its_eos(engines,
 
 def test_the_host_stays_one_call_ahead_of_the_device():
     """``_hold_lead`` waits, oldest first, for every call in flight but
-    the newest, and for nothing when one or none is."""
+    the newest, and for nothing when one or none is; the thread is in
+    ``hold`` for the wait and for nothing else."""
     import collections
     import types
-    waited = []
+    waited, entered = [], []
 
     class Tokens:
         def __init__(self, no):
@@ -222,11 +223,13 @@ def test_the_host_stays_one_call_ahead_of_the_device():
             return self
 
     sched = types.SimpleNamespace(
-        _in_flight=collections.deque(Tokens(i) for i in range(3)))
+        _in_flight=collections.deque(Tokens(i) for i in range(3)),
+        _enter=lambda state: entered.append((state, list(waited))))
     IterBatchingEngine._hold_lead(sched)
     assert waited == [0, 1] and [t.no for t in sched._in_flight] == [2]
+    assert entered == [("hold", []), ("other", [0, 1])]
     IterBatchingEngine._hold_lead(sched)
     assert waited == [0, 1] and len(sched._in_flight) == 1
     sched._in_flight.clear()
     IterBatchingEngine._hold_lead(sched)
-    assert waited == [0, 1]
+    assert waited == [0, 1] and len(entered) == 2
