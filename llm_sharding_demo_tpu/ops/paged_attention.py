@@ -50,11 +50,14 @@ Two consumption patterns:
   tests/test_paged_attention.py.
 - ``gather_kv`` / ``scatter_kv``: the segment-granularity path the
   decode engines use (runtime.kv_pool): gather the pool-resident rows
-  into a contiguous working cache ONCE per compiled decode segment, run
-  the engine's existing (unchanged, byte-pinned) segment program, and
-  scatter the updated rows back. Two extra cache passes per
-  ``seg_steps`` tokens (~3% extra HBM traffic at 32-step segments)
-  buys paging without touching a single model program.
+  into a contiguous working cache, run the engine's existing
+  (unchanged, byte-pinned) segment program, and scatter the updated
+  rows back: paging without touching a single model program. The paged
+  runner does so ONCE per compiled decode segment. The iteration
+  scheduler, whose calls end at every retirement, keeps the working
+  cache for the batch's life and scatters only the columns a call
+  wrote (``column_span``): the whole-row forms then serve its seeds
+  and joiners, and nothing gathers.
 
 ``scatter_kv`` writes with an UNROLLED ``dynamic_update_slice`` chain,
 not ``.at[].set``: duplicate targets (every ghost/pad entry aliases the
@@ -204,6 +207,25 @@ def scatter_rows(pool: jnp.ndarray, k: jnp.ndarray,
             (zero, flat[i], zero, zero, zero, zero))
 
     return jax.lax.fori_loop(0, b * nbm, one, pool)
+
+
+def span_blocks(seg_steps: int, block_size: int, nbm: int) -> int:
+    """Table columns a decode call of at most ``seg_steps`` positions
+    can write, wherever in a block it starts (the first position may be
+    a block's last), capped at the table's width."""
+    return min(nbm, (seg_steps + block_size - 2) // block_size + 1)
+
+
+def column_span(tables: jnp.ndarray, col, span: int, *views):
+    """Table columns ``[col, col + span)`` and the slots they hold of
+    each contiguous view ``[L, B, H, NBm*bs, w]``: what the whole-row
+    scatters above take, ``span`` blocks wide. ``col`` is a TRACED
+    scalar (one program serves every depth), at most ``NBm - span``,
+    and ``span`` is static."""
+    bs = views[0].shape[-2] // tables.shape[1]
+    return (jax.lax.dynamic_slice_in_dim(tables, col, span, axis=1),
+            *(jax.lax.dynamic_slice_in_dim(x, col * bs, span * bs, axis=-2)
+              for x in views))
 
 
 def copy_blocks(pool: jnp.ndarray, src: jnp.ndarray,

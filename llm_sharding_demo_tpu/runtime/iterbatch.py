@@ -77,14 +77,28 @@ into the live batch at the current depth. Exact (store replay is
 byte-identical to a cold prefill) and compile-bounded by the store's
 chunk programs.
 
-Paged KV composition (``pool=``, runtime.kv_pool): rows' KV state lives
-in ref-counted pool BLOCKS between segments instead of a permanently
-allocated ``[B, max_seq]`` arena. Each segment boundary gathers the
-tabled rows into a contiguous working cache, runs the UNCHANGED segment
-program (same program keys, byte-identical tokens), and scatters the
-updated rows back; fully-padded table positions point at the shared
-trash block, so a short row costs ``ceil(content/block_size)`` blocks,
-not ``max_seq`` slots. The pool is also the ADMISSION authority:
+Paged KV composition (``pool=``, runtime.kv_pool): rows' KV state is
+accounted in ref-counted pool BLOCKS; fully-padded table positions point
+at the shared trash block, so a short row costs
+``ceil(content/block_size)`` blocks, not ``max_seq`` slots. The live
+batch keeps its contiguous working cache on the device from its seed to
+its end, as the un-pooled scheduler does (``state.cache``,
+``_admit_cache``), and runs the UNCHANGED segment program on it (same
+program keys, byte-identical tokens); the pool is WRITTEN while the
+batch lives: the seed's prefill and a joiner's row whole, and behind
+every decode call only the table columns that call wrote
+(``KVBlockPool.scatter_span``: two or three blocks a row where the
+table has ``max_seq / block_size``). So at every boundary a gather of
+a live row's table equals the resident row over ``[pad, depth)``: the
+pool stays truthful for whoever reads blocks (the prefix store, a tier,
+a kernel that reads through the tables). The batch itself reads it
+once a GROW: the wider cache is gathered from the pool, so that two
+widths of it never stand side by side (``_grow``). Two kinds of batch keep a whole gather in front of every call and a
+whole scatter behind it: one on a QUANTIZED pool (its served tokens
+depend on every position being read back through its block's scale),
+and a speculative one (its segment rolls whole rows). The state slab's
+rows are still fetched for a call and handed back after it. The pool is
+also the ADMISSION authority:
 
 - admission of a policy-compatible request defers (without closing the
   batch) while the allocator's watermark says its blocks don't fit —
@@ -124,6 +138,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..ops import paged_attention as PA
 from ..ops.attention import KVCache
 from ..utils import graftfault, graftmem, graftsched, graftscope, \
     grafttime, tracing
@@ -154,6 +169,7 @@ DONATED_ARGS = {"_admit_cache": (0,)}
 # are this batch's ``_Slot.blk_ids`` allocations or the trash block).
 POOL_MOVER_SCOPES = ("IterBatchingEngine._init_tables",
                      "IterBatchingEngine._place_admitted",
+                     "IterBatchingEngine._grow",
                      "IterBatchingEngine._advance",
                      "IterBatchingEngine._advance_spec")
 
@@ -204,12 +220,13 @@ TIMELINE_EVENTS = {
 # HBM-ledger contract (tools/graftcheck memory pass + utils/graftmem):
 # the live batch's long-lived device holdings, by graftmem component —
 # both live on ``_BatchState`` (handle-keyed per batch). ``cache`` is
-# the contiguous working cache (contiguous mode only: registered at
-# seed, re-measured at grow/admit rebinds, released when a pool takes
-# ownership of the state or the batch tears down); ``buf`` is the spec
-# verify token buffer (spec batches only). Pool-mode block storage is
-# the POOL's ledger entry (runtime/kv_pool.py) — tables hold ids, not
-# bytes, so nothing double-counts.
+# the contiguous working cache (registered at seed, re-measured at
+# grow/admit rebinds, released when the batch tears down, or at the
+# seed where a quantized pool or a speculative batch gives it up to the
+# pool); ``buf`` is the spec verify token buffer (spec batches only).
+# The pool's block storage is the POOL's ledger entry
+# (runtime/kv_pool.py): a resident working cache beside it is a second
+# copy of the live rows and is counted as one.
 MEMORY_LEDGER = {
     "cache": "engine_cache",
     "buf": "spec_buffers",
@@ -229,6 +246,8 @@ GUARDED_STATE = {
     "spec_segments_run": "_stats_lock", "eos_retires": "_stats_lock",
     "segments_cut": "_stats_lock", "steps_paid": "_stats_lock",
     "gaps_answered": "_stats_lock",
+    "calls_resident": "_stats_lock", "cache_gathers": "_stats_lock",
+    "blocks_written_back": "_stats_lock",
     "grows": "_stats_lock", "preemptions": "_stats_lock",
     "resumes": "_stats_lock", "fault_parks": "_stats_lock",
     "batches_closed": "_stats_lock", "_turned": "_stats_lock",
@@ -448,9 +467,10 @@ class _BatchState:
     def __init__(self, sampling, token, cache, pad_j, depth):
         self.sampling = sampling
         self.token = token            # [B] device
-        self.cache = cache            # contiguous mode only; None when a
-                                      # pool owns the state between
-                                      # segments (tables instead)
+        self.cache = cache            # the working cache, resident from
+                                      # seed to end; None where the pool
+                                      # is gathered for every call
+                                      # (``_init_tables``)
         self.pad_j = pad_j            # [B] device int32
         self.depth = depth            # uniform cache depth (host int)
         self.tables: Optional[np.ndarray] = None   # [B, NBm] (pool mode)
@@ -583,6 +603,17 @@ class IterBatchingEngine:
         self.segments_cut = 0
         self.steps_paid = 0
         self.gaps_answered = 0
+        # pooled batches: decode calls that ran on the resident cache
+        # with no gather in front of them, whole gathers of a live
+        # batch (one a grow; one a call where the pool keeps the cache),
+        # and blocks of live rows the write-backs behind the calls
+        # rewrote
+        self.calls_resident = 0
+        self.cache_gathers = 0
+        self.blocks_written_back = 0
+        # table columns one call's positions can span (pool mode)
+        self._span = (None if pool is None else PA.span_blocks(
+            seg_steps, pool.block_size, pool.nbm))
         self.grows = 0                # width upgrades of a live batch
         self.preemptions = 0          # rows parked under pool pressure
         self.resumes = 0              # parked rows recomputed back in
@@ -721,7 +752,11 @@ class IterBatchingEngine:
                    "eos_retires": self.eos_retires,
                    "segments_cut": self.segments_cut,
                    "steps_paid": self.steps_paid,
-                   "gaps_answered": self.gaps_answered, "grows": self.grows,
+                   "gaps_answered": self.gaps_answered,
+                   "calls_resident": self.calls_resident,
+                   "cache_gathers": self.cache_gathers,
+                   "blocks_written_back": self.blocks_written_back,
+                   "grows": self.grows,
                    "preemptions": self.preemptions,
                    "resumes": self.resumes,
                    "fault_parks": self.fault_parks,
@@ -1393,7 +1428,15 @@ class IterBatchingEngine:
         pad_j / cache along the batch axis by replicating row 0 (any
         live content is valid ghost material — rows are independent).
         One tiny concat program per (width, cache-shape) pair, from the
-        same bounded width set as the decode programs."""
+        same bounded width set as the decode programs.
+
+        A pooled batch's resident cache is not widened but let go and
+        GATHERED again at the new width: the pool holds every live row
+        as the cache does (a grow happens at a boundary), and the two
+        widths then never stand on the device side by side (widening
+        16 rows of ``chat`` by concatenation held 4.3 GB where the
+        cache is 2.15: PERF.md 6, PR 41). The one gather a live batch
+        still makes: four in a batch's life at most."""
         old = len(state.slots)
         new = min(_next_pow2(old + 1), self.max_batch)
         pad_rows = new - old
@@ -1416,16 +1459,25 @@ class IterBatchingEngine:
 
         state.token = rep(state.token, 0)
         state.pad_j = rep(state.pad_j, 0)
-        if state.cache is not None:
-            state.cache = grow_cache(state.cache)
-            graftmem.update(state.mem_cache, state.cache)
+        resident = state.cache is not None
+        if resident:
+            state.cache = (None if state.tables is not None
+                           else grow_cache(state.cache))
         if state.tables is not None:
-            # ghost lanes read (and scatter) the trash block only (and
-            # the slab's trash slot: ``_state_ids``)
+            # a ghost lane reads the trash block here and its
+            # write-back lands there, like a retired row's stale lane
+            # (and its state in the slab's trash slot: ``_state_ids``)
             state.tables = np.concatenate(
                 [state.tables,
                  np.full((pad_rows, self.pool.nbm), self.pool.trash,
                          dtype=np.int32)], axis=0)
+            if resident:
+                state.cache = self.pool.gather(state.tables, state.depth)
+                with self._stats_lock:
+                    self.cache_gathers += 1
+                REGISTRY.inc("iter_cache_gathers_total")
+        if resident:
+            graftmem.update(state.mem_cache, state.cache)
         if state.spec_mode:
             # ghost rows clone row 0's buffer/key lane; their zero
             # budgets keep them inert through every verify (n_emit = 0)
@@ -1531,7 +1583,7 @@ class IterBatchingEngine:
         if self.pool is not None:
             blk_lo, blk_ids = self._place_admitted(
                 state, slot, solo, state.depth - sp, reserved)
-        else:
+        if state.cache is not None:
             state.cache = _admit_cache(
                 state.cache, solo, jnp.asarray(slot, jnp.int32),
                 jnp.asarray(state.depth - sp, jnp.int32))
@@ -1589,9 +1641,13 @@ class IterBatchingEngine:
 
     def _init_tables(self, state: _BatchState) -> None:
         """Seed-time placement: allocate each live row's content blocks
-        (pad-prefix positions stay on trash), scatter the seed prefill
-        into them, and drop the contiguous cache — between segments the
-        POOL is the only storage."""
+        (pad-prefix positions stay on trash) and scatter the seed
+        prefill into them, whole. The contiguous cache STAYS with the
+        batch as its resident working cache (its K/V planes: what a row
+        holds beside its positions lives in the slab); from here on the
+        pool is written behind every call and read only where the batch
+        grows (``_grow``). A quantized pool and a speculative batch give
+        the cache up here and gather it anew for every call."""
         bs = self.pool.block_size
         state.tables = np.full((len(state.slots), self.pool.nbm),
                                self.pool.trash, dtype=np.int32)
@@ -1622,11 +1678,15 @@ class IterBatchingEngine:
             for i in range(len(state.slots)):
                 self._release_blocks(state, i)
             raise
-        state.cache = None
-        # the pool now owns the KV bytes (its own ledger entry); the
-        # contiguous working view is gone
-        graftmem.release(state.mem_cache)
-        state.mem_cache = 0
+        if self.pool.block_dtype is not None or state.spec_mode:
+            state.cache = None
+            # the pool alone holds the KV bytes (its own ledger entry)
+            graftmem.release(state.mem_cache)
+            state.mem_cache = 0
+        else:
+            # what a row holds beside its positions lives in the slab
+            state.cache = state.cache._replace(state=None)
+            graftmem.update(state.mem_cache, state.cache)
 
     def _place_admitted(self, state: _BatchState, slot: int,
                         solo, roll: int,
@@ -1636,8 +1696,10 @@ class IterBatchingEngine:
         grant — allocation no longer happens here, so the watermark
         check and the grant cannot be split by a concurrent pool user)
         and scatter of the rolled row (the paged form of
-        ``_admit_cache``'s roll merge). ``_admit_one`` owns freeing the
-        reservation on failure; this only resets the table row."""
+        ``_admit_cache``'s roll merge, which the caller makes as well
+        where the batch has a resident cache: the two then hold the
+        same row). ``_admit_one`` owns freeing the reservation on
+        failure; this only resets the table row."""
         p_lo, ids, state_slot = reserved
         try:
             state.tables[slot, :] = self.pool.trash
@@ -1908,13 +1970,14 @@ class IterBatchingEngine:
             self._ensure_blocks(state, d + n)
             if not state.active():
                 return  # everyone preempted (single-row pool squeeze)
-            cache = self.pool.gather(state.tables, d)
-            if self._slab is not None:
-                # the segment program carries the slab rows it runs
-                slots_j = self._state_ids(state)
-                cache = cache._replace(state=self._slab.gather(slots_j))
-        else:
-            cache = state.cache
+        # the batch's own working cache; a batch that gave it up to the
+        # pool at its seed gathers the whole of it (``_init_tables``)
+        resident = state.cache is not None
+        cache = state.cache if resident else self.pool.gather(state.tables, d)
+        if self._slab is not None:
+            # the segment program carries the slab rows it runs
+            slots_j = self._state_ids(state)
+            cache = cache._replace(state=self._slab.gather(slots_j))
         # keys for ``seg_steps`` steps whatever ``n`` (a row's keys are
         # prefix-stable) and ``n`` an operand: one program a width
         step_keys = self._segment_keys(state, self.seg_steps)
@@ -1924,12 +1987,21 @@ class IterBatchingEngine:
             step_keys, np.int32(n), sampling=state.sampling, window=window)
         routing = self._routing_counters(cache, False)
         if pooled:
-            self.pool.scatter(cache, state.tables)
+            bs = self.pool.block_size
+            if resident:
+                # only the columns that hold positions [d, d + n)
+                self.pool.scatter_span(cache, state.tables, d // bs,
+                                       self._span)
+                wrote = (d + n - 1) // bs - d // bs + 1
+            else:
+                self.pool.scatter(cache, state.tables)
+                wrote = self.pool.nbm
             self.pool.note_compiles()
             if self._slab is not None:
                 self._slab.scatter(cache.state, slots_j)
                 self._slab.note_compiles()
-        else:
+                cache = cache._replace(state=None)
+        if resident:
             state.cache = cache
         state.depth = d + n
         self._in_flight.append(out)
@@ -1937,13 +2009,23 @@ class IterBatchingEngine:
         t1 = time.perf_counter()
         eng._note_compiles()
         cut = n < longest
+        live = sum(s is not None for s in state.slots)
         with self._stats_lock:
             seg_no = self.segments_run
             self.segments_run += 1
             self.segments_cut += cut
+            if pooled:
+                self.calls_resident += resident
+                self.cache_gathers += not resident
+                self.blocks_written_back += live * wrote
         REGISTRY.inc("iter_segments_total")
         if cut:
             REGISTRY.inc("iter_segments_cut_total")
+        if pooled:
+            REGISTRY.inc("iter_calls_resident_total" if resident
+                         else "iter_cache_gathers_total")
+            REGISTRY.inc("kv_pool_blocks_written_back_total",
+                         value=live * wrote)
         covered = []
         for s in state.slots:
             if s is not None:

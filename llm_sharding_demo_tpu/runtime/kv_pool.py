@@ -99,8 +99,8 @@ from .engine import (DecodeEngine, GenerateResult, SamplingConfig,
 # The ``_q`` names are the quantized-pool mover family (constructed
 # instead of — never alongside — the plain family when ``block_dtype``
 # is set); ``_poison_q`` is its GRAFTSAN-only poisoner.
-JIT_ENTRY_POINTS = ("_gather", "_scatter", "_scatter_row", "_copy",
-                    "_poison", "_gather_q", "_scatter_q",
+JIT_ENTRY_POINTS = ("_gather", "_scatter", "_scatter_span", "_scatter_row",
+                    "_copy", "_poison", "_gather_q", "_scatter_q",
                     "_scatter_row_q", "_copy_q", "_poison_q")
 
 # Observability contract (tools/graftcheck scope pass + utils/graftscope):
@@ -110,8 +110,8 @@ JIT_ENTRY_POINTS = ("_gather", "_scatter", "_scatter_row", "_copy",
 # GRAFTSAN-only free-block poisoners, sanitizer hooks off every serving
 # path — baselined in tools/graftcheck/baseline.txt with that
 # justification.
-PROFILED_SCOPES = ("_gather", "_scatter", "_scatter_row", "_copy",
-                   "_gather_q", "_scatter_q", "_scatter_row_q",
+PROFILED_SCOPES = ("_gather", "_scatter", "_scatter_span", "_scatter_row",
+                   "_copy", "_gather_q", "_scatter_q", "_scatter_row_q",
                    "_copy_q")
 
 # Timeline contract (tools/graftcheck timeline pass): the allocator's
@@ -170,6 +170,11 @@ def _scatter_scope_key(pool, k, v, tables):
     return (int(tables.shape[0]), int(tables.shape[1]))
 
 
+def _scatter_span_scope_key(pool, k, v, tables, col, span):
+    # the first column is traced: a width's calls share one program
+    return (int(tables.shape[0]), int(tables.shape[1]), int(span))
+
+
 def _scatter_row_scope_key(pool, k, v, table_row, roll):
     return (int(k.shape[-2]), int(table_row.shape[0]))
 
@@ -203,7 +208,8 @@ def _copy_q_scope_key(data, scales, src, dst):
 # host view of it. The quantized movers additionally consume the scale
 # array (arg 1): ``self.scales`` is re-bound in the same statement, so
 # (data, scales) stay one atomic device state.
-DONATED_ARGS = {"_scatter": (0,), "_scatter_row": (0,), "_copy": (0,),
+DONATED_ARGS = {"_scatter": (0,), "_scatter_span": (0,),
+                "_scatter_row": (0,), "_copy": (0,),
                 "_poison": (0,), "_scatter_q": (0, 1),
                 "_scatter_row_q": (0, 1), "_copy_q": (0, 1),
                 "_poison_q": (0, 1)}
@@ -1016,6 +1022,20 @@ class KVBlockPool:
                 return PA.scatter_rows(pool, k, tables)
             return PA.scatter_kv(pool, *split(k, v), tables)
 
+        def _scatter_span_impl(pool, k, v, tables, col, span):
+            # a decode call's write-back: of a working cache that stays
+            # with its caller, only the ``span`` table columns from
+            # ``col`` on (ops.paged_attention.column_span), B x span
+            # block updates whatever the table's width
+            if planes == 1:
+                tw, kw = PA.column_span(tables, col, span, k)
+                return PA.scatter_rows(pool, kw, tw)
+            if fused:
+                tw, kw = PA.column_span(tables, col, span, k)
+                return PA.scatter_kv(pool, *split(kw, v), tw)
+            tw, kw, vw = PA.column_span(tables, col, span, k, v)
+            return PA.scatter_kv(pool, kw, vw, tw)
+
         def _scatter_one_rolled(pool, k, v, table_row, roll):
             # admission merge: roll a solo-prefilled row's K/V content
             # along the slot axis (engine left-pad convention — wrap
@@ -1037,6 +1057,10 @@ class KVBlockPool:
         self._scatter = graftscope.instrument(
             jax.jit(_scatter_impl, donate_argnums=(0,)),
             "kv_pool._scatter", key_fn=_scatter_scope_key)
+        self._scatter_span = graftscope.instrument(
+            jax.jit(_scatter_span_impl, donate_argnums=(0,),
+                    static_argnums=(5,)),
+            "kv_pool._scatter_span", key_fn=_scatter_span_scope_key)
         self._scatter_row = graftscope.instrument(
             jax.jit(_scatter_one_rolled, donate_argnums=(0,)),
             "kv_pool._scatter_row", key_fn=_scatter_row_scope_key)
@@ -1046,6 +1070,7 @@ class KVBlockPool:
         watches = [
             CompileWatch("kv_pool", self._gather),
             CompileWatch("kv_pool", self._scatter),
+            CompileWatch("kv_pool", self._scatter_span),
             CompileWatch("kv_pool", self._scatter_row),
             CompileWatch("kv_pool", self._copy)]
         if self.allocator.sanitize:
@@ -1246,9 +1271,14 @@ class KVBlockPool:
         return jnp.asarray(np.array(tables, dtype=np.int32))
 
     def gather(self, tables: np.ndarray, length: int) -> KVCache:
-        """Contiguous working cache for the tabled rows (a FRESH buffer
-        — downstream decode may donate it). ``length`` is the logical
-        depth the caller tracks host-side."""
+        """Contiguous working cache for the tabled rows: a FRESH buffer
+        that is the caller's from here on (to donate to a decode call,
+        or to keep). ``length`` is the logical depth the caller tracks
+        host-side. The iteration scheduler keeps its working cache on
+        the device from a batch's seed to its end and calls this for a
+        live batch of a full-precision pool only where the batch grows;
+        what it returns there is that cache over each row's ``[pad,
+        depth)``, at the new width."""
         with self._dev_lock:
             if self.allocator.sanitize:
                 self._graftsan_check_tables(tables, "gather")
@@ -1269,6 +1299,26 @@ class KVBlockPool:
                     self.data, self.scales, cache.k, cache.v, tj)
             else:
                 self.data = self._scatter(self.data, cache.k, cache.v, tj)
+
+    def scatter_span(self, cache: KVCache, tables: np.ndarray,
+                     col: int, span: int) -> None:
+        """Write back table columns ``[col, col + span)`` of a
+        full-width working cache the caller keeps: the blocks a decode
+        call wrote. ``col`` is an operand and ``span`` is fixed by the
+        caller (``ops.paged_attention.span_blocks``), so a batch width
+        has ONE program whatever the depth; a span that would pass the
+        last column ends on it instead, rewriting a block or two with
+        the cache's own content. Full-precision pools only: a quantized
+        block's scale is of its whole content, so that pool's callers
+        stay on ``gather`` / ``scatter``."""
+        col = min(col, self.nbm - span)
+        with self._dev_lock:
+            if self.allocator.sanitize:
+                self._graftsan_check_tables(tables[:, col:col + span],
+                                            "scatter_span", write=True)
+            self.data = self._scatter_span(
+                self.data, cache.k, cache.v, self._device_tables(tables),
+                np.int32(col), span)
 
     def scatter_columns(self, cache: KVCache, tables: np.ndarray,
                         nb_lo: int) -> None:

@@ -81,6 +81,13 @@ METRIC_CATALOG: Dict[str, str] = {
     "iter_grows_total": "counter",
     "iter_eos_retires_total": "counter",
     "iter_segments_cut_total": "counter",
+    # pooled batches: decode calls run on the batch's resident working
+    # cache with no gather, whole gathers of a live batch (one where it
+    # grows; one in front of every call on a quantized pool), and
+    # blocks of live rows the write-backs behind the calls rewrote
+    "iter_calls_resident_total": "counter",
+    "iter_cache_gathers_total": "counter",
+    "kv_pool_blocks_written_back_total": "counter",
     "iter_steps_paid_total": "counter",
     "iter_gaps_answered_total": "counter",
     "iter_rows_total": "counter",

@@ -384,3 +384,22 @@ def test_fp8_stays_out_of_engine_regime_vocabulary():
     assert engine_regime_of("bfloat16") == "bf16"
     with pytest.raises(GraftnumError, match="ENGINE regime"):
         engine_regime_of("fp8")
+
+
+def test_quantized_pool_is_gathered_for_every_call(setup):
+    """A quantized pool has no column-range mover (a block's scale is
+    of its whole content) and the scheduler keeps no working cache
+    beside it: every decode call gathers the batch through the blocks'
+    scales and scatters it back whole, and the counters say so."""
+    cfg, params, eng = setup
+    pool = KVBlockPool.for_engine(eng, num_blocks=16, block_size=BS,
+                                  block_dtype="int8")
+    assert not hasattr(pool, "_scatter_span")
+    ib = IterBatchingEngine(eng, max_batch=2, seg_steps=8, pool=pool)
+    res = ib.generate(np.arange(5) + 3, 20)
+    assert res.new_tokens == 20
+    st = ib.stats()
+    assert st["segments"] == st["cache_gathers"] == 3
+    assert st["calls_resident"] == 0
+    assert st["blocks_written_back"] == 3 * pool.nbm
+    assert pool._gather_q._cache_size() == 1

@@ -252,6 +252,61 @@ def test_the_counted_segment_holds_no_more_than_the_scan(one_chip, built,
     assert _held(got) <= _held(want) + 2**17, (_held(got), _held(want))
 
 
+def _write_back(chip, built, name, batch):
+    """The program behind a decode call of a pooled batch, compiled at
+    the cell's pool (``KV_POOL_BLOCKS`` blocks, the configuration's
+    whole depth) for ``batch`` rows: its memory report, its text and
+    the pool's bytes. The pool object itself is a two-layer one of one
+    row's blocks: its mover is compiled from shapes."""
+    from llm_sharding_demo_tpu.models import cache_entry, cache_layers
+    from llm_sharding_demo_tpu.ops import paged_attention as PA
+    from llm_sharding_demo_tpu.runtime.kv_pool import KVBlockPool
+    eng, _ = built(name)
+    config = Spec().config(name)
+    cfg, env = server.family_config(config), config["serving_env"]
+    bs = int(env["KV_BLOCK_SIZE"])
+    pool = KVBlockPool.for_engine(eng, eng._cache_seq // bs, block_size=bs,
+                                  state_slots=2)
+    planes, heads, width = cache_entry(cfg)
+    layers = cache_layers(cfg)
+    data = chip.shape(PA.pool_shape(layers, int(env["KV_POOL_BLOCKS"]),
+                                    heads, bs, width, planes))
+    cache = jax.eval_shape(lambda: eng._fresh_cache(batch))
+    k = chip.shape((layers,) + cache.k.shape[1:])
+    v = chip.shape(cache.v.shape if cache.v.ndim <= 1
+                   else (layers,) + cache.v.shape[1:], cache.v.dtype)
+    span = PA.span_blocks(SEG_STEPS, bs, pool.nbm)
+    assert span == 3
+    compiled = pool._scatter_span.lower(
+        data, k, v, chip.shape((batch, pool.nbm), jnp.int32),
+        chip.shape((), jnp.int32), span).compile()
+    return (compiled.memory_analysis(), compiled.as_text(),
+            data.size * data.dtype.itemsize)
+
+
+@pytest.mark.parametrize("batch", WIDTHS)
+@pytest.mark.parametrize("name", ["mistral-7b-l16", "joyai-llm-flash-ep16"])
+def test_the_write_back_holds_three_updates_a_row(one_chip, built, name,
+                                                  batch):
+    """``KVBlockPool.scatter_span`` at the two-plane pool of the
+    ``chat`` cells and at the one-plane pool of ``assist``: the first
+    column is an operand (one program a width, whatever a call's depth
+    and length), ``batch`` x 3 block updates (unrolled, or the trips of
+    one loop) where the whole-row scatter has ``batch`` x 128 or 192,
+    the pool updated in place and nothing of its size beside it (the
+    compiler's own report here: 1.7 MB of temporaries at 16 rows of the
+    2.69 GB pool, 0.13 MB at the latent one's 2.10 GB)."""
+    mem, text, pool_bytes = _write_back(one_chip, built, name, batch)
+    entry = text[text.index("\nENTRY "):].split("\n", 2)[1]
+    assert " s32[]" in entry, entry     # the first column, traced
+    updates = len(re.findall(r" dynamic-update-slice\(", text))
+    trips = _trip_bounds(text)
+    assert (updates, trips) in ((batch * 3, set()), (1, {batch * 3})), (
+        updates, trips)
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert mem.temp_size_in_bytes < 2**23, mem.temp_size_in_bytes
+
+
 @pytest.mark.parametrize("name,batch", sorted(LAYER_OPERATIONS))
 def test_decode_segment_layer_operations(one_chip, built, name, batch):
     """The census of ISSUE 31 (PERF.md 3): the decode segment at the

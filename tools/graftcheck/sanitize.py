@@ -23,7 +23,8 @@ In-file declarations (the registration annotations, same idiom as
   donating callable is known to consume those argument positions.
 - ``POOL_MOVER_SCOPES``: tuple of function qualnames in which invoking
   a pool data mover (``pool.gather`` / ``pool.scatter`` /
-  ``pool.scatter_row`` / ``pool.scatter_columns`` / ``pool.cow_copy``)
+  ``pool.scatter_span`` / ``pool.scatter_row`` /
+  ``pool.scatter_columns`` / ``pool.cow_copy``)
   is legal — the scopes that provably hold a live ``BlockAllocator``
   lease on every block id they move. A mover call outside a declared
   scope is a finding; the dynamic sanitizer enforces the same property
@@ -74,8 +75,8 @@ SANITIZE_RULE_IDS = ("undeclared-donation", "donated-view",
 
 # pool data movers (KVBlockPool's device-op surface) and the receiver
 # names a consumer holds a pool under
-_MOVER_NAMES = {"gather", "scatter", "scatter_row", "scatter_columns",
-                "cow_copy"}
+_MOVER_NAMES = {"gather", "scatter", "scatter_span", "scatter_row",
+                "scatter_columns", "cow_copy"}
 _POOL_RECEIVERS = {"pool", "_pool"}
 
 
