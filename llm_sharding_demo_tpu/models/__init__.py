@@ -17,9 +17,12 @@ def family_module(config):
     standalone. Plain GPT2Config is the only family the dense pipeline
     partitioner (parallel.partition) can stage.
     """
-    from . import gdn_moe, gpt2, latent_moe, llama, moe, window_moe
+    from . import (gdn_moe, gpt2, hybrid_ssm, latent_moe, llama, moe,
+                   window_moe)
     if isinstance(config, moe.MoEConfig):
         return moe
+    if isinstance(config, hybrid_ssm.HybridSSMConfig):
+        return hybrid_ssm
     if isinstance(config, window_moe.WindowMoEConfig):
         return window_moe
     if isinstance(config, gdn_moe.GDNMoEConfig):
@@ -51,7 +54,9 @@ def cache_layers(config) -> int:
     """How many of a model's layers cache positions: all of them unless
     the family says otherwise (``gdn_moe``: the softmax layers, one in
     ``full_attention_interval``; ``window_moe``: the full-attention
-    layers, likewise; their other layers hold ``row_state``)."""
+    layers, likewise; their other layers hold ``row_state``;
+    ``hybrid_ssm`` says all of them, and holds ``row_state`` in all of
+    them too)."""
     declared = getattr(family_module(config), "cache_layers", None)
     return config.n_layer if declared is None else declared(config)
 
@@ -62,7 +67,8 @@ def row_state(config, dtype) -> tuple:
     ``()`` for the families whose every layer caches positions
     (``gdn_moe``: the linear-attention matrices and convolution tails;
     ``window_moe``: the sliding layers' rings of their last window of
-    positions). The state slab (``runtime.state_slab.StateSlab``) sizes
+    positions; ``hybrid_ssm``: every layer's state-space matrices and
+    convolution tails, beside every layer's positions). The state slab (``runtime.state_slab.StateSlab``) sizes
     itself from this."""
     declared = getattr(family_module(config), "row_state", None)
     return () if declared is None else declared(config, dtype)
@@ -93,7 +99,8 @@ def is_window_independent(config) -> bool:
     shapes (speculative verify windows, chunked prefill, prefix-cache
     continuations). MoE capacity-factor routing makes tokens compete for
     expert slots within a window, so it is window-DEPENDENT; the dense
-    families are independent, and so are ``latent_moe``, ``gdn_moe`` and
-    ``window_moe``, whose routing has no capacity and drops no token."""
+    families are independent (``hybrid_ssm`` is one), and so are
+    ``latent_moe``, ``gdn_moe`` and ``window_moe``, whose routing has no
+    capacity and drops no token."""
     from . import moe
     return not isinstance(config, moe.MoEConfig)

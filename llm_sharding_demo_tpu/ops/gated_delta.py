@@ -99,18 +99,23 @@ def gates(a: jnp.ndarray, b: jnp.ndarray, a_log: jnp.ndarray,
 
 
 def causal_conv(u: jnp.ndarray, tail: jnp.ndarray, w: jnp.ndarray,
+                bias: Optional[jnp.ndarray] = None,
                 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Depthwise causal convolution with a carried tail, then SiLU.
 
     ``u`` [B, T, C] this call's inputs, ``tail`` [B, W-1, C] the inputs
     of the ``W - 1`` positions before it (zeros before position 0),
-    ``w`` [C, W]: ``c_t = silu(sum_j w[:, j] u_{t - (W-1) + j})``.
+    ``w`` [C, W], ``bias`` [C] or ``None`` (nothing is added, and the
+    program is what it was without the argument): ``c_t = silu(bias +
+    sum_j w[:, j] u_{t - (W-1) + j})``.
     Returns ``(c [B, T, C] float32, the new tail)`` in ``tail``'s type."""
     t, width = u.shape[1], w.shape[1]
     full = jnp.concatenate([tail.astype(u.dtype), u], axis=1)
     w32 = w.astype(jnp.float32)
     c = sum(full[:, j:j + t].astype(jnp.float32) * w32[:, j]
             for j in range(width))
+    if bias is not None:
+        c = c + bias.astype(jnp.float32)
     return jax.nn.silu(c), full[:, t:].astype(tail.dtype)
 
 

@@ -72,6 +72,7 @@ MEMORY_LEDGER = {"data": "state_slab"}
 GUARDED_STATE = {
     "_free": "_lock", "_snap": "_lock", "in_use": "_lock",
     "peak": "_lock", "restores": "_lock", "evictions": "_lock",
+    "rows_gathered": "_lock", "rows_scattered": "_lock",
     "data": "_dev_lock",
 }
 
@@ -116,6 +117,11 @@ class StateSlab:
         self.peak = 0
         self.restores = 0
         self.evictions = 0
+        # records the movers carried, counted on the host from the ids
+        # they were handed (no device work, no fetch): times
+        # ``bytes_per_slot`` the bytes a batch's boundaries moved
+        self.rows_gathered = 0
+        self.rows_scattered = 0
         graftmem.track(self, "data", "state_slab", self.data)
 
         def _gather_state_impl(data, ids):
@@ -185,11 +191,15 @@ class StateSlab:
         """The state of the rows in slots ``ids`` [B] (the trash slot
         for ghost lanes), as ``KVCache.state``: fresh buffers, safe to
         donate."""
+        with self._lock:
+            self.rows_gathered += len(ids)
         with self._dev_lock:
             return self._gather(self.data, self._ids(ids))
 
     def scatter(self, state: tuple, ids) -> None:
         """Write ``state`` (batch on axis 1) back into slots ``ids``."""
+        with self._lock:
+            self.rows_scattered += len(ids)
         with self._dev_lock:
             self.data = self._scatter(self.data, state, self._ids(ids))
 
@@ -217,6 +227,7 @@ class StateSlab:
                 if slot is None:
                     return None
                 self.restores += 1
+                self.rows_gathered += 1
             return self._gather(self.data, self._ids([slot]))
 
     def drop(self, keys) -> None:
@@ -235,4 +246,7 @@ class StateSlab:
                     "state.peak": self.peak,
                     "state.snapshots": len(self._snap),
                     "state.restores": self.restores,
-                    "state.evictions": self.evictions}
+                    "state.evictions": self.evictions,
+                    "state.rows_gathered": self.rows_gathered,
+                    "state.rows_scattered": self.rows_scattered,
+                    "state.row_bytes": self.bytes_per_slot}

@@ -424,6 +424,34 @@ def create_app(cfg: Optional[ServingConfig] = None,
              "weight stacks; it serves float32 or bfloat16"),
         )
         _refuse(refused)
+    from ..models import hybrid_ssm as _hybrid
+    if isinstance(config, _hybrid.HybridSSMConfig):
+        # what the state-space / attention family refuses, one message
+        # each: it serves through the single-device engine (solo, the
+        # iteration scheduler, the paged pool of every layer's positions
+        # with every layer's row state in the state slab, the prefix
+        # store) in float32, bfloat16 or with int8 weights
+        name = type(config).__name__
+        refused = (
+            (cfg.spec_decode > 0,
+             f"SPEC_DECODE: a rejected draft cannot be rewound out of "
+             f"{name}'s per-row state (it has no position axis) without "
+             "a snapshot a verify; serve it without speculation"),
+            (cfg.kv_pool_dtype,
+             f"KV_POOL_DTYPE={cfg.kv_pool_dtype}: {name}'s pool holds "
+             "fused [K | V] rows in one plane and its rows' state is "
+             "float32 by contract; the quantized movers have not been "
+             "fitted to either"),
+            (cfg.kv_host_blocks > 0,
+             f"KV_HOST_BLOCKS: a demoted entry of {name} would need its "
+             "state snapshot demoted with its blocks; the host tier "
+             "moves blocks only"),
+            (cfg.pp_decode or cfg.tp_decode or cfg.ep_decode,
+             f"PP/TP/EP_DECODE: no multi-chip decoder stages or shards "
+             f"{name} (a state slab beside the pool in every layer, two "
+             "state-space groups to divide); it serves on one chip"),
+        )
+        _refuse(refused)
     if cfg.ep_decode:
         if not (cfg.shard_role == "coordinator" and cfg.dispatch == "local"):
             raise ValueError("EP_DECODE applies to the coordinator's local "

@@ -97,7 +97,7 @@ def hub_reachable(timeout: float = 1.0) -> bool:
 def _fallback_configs():
     # HF model ids / family names -> architecture configs for the
     # random-init fallback (lazy so importing loader stays light).
-    from ..models import llama, window_moe
+    from ..models import hybrid_ssm, llama, window_moe
     return {
         "sshleifer/tiny-gpt2": gpt2.CONFIGS["tiny-gpt2"],
         "gpt2": gpt2.CONFIGS["gpt2"],
@@ -105,6 +105,7 @@ def _fallback_configs():
         "llama-tiny": llama.CONFIGS["llama-tiny"],
         "llama-124m": llama.CONFIGS["llama-124m"],
         "window-moe-tiny": window_moe.CONFIGS["window-moe-tiny"],
+        "hybrid-ssm-tiny": hybrid_ssm.CONFIGS["hybrid-ssm-tiny"],
     }
 
 

@@ -488,3 +488,86 @@ def test_the_chunked_rule_holds_no_serial_solve(walks, prefills, ids):
     for gone in SERIAL_SOLVE:
         assert not re.search(gone, text), gone
     assert gated_delta.CHUNK not in _trip_bounds(text)
+
+
+# -- the state-space / attention family (ISSUE 42) ----------------------------
+#
+# Its six layers are all alike, so the layer loop of its decode segment
+# runs over LAYERS and one iteration is counted, as for Mistral: a
+# state-space mixer (one projection in, the convolution, the state
+# kernel, the gated norm, one projection out) and attention (three
+# projections, rotary, the two-plane decode kernel at FIVE query heads a
+# key-value head, one projection out) side by side, then the SwiGLU.
+# The numbers reached (59 operations a layer at one row, 65 at 16);
+# two kernel calls a layer, twelve a step.
+SSM = "falcon-h1-34b-l6"
+SSM_LAYER_OPERATIONS = {1: 59, 16: 65}
+# GB beside arguments and results (the compiler's report here): a
+# decode segment 0.575 at one row and 0.764 from two on, which is NOT
+# activations: the compiler copies three stacked weights once a call,
+# ahead of the steps' loop (the mixer's projection [6, 5120, 9248],
+# 0.57 GB, whose width is no whole number of lane tiles, and the query
+# and key projections the other way round, 0.19 GB; PERF.md 7); the
+# store's strides 0.002-0.006; a seed's longest prompt, 768 ids, 0.065.
+# EVERY position's logits of that prefill would be [768, 261120] float32
+# = 0.80 GB on their own: the family's calls of several positions run
+# the head on the last one
+SSM_TEMPORARIES, SSM_EXTEND_TEMPORARIES, SSM_PREFILL_TEMPORARIES = 0.8, 0.02, 0.1
+
+
+@pytest.mark.parametrize("batch", WIDTHS)
+def test_hybrid_ssm_decode_segment_compiles(one_chip, built, batch):
+    eng, params = built(SSM, None)
+    compiled, cache = _decode_segment(one_chip, eng, params, batch)
+    mem = one_chip.check(compiled)
+    cache_bytes = sum(x.size * x.dtype.itemsize
+                      for x in jax.tree.leaves(cache))
+    # positions AND row state of all six layers are updated in place
+    assert [x.shape[0] for x in jax.tree.leaves(cache)
+            if x.ndim > 1] == [6, 6, 6]
+    assert mem.alias_size_in_bytes >= cache_bytes
+    assert mem.temp_size_in_bytes < SSM_TEMPORARIES * 1e9, (
+        f"{mem.temp_size_in_bytes / 1e9:.3f} GB of temporaries")
+
+
+@pytest.mark.parametrize("batch", sorted(SSM_LAYER_OPERATIONS))
+def test_hybrid_ssm_decode_segment_layer_operations(one_chip, built, batch):
+    eng, params = built(SSM, None)
+    compiled, _ = _decode_segment(one_chip, eng, params, batch)
+    loops = _loops(compiled.as_text())
+    steps = [b for b, (holder, _) in loops.items() if holder not in loops]
+    assert len(steps) == 1, sorted(loops)
+    layers = [b for b, (holder, _) in loops.items() if holder == steps[0]]
+    assert len(layers) == 1, sorted(loops)
+    assert eng.config.n_layer in _trip_bounds(compiled.as_text())
+    layer = _operations(loops[layers[0]][1])
+    listed = "\n".join([f"{len(layer)} operations in a layer:"] + layer)
+    kernels = [x for x in loops[layers[0]][1] if "tpu_custom_call" in x]
+    # the state kernel and the two-plane decode kernel, once a layer
+    assert len(kernels) == 2, kernels
+    assert sum("ssm_state_update" in x for x in kernels) == 1, kernels
+    assert len(layer) <= SSM_LAYER_OPERATIONS[batch], listed
+    assert not [x for x in layer if re.search(r" sort\(", x)]
+
+
+@pytest.mark.parametrize("ids", [64, 128, 256])
+def test_hybrid_ssm_prefix_store_extend_compiles(walks, ids):
+    mem = walks(SSM, ids).memory_analysis()
+    assert mem.temp_size_in_bytes < SSM_EXTEND_TEMPORARIES * 1e9, (
+        f"{mem.temp_size_in_bytes / 1e9:.3f} GB of temporaries")
+
+
+def test_hybrid_ssm_prefill_holds_one_positions_logits(prefills):
+    """A seed's longest prompt (768 ids) in one call: the program holds
+    the LAST position's logits and no ``[768, 261120]`` array of any
+    type, and its temporaries stay under what that array alone would
+    take."""
+    name, compiled = prefills(SSM + ".burstchat")
+    assert name == SSM
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < SSM_PREFILL_TEMPORARIES * 1e9, (
+        f"{mem.temp_size_in_bytes / 1e9:.3f} GB of temporaries")
+    text = compiled.as_text()
+    assert not re.search(r"\[(1,)?768,261120\]", text)
+    assert re.search(r"f32\[1,(1,)?261120\]", text)
+    assert 768 * 261120 * 4 > SSM_PREFILL_TEMPORARIES * 1e9
