@@ -23,10 +23,14 @@ copy. Per (batch row, kv head) grid cell:
 
 - the new token's fused row is DMA'd into the cache IN PLACE
   (``input_output_aliases`` — the cache never copies);
-- KV blocks stream HBM -> VMEM double-buffered, and the block loop's
-  trip count is ``ceil(offset/BLOCK_S)`` — a *dynamic* bound, so reads
-  track live depth with no per-depth recompiles (the XLA path needs
-  windowed segments for a weaker version of this);
+- KV blocks stream HBM -> VMEM double-buffered, and both the block
+  loop's bounds and each row's copies are *dynamic*: a row's reads track
+  its own span ``[k_valid_from[b], offset)`` with no recompiles per
+  depth, pad or live count. A block that lies wholly under a row's left
+  pad is not read for that row, a lane whose span is empty (the iter
+  scheduler gives a lane without a request one) reads nothing, and the
+  loop starts at the first block some row needs (``first_block``; the
+  XLA path reads the whole window whatever lives in it);
 - online softmax over the blocks; the current token's contribution
   comes from the in-register ``k_new``/``v_new`` (its HBM write may
   still be in flight);
@@ -51,6 +55,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -94,7 +99,31 @@ def stream_block(bh: int, hd: int, itemsize: int) -> int:
     return bs
 
 
+def first_block(pad, off, block_s: int):
+    """The first block of ``block_s`` positions that a row with the span
+    ``[pad, off)`` streams: the block that holds ``pad``, and the block
+    count ``ceil(off / block_s)`` (no block at all) where the span is
+    empty. Row ``b`` reads exactly the blocks ``first_block(pad[b]) <= i
+    < ceil(off / block_s)``, and the loop starts at the smallest of the
+    rows' first blocks. Operators only: the kernel applies it to its SMEM
+    scalars and the scheduler's counter to numpy arrays
+    (``streamed_blocks``), so the two cannot drift apart."""
+    n_blk = (off + block_s - 1) // block_s
+    lo = pad // block_s
+    return lo + (pad >= off) * (n_blk - lo)
+
+
+def streamed_blocks(pads, off, block_s: int):
+    """How many blocks each row's stream reads at offset ``off``: ``[B]``
+    for ``pads`` ``[B]`` (and ``[B, T]`` for ``off`` ``[T]``, the steps
+    of a call), zero for an empty span."""
+    pads = np.asarray(pads)[(...,) + (None,) * np.ndim(off)]
+    return (np.asarray(off) + block_s - 1) // block_s - first_block(
+        pads, np.asarray(off), block_s)
+
+
 def _kernel(meta_ref,                      # SMEM  [2] int32 (li, off)
+            span_ref,                      # SMEM  [B] int32 pad per row
             q_ref, knew_ref, vnew_ref,     # VMEM (full arrays, [BH, ...])
             vf_ref,                        # VMEM [BH, 1, 1] int32 pad mask
             kv_in,                         # HBM fused cache (aliases out)
@@ -102,13 +131,18 @@ def _kernel(meta_ref,                      # SMEM  [2] int32 (li, off)
             acc_ref, m_ref, l_ref,         # VMEM scratch
             kvbuf, winbuf, copy_sems, write_sem,
             *, batch: int, hkv: int, g: int, hd: int):
-    """One grid cell, one DMA per S-block: each fetch carries ALL
-    (batch row, kv head) slices of the block and the compute is batched
-    over them, so the loop runs only ``ceil(off/block_s)`` iterations.
-    (Earlier shapes measured: a (b, h) grid ~2.6x slower and a flattened
-    per-(b,h,block) loop ~1.9x slower — both drowned in per-iteration
-    DMA/fence overhead at 64 KB blocks; this shape moves ~6 MB per DMA
-    at GPT-2-124M bs=8.)"""
+    """One grid cell, and per S-block one DMA a ROW that has a position
+    of its span in the block (all of the row's kv heads: 1 MB at Mistral's
+    geometry; issued and awaited in loops over the rows); the compute is
+    batched over every (batch row, kv head), so the block loop runs one
+    iteration a visited block. (Earlier shapes measured:
+    a (b, h) grid ~2.6x slower and a flattened per-(b,h,block) loop ~1.9x
+    slower — both drowned in per-iteration DMA/fence overhead at 64 KB
+    blocks.) A row whose slice of a visited block was not fetched computes
+    on zeros: the double buffer's lanes are cleared at entry wherever the
+    row's first copy into them comes later (stale VMEM under ``p = 0``
+    would be NaN if its bits are), and every score there is masked, so
+    the block leaves the row's ``m``/``l``/``acc`` as they were."""
     li = meta_ref[0]
     off = meta_ref[1]
     bh = batch * hkv
@@ -132,14 +166,40 @@ def _kernel(meta_ref,                      # SMEM  [2] int32 (li, off)
                                 preferred_element_type=jnp.float32)
     vf_bh = vf_ref[...]                                    # [BH, 1, 1]
 
-    n_blk = jnp.maximum((off + block_s - 1) // block_s, 1)
+    # the stream's plan, from the rows' spans: row b copies blocks
+    # [lo(b), n_blk), the loop visits [first, n_blk). Loops over the rows
+    # (not unrolled: a program's size is what set-up pays for)
+    n_blk = (off + block_s - 1) // block_s
 
-    def fetch(slot, i):
+    def lo(b):
+        return first_block(span_ref[b], off, block_s)
+
+    first = jax.lax.fori_loop(
+        0, batch, lambda b, lowest: jnp.minimum(lowest, lo(b)), n_blk)
+
+    def fetch(slot, i, b):
         return pltpu.make_async_copy(
-            kv_in.at[li, :, :, pl.ds(i * block_s, block_s), :],
-            kvbuf.at[slot], copy_sems.at[slot])
+            kv_in.at[li, b, :, pl.ds(i * block_s, block_s), :],
+            kvbuf.at[slot, b], copy_sems.at[slot, b])
 
-    fetch(0, 0).start()
+    def rows_of(ok, act):
+        """``act(b)`` for every row ``b`` with ``ok(b)``."""
+        def row(b, _):
+            pl.when(ok(b))(functools.partial(act, b))
+            return 0
+        jax.lax.fori_loop(0, batch, row, 0)
+
+    rows_of(lambda b: (first < n_blk) & (lo(b) == first),
+            lambda b: fetch(jax.lax.rem(first, 2), first, b).start())
+    # a lane of the double buffer is computed on before its row's first
+    # copy into it lands wherever the row starts after the first (slot of
+    # ``first``) or second visited block: those lanes, and no lane a copy
+    # above or in the loop writes before it is read, are zeroed
+    for k in range(2):
+        def clear(b, k=k):
+            kvbuf[jax.lax.rem(first + k, 2), b] = jnp.zeros(
+                kvbuf.shape[2:], kvbuf.dtype)
+        rows_of(lambda b, k=k: lo(b) > first + k, clear)
     # the column write's RMW window read starts NOW so its latency hides
     # behind the block stream (it reads pre-write state: rows < off are
     # never touched by this kernel until the final write below)
@@ -154,11 +214,9 @@ def _kernel(meta_ref,                      # SMEM  [2] int32 (li, off)
     def body(i, _):
         slot = jax.lax.rem(i, 2)
 
-        @pl.when(i + 1 < n_blk)
-        def _():
-            fetch(1 - slot, i + 1).start()
-
-        fetch(slot, i).wait()
+        rows_of(lambda b: (i + 1 < n_blk) & (i + 1 >= lo(b)),
+                lambda b: fetch(1 - slot, i + 1, b).start())
+        rows_of(lambda b: i >= lo(b), lambda b: fetch(slot, i, b).wait())
         kvb = kvbuf[slot].astype(jnp.float32).reshape(bh, block_s, 2 * hd)
         # q_ext's V lanes are zero, so the 2hd contraction is q . K
         s = jax.lax.dot_general(q_ext, kvb, (((2,), (2,)), ((0,), (0,))),
@@ -179,7 +237,7 @@ def _kernel(meta_ref,                      # SMEM  [2] int32 (li, off)
         m_ref[...] = m_new
         return 0
 
-    jax.lax.fori_loop(0, n_blk, body, 0)
+    jax.lax.fori_loop(first, n_blk, body, 0)
 
     # fold the current token's self term in once, extract the V half on
     # the MXU, and emit every (b, h) at once
@@ -222,14 +280,14 @@ def _kernel(meta_ref,                      # SMEM  [2] int32 (li, off)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def _call(q4, k_new, v_new, vf_bh, KV, meta, *, interpret: bool):
+def _call(q4, k_new, v_new, pads, vf_bh, KV, meta, *, interpret: bool):
     L, B, Hkv, Smax, hd2 = KV.shape
     hd = hd2 // 2
     g = q4.shape[2]
     block_s = stream_block(B * Hkv, hd, KV.dtype.itemsize)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,             # meta, and the rows' pads
         grid=(1,),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.VMEM),  # q [BH, g, hd]
@@ -248,7 +306,7 @@ def _call(q4, k_new, v_new, vf_bh, KV, meta, *, interpret: bool):
             pltpu.VMEM((B * Hkv, g, 1), jnp.float32),       # l
             pltpu.VMEM((2, B, Hkv, block_s, 2 * hd), KV.dtype),  # dbl buf
             pltpu.VMEM((B, Hkv, _WRITE_ROWS, 2 * hd), KV.dtype),  # RMW win
-            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SemaphoreType.DMA((2, B)),
             pltpu.SemaphoreType.DMA(()),
         ],
     )
@@ -260,9 +318,9 @@ def _call(q4, k_new, v_new, vf_bh, KV, meta, *, interpret: bool):
             jax.ShapeDtypeStruct((B * Hkv, g, hd), q4.dtype),
             jax.ShapeDtypeStruct(KV.shape, KV.dtype),
         ],
-        # inputs (incl. the scalar operand): meta=0, q=1, k_new=2,
-        # v_new=3, vf=4, KV=5 -> outputs (out=0, KV=1)
-        input_output_aliases={5: 1},
+        # inputs (incl. the scalar operands): meta=0, pads=1, q=2,
+        # k_new=3, v_new=4, vf=5, KV=6 -> outputs (out=0, KV=1)
+        input_output_aliases={6: 1},
         # the double buffer alone is ~2*B*Hkv*block_s*2hd*2 bytes (12.6 MB
         # at GPT-2-124M bs=8) — past the default 16 MB scoped-vmem limit
         # once accumulators join; v5e has 128 MB of VMEM to give, and
@@ -270,7 +328,7 @@ def _call(q4, k_new, v_new, vf_bh, KV, meta, *, interpret: bool):
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=100 * 1024 * 1024),
         interpret=interpret,
-    )(meta, q4.reshape(B * Hkv, g, hd),
+    )(meta, pads, q4.reshape(B * Hkv, g, hd),
       k_new.reshape(B * Hkv, 1, hd), v_new.reshape(B * Hkv, 1, hd),
       vf_bh, KV)
     return out, KV
@@ -288,7 +346,9 @@ def decode_attention(q: jnp.ndarray, k_new: jnp.ndarray, v_new: jnp.ndarray,
     aliases the input — callers must treat the passed buffer as consumed,
     which the decode scan's carry semantics already do).
     ``layer_idx``/``offset`` are traced scalars; ``k_valid_from`` [B]
-    masks each row's left-pad prefix like ``causal_attention``.
+    masks each row's left-pad prefix like ``causal_attention``, and is
+    where the row's stream starts: a row with ``k_valid_from >= offset``
+    reads nothing and attends to its own token alone.
     """
     B, H, q_len, hd = q.shape
     L, _, Hkv, Smax, hd2 = KV.shape
@@ -302,9 +362,13 @@ def decode_attention(q: jnp.ndarray, k_new: jnp.ndarray, v_new: jnp.ndarray,
     q4 = q.reshape(B, Hkv, g, hd)
     if k_valid_from is None:
         k_valid_from = jnp.zeros((B,), jnp.int32)
-    # per-row pad bound, pre-expanded to the [BH, 1, 1] layout the kernel
-    # consumes (building it from SMEM scalars in-kernel is unsupported)
-    vf_bh = jnp.repeat(k_valid_from.astype(jnp.int32), Hkv)[:, None, None]
+    # the pads twice: [B] scalars in SMEM, where the stream's copies are
+    # decided, and pre-expanded to the [BH, 1, 1] layout the score mask
+    # consumes in VMEM (building it from SMEM scalars in-kernel is
+    # unsupported)
+    pads = k_valid_from.astype(jnp.int32)
+    vf_bh = jnp.repeat(pads, Hkv)[:, None, None]
     meta = jnp.asarray([layer_idx, offset], jnp.int32).reshape(2)
-    out, KV = _call(q4, k_new, v_new, vf_bh, KV, meta, interpret=interpret)
+    out, KV = _call(q4, k_new, v_new, pads, vf_bh, KV, meta,
+                    interpret=interpret)
     return out.reshape(B, H, 1, hd), KV

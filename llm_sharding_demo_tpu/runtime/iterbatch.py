@@ -39,7 +39,14 @@ Batches are RIGHT-SIZED (ADVICE r4): a batch compiles at the smallest
 power-of-two width that fits its seed and grows on demand when an
 arrival finds no free slot — a lone request decodes at width 1 instead
 of paying ``max_batch`` x ghost-row FLOPs. Ghost rows (width minus live
-rows) replicate a real row; per-row independence keeps them inert.
+rows) replicate a real row; per-row independence keeps them inert. A
+lane WITHOUT a request (a ghost, a retired or parked row's) carries an
+EMPTY span: its entry of ``pad_j`` is the cache's length, a pad no depth
+reaches, so the decode kernel, which streams each row's own ``[pad,
+depth)``, reads nothing for it (``_empty_span``); a lane WITH a request
+keeps its pad to the digit. ``attn_positions_streamed`` over
+``attn_positions_rect`` (``stats()``) says what share of the rectangle
+width x depth the live rows' spans are.
 
 Compiled-program inventory (bounded): the engine's prefill programs
 (prompt-bucketed: multiples of ``prompt_bucket``, or the ladder a family
@@ -140,6 +147,7 @@ import numpy as np
 
 from ..ops import paged_attention as PA
 from ..ops.attention import KVCache
+from ..ops.decode_attention import BLOCK_S, streamed_blocks
 from ..utils import graftfault, graftmem, graftsched, graftscope, \
     grafttime, tracing
 from ..utils.metrics import REGISTRY, kv_block_gauges
@@ -248,6 +256,8 @@ GUARDED_STATE = {
     "gaps_answered": "_stats_lock",
     "calls_resident": "_stats_lock", "cache_gathers": "_stats_lock",
     "blocks_written_back": "_stats_lock",
+    "attn_positions_streamed": "_stats_lock",
+    "attn_positions_rect": "_stats_lock",
     "grows": "_stats_lock", "preemptions": "_stats_lock",
     "resumes": "_stats_lock", "fault_parks": "_stats_lock",
     "batches_closed": "_stats_lock", "_turned": "_stats_lock",
@@ -378,6 +388,11 @@ class _Slot:
     # admission order: THE preemption priority (higher = admitted later
     # = preempted first). Monotonic across the scheduler's lifetime.
     order: int = 0
+    # the row's left pad in the batch's cache, as ``pad_j`` holds it on
+    # the device (a plain batch never moves it): its span is
+    # ``[pad, depth)``, which the decode kernel streams and
+    # ``attn_positions_streamed`` counts
+    pad: int = 0
     # pool mode: this row's block ids at table columns
     # [blk_lo, blk_lo + len(blk_ids)) — everything outside points at
     # the trash block
@@ -611,6 +626,17 @@ class IterBatchingEngine:
         self.calls_resident = 0
         self.cache_gathers = 0
         self.blocks_written_back = 0
+        # cache positions the decode kernel's stream reads for the live
+        # rows of the plain calls (each row's own span ``[pad, depth)`` in
+        # whole blocks, summed over a call's steps), and the positions of
+        # the rectangle width x depth a stream of whole batches reads:
+        # how often spans spare the kernel a read (``_count_stream``)
+        self.attn_positions_streamed = 0
+        self.attn_positions_rect = 0
+        # what a lane WITHOUT a request holds in ``pad_j``: a pad no
+        # depth reaches, so that its span is empty and the kernel reads
+        # nothing for it (``_vacate``)
+        self._no_span = int(engine._cache_seq)
         # table columns one call's positions can span (pool mode)
         self._span = (None if pool is None else PA.span_blocks(
             seg_steps, pool.block_size, pool.nbm))
@@ -756,6 +782,8 @@ class IterBatchingEngine:
                    "calls_resident": self.calls_resident,
                    "cache_gathers": self.cache_gathers,
                    "blocks_written_back": self.blocks_written_back,
+                   "attn_positions_streamed": self.attn_positions_streamed,
+                   "attn_positions_rect": self.attn_positions_rect,
                    "grows": self.grows,
                    "preemptions": self.preemptions,
                    "resumes": self.resumes,
@@ -1143,6 +1171,11 @@ class IterBatchingEngine:
                         prompt_len=len(r.prompt))
                 covered.append((r.trace, pre))
 
+        if not spec_mode:
+            # the prefill's ghost lanes (``_empty_span``); a new array,
+            # the prefill's own pads may still alias ``pad``
+            pad_j = jnp.asarray(np.where(np.arange(b) < len(seed), pad,
+                                         np.int32(self._no_span)))
         state = _BatchState(sampling, first, cache, pad_j, s_max)
         # the span's window is the dispatch; the shared first-token
         # array says when the prefill had run
@@ -1178,7 +1211,8 @@ class IterBatchingEngine:
             if isinstance(e, _Parked):
                 n_res += 1
                 state.slots[i] = _Slot(
-                    req=r, plen=e.plen, row=i, first_ref=None,
+                    req=r, plen=e.plen, row=i, pad=int(pad[i]),
+                    first_ref=None,
                     first_idx=0, dk=None if dks is None else dks[i],
                     emitted=e.emitted, resumed_prefix=e.tokens,
                     order=e.order, t0=e.t0,
@@ -1186,6 +1220,7 @@ class IterBatchingEngine:
             else:
                 self._order += 1
                 state.slots[i] = _Slot(req=r, plen=len(r.prompt), row=i,
+                                       pad=int(pad[i]),
                                        first_ref=first_ref, first_idx=i,
                                        dk=None if dks is None else dks[i],
                                        order=self._order, t0=t0)
@@ -1425,8 +1460,9 @@ class IterBatchingEngine:
 
     def _grow(self, state: _BatchState):
         """Widen the live batch to the next power of two: pad token /
-        pad_j / cache along the batch axis by replicating row 0 (any
-        live content is valid ghost material — rows are independent).
+        cache along the batch axis by replicating row 0 (any live
+        content is valid ghost material — rows are independent), and
+        pad_j with the empty span of a lane without a request.
         One tiny concat program per (width, cache-shape) pair, from the
         same bounded width set as the decode programs.
 
@@ -1458,7 +1494,12 @@ class IterBatchingEngine:
             return one(c)
 
         state.token = rep(state.token, 0)
-        state.pad_j = rep(state.pad_j, 0)
+        # a ghost lane's span is empty (``_empty_span``); a speculative
+        # batch's keeps row 0's pad
+        state.pad_j = (rep(state.pad_j, 0) if state.spec_mode
+                       else jnp.concatenate(
+                           [state.pad_j, jnp.full((pad_rows,), self._no_span,
+                                                  jnp.int32)]))
         resident = state.cache is not None
         if resident:
             state.cache = (None if state.tables is not None
@@ -1612,7 +1653,7 @@ class IterBatchingEngine:
                 state.keys = state.keys.at[slot].set(chain)
         self._order += 1
         state.slots[slot] = _Slot(
-            req=req, plen=plen, row=slot,
+            req=req, plen=plen, row=slot, pad=state.depth - plen_eff,
             first_ref=None if resume is not None else _SegOut(first[None]),
             first_idx=0, dk=dk, t0=t0,
             emitted=resume.emitted if resume is not None else 1,
@@ -1713,6 +1754,26 @@ class IterBatchingEngine:
             raise
         return p_lo, ids
 
+    def _vacate(self, state: _BatchState, i: int) -> None:
+        """Lane ``i`` holds no request from here: its blocks go back and
+        its span is EMPTY (``_empty_span``)."""
+        self._release_blocks(state, i)
+        state.slots[i] = None
+        self._empty_span(state, i)
+
+    def _empty_span(self, state: _BatchState, i: int) -> None:
+        """A lane without a request (retired, parked, a ghost of the
+        seed's width) gets a pad that no depth reaches: the decode kernel
+        streams a row's span ``[pad, depth)`` and can tell such a lane
+        from a live one by nothing else, so with its row's stale pad it
+        would go on reading the row's blocks. The lane still computes
+        (rows are independent; its output is its own token's value, its
+        positions clip at 0). A speculative batch keeps its lanes' pads:
+        its segment rolls and rewrites every lane's pad, and runs the
+        XLA attention, which reads the window whatever the pads say."""
+        if not state.spec_mode:
+            state.pad_j = state.pad_j.at[i].set(self._no_span)
+
     def _release_blocks(self, state: _BatchState, i: int) -> None:
         s = state.slots[i]
         if self.pool is None or s is None:
@@ -1801,8 +1862,7 @@ class IterBatchingEngine:
                          preempt_t=time.perf_counter(),
                          spec_key=spec_key,
                          fault_budget_used=fault_budget_used)
-        self._release_blocks(state, s.row)
-        state.slots[s.row] = None
+        self._vacate(state, s.row)
         self._park(parked)
         grafttime.emit("park", rid=_rid_of(s.req), reason=reason,
                        emitted=parked.emitted)
@@ -1855,8 +1915,7 @@ class IterBatchingEngine:
                 s.req.fail(graftfault.FaultBudgetError(
                     f"row exhausted its transient-fault park budget "
                     f"({FAULT_PARK_BUDGET}); last fault: {fault}"))
-                self._release_blocks(state, i)
-                state.slots[i] = None
+                self._vacate(state, i)
                 continue
             if s.req.trace is not None:
                 s.req.trace.labels["fault_parks"] = (
@@ -2003,6 +2062,7 @@ class IterBatchingEngine:
                 cache = cache._replace(state=None)
         if resident:
             state.cache = cache
+        self._count_stream(state, d, n)
         state.depth = d + n
         self._in_flight.append(out)
         seg = _SegOut(out)
@@ -2053,6 +2113,31 @@ class IterBatchingEngine:
                                          counters=routing)
         self._retire_finished(state)
         self._set_gauges(state)
+
+    def _count_stream(self, state: _BatchState, d: int, n: int) -> None:
+        """What the decode kernel's stream reads in a call of ``n`` steps
+        from depth ``d``, reckoned on the host with the kernel's own
+        arithmetic (``ops.decode_attention.streamed_blocks``) in blocks
+        of ``BLOCK_S`` positions (a batch wide enough to stream finer
+        blocks reads a little under it): every live row's span, and the
+        rectangle width x depth that a stream of whole batches read. The
+        quotient is the share of the rectangle that spans still read:
+        near 1 for a lone row without pad, the lower the more lanes are
+        empty or pad."""
+        offs = np.arange(d, d + n)
+        streamed = BLOCK_S * int(streamed_blocks(
+            [s.pad for s in state.slots if s is not None], offs,
+            BLOCK_S).sum())
+        rect = BLOCK_S * len(state.slots) * int(
+            streamed_blocks([0], offs, BLOCK_S).sum())
+        with self._stats_lock:
+            self.attn_positions_streamed += streamed
+            self.attn_positions_rect += rect
+            share = self.attn_positions_streamed / max(
+                self.attn_positions_rect, 1)
+        REGISTRY.inc("iter_attn_positions_streamed_total", value=streamed)
+        REGISTRY.inc("iter_attn_positions_rect_total", value=rect)
+        REGISTRY.gauge("iter_attn_stream_share", round(share, 4))
 
     def _advance_spec(self, state: _BatchState):
         """One draft-verify SEGMENT (spec batches): up to
@@ -2223,8 +2308,7 @@ class IterBatchingEngine:
                     "deadline budget exhausted mid-decode; row "
                     "cancelled at the segment boundary"))
                 s.req.cancelled.set()
-                self._release_blocks(state, i)
-                state.slots[i] = None
+                self._vacate(state, i)
                 continue
             if s.req.cancelled.is_set():
                 # Caller timed out and left: free the slot instead of
@@ -2237,8 +2321,7 @@ class IterBatchingEngine:
                     s.req.trace.add_span("abandoned", t, t,
                                          scheduler="iter",
                                          emitted=s.emitted)
-                self._release_blocks(state, i)
-                state.slots[i] = None
+                self._vacate(state, i)
                 continue
             done = s.emitted >= s.req.max_new_tokens
             eos_at = None
@@ -2283,8 +2366,7 @@ class IterBatchingEngine:
         s.done_t = time.monotonic()
         s.req.payload = (s, eos_at)
         s.req.done.set()
-        self._release_blocks(state, i)
-        state.slots[i] = None
+        self._vacate(state, i)
         gaps = (min(s.emitted, s.req.max_new_tokens) - 1 if eos_at is None
                 else eos_at)
         with self._stats_lock:

@@ -88,6 +88,12 @@ METRIC_CATALOG: Dict[str, str] = {
     "iter_calls_resident_total": "counter",
     "iter_cache_gathers_total": "counter",
     "kv_pool_blocks_written_back_total": "counter",
+    # cache positions the decode kernel's stream reads for the live rows'
+    # own spans, the positions of the rectangles width x depth, and the
+    # running quotient (iterbatch._count_stream)
+    "iter_attn_positions_streamed_total": "counter",
+    "iter_attn_positions_rect_total": "counter",
+    "iter_attn_stream_share": "gauge",
     "iter_steps_paid_total": "counter",
     "iter_gaps_answered_total": "counter",
     "iter_rows_total": "counter",

@@ -18,9 +18,11 @@ from llm_sharding_demo_tpu.models import gpt2, llama
 from llm_sharding_demo_tpu.ops.attention import (cached_attention_fused,
                                                  create_fused_cache,
                                                  is_fused_cache)
-from llm_sharding_demo_tpu.ops.decode_attention import (BLOCK_S,
+from llm_sharding_demo_tpu.ops.decode_attention import (BLOCK_S, _call,
                                                         decode_attention,
-                                                        eligible)
+                                                        eligible, first_block,
+                                                        stream_block,
+                                                        streamed_blocks)
 from llm_sharding_demo_tpu.runtime.engine import DecodeEngine
 
 
@@ -28,31 +30,131 @@ def _rand(key, shape, dtype=jnp.float32):
     return jax.random.normal(key, shape, dtype)
 
 
-@pytest.mark.parametrize("off,vf", [
-    (37, None),                       # single partial block
-    (255, [0, 5]),                    # block boundary - 1, ragged mask
-    (256, None),                      # exactly one full block
-    (509, [100, 0]),                  # deep, ragged
-])
-@pytest.mark.parametrize("hkv", [2, 4])   # GQA (g=2) and MHA (g=1)
-def test_kernel_matches_fused_xla(off, vf, hkv):
-    L, B, H, S, hd = 3, 2, 4, 512, 64
+# eight rows whose spans start in different blocks of 256 positions
+RAGGED = [0, 10, 260, 520, 699, 300, 0, 512]
+
+
+def _operands(off, vf, hkv, L=3, H=4, hd=64):
+    """A batch as wide as ``vf`` (two rows without one) over a cache of
+    whole blocks that holds ``off``, written up to ``off``."""
+    B = 2 if vf is None else len(vf)
+    S = BLOCK_S * (off // BLOCK_S + 1) if off >= 2 * BLOCK_S else 512
     ks = jax.random.split(jax.random.PRNGKey(0), 5)
     KV = _rand(ks[0], (L, B, hkv, S, 2 * hd))
     KV = KV.at[..., off:, :].set(0)   # slots >= off unwritten (zeros)
     q = _rand(ks[1], (B, H, 1, hd))
     kn = _rand(ks[2], (B, hkv, 1, hd))
     vn = _rand(ks[3], (B, hkv, 1, hd))
-    vf_j = None if vf is None else jnp.asarray(vf, jnp.int32)
-    for li in (0, L - 1):
+    return KV, q, kn, vn, None if vf is None else jnp.asarray(vf, jnp.int32)
+
+
+def _under_pad(KV, vf, value):
+    """``KV`` with ``value`` in every streamed block that lies wholly
+    under its row's pad, all layers and heads."""
+    bs = stream_block(KV.shape[1] * KV.shape[2], KV.shape[-1] // 2,
+                      KV.dtype.itemsize)
+    for b, pad in enumerate(vf):
+        KV = KV.at[:, b, :, :min(pad // bs * bs, KV.shape[3])].set(value)
+    return KV
+
+
+@pytest.mark.parametrize("off,vf", [
+    (37, None),                       # single partial block
+    (255, [0, 5]),                    # block boundary - 1, ragged mask
+    (256, None),                      # exactly one full block
+    (509, [100, 0]),                  # deep, ragged
+    (700, RAGGED),                    # a first block a row
+    (700, [260, 300, 520, 256, 699, 511, 512, 600]),   # none in block 0
+    (700, [0, 10, 700, 520, 699, 300, 0, 512]),        # one empty span
+    (700, [1024, 10, 900, 520, 1024, 300, 700, 512]),  # several
+    (300, [512, 300]),                # every span empty: no block visited
+    (768, RAGGED),                    # on a block boundary
+    (767, RAGGED),                    # and one short of it
+])
+@pytest.mark.parametrize("hkv", [2, 4])   # GQA (g=2) and MHA (g=1)
+def test_kernel_matches_fused_xla(off, vf, hkv):
+    KV, q, kn, vn, vf_j = _operands(off, vf, hkv)
+    # a lane past ``off`` masks its own token too in the XLA form; the
+    # kernel's self term stands, so the lane's output is its new value
+    dead = np.zeros(q.shape[0], bool) if vf is None else np.asarray(vf) > off
+    g = q.shape[1] // hkv
+    for li in (0, KV.shape[0] - 1):
         ref, KV1 = cached_attention_fused(q, kn, vn, KV, li, off, vf_j)
         out, KV2 = decode_attention(q, kn, vn, KV, li, off, vf_j,
                                     interpret=True)
+        ref = jnp.where(dead[:, None, None, None],
+                        jnp.repeat(vn, g, axis=1), ref)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=2e-6, rtol=2e-6)
         # the in-place column write must be byte-identical to the XLA
         # write (values pass through untouched)
         assert jnp.array_equal(KV1, KV2)
+
+
+@pytest.mark.parametrize("off,vf", [
+    (700, RAGGED), (700, [1024, 10, 900, 520, 1024, 300, 700, 512]),
+    (768, [256, 767, 512, 300, 768, 0, 511, 513])])
+def test_kernel_reads_no_block_under_a_rows_pad(off, vf):
+    """NaN in every block that lies wholly under a row's pad (a lane with
+    an empty span: every block) and the clean cache's result all the
+    same: those blocks are not consumed. (The interpreter's scratch
+    starts as NaN too: what a row computes on where it fetched nothing
+    is the cleared buffer.)"""
+    KV, q, kn, vn, vf_j = _operands(off, vf, hkv=2)
+    bad = _under_pad(KV, vf, jnp.nan)
+    assert bool(jnp.isnan(bad).any())
+    for li in (0, KV.shape[0] - 1):
+        want, _ = decode_attention(q, kn, vn, KV, li, off, vf_j,
+                                   interpret=True)
+        got, KV2 = decode_attention(q, kn, vn, bad, li, off, vf_j,
+                                    interpret=True)
+        assert jnp.array_equal(want, got)
+        _, KV1 = cached_attention_fused(q, kn, vn, bad, li, off, vf_j)
+        np.testing.assert_array_equal(np.asarray(KV1), np.asarray(KV2))
+
+
+@pytest.mark.parametrize("off,vf", [
+    (700, RAGGED), (700, [260, 300, 520, 256, 699, 511, 512, 600]),
+    (767, [1024, 10, 900, 520, 1024, 300, 700, 512])])
+def test_skipped_blocks_change_no_bit(off, vf):
+    """The rows' outputs against the stream of the WHOLE rectangle (what
+    the kernel read before spans reached it): the same call with all-zero
+    pads for the stream and the rows' pads for the score mask, on a cache
+    whose pad region is zero. A block that was not read is one whose
+    every score was masked: bit for bit the same."""
+    hkv = 2
+    KV, q, kn, vn, vf_j = _operands(off, vf, hkv)
+    KV = _under_pad(KV, vf, 0.0)
+    B, H, _, hd = q.shape
+    args = (q.reshape(B, hkv, H // hkv, hd), kn, vn)
+    mask = jnp.repeat(vf_j, hkv)[:, None, None]
+    meta = jnp.asarray([1, off], jnp.int32)
+    spans, _ = _call(*args, vf_j, mask, KV, meta, interpret=True)
+    whole, _ = _call(*args, jnp.zeros_like(vf_j), mask, KV, meta,
+                     interpret=True)
+    assert jnp.array_equal(spans, whole)
+
+
+def test_first_block_and_streamed_blocks_count_the_fetches():
+    """Which (row, block) pairs the stream fetches, as the kernel and the
+    scheduler's counter both reckon it."""
+    # a lane with an empty span: no block; [520, 700) in 256-blocks: one
+    assert list(streamed_blocks([700, 1024, 520, 0, 699, 256], 700, 256)
+                ) == [0, 0, 1, 3, 1, 2]
+    assert first_block(520, 700, 256) == 2
+    assert first_block(700, 700, 256) == 3 == first_block(2048, 700, 256)
+    # the loop's first block is the smallest of the rows' first blocks
+    assert min(first_block(np.asarray([520, 300, 2048]), 700, 256)) == 1
+    # on a block boundary and one past it; nothing at depth 0
+    assert list(streamed_blocks([0, 255, 256, 511], 512, 256)) == [2, 2, 1, 1]
+    assert list(streamed_blocks([0, 255, 256, 511], 513, 256)) == [3, 3, 2, 2]
+    assert list(streamed_blocks([0, 5], 0, 256)) == [0, 0]
+    # a call's steps at once: [rows, steps]
+    np.testing.assert_array_equal(
+        streamed_blocks([0, 300, 2048], np.arange(510, 514), 256),
+        [[2, 2, 2, 3], [1, 1, 1, 2], [0, 0, 0, 0]])
+    # narrower blocks (a wide batch's stream)
+    assert list(streamed_blocks([0, 130, 700], 700, 128)) == [6, 5, 0]
 
 
 def test_engine_kernel_greedy_stream_matches_xla_gpt2():

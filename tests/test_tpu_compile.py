@@ -11,13 +11,17 @@ whole programs are compiled in ``tests/test_tpu_compile_engine.py``, a
 file (and so, under ``--dist loadfile``, a worker) of their own.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
 
 from llm_sharding_demo_tpu.models import gpt2
+from llm_sharding_demo_tpu.ops import decode_attention as decode_attention_mod
 from llm_sharding_demo_tpu.ops import quant
-from llm_sharding_demo_tpu.ops.decode_attention import decode_attention
+from llm_sharding_demo_tpu.ops.decode_attention import (decode_attention,
+                                                        stream_block)
 from llm_sharding_demo_tpu.ops.flash_attention import flash_attention
 
 
@@ -38,6 +42,43 @@ def test_decode_attention_compiles(one_chip, geometry, batch):
         shape((batch, h, 1, hd)), shape((batch, hkv, 1, hd)),
         shape((batch, hkv, 1, hd)),
         shape((layers, batch, hkv, depth, 2 * hd)))
+
+
+# the same kernel with the rows' pads, as the iter scheduler calls it
+# (the spans its copies follow: a second scalar operand, a copy and a
+# semaphore a row): ``mistral-7b-l16`` at the two widest batches its
+# cells run, ``falcon-h1-34b-l6`` (20 query heads over 4) at its widest,
+# ``qwen3-next-80b-ep32``'s softmax layers (16 over 2 of 256) at 4
+@pytest.mark.parametrize("geometry,batch", [
+    *[pytest.param((32, 8, 128, 16, 2048), b, id=f"mistral-7b-l16-{b}")
+      for b in (8, 16)],
+    pytest.param((20, 4, 128, 6, 1024), 16, id="falcon-h1-34b-l6-16"),
+    pytest.param((16, 2, 256, 12, 3072), 4, id="qwen3-next-80b-ep32-4")])
+def test_decode_attention_with_spans_compiles(one_chip, geometry, batch):
+    h, hkv, hd, layers, depth = geometry
+    shape = one_chip.shape
+    compiled = jax.jit(
+        lambda q, k, v, kv, li, off, pad: decode_attention(
+            q, k, v, kv, li, off, pad)).lower(
+        shape((batch, h, 1, hd)), shape((batch, hkv, 1, hd)),
+        shape((batch, hkv, 1, hd)),
+        shape((layers, batch, hkv, depth, 2 * hd)),
+        shape((), jnp.int32), shape((), jnp.int32),
+        shape((batch,), jnp.int32)).compile()
+    one_chip.check(compiled)
+    # the VMEM the compiler held the kernel to (the program's own report
+    # of its scoped memory: a kernel past it does not compile) lies
+    # inside the room ``stream_block`` reckons with, and so does the
+    # stream it sized: the double buffer and the body's temporaries
+    room = (decode_attention_mod._VMEM_BYTES
+            - decode_attention_mod._VMEM_HEADROOM)
+    held = max(int(n) for n in re.findall(
+        r'"memory_space":"1","offset":"0","size":"(\d+)"',
+        compiled.as_text()))
+    assert 0 < held <= room
+    block = stream_block(batch * hkv, hd, 2)
+    assert block * batch * hkv * 2 * hd * (
+        2 * 2 + 4 * decode_attention_mod._F32_TEMPORARIES) <= room
 
 
 @pytest.mark.parametrize("batch,lanes", [(1, 640), (16, 640)])
