@@ -17,8 +17,8 @@ def family_module(config):
     standalone. Plain GPT2Config is the only family the dense pipeline
     partitioner (parallel.partition) can stage.
     """
-    from . import (gdn_moe, gpt2, hybrid_ssm, latent_moe, llama, moe,
-                   window_moe)
+    from . import (gdn_moe, gpt2, hybrid_ssm, kda_moe, latent_moe, llama,
+                   moe, window_moe)
     if isinstance(config, moe.MoEConfig):
         return moe
     if isinstance(config, hybrid_ssm.HybridSSMConfig):
@@ -27,6 +27,8 @@ def family_module(config):
         return window_moe
     if isinstance(config, gdn_moe.GDNMoEConfig):
         return gdn_moe
+    if isinstance(config, kda_moe.KDAMoEConfig):
+        return kda_moe
     if isinstance(config, latent_moe.LatentMoEConfig):
         return latent_moe
     if isinstance(config, llama.LlamaConfig):
@@ -41,8 +43,8 @@ def cache_entry(config) -> tuple:
     CACHED layer, as the family declares it (``cache_layers`` says how
     many layers those are). The dense families keep two planes (keys,
     values) of ``n_kv_head x head_dim``; a family whose cache is
-    something else (``latent_moe``: one plane of one latent vector) says
-    so in its own ``cache_entry``. The paged pool, its movers, the
+    something else (``latent_moe`` and ``kda_moe``: one plane of one
+    latent vector) says so in its own ``cache_entry``. The paged pool, its movers, the
     prefix store and the byte accounting size themselves from this."""
     declared = getattr(family_module(config), "cache_entry", None)
     if declared is not None:
@@ -54,7 +56,8 @@ def cache_layers(config) -> int:
     """How many of a model's layers cache positions: all of them unless
     the family says otherwise (``gdn_moe``: the softmax layers, one in
     ``full_attention_interval``; ``window_moe``: the full-attention
-    layers, likewise; their other layers hold ``row_state``;
+    layers, likewise; ``kda_moe``: the latent layers its published
+    list names; their other layers hold ``row_state``;
     ``hybrid_ssm`` says all of them, and holds ``row_state`` in all of
     them too)."""
     declared = getattr(family_module(config), "cache_layers", None)
@@ -65,7 +68,8 @@ def row_state(config, dtype) -> tuple:
     """What one ROW holds beside its cached positions: ``(shape,
     dtype)`` of each leaf of ``KVCache.state``, batch axis left out, or
     ``()`` for the families whose every layer caches positions
-    (``gdn_moe``: the linear-attention matrices and convolution tails;
+    (``gdn_moe`` and ``kda_moe``: the linear-attention matrices and
+    convolution tails;
     ``window_moe``: the sliding layers' rings of their last window of
     positions; ``hybrid_ssm``: every layer's state-space matrices and
     convolution tails, beside every layer's positions). The state slab (``runtime.state_slab.StateSlab``) sizes
@@ -100,7 +104,7 @@ def is_window_independent(config) -> bool:
     continuations). MoE capacity-factor routing makes tokens compete for
     expert slots within a window, so it is window-DEPENDENT; the dense
     families are independent (``hybrid_ssm`` is one), and so are
-    ``latent_moe``, ``gdn_moe`` and ``window_moe``, whose routing has no
-    capacity and drops no token."""
+    ``latent_moe``, ``gdn_moe``, ``kda_moe`` and ``window_moe``, whose
+    routing has no capacity and drops no token."""
     from . import moe
     return not isinstance(config, moe.MoEConfig)

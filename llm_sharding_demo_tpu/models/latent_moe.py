@@ -5,7 +5,8 @@ JAX, with the family surface every runtime module dispatches on
 (``init_params`` / ``forward`` / ``forward_with_cache`` / ``make_cache``):
 
 - **Latent attention** (``ops.latent_attention``): queries through a
-  ``q_lora_rank`` bottleneck with its own RMSNorm; keys and values
+  ``q_lora_rank`` bottleneck with its own RMSNorm (``None``: one
+  projection, ``wq``, and no bottleneck); keys and values
   through a ``kv_lora_rank`` latent, normalised, plus one rotary key of
   ``qk_rope_head_dim`` shared by all heads. THE CACHE HOLDS ``[c_kv |
   k_pe]`` AND NOTHING ELSE: one plane, one "head", ``kv_lora_rank +
@@ -22,7 +23,9 @@ JAX, with the family surface every runtime module dispatches on
   rotary parts are turned pair by pair where they lie (``ops.rope.
   rotate_pairs``: the published layout is interleaved), queries and
   keys alike in every form, so ``k_pe`` is stored in the order the
-  weights give it.
+  weights give it. ``mla_use_nope`` leaves every rotation out: the
+  "rotary" dimensions are then plain dimensions of the one shared key
+  (``models.kda_moe``, whose other layers carry the order).
 - **A single position has its own forms** where a cheaper one exists,
   chosen by the shapes a call brings (a decode step is ``[B, 1]``):
   the folds into and out of the latent as one matmul each against the
@@ -105,7 +108,7 @@ class LatentMoEConfig:
     n_embd: int = 2048
     n_layer: int = 40
     n_head: int = 32
-    q_lora_rank: int = 1536
+    q_lora_rank: Optional[int] = 1536    # None: no query bottleneck
     kv_lora_rank: int = 512
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
@@ -122,6 +125,7 @@ class LatentMoEConfig:
     norm_topk_prob: bool = True
     rms_norm_eps: float = 1e-6
     rope_theta: float = 10000.0
+    mla_use_nope: bool = False           # True: nothing is rotated
     attention_impl: str = "xla"
 
     @property
@@ -245,12 +249,18 @@ def init_params(config: LatentMoEConfig, key: jax.Array,
         std = std if std is not None else fan_in ** -0.5
         return (jax.random.normal(next(keys), shape) * std).astype(dtype)
 
-    def attn(l):
+    def queries(l):
+        if c.q_lora_rank is None:
+            return {"wq": {"kernel": normal((l, d, h * c.head_dim), d)}}
         return {
             "wdq": {"kernel": normal((l, d, c.q_lora_rank), d)},
             "q_norm": {"scale": jnp.ones((l, c.q_lora_rank), dtype)},
             "wuq": {"kernel": normal((l, c.q_lora_rank, h * c.head_dim),
-                                     c.q_lora_rank)},
+                                     c.q_lora_rank)}}
+
+    def attn(l):
+        return {
+            **queries(l),
             "wdkv": {"kernel": normal((l, d, c.cache_width), d)},
             "kv_norm": {"scale": jnp.ones((l, c.kv_lora_rank), dtype)},
             "wuk": {"kernel": normal(
@@ -295,20 +305,29 @@ def _attention(attn: Params, a: jnp.ndarray, config: LatentMoEConfig,
                cos, sin, cache: Optional[jnp.ndarray], layer_idx, offset,
                pad: Optional[jnp.ndarray], fresh: bool,
                decode_kernel: Optional[str] = None):
-    """The mixer: ``a`` [B, S, d] normed -> ``(out [B, S, d], cache)``."""
+    """The mixer: ``a`` [B, S, d] normed -> ``(out [B, S, d], cache)``.
+    ``config`` is any family's that has this mixer's sizes under these
+    names; with ``mla_use_nope`` the angles are not read."""
     c = config
     b, s, _ = a.shape
     with jax.named_scope("latent_attn"):
-        c_q = rms_norm(linear(a, attn["wdq"]["kernel"]),
-                       attn["q_norm"]["scale"], c.rms_norm_eps)
-        q = linear(c_q, attn["wuq"]["kernel"]).reshape(
-            b, s, c.n_head, c.head_dim).transpose(0, 2, 1, 3)
-        q_nope = q[..., :c.qk_nope_head_dim]
-        q_pe = rotate_pairs(q[..., c.qk_nope_head_dim:], cos, sin)
+        if c.q_lora_rank is None:
+            q = linear(a, attn["wq"]["kernel"])
+        else:
+            c_q = rms_norm(linear(a, attn["wdq"]["kernel"]),
+                           attn["q_norm"]["scale"], c.rms_norm_eps)
+            q = linear(c_q, attn["wuq"]["kernel"])
+        q = q.reshape(b, s, c.n_head, c.head_dim).transpose(0, 2, 1, 3)
+        q_nope, q_pe = (q[..., :c.qk_nope_head_dim],
+                        q[..., c.qk_nope_head_dim:])
+        if not c.mla_use_nope:
+            q_pe = rotate_pairs(q_pe, cos, sin)
         down = linear(a, attn["wdkv"]["kernel"])
         c_kv = rms_norm(down[..., :c.kv_lora_rank],
                         attn["kv_norm"]["scale"], c.rms_norm_eps)
-        k_pe = rotate_pairs(down[:, None, :, c.kv_lora_rank:], cos, sin)[:, 0]
+        k_pe = down[..., c.kv_lora_rank:]
+        if not c.mla_use_nope:
+            k_pe = rotate_pairs(k_pe[:, None], cos, sin)[:, 0]
         wuk, wuv = attn["wuk"]["kernel"], attn["wuv"]["kernel"]
         if cache is not None:
             # the row's lanes past ``cache_width`` are zeros since

@@ -13,6 +13,17 @@ nothing here is paged, rolled or windowed; what a caller carries beside
 it is the last ``width - 1`` inputs of the depthwise causal convolution
 in front of the rule (``causal_conv``'s tail).
 
+This module is the rule with ONE decay a head a position (``g`` a
+scalar); ``ops.kda`` is the rule with one decay a key CHANNEL and takes
+``l2norm``, ``gates``, ``causal_conv``, ``unit_lower_solve``, the cut
+into chunks and the kernel's call from here. What is said below of ``L``'s
+factoring (``e^{G_i - G_j}`` outside the dot product) and of every
+exponent being <= 0 BY CONSTRUCTION holds for the scalar gate only: per
+channel the decay sits inside the sum over channels, and the obvious
+factoring forms ``e^{-G}``, which overflows (``ops.kda`` says what it
+does instead). The recurrence, the solve, the state's carry through the
+chunks and the masked positions are the same in both.
+
 Three forms of the same sums:
 
 - ``recurrence``: the line above, position by position (``lax.scan``):
@@ -162,6 +173,25 @@ def unit_lower_solve(lower: jnp.ndarray, rhs: jnp.ndarray) -> jnp.ndarray:
     return mm(inv, rhs)
 
 
+def in_chunks(xs, chunk: int):
+    """Each ``[B, H, T, ...]`` of ``xs`` padded on the right with zeros
+    (positions that change nothing) to whole chunks and cut into them:
+    ``[n, B, H, chunk, ...]``."""
+    b, h, t = xs[0].shape[:3]
+    n = -(-t // chunk)
+    extra = n * chunk - t
+    if extra:
+        def fill(x):
+            return jnp.pad(x, [(0, 0), (0, 0), (0, extra)]
+                           + [(0, 0)] * (x.ndim - 3))
+        xs = tuple(map(fill, xs))
+
+    def cut(x):                        # [B,H,n*C,...] -> [n,B,H,C,...]
+        return jnp.moveaxis(x.reshape((b, h, n, chunk) + x.shape[3:]), 2, 0)
+
+    return tuple(map(cut, xs))
+
+
 def chunked(q, k, v, g, beta, state, chunk: int = CHUNK):
     """The rule in chunks of ``chunk`` positions from the call's first
     (module docstring). Shapes as ``recurrence``; ``T`` is padded on the
@@ -169,17 +199,7 @@ def chunked(q, k, v, g, beta, state, chunk: int = CHUNK):
     b, h, t, dk = q.shape
     dv = v.shape[-1]
     n = -(-t // chunk)
-    extra = n * chunk - t
-    if extra:
-        def fill(x):
-            return jnp.pad(x, [(0, 0), (0, 0), (0, extra)]
-                           + [(0, 0)] * (x.ndim - 3))
-        q, k, v, g, beta = map(fill, (q, k, v, g, beta))
-
-    def cut(x):                        # [B,H,n*C,...] -> [n,B,H,C,...]
-        return jnp.moveaxis(x.reshape((b, h, n, chunk) + x.shape[3:]), 2, 0)
-
-    q, k, v, g, beta = map(cut, (q, k, v, g, beta))
+    q, k, v, g, beta = in_chunks((q, k, v, g, beta), chunk)
     big = jnp.cumsum(g, axis=-1)                               # G
     i = jnp.arange(chunk)
     seen = i[:, None] >= i[None, :]
@@ -222,20 +242,22 @@ def kernel_eligible(dk: int, dv: int, heads: int) -> bool:
     return dk % 128 == 0 and dv % 128 == 0 and heads % hb == 0
 
 
+def stood_up(rows):
+    """Inside a kernel: each of the ``n`` vectors of ``rows`` [hb, n, K],
+    which lie along the lanes, stood up along the sublanes ([hb, K, 1]):
+    the diagonal of its broadcast, summed over the lanes."""
+    dk = rows.shape[-1]
+    eye = (jax.lax.broadcasted_iota(jnp.int32, (dk, dk), 0)
+           == jax.lax.broadcasted_iota(jnp.int32, (dk, dk), 1))
+    return [jnp.sum(jnp.where(eye, rows[:, i:i + 1], 0.0), axis=-1,
+                    keepdims=True) for i in range(rows.shape[1])]
+
+
 def _step_kernel(li_ref, qk_ref, vdb_ref, s_ref, o_ref, s_out_ref):
     del li_ref                          # used by the index maps
     qk = qk_ref[...]                    # [hb, 2, K]   q, k
     vdb = vdb_ref[...]                  # [hb, 3, V]   v, exp(g), beta
-    dk = qk.shape[-1]
-    # a vector that lies along the lanes, stood up along the sublanes:
-    # the diagonal of its broadcast, summed over the lanes
-    eye = (jax.lax.broadcasted_iota(jnp.int32, (dk, dk), 0)
-           == jax.lax.broadcasted_iota(jnp.int32, (dk, dk), 1))
-
-    def column(row):                    # [hb, 1, K] -> [hb, K, 1]
-        return jnp.sum(jnp.where(eye, row, 0.0), axis=-1, keepdims=True)
-
-    q_col, k_col = column(qk[:, 0:1]), column(qk[:, 1:2])
+    q_col, k_col = stood_up(qk)
     v, decay, beta = vdb[:, 0:1], vdb[:, 1:2], vdb[:, 2:3]
     s = s_ref[...] * decay                                   # [hb, K, V]
     d = beta * (v - jnp.sum(s * k_col, axis=1, keepdims=True))
@@ -244,16 +266,23 @@ def _step_kernel(li_ref, qk_ref, vdb_ref, s_ref, o_ref, s_out_ref):
     o_ref[...] = jnp.sum(s * q_col, axis=1, keepdims=True)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _step_call(qk, vdb, states, layer_idx, *, interpret: bool):
+@functools.partial(jax.jit, static_argnames=("kernel", "name", "interpret"))
+def _step_call(qk, vdb, states, layer_idx, *, interpret: bool,
+               kernel=_step_kernel, name: str = "gdn_state_update"):
+    """``kernel`` over a grid of (row, block of heads): its vectors along
+    the keys ``qk`` [B, H, n, K], those along the values ``vdb`` [B, H,
+    m, V], and layer ``layer_idx`` of ``states`` streamed through VMEM
+    and written back in place."""
     _, b, h, dk, dv = states.shape
     hb = min(HEAD_BLOCK, h)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(b, h // hb),
         in_specs=[
-            pl.BlockSpec((None, hb, 2, dk), lambda i, j, li: (i, j, 0, 0)),
-            pl.BlockSpec((None, hb, 3, dv), lambda i, j, li: (i, j, 0, 0)),
+            pl.BlockSpec((None, hb, qk.shape[2], dk),
+                         lambda i, j, li: (i, j, 0, 0)),
+            pl.BlockSpec((None, hb, vdb.shape[2], dv),
+                         lambda i, j, li: (i, j, 0, 0)),
             pl.BlockSpec((None, None, hb, dk, dv),
                          lambda i, j, li: (li[0], i, j, 0, 0)),
         ],
@@ -264,7 +293,7 @@ def _step_call(qk, vdb, states, layer_idx, *, interpret: bool):
         ],
     )
     return pl.pallas_call(
-        _step_kernel,
+        kernel,
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((b, h, 1, dv), jnp.float32),
                    jax.ShapeDtypeStruct(states.shape, states.dtype)],
@@ -274,7 +303,7 @@ def _step_call(qk, vdb, states, layer_idx, *, interpret: bool):
             dimension_semantics=("parallel", "parallel"),
             vmem_limit_bytes=64 * 1024 * 1024),
         interpret=interpret,
-        name="gdn_state_update",
+        name=name,
     )(jnp.asarray(layer_idx, jnp.int32).reshape(1), qk, vdb, states)
 
 

@@ -361,10 +361,10 @@ def create_app(cfg: Optional[ServingConfig] = None,
              "weight stacks; it serves float32 or bfloat16"),
         )
         _refuse(refused)
-    from ..models import gdn_moe as _gdn
-    if isinstance(config, _gdn.GDNMoEConfig):
-        # what the linear-attention / sparse-expert family refuses, one
-        # message each: it serves through the single-device engine
+    from ..models import gdn_moe as _gdn, kda_moe as _kda
+    if isinstance(config, (_gdn.GDNMoEConfig, _kda.KDAMoEConfig)):
+        # what the linear-attention / sparse-expert families refuse, one
+        # message each: they serve through the single-device engine
         # (solo, the iteration scheduler, the paged pool with its state
         # slab, the prefix store) in float32 or bfloat16
         name = type(config).__name__
@@ -374,9 +374,9 @@ def create_app(cfg: Optional[ServingConfig] = None,
              f"{name}'s per-row state (it has no position axis) without "
              "a snapshot a verify; serve it without speculation"),
             (cfg.kv_pool_dtype,
-             f"KV_POOL_DTYPE={cfg.kv_pool_dtype}: {name}'s pool is fused "
-             "with counters in its second leaf and its rows' state is "
-             "float32 by contract; the quantized movers have not been "
+             f"KV_POOL_DTYPE={cfg.kv_pool_dtype}: {name}'s pool is one "
+             "plane with counters in its second leaf and its rows' state "
+             "is float32 by contract; the quantized movers have not been "
              "fitted to it"),
             (cfg.kv_host_blocks > 0,
              f"KV_HOST_BLOCKS: a demoted entry of {name} would need its "
@@ -384,7 +384,7 @@ def create_app(cfg: Optional[ServingConfig] = None,
              "moves blocks only"),
             (cfg.pp_decode or cfg.tp_decode or cfg.ep_decode,
              f"PP/TP/EP_DECODE: no multi-chip decoder stages or shards "
-             f"{name} (periods of unlike layers, a state slab beside "
+             f"{name} (runs of unlike layers, a state slab beside "
              "the pool, experts indexed in place); it serves on one "
              "chip, told which experts it holds"),
             (cfg.inference_dtype == "int8",

@@ -571,3 +571,82 @@ def test_hybrid_ssm_prefill_holds_one_positions_logits(prefills):
     assert not re.search(r"\[(1,)?768,261120\]", text)
     assert re.search(r"f32\[1,(1,)?261120\]", text)
     assert 768 * 261120 * 4 > SSM_PREFILL_TEMPORARIES * 1e9
+
+
+# -- the per-channel delta-rule / latent family (ISSUE 46) ---------------------
+#
+# Its depth comes from two published lists and cannot be cut: the whole
+# 27 layers are compiled. A decode step is three groups (models.kda_moe's
+# ``layer_plan``): the dense first layer ``K'`` and the tail ``K M``
+# written out in the step's body (a group of one repeat is a scan of
+# one, which the compiler unrolls) and ONE loop of six over ``K K M K``.
+# The compiler's report here: a decode segment holds 0.27 GB beside its
+# arguments at 8 and 16 rows (its own prefetches of the written-out
+# groups' weights), a seed's longest prompt, 1,536 ids, 1.04 GB, of
+# which every position's logits are 1.0.
+KDA = "kimi-linear-48b-ep16"
+KDA_TEMPORARIES, KDA_EXTEND_TEMPORARIES, KDA_PREFILL_TEMPORARIES = 0.4, 0.3, 1.3
+
+
+@pytest.mark.parametrize("batch", [1, 16])
+def test_kda_moe_decode_segment_compiles(one_chip, built, batch):
+    eng, params = built(KDA, None)
+    compiled, cache = _decode_segment(one_chip, eng, params, batch)
+    mem = one_chip.check(compiled)
+    cache_bytes = sum(x.size * x.dtype.itemsize
+                      for x in jax.tree.leaves(cache))
+    # the 7 latent layers' positions, the 20 delta-rule layers' matrices
+    # and tails: all updated in place
+    assert [x.shape[0] for x in jax.tree.leaves(cache)
+            if x.ndim > 1] == [7, 20, 20]
+    assert mem.alias_size_in_bytes >= cache_bytes
+    assert mem.temp_size_in_bytes < KDA_TEMPORARIES * 1e9, (
+        f"{mem.temp_size_in_bytes / 1e9:.3f} GB of temporaries")
+    text = compiled.as_text()
+    loops = _loops(text)
+    steps = [b for b, (holder, _) in loops.items() if holder not in loops]
+    assert len(steps) == 1, sorted(loops)
+    inner = [b for b, (holder, _) in loops.items() if holder == steps[0]]
+    # two loops over the experts hit (the tail's two expert layers; the
+    # first layer's feed-forward is dense) and the loop over the six
+    # like runs, which holds four more
+    runs = [b for b in inner
+            if sum(h == b for h, _ in loops.values()) == 4]
+    assert len(inner) == 3 and len(runs) == 1, sorted(loops)
+    assert 6 in _trip_bounds(text)
+
+    def kernels(lines):
+        found = [x for x in lines if "tpu_custom_call" in x]
+        return (sum("kda_state_update" in x for x in found),
+                sum("latent_decode_attention" in x for x in found))
+
+    # 2 + 3 x 6 = 20 state kernels and 1 + 6 = 7 latent ones a step
+    assert kernels(loops[steps[0]][1]) == (2, 1)
+    assert kernels(loops[runs[0]][1]) == (3, 1)
+
+
+@pytest.mark.parametrize("ids", [64, 256, "longest prompt"])
+def test_kda_moe_walks_and_prefill_compile(one_chip, built, ids):
+    """The store's ``_extend`` at a stride of one and of four chunks and
+    a seed's longest prompt: the chunked rule per channel (sub-blocks of
+    16, no exponent above 0) inverts its triangle by matmuls too."""
+    eng, params = built(KDA, None)
+    if isinstance(ids, str):
+        longest = Spec().traffic("longanswer")["prompt"]["max"]
+        compiled = jax.jit(eng._prefill_impl).lower(
+            params, one_chip.shape((1, longest), jnp.int32),
+            one_chip.shape((1,), jnp.int32)).compile()
+        limit = KDA_PREFILL_TEMPORARIES
+    else:
+        store = PrefixCachingEngine(eng, capacity=8, chunk=64)
+        row = one_chip.placed(jax.eval_shape(lambda: eng._fresh_cache(1)))
+        compiled = store._extend.lower(
+            params, row, one_chip.shape((1, ids), jnp.int32)).compile()
+        limit = KDA_EXTEND_TEMPORARIES
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < limit * 1e9, (
+        f"{mem.temp_size_in_bytes / 1e9:.3f} GB of temporaries")
+    text = compiled.as_text()
+    for gone in SERIAL_SOLVE:
+        assert not re.search(gone, text), gone
+    assert gated_delta.CHUNK not in _trip_bounds(text)
