@@ -323,12 +323,14 @@ def init_params(config: GDNMoEConfig, key: jax.Array,
 
 def _linear_attention(attn: Params, a: jnp.ndarray, config: GDNMoEConfig,
                       state, li, valid: Optional[jnp.ndarray],
-                      kernel: Optional[str]):
+                      kernel: Optional[str], lanes=None):
     """The linear-attention mixer: ``a`` [B, T, d] normed -> ``(out
     [B, T, d], state)``. ``state`` is ``(matrices, tails)`` of ALL the
     linear layers (or ``None``: no cache, zeros come in and nothing goes
     out), ``li`` this layer's index among them; ``valid`` [B, T] marks
-    the positions that count (``None``: all)."""
+    the positions that count (``None``: all); ``lanes`` says which rows
+    a single position's kernel streams (``gated_delta.live_lanes``;
+    ``None``: all)."""
     c = config
     b, t, _ = a.shape
     hk, hv = c.linear_num_key_heads, c.linear_num_value_heads
@@ -363,7 +365,8 @@ def _linear_attention(attn: Params, a: jnp.ndarray, config: GDNMoEConfig,
     with jax.named_scope("gdn_state"):
         if t == 1 and state is not None:
             o, mats = gated_delta.step(q[:, 0], k[:, 0], v[:, 0], g[:, 0],
-                                       beta[:, 0], state[0], li, kernel)
+                                       beta[:, 0], state[0], li, kernel,
+                                       lanes)
             o = o[:, None]                                   # [B, 1, Hv, V]
         else:
             s0 = (jnp.zeros((b, hv, dk, dv), jnp.float32) if state is None
@@ -464,6 +467,7 @@ def apply_blocks(params: Params, h: jnp.ndarray, config: GDNMoEConfig,
     valid = None
     if pad is not None and t > 1:
         valid = (offset + jnp.arange(t))[None, :] >= pad[:, None]
+    lanes = gated_delta.live_lanes(pad, offset, t, decode_kernel)
 
     def period(carry, xs):
         h, kv, state = carry
@@ -482,7 +486,7 @@ def apply_blocks(params: Params, h: jnp.ndarray, config: GDNMoEConfig,
                 pj, h, c.rms_norm_eps,
                 lambda a, pj=pj, j=j, state=state: _linear_attention(
                     pj["attn"], a, c, state, pi * nl + j, valid,
-                    decode_kernel),
+                    decode_kernel, lanes),
                 feed(pj["moe"], pi * (nl + 1) + j), norm=rms_norm_offset)
         pf = p["full"]
         h, kv = pre_norm_block(

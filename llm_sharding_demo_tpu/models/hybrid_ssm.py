@@ -281,12 +281,14 @@ def init_params(config: HybridSSMConfig, key: jax.Array,
 
 def _ssm_mixer(ssm: Params, s: jnp.ndarray, config: HybridSSMConfig,
                state, li, valid: Optional[jnp.ndarray],
-               kernel: Optional[str]):
+               kernel: Optional[str], lanes=None):
     """The state-space mixer: ``s`` [B, T, d] (normed, times
     ``ssm_in_multiplier``) -> ``(out [B, T, d], state)``. ``state`` is
     ``(matrices, tails)`` of ALL the layers (or ``None``: no cache,
     zeros come in and nothing goes out), ``li`` this layer; ``valid``
-    [B, T] marks the positions that count (``None``: all)."""
+    [B, T] marks the positions that count (``None``: all); ``lanes``
+    says which rows a single position's kernel streams
+    (``gated_delta.live_lanes``; ``None``: all)."""
     c = config
     b, t, _ = s.shape
     h, p, n, g = (c.mamba_n_heads, c.mamba_d_head, c.mamba_d_state,
@@ -323,7 +325,7 @@ def _ssm_mixer(ssm: Params, s: jnp.ndarray, config: HybridSSMConfig,
     with jax.named_scope("ssm_state"):
         if t == 1 and state is not None:
             y, mats = ssd.step(x[:, 0], dt[:, 0], a, bm[:, 0], cm[:, 0],
-                               state[0], li, kernel)
+                               state[0], li, kernel, lanes)
             y = y[:, None]                                   # [B, 1, H, P]
         else:
             s0 = (jnp.zeros((b, h, n, p), jnp.float32) if state is None
@@ -395,6 +397,7 @@ def apply_blocks(params: Params, h: jnp.ndarray, config: HybridSSMConfig,
     valid = None
     if pad is not None and t > 1:
         valid = (offset + jnp.arange(t))[None, :] >= pad[:, None]
+    lanes = gated_delta.live_lanes(pad, offset, t, decode_kernel)
 
     def layer(carry, xs):
         h, kv, state = carry
@@ -402,7 +405,7 @@ def apply_blocks(params: Params, h: jnp.ndarray, config: HybridSSMConfig,
         u = rms_norm(h, p["ln_attn"]["scale"], c.rms_norm_eps)
         mixed, state = _ssm_mixer(
             p["ssm"], u * jnp.asarray(c.ssm_in_multiplier, u.dtype), c,
-            state, li, valid, decode_kernel)
+            state, li, valid, decode_kernel, lanes)
         seen, kv = _attention(
             p["attn"], u * jnp.asarray(c.attention_in_multiplier, u.dtype),
             c, cos, sin, kv, li, offset, pad, decode_kernel)

@@ -405,12 +405,14 @@ def init_params(config: KDAMoEConfig, key: jax.Array,
 
 def _delta_attention(attn: Params, a: jnp.ndarray, config: KDAMoEConfig,
                      state, li, valid: Optional[jnp.ndarray],
-                     kernel: Optional[str]):
+                     kernel: Optional[str], lanes=None):
     """The delta-rule mixer: ``a`` [B, T, d] normed -> ``(out [B, T, d],
     state)``. ``state`` is ``(matrices, tails)`` of ALL the delta-rule
     layers (or ``None``: no cache, zeros come in and nothing goes out),
     ``li`` this layer's index among them; ``valid`` [B, T] marks the
-    positions that count (``None``: all)."""
+    positions that count (``None``: all); ``lanes`` says which rows a
+    single position's kernel streams (``gated_delta.live_lanes``;
+    ``None``: all)."""
     c = config
     b, t, _ = a.shape
     h, hd, r = c.linear_num_heads, c.linear_head_dim, c.gate_rank
@@ -442,7 +444,7 @@ def _delta_attention(attn: Params, a: jnp.ndarray, config: KDAMoEConfig,
     with jax.named_scope("kda_state"):
         if t == 1 and state is not None:
             o, mats = kda.step(q[:, 0], k[:, 0], v[:, 0], g[:, 0],
-                               beta[:, 0], state[0], li, kernel)
+                               beta[:, 0], state[0], li, kernel, lanes)
             o = o[:, None]                                   # [B, 1, H, V]
         else:
             s0 = (jnp.zeros((b, h, hd, hd), jnp.float32) if state is None
@@ -471,7 +473,7 @@ def _delta_attention(attn: Params, a: jnp.ndarray, config: KDAMoEConfig,
 @functools.partial(jax.jit,
                    static_argnames=("config", "kind", "fresh", "kernel"))
 def _layer(p: Params, experts: Params, h, held, li, expert_layer_idx,
-           offset, pad, valid, *, config: KDAMoEConfig, kind: str,
+           offset, pad, valid, lanes, *, config: KDAMoEConfig, kind: str,
            fresh: bool, kernel: Optional[str]):
     """One layer of either kind on its own leaves ``p``: ``held`` is what
     its mixer carries (the rows' state for a delta-rule layer, the
@@ -484,7 +486,7 @@ def _layer(p: Params, experts: Params, h, held, li, expert_layer_idx,
     def mixer(a):
         if kind == KDA:
             return _delta_attention(p["attn"], a, config, held, li, valid,
-                                    kernel)
+                                    kernel, lanes)
         return _attention(p["attn"], a, config, None, None, held, li,
                           offset, pad, fresh, kernel)
 
@@ -519,6 +521,7 @@ def apply_blocks(params: Params, h: jnp.ndarray, config: KDAMoEConfig,
     valid = None
     if pad is not None and t > 1:
         valid = (offset + jnp.arange(t))[None, :] >= pad[:, None]
+    lanes = gated_delta.live_lanes(pad, offset, t, decode_kernel)
 
     def run(group: Group):
         def body(carry, xs):
@@ -533,7 +536,7 @@ def apply_blocks(params: Params, h: jnp.ndarray, config: KDAMoEConfig,
                 e = group.first + rep * len(places) + j - c.first_k_dense
                 h, held[kind], counts = _layer(
                     p, experts, h, held[kind], at[kind], e, offset, pad,
-                    valid, config=c, kind=kind, fresh=fresh,
+                    valid, lanes, config=c, kind=kind, fresh=fresh,
                     kernel=decode_kernel)
                 at[kind] = at[kind] + 1
                 if counts is not None:

@@ -60,7 +60,13 @@ Three forms of the same sums:
 - ``step_kernel``: one position as a Pallas kernel that streams a row's
   state through VMEM once, a block of heads at a time (read, decay,
   correct, write back in place, read out): 2 x ``H K V`` x 4 bytes a row
-  a layer and nothing else of size.
+  a layer and nothing else of size. It streams the rows that hold a
+  request and no other (``lane_order``, ``lane_maps``, ``streamed``,
+  which ``ops.kda`` and ``ops.ssd`` take from here): the live lanes'
+  indices and their count are a scalar operand, the grid's rows read
+  through it, and a lane without a request (an empty span:
+  ``live_lanes``) is copied neither in nor out: its state stays bit for
+  bit what came in, its output row is zeros.
 
 Positions a caller masks (``valid`` false: the left pad of a prompt
 bucket, the right pad up to a whole chunk) get ``beta = 0`` and ``g =
@@ -253,8 +259,95 @@ def stood_up(rows):
                     keepdims=True) for i in range(rows.shape[1])]
 
 
-def _step_kernel(li_ref, qk_ref, vdb_ref, s_ref, o_ref, s_out_ref):
-    del li_ref                          # used by the index maps
+def lane_order(live: jnp.ndarray) -> jnp.ndarray:
+    """``live`` [B] bool (the lanes that hold a request) -> what the
+    kernels' grids walk, int32 ``[B + 1]``: the live lanes' indices in
+    their order, then the others', then the live lanes' count. Three
+    small sums (no sort): a model step works it out ONCE and hands it to
+    every layer's kernel."""
+    b = live.shape[0]
+    ghost = jnp.logical_not(live)
+    count = jnp.sum(live, dtype=jnp.int32)
+    place = jnp.where(live, jnp.cumsum(live, dtype=jnp.int32) - 1,
+                      count + jnp.cumsum(ghost, dtype=jnp.int32) - 1)
+    lane = jnp.arange(b, dtype=jnp.int32)
+    order = jnp.sum(jnp.where(place[None, :] == lane[:, None], lane, 0),
+                    axis=1, dtype=jnp.int32)
+    return jnp.concatenate([order, count[None]])
+
+
+def live_lanes(pad: Optional[jnp.ndarray], offset, t: int,
+               kernel: Optional[str]) -> Optional[jnp.ndarray]:
+    """What a model step hands its state kernels: in a call of ONE
+    position through the kernel with the rows' left pads, the
+    ``lane_order`` of the rows whose span ``[pad, offset]`` is not
+    empty. A lane without a request carries a pad that no depth reaches
+    (``runtime.iterbatch._empty_span``); a row with a request has its
+    pad under its depth. ``None`` (every lane) otherwise: no pads, the
+    recurrence, several positions."""
+    if pad is None or t != 1 or kernel is None:
+        return None
+    return lane_order(pad <= offset)
+
+
+def every_lane(b: int) -> jnp.ndarray:
+    """``lane_order`` of ``b`` live lanes: each in its place, ``b``."""
+    return jnp.arange(b + 1, dtype=jnp.int32)
+
+
+def lane_maps(b: int, blocks: int):
+    """The two halves of a state kernel's index maps over a grid of
+    ``(b rows, blocks of heads)`` with ``lanes`` (``lane_order``) in the
+    scalar prefetch. ``held(i, j, lanes)``: the ``(lane, block)`` whose
+    inputs and state step ``(i, j)`` holds, the ``i``-th LIVE lane's for
+    the first ``count`` rows; every later step stays on the last block
+    of the last live lane, which the step before it already holds, and a
+    block whose indices repeat is copied neither in nor out again.
+    ``own(i, j, lanes)``: the ``(lane, block)`` of the output row, each
+    lane's once (a skipped lane's is written too: zeros)."""
+    def held(i, j, lanes):
+        count = lanes[b]
+        last = jnp.maximum(count - 1, 0)
+        return (lanes[jnp.minimum(i, last)],
+                jnp.where(i < count, j, blocks - 1))
+
+    def own(i, j, lanes):
+        return lanes[i], j
+
+    return held, own
+
+
+def streamed(body, b: int):
+    """``body`` (a family's update on its blocks' refs: inputs, the
+    state, then the output row and the state out) as the kernel of a
+    grid that ``lane_maps`` steers: run for the first ``count`` rows of
+    the grid, the live lanes; a later step writes its lane's output row
+    as zeros and touches nothing else. With NO live lane every step
+    holds one and the same block, whose state goes out as it came in."""
+    def kernel(li_ref, lanes_ref, *refs):
+        del li_ref                      # used by the index maps
+        s_ref, o_ref, s_out_ref = refs[-3:]
+        count = lanes_ref[b]
+        live = pl.program_id(0) < count
+        pl.when(live)(functools.partial(body, *refs))
+
+        @pl.when(jnp.logical_not(live))
+        def _():
+            o_ref[...] = jnp.zeros_like(o_ref)
+
+        @pl.when(count == 0)
+        def _():
+            s_out_ref[...] = s_ref[...]
+
+    return kernel
+
+
+# a skipped lane's steps revisit the block before them: the grid runs
+# in order on one core
+LANE_SEMANTICS = ("arbitrary", "arbitrary")
+
+
+def _step_kernel(qk_ref, vdb_ref, s_ref, o_ref, s_out_ref):
     qk = qk_ref[...]                    # [hb, 2, K]   q, k
     vdb = vdb_ref[...]                  # [hb, 3, V]   v, exp(g), beta
     q_col, k_col = stood_up(qk)
@@ -267,70 +360,85 @@ def _step_kernel(li_ref, qk_ref, vdb_ref, s_ref, o_ref, s_out_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("kernel", "name", "interpret"))
-def _step_call(qk, vdb, states, layer_idx, *, interpret: bool,
+def _step_call(qk, vdb, states, layer_idx, lanes, *, interpret: bool,
                kernel=_step_kernel, name: str = "gdn_state_update"):
     """``kernel`` over a grid of (row, block of heads): its vectors along
     the keys ``qk`` [B, H, n, K], those along the values ``vdb`` [B, H,
     m, V], and layer ``layer_idx`` of ``states`` streamed through VMEM
-    and written back in place."""
+    and written back in place, for the live lanes of ``lanes``
+    (``lane_order``) and no other (``lane_maps``, ``streamed``)."""
     _, b, h, dk, dv = states.shape
     hb = min(HEAD_BLOCK, h)
+    held, own = lane_maps(b, h // hb)
+
+    def vectors(i, j, li, ln):
+        return (*held(i, j, ln), 0, 0)
+
+    def state(i, j, li, ln):
+        return (li[0], *held(i, j, ln), 0, 0)
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(b, h // hb),
         in_specs=[
-            pl.BlockSpec((None, hb, qk.shape[2], dk),
-                         lambda i, j, li: (i, j, 0, 0)),
-            pl.BlockSpec((None, hb, vdb.shape[2], dv),
-                         lambda i, j, li: (i, j, 0, 0)),
-            pl.BlockSpec((None, None, hb, dk, dv),
-                         lambda i, j, li: (li[0], i, j, 0, 0)),
+            pl.BlockSpec((None, hb, qk.shape[2], dk), vectors),
+            pl.BlockSpec((None, hb, vdb.shape[2], dv), vectors),
+            pl.BlockSpec((None, None, hb, dk, dv), state),
         ],
         out_specs=[
-            pl.BlockSpec((None, hb, 1, dv), lambda i, j, li: (i, j, 0, 0)),
-            pl.BlockSpec((None, None, hb, dk, dv),
-                         lambda i, j, li: (li[0], i, j, 0, 0)),
+            pl.BlockSpec((None, hb, 1, dv),
+                         lambda i, j, li, ln: (*own(i, j, ln), 0, 0)),
+            pl.BlockSpec((None, None, hb, dk, dv), state),
         ],
     )
     return pl.pallas_call(
-        kernel,
+        streamed(kernel, b),
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((b, h, 1, dv), jnp.float32),
                    jax.ShapeDtypeStruct(states.shape, states.dtype)],
-        # inputs with the scalar operand: li=0, qk=1, vdb=2, states=3
-        input_output_aliases={3: 1},
+        # inputs with the scalar operands: li=0, lanes=1, qk=2, vdb=3,
+        # states=4
+        input_output_aliases={4: 1},
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel"),
+            dimension_semantics=LANE_SEMANTICS,
             vmem_limit_bytes=64 * 1024 * 1024),
         interpret=interpret,
         name=name,
-    )(jnp.asarray(layer_idx, jnp.int32).reshape(1), qk, vdb, states)
+    )(jnp.asarray(layer_idx, jnp.int32).reshape(1), lanes, qk, vdb, states)
 
 
 def step_kernel(q, k, v, g, beta, states, layer_idx,
-                interpret: bool = False):
-    """One position of every row through the kernel. ``q``, ``k``
+                interpret: bool = False, lanes=None):
+    """One position of every LIVE row through the kernel. ``q``, ``k``
     [B, H, K], ``v`` [B, H, V], ``g``, ``beta`` [B, H] (float32);
     ``states`` the WHOLE ``[layers, B, H, K, V]`` float32 stack, of
     which layer ``layer_idx`` is read and written in place (the input
-    aliases the output: treat the passed buffer as consumed). Returns
+    aliases the output: treat the passed buffer as consumed); ``lanes``
+    the ``lane_order`` of the lanes that hold a request (``None``: every
+    lane does). A lane that holds none is not streamed: its state stays
+    bit for bit what came in and its row of ``o`` is zeros. Returns
     ``(o [B, H, V], states)``."""
     dv = v.shape[-1]
     qk = jnp.stack([q, k], axis=2).astype(jnp.float32)
     vdb = jnp.stack([v.astype(jnp.float32),
                      jnp.broadcast_to(jnp.exp(g)[..., None], v.shape),
                      jnp.broadcast_to(beta[..., None], v.shape)], axis=2)
-    o, states = _step_call(qk, vdb, states, layer_idx, interpret=interpret)
+    if lanes is None:
+        lanes = every_lane(q.shape[0])
+    o, states = _step_call(qk, vdb, states, layer_idx, lanes,
+                           interpret=interpret)
     return o.reshape(o.shape[0], o.shape[1], dv), states
 
 
 def step(q, k, v, g, beta, states, layer_idx,
-         kernel: Optional[str] = None):
+         kernel: Optional[str] = None, lanes=None):
     """One position, by the kernel (``kernel``: ``"device"`` or
-    ``"interpret"``) or by the recurrence on the layer's slice."""
+    ``"interpret"``; the live lanes of ``lanes`` alone, ``step_kernel``)
+    or by the recurrence on the layer's slice, which computes every
+    lane whatever ``lanes`` says."""
     if kernel is not None:
         return step_kernel(q, k, v, g, beta, states, layer_idx,
-                           interpret=kernel == "interpret")
+                           interpret=kernel == "interpret", lanes=lanes)
     s = jax.lax.dynamic_index_in_dim(states, layer_idx, 0, keepdims=False)
     o, s = recurrence(q[:, :, None], k[:, :, None], v[:, :, None],
                       g[:, :, None], beta[:, :, None], s)

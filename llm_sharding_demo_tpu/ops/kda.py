@@ -38,7 +38,9 @@ module's. Three forms of the same sums:
 - ``step_kernel``: one position as a Pallas kernel that streams a row's
   state through VMEM once, a block of heads at a time, the decay stood
   up as a column along the key axis beside ``q`` and ``k``: 2 x ``H K
-  V`` x 4 bytes a row a layer and nothing else of size.
+  V`` x 4 bytes a row a layer and nothing else of size; for the rows
+  that hold a request alone (``gated_delta``'s grid over the live
+  lanes): another lane's state is left as it came in, its output zeros.
 """
 
 from __future__ import annotations
@@ -152,8 +154,7 @@ def chunked(q, k, v, g, beta, state, chunk: int = CHUNK, sub: int = SUB):
 # -- one position, on the chip ------------------------------------------------
 
 
-def _step_kernel(li_ref, qkd_ref, vb_ref, s_ref, o_ref, s_out_ref):
-    del li_ref                          # used by the index maps
+def _step_kernel(qkd_ref, vb_ref, s_ref, o_ref, s_out_ref):
     vb = vb_ref[...]                    # [hb, 2, V]   v, beta
     q_col, k_col, decay = stood_up(qkd_ref[...])   # [hb, 3, K] q, k, exp(g)
     v, beta = vb[:, 0:1], vb[:, 1:2]
@@ -165,30 +166,37 @@ def _step_kernel(li_ref, qkd_ref, vb_ref, s_ref, o_ref, s_out_ref):
 
 
 def step_kernel(q, k, v, g, beta, states, layer_idx,
-                interpret: bool = False):
-    """One position of every row through the kernel. ``q``, ``k``, ``g``
-    [B, H, K], ``v`` [B, H, V], ``beta`` [B, H] (float32); ``states``
-    the WHOLE ``[layers, B, H, K, V]`` float32 stack, of which layer
-    ``layer_idx`` is read and written in place (the input aliases the
-    output: treat the passed buffer as consumed). Returns ``(o
-    [B, H, V], states)``."""
+                interpret: bool = False, lanes=None):
+    """One position of every LIVE row through the kernel. ``q``, ``k``,
+    ``g`` [B, H, K], ``v`` [B, H, V], ``beta`` [B, H] (float32);
+    ``states`` the WHOLE ``[layers, B, H, K, V]`` float32 stack, of
+    which layer ``layer_idx`` is read and written in place (the input
+    aliases the output: treat the passed buffer as consumed); ``lanes``
+    the ``gated_delta.lane_order`` of the lanes that hold a request
+    (``None``: every lane does). A lane that holds none is not streamed:
+    its state stays bit for bit what came in and its row of ``o`` is
+    zeros. Returns ``(o [B, H, V], states)``."""
     dv = v.shape[-1]
     qkd = jnp.stack([q, k, jnp.exp(g)], axis=2).astype(jnp.float32)
     vb = jnp.stack([v.astype(jnp.float32),
                     jnp.broadcast_to(beta[..., None], v.shape)], axis=2)
+    if lanes is None:
+        lanes = gated_delta.every_lane(q.shape[0])
     o, states = gated_delta._step_call(
-        qkd, vb, states, layer_idx, interpret=interpret,
+        qkd, vb, states, layer_idx, lanes, interpret=interpret,
         kernel=_step_kernel, name=KERNEL_NAME)
     return o.reshape(o.shape[0], o.shape[1], dv), states
 
 
 def step(q, k, v, g, beta, states, layer_idx,
-         kernel: Optional[str] = None):
+         kernel: Optional[str] = None, lanes=None):
     """One position, by the kernel (``kernel``: ``"device"`` or
-    ``"interpret"``) or by the recurrence on the layer's slice."""
+    ``"interpret"``; the live lanes of ``lanes`` alone, ``step_kernel``)
+    or by the recurrence on the layer's slice, which computes every
+    lane whatever ``lanes`` says."""
     if kernel is not None:
         return step_kernel(q, k, v, g, beta, states, layer_idx,
-                           interpret=kernel == "interpret")
+                           interpret=kernel == "interpret", lanes=lanes)
     s = jax.lax.dynamic_index_in_dim(states, layer_idx, 0, keepdims=False)
     o, s = recurrence(q[:, :, None], k[:, :, None], v[:, :, None],
                       g[:, :, None], beta[:, :, None], s)

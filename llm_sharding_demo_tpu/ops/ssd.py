@@ -38,7 +38,9 @@ Three forms of the same sums:
 - ``step_kernel``: one position as a Pallas kernel that streams a row's
   state through VMEM once, a block of heads at a time (read, decay,
   rank-one write, write back in place, read out): 2 x ``H N P`` x 4
-  bytes a row a layer and nothing else of size.
+  bytes a row a layer and nothing else of size; for the rows that hold
+  a request alone (``ops.gated_delta``'s grid over the live lanes):
+  another lane's state is left as it came in, its output zeros.
 
 Positions a caller masks (the left pad of a prompt bucket, the right pad
 up to a whole chunk) get ``dt = 0``: they change nothing.
@@ -53,6 +55,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from .gated_delta import LANE_SEMANTICS, every_lane, lane_maps, streamed
 
 # Numerics contract (tools/graftcheck numerics pass): the rule runs in
 # float32 whatever the regime (the state is a running sum over the whole
@@ -169,8 +173,7 @@ def kernel_eligible(n: int, p: int, heads: int, groups: int,
     return whole and (not compiled or (n % 128 == 0 and p % 128 == 0))
 
 
-def _step_kernel(li_ref, bc_ref, xd_ref, s_ref, o_ref, s_out_ref):
-    del li_ref                          # used by the index maps
+def _step_kernel(bc_ref, xd_ref, s_ref, o_ref, s_out_ref):
     bc = bc_ref[...]                    # [2, N]       B, C of the group
     xd = xd_ref[...]                    # [hb, 2, P]   dt x, exp(dt A)
     n = bc.shape[-1]
@@ -190,65 +193,87 @@ def _step_kernel(li_ref, bc_ref, xd_ref, s_ref, o_ref, s_out_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def _step_call(bc, xd, states, layer_idx, *, interpret: bool):
+def _step_call(bc, xd, states, layer_idx, lanes, *, interpret: bool):
+    """``_step_kernel`` over a grid of (row, block of heads), for the
+    live lanes of ``lanes`` (``gated_delta.lane_order``) and no other:
+    the grid and the kernel around the update are ``gated_delta``'s
+    (``lane_maps``, ``streamed``)."""
     _, b, h, n, p = states.shape
     groups = bc.shape[1]
     hb = _head_block(h, groups)
     per_group = h // groups // hb       # blocks a group's heads make
+    held, own = lane_maps(b, h // hb)
+
+    def group(i, j, li, ln):
+        lane, block = held(i, j, ln)
+        return lane, block // per_group, 0, 0
+
+    def state(i, j, li, ln):
+        return (li[0], *held(i, j, ln), 0, 0)
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(b, h // hb),
         in_specs=[
-            pl.BlockSpec((None, None, 2, n),
-                         lambda i, j, li: (i, j // per_group, 0, 0)),
-            pl.BlockSpec((None, hb, 2, p), lambda i, j, li: (i, j, 0, 0)),
-            pl.BlockSpec((None, None, hb, n, p),
-                         lambda i, j, li: (li[0], i, j, 0, 0)),
+            pl.BlockSpec((None, None, 2, n), group),
+            pl.BlockSpec((None, hb, 2, p),
+                         lambda i, j, li, ln: (*held(i, j, ln), 0, 0)),
+            pl.BlockSpec((None, None, hb, n, p), state),
         ],
         out_specs=[
-            pl.BlockSpec((None, hb, 1, p), lambda i, j, li: (i, j, 0, 0)),
-            pl.BlockSpec((None, None, hb, n, p),
-                         lambda i, j, li: (li[0], i, j, 0, 0)),
+            pl.BlockSpec((None, hb, 1, p),
+                         lambda i, j, li, ln: (*own(i, j, ln), 0, 0)),
+            pl.BlockSpec((None, None, hb, n, p), state),
         ],
     )
     return pl.pallas_call(
-        _step_kernel,
+        streamed(_step_kernel, b),
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((b, h, 1, p), jnp.float32),
                    jax.ShapeDtypeStruct(states.shape, states.dtype)],
-        # inputs with the scalar operand: li=0, bc=1, xd=2, states=3
-        input_output_aliases={3: 1},
+        # inputs with the scalar operands: li=0, lanes=1, bc=2, xd=3,
+        # states=4
+        input_output_aliases={4: 1},
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel"),
+            dimension_semantics=LANE_SEMANTICS,
             vmem_limit_bytes=64 * 1024 * 1024),
         interpret=interpret,
         name="ssm_state_update",
-    )(jnp.asarray(layer_idx, jnp.int32).reshape(1), bc, xd, states)
+    )(jnp.asarray(layer_idx, jnp.int32).reshape(1), lanes, bc, xd, states)
 
 
 def step_kernel(x, dt, a, bm, cm, states, layer_idx,
-                interpret: bool = False):
-    """One position of every row through the kernel. ``x`` [B, H, P],
-    ``dt`` [B, H], ``a`` [H], ``bm``, ``cm`` [B, G, N] (float32);
-    ``states`` the WHOLE ``[layers, B, H, N, P]`` float32 stack, of
-    which layer ``layer_idx`` is read and written in place (the input
-    aliases the output: treat the passed buffer as consumed). Returns
-    ``(y [B, H, P], states)``."""
+                interpret: bool = False, lanes=None):
+    """One position of every LIVE row through the kernel. ``x``
+    [B, H, P], ``dt`` [B, H], ``a`` [H], ``bm``, ``cm`` [B, G, N]
+    (float32); ``states`` the WHOLE ``[layers, B, H, N, P]`` float32
+    stack, of which layer ``layer_idx`` is read and written in place
+    (the input aliases the output: treat the passed buffer as consumed);
+    ``lanes`` the ``gated_delta.lane_order`` of the lanes that hold a
+    request (``None``: every lane does). A lane that holds none is not
+    streamed: its state stays bit for bit what came in and its row of
+    ``y`` is zeros. Returns ``(y [B, H, P], states)``."""
     x, dt = x.astype(jnp.float32), dt.astype(jnp.float32)
     bc = jnp.stack([bm, cm], axis=2).astype(jnp.float32)
     xd = jnp.stack([dt[..., None] * x,
                     jnp.broadcast_to(jnp.exp(dt * a)[..., None], x.shape)],
                    axis=2)
-    y, states = _step_call(bc, xd, states, layer_idx, interpret=interpret)
+    if lanes is None:
+        lanes = every_lane(x.shape[0])
+    y, states = _step_call(bc, xd, states, layer_idx, lanes,
+                           interpret=interpret)
     return y.reshape(x.shape), states
 
 
-def step(x, dt, a, bm, cm, states, layer_idx, kernel: Optional[str] = None):
+def step(x, dt, a, bm, cm, states, layer_idx, kernel: Optional[str] = None,
+         lanes=None):
     """One position, by the kernel (``kernel``: ``"device"`` or
-    ``"interpret"``) or by the recurrence on the layer's slice."""
+    ``"interpret"``; the live lanes of ``lanes`` alone, ``step_kernel``)
+    or by the recurrence on the layer's slice, which computes every
+    lane whatever ``lanes`` says."""
     if kernel is not None:
         return step_kernel(x, dt, a, bm, cm, states, layer_idx,
-                           interpret=kernel == "interpret")
+                           interpret=kernel == "interpret", lanes=lanes)
     s = jax.lax.dynamic_index_in_dim(states, layer_idx, 0, keepdims=False)
     y, s = recurrence(x[:, None], dt[:, None], a, bm[:, None], cm[:, None], s)
     return y[:, 0], jax.lax.dynamic_update_index_in_dim(

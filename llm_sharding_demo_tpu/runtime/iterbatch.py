@@ -43,10 +43,13 @@ rows) replicate a real row; per-row independence keeps them inert. A
 lane WITHOUT a request (a ghost, a retired or parked row's) carries an
 EMPTY span: its entry of ``pad_j`` is the cache's length, a pad no depth
 reaches, so the decode kernel, which streams each row's own ``[pad,
-depth)``, reads nothing for it (``_empty_span``); a lane WITH a request
-keeps its pad to the digit. ``attn_positions_streamed`` over
-``attn_positions_rect`` (``stats()``) says what share of the rectangle
-width x depth the live rows' spans are.
+depth)``, reads nothing for it (``_empty_span``), and the state kernels
+of a family whose rows hold a state stream the other lanes alone; a lane
+WITH a request keeps its pad to the digit. ``attn_positions_streamed``
+over ``attn_positions_rect`` (``stats()``) says what share of the
+rectangle width x depth the live rows' spans are, and
+``state_lanes_streamed`` over ``state_lanes_compiled`` what share of
+the compiled lanes the state kernels stream.
 
 Compiled-program inventory (bounded): the engine's prefill programs
 (prompt-bucketed: multiples of ``prompt_bucket``, or the ladder a family
@@ -258,6 +261,8 @@ GUARDED_STATE = {
     "blocks_written_back": "_stats_lock",
     "attn_positions_streamed": "_stats_lock",
     "attn_positions_rect": "_stats_lock",
+    "state_lanes_streamed": "_stats_lock",
+    "state_lanes_compiled": "_stats_lock",
     "grows": "_stats_lock", "preemptions": "_stats_lock",
     "resumes": "_stats_lock", "fault_parks": "_stats_lock",
     "batches_closed": "_stats_lock", "_turned": "_stats_lock",
@@ -633,6 +638,11 @@ class IterBatchingEngine:
         # how often spans spare the kernel a read (``_count_stream``)
         self.attn_positions_streamed = 0
         self.attn_positions_rect = 0
+        # lanes the state kernels stream (the live rows') and lanes of
+        # the compiled width, a step, for the plain calls of batches
+        # whose rows hold a state in the slab
+        self.state_lanes_streamed = 0
+        self.state_lanes_compiled = 0
         # what a lane WITHOUT a request holds in ``pad_j``: a pad no
         # depth reaches, so that its span is empty and the kernel reads
         # nothing for it (``_vacate``)
@@ -784,6 +794,8 @@ class IterBatchingEngine:
                    "blocks_written_back": self.blocks_written_back,
                    "attn_positions_streamed": self.attn_positions_streamed,
                    "attn_positions_rect": self.attn_positions_rect,
+                   "state_lanes_streamed": self.state_lanes_streamed,
+                   "state_lanes_compiled": self.state_lanes_compiled,
                    "grows": self.grows,
                    "preemptions": self.preemptions,
                    "resumes": self.resumes,
@@ -1766,11 +1778,14 @@ class IterBatchingEngine:
         seed's width) gets a pad that no depth reaches: the decode kernel
         streams a row's span ``[pad, depth)`` and can tell such a lane
         from a live one by nothing else, so with its row's stale pad it
-        would go on reading the row's blocks. The lane still computes
-        (rows are independent; its output is its own token's value, its
-        positions clip at 0). A speculative batch keeps its lanes' pads:
-        its segment rolls and rewrites every lane's pad, and runs the
-        XLA attention, which reads the window whatever the pads say."""
+        would go on reading the row's blocks. The state kernels tell it
+        by the same pad (``ops.gated_delta.live_lanes``): they copy its
+        state neither in nor out and give zeros for it. Everything else
+        of the lane still computes (rows are independent; its output is
+        its own token's value, its positions clip at 0). A speculative
+        batch keeps its lanes' pads: its segment rolls and rewrites
+        every lane's pad, and runs the XLA attention, which reads the
+        window whatever the pads say."""
         if not state.spec_mode:
             state.pad_j = state.pad_j.at[i].set(self._no_span)
 
@@ -2123,21 +2138,33 @@ class IterBatchingEngine:
         rectangle width x depth that a stream of whole batches read. The
         quotient is the share of the rectangle that spans still read:
         near 1 for a lone row without pad, the lower the more lanes are
-        empty or pad."""
+        empty or pad. Beside it, for a batch whose rows hold a state in
+        the slab: the lanes the state kernels stream a step (the live
+        rows', ``ops.gated_delta.live_lanes``) and the lanes of the
+        compiled width."""
         offs = np.arange(d, d + n)
         streamed = BLOCK_S * int(streamed_blocks(
             [s.pad for s in state.slots if s is not None], offs,
             BLOCK_S).sum())
         rect = BLOCK_S * len(state.slots) * int(
             streamed_blocks([0], offs, BLOCK_S).sum())
+        lanes = compiled = 0
+        if self._slab is not None:
+            lanes = n * sum(s is not None for s in state.slots)
+            compiled = n * len(state.slots)
         with self._stats_lock:
             self.attn_positions_streamed += streamed
             self.attn_positions_rect += rect
+            self.state_lanes_streamed += lanes
+            self.state_lanes_compiled += compiled
             share = self.attn_positions_streamed / max(
                 self.attn_positions_rect, 1)
         REGISTRY.inc("iter_attn_positions_streamed_total", value=streamed)
         REGISTRY.inc("iter_attn_positions_rect_total", value=rect)
         REGISTRY.gauge("iter_attn_stream_share", round(share, 4))
+        if compiled:
+            REGISTRY.inc("iter_state_lanes_streamed_total", value=lanes)
+            REGISTRY.inc("iter_state_lanes_compiled_total", value=compiled)
 
     def _advance_spec(self, state: _BatchState):
         """One draft-verify SEGMENT (spec batches): up to

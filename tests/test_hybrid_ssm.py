@@ -270,6 +270,51 @@ def test_the_kernel_is_the_recurrence():
     assert ssd.kernel_eligible(24, 16, 4, 2, compiled=False)
 
 
+# which lanes hold a request, by name, at any number of rows: the live
+# lanes' kernel cases (ISSUE 47)
+LIVE = {"all-live": lambda b: [True] * b,
+        "one-live": lambda b: [i == b // 2 for i in range(b)],
+        "lane-0-ghost": lambda b: [i > 0 for i in range(b)],
+        "last-lane-ghost": lambda b: [i < b - 1 for i in range(b)],
+        "alternating": lambda b: [i % 2 == 0 for i in range(b)],
+        "none-live": lambda b: [False] * b}
+
+
+@pytest.mark.parametrize("rows", [2, 4])
+@pytest.mark.parametrize("pattern", sorted(LIVE))
+def test_the_kernel_streams_the_live_lanes(rows, pattern):
+    """Over four blocks of heads, two a group: a live lane's output and
+    state are the recurrence's; a lane without a request is not
+    streamed: its state, in every layer of the stack, is bit for bit
+    what came in, its output row exactly zero."""
+    live = np.asarray(LIVE[pattern](rows))
+    x, dt, a, bm, cm, _ = rule_inputs(5, rows, 1, 64, 2, 16, 24)
+    states = jax.random.normal(jax.random.PRNGKey(9), (3, rows, 64, 24, 16))
+    args = (x[:, 0], dt[:, 0], a, bm[:, 0], cm[:, 0])
+    y1, s1 = map(np.asarray, ssd.step(*args, states, 1, None))
+    y2, s2 = map(np.asarray, ssd.step(
+        *args, states, 1, "interpret",
+        gated_delta.lane_order(jnp.asarray(live))))
+    assert np.abs(y1[live] - y2[live]).max(initial=0) < 1e-5
+    assert np.abs(s1[:, live] - s2[:, live]).max(initial=0) < 1e-6
+    assert np.array_equal(s2[:, ~live], np.asarray(states)[:, ~live])
+    assert np.array_equal(s2[[0, 2]], np.asarray(states)[[0, 2]])
+    assert np.all(y2[~live] == 0) and np.all(np.isfinite(y2))
+    if live.any():
+        assert not np.array_equal(s2[1, live], np.asarray(states)[1, live])
+
+
+def test_no_lanes_given_is_every_lane_live():
+    x, dt, a, bm, cm, _ = rule_inputs(5, 4, 1, 64, 2, 16, 24)
+    states = jax.random.normal(jax.random.PRNGKey(9), (3, 4, 64, 24, 16))
+    args = (x[:, 0], dt[:, 0], a, bm[:, 0], cm[:, 0])
+    y1, s1 = ssd.step(*args, states, 1, "interpret")
+    y2, s2 = ssd.step(*args, states, 1, "interpret",
+                      gated_delta.lane_order(jnp.ones((4,), bool)))
+    assert np.array_equal(np.asarray(y1), np.asarray(y2))
+    assert np.array_equal(np.asarray(s1), np.asarray(s2))
+
+
 def test_the_gated_group_norm_gates_first_and_norms_by_group():
     y = jax.random.normal(jax.random.PRNGKey(0), (2, 5, 64))
     z = jax.random.normal(jax.random.PRNGKey(1), (2, 5, 64))
@@ -402,6 +447,32 @@ def test_the_pool_and_the_slab_both_hold_every_layer(whole):
         KVBlockPool.for_engine(eng, 32, block_size=16, block_dtype="int8")
     with pytest.raises(ValueError, match="state_slots"):
         KVBlockPool.for_engine(eng, 32, block_size=16)
+
+
+def test_a_lane_without_a_request_is_not_streamed_by_the_step(wide):
+    """Three rows after their prompts, one position through
+    ``forward_with_cache``: with lane 2's pad at the cache's length (a
+    lane without a request, ``iterbatch._empty_span``) the interpreted
+    kernels give the live rows the XLA path's logits, as they do with
+    every lane live; the empty lane's state goes out as it came in and
+    its logits are finite."""
+    _, cfg, params = wide
+    ids = jnp.asarray(np.random.RandomState(4).randint(0, 256, (3, 41)))
+    fwd = jax.jit(lambda p, i, c, pad, kernel: hybrid_ssm.forward_with_cache(
+        p, i, cfg, c, pad, decode_kernel=kernel),
+        static_argnames=("kernel",))
+    _, cache = fwd(params, ids[:, :40], hybrid_ssm.make_cache(cfg, 3, 256),
+                   jnp.asarray([0, 5, 0]), None)
+    pad = jnp.asarray([0, 5, 256])
+    want, _ = fwd(params, ids[:, 40:], cache, pad, None)
+    got, after = fwd(params, ids[:, 40:], cache, pad, "interpret")
+    assert np.abs(np.asarray(got[:2] - want[:2])).max() < TOL
+    assert np.all(np.isfinite(np.asarray(got)))
+    before = cache.state[0]
+    assert np.array_equal(np.asarray(after.state[0][:, 2]),
+                          np.asarray(before[:, 2]))
+    assert not np.array_equal(np.asarray(after.state[0][:, :2]),
+                              np.asarray(before[:, :2]))
 
 
 @pytest.mark.parametrize("kernel", ["xla", "interpret"])

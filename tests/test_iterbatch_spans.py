@@ -4,7 +4,8 @@ a request keeps its pad to the digit and a lane WITHOUT one (a ghost of
 the seed's width or of a grow, a retired or parked row's) carries an
 EMPTY span, a pad no depth reaches. And the counter that says how often
 that spares a read: ``attn_positions_streamed`` over
-``attn_positions_rect``.
+``attn_positions_rect``; for a family whose rows hold a state in the
+slab, ``state_lanes_streamed`` over ``state_lanes_compiled``.
 
 Tiny sizes on the CPU. The state is read on the worker's own thread, at
 every entry into ``_advance`` (a boundary: whatever seeded, grew, joined
@@ -17,7 +18,7 @@ import time
 import jax
 import numpy as np
 
-from llm_sharding_demo_tpu.models import gpt2
+from llm_sharding_demo_tpu.models import gpt2, hybrid_ssm
 from llm_sharding_demo_tpu.ops.decode_attention import BLOCK_S
 from llm_sharding_demo_tpu.runtime.engine import DecodeEngine
 from llm_sharding_demo_tpu.runtime.iterbatch import IterBatchingEngine
@@ -180,3 +181,55 @@ def test_a_lone_row_without_pad_streams_its_rectangle():
     st = ib.stats()
     assert st["attn_positions_streamed"] == st["attn_positions_rect"] \
         == 19 * BLOCK_S
+
+
+def test_state_lane_counters_after_two_rows_retire():
+    """A slab batch of width 4 (the state-space family, every layer a
+    row's state): while all four rows live the state kernels stream
+    every compiled lane; over the calls after two rows have retired,
+    half of them. The streams are the solo runs'."""
+    cfg = hybrid_ssm.CONFIGS["hybrid-ssm-tiny"]
+    engine = DecodeEngine(
+        hybrid_ssm.init_params(cfg, jax.random.PRNGKey(0)), cfg, max_seq=128)
+    pool = KVBlockPool.for_engine(engine, num_blocks=40, block_size=8,
+                                  state_slots=4)
+    ib = IterBatchingEngine(engine, max_batch=4, seg_steps=8,
+                            max_wait_ms=300.0, pool=pool)
+    marks, inner = [], ib._advance
+
+    def advance(state):
+        marks.append((sum(s is not None for s in state.slots),
+                      len(state.slots), ib.stats()))
+        return inner(state)
+    ib._advance = advance
+    rng = np.random.default_rng(8)
+    prompts = [rng.integers(0, 320, size=(n,)) for n in (9, 5, 12, 7)]
+    news = [10, 40, 10, 40]
+    want = [engine.generate(p[None, :], n).tokens[0]
+            for p, n in zip(prompts, news)]
+    got = _staggered(ib, [(p, n, lambda: True)
+                          for p, n in zip(prompts, news)])
+    for r, w in zip(got, want):
+        np.testing.assert_array_equal(r.tokens[0], w)
+    assert ib.stats()["batches"] == 1
+    assert {width for _, width, _ in marks} == {4}
+    assert [live for live, _, _ in marks][0] == 4
+    full = next(st for live, _, st in marks if live == 2)
+    assert full["state_lanes_streamed"] == full["state_lanes_compiled"] > 0
+    end = ib.stats()
+    streamed = end["state_lanes_streamed"] - full["state_lanes_streamed"]
+    compiled = end["state_lanes_compiled"] - full["state_lanes_compiled"]
+    assert isinstance(streamed, int) and compiled == 4 * 30
+    assert streamed / compiled == 0.5
+
+
+def test_a_pooled_batch_without_a_slab_counts_no_state_lanes():
+    engine = _engine(200)
+    pool = KVBlockPool.for_engine(engine, num_blocks=60, block_size=8)
+    assert pool.slab is None
+    ib = IterBatchingEngine(engine, max_batch=4, seg_steps=8,
+                            max_wait_ms=5.0, pool=pool)
+    ib.generate(np.random.default_rng(9).integers(0, 211, size=(16,)), 20)
+    st = ib.stats()
+    assert st["attn_positions_rect"] > 0
+    assert st["state_lanes_streamed"] == st["state_lanes_compiled"] == 0

@@ -339,6 +339,51 @@ def test_the_kernel_is_the_recurrence(decay):
     assert not np.array_equal(np.asarray(s2[1]), np.asarray(states[1]))
 
 
+# which lanes hold a request, by name, at any number of rows: the live
+# lanes' kernel cases (ISSUE 47)
+LIVE = {"all-live": lambda b: [True] * b,
+        "one-live": lambda b: [i == b // 2 for i in range(b)],
+        "lane-0-ghost": lambda b: [i > 0 for i in range(b)],
+        "last-lane-ghost": lambda b: [i < b - 1 for i in range(b)],
+        "alternating": lambda b: [i % 2 == 0 for i in range(b)],
+        "none-live": lambda b: [False] * b}
+
+
+@pytest.mark.parametrize("rows", [2, 4])
+@pytest.mark.parametrize("pattern", sorted(LIVE))
+def test_the_kernel_streams_the_live_lanes(rows, pattern):
+    """Over two blocks of heads: a live lane's output and state are the
+    recurrence's; a lane without a request is not streamed: its state,
+    in every layer of the stack, is bit for bit what came in, its output
+    row exactly zero."""
+    live = np.asarray(LIVE[pattern](rows))
+    q, k, v, g, beta, _ = rule_inputs(5, rows, 32, 1, 16, 24)
+    states = jax.random.normal(jax.random.PRNGKey(9), (3, rows, 32, 16, 24))
+    args = (q[:, :, 0], k[:, :, 0], v[:, :, 0], g[:, :, 0], beta[:, :, 0])
+    o1, s1 = map(np.asarray, kda.step(*args, states, 1, None))
+    o2, s2 = map(np.asarray, kda.step(
+        *args, states, 1, "interpret",
+        gated_delta.lane_order(jnp.asarray(live))))
+    assert np.abs(o1[live] - o2[live]).max(initial=0) < 1e-6
+    assert np.abs(s1[:, live] - s2[:, live]).max(initial=0) < 1e-6
+    assert np.array_equal(s2[:, ~live], np.asarray(states)[:, ~live])
+    assert np.array_equal(s2[[0, 2]], np.asarray(states)[[0, 2]])
+    assert np.all(o2[~live] == 0) and np.all(np.isfinite(o2))
+    if live.any():
+        assert not np.array_equal(s2[1, live], np.asarray(states)[1, live])
+
+
+def test_no_lanes_given_is_every_lane_live():
+    q, k, v, g, beta, _ = rule_inputs(5, 4, 32, 1, 16, 24)
+    states = jax.random.normal(jax.random.PRNGKey(9), (3, 4, 32, 16, 24))
+    args = (q[:, :, 0], k[:, :, 0], v[:, :, 0], g[:, :, 0], beta[:, :, 0])
+    o1, s1 = kda.step(*args, states, 1, "interpret")
+    o2, s2 = kda.step(*args, states, 1, "interpret",
+                      gated_delta.lane_order(jnp.ones((4,), bool)))
+    assert np.array_equal(np.asarray(o1), np.asarray(o2))
+    assert np.array_equal(np.asarray(s1), np.asarray(s2))
+
+
 def test_a_left_padded_bucket_is_the_unpadded_prompt(whole):
     """Row 1 of a bucket of 140 is a prompt of 118 behind 22 pad
     positions: its logits and its state are the unpadded prompt's (the
@@ -419,6 +464,32 @@ def test_the_pool_holds_the_latent_layers_and_the_slab_the_rest(whole):
     assert cache.state is None          # rows' state is the slab's
     with pytest.raises(ValueError, match="state_slots"):
         KVBlockPool.for_engine(eng, 32, block_size=16)
+
+
+def test_a_lane_without_a_request_is_not_streamed_by_the_step(whole):
+    """Three rows after their prompts, one position through
+    ``forward_with_cache``: with lane 2's pad at the cache's length (a
+    lane without a request, ``iterbatch._empty_span``) the interpreted
+    kernels give the live rows the XLA path's logits, as they do with
+    every lane live; the empty lane's state goes out as it came in and
+    its logits are finite."""
+    _, cfg, params = whole
+    ids = jnp.asarray(np.random.RandomState(4).randint(0, 256, (3, 41)))
+    fwd = jax.jit(lambda p, i, c, pad, kernel: kda_moe.forward_with_cache(
+        p, i, cfg, c, pad, decode_kernel=kernel),
+        static_argnames=("kernel",))
+    _, cache = fwd(params, ids[:, :40], kda_moe.make_cache(cfg, 3, 256),
+                   jnp.asarray([0, 5, 0]), None)
+    pad = jnp.asarray([0, 5, 256])
+    want, _ = fwd(params, ids[:, 40:], cache, pad, None)
+    got, after = fwd(params, ids[:, 40:], cache, pad, "interpret")
+    assert np.abs(np.asarray(got[:2] - want[:2])).max() < TOL
+    assert np.all(np.isfinite(np.asarray(got)))
+    before = cache.state[0]
+    assert np.array_equal(np.asarray(after.state[0][:, 2]),
+                          np.asarray(before[:, 2]))
+    assert not np.array_equal(np.asarray(after.state[0][:, :2]),
+                              np.asarray(before[:, :2]))
 
 
 @pytest.mark.parametrize("kernel", ["xla", "interpret"])
