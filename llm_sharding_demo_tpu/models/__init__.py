@@ -72,8 +72,8 @@ def row_state(config, dtype) -> tuple:
     convolution tails;
     ``window_moe``: the sliding layers' rings of their last window of
     positions; ``hybrid_ssm``: every layer's state-space matrices and
-    convolution tails, beside every layer's positions). The state slab (``runtime.state_slab.StateSlab``) sizes
-    itself from this."""
+    convolution tails, beside every layer's positions). The state slab
+    (``runtime.state_slab.StateSlab``) sizes itself from this."""
     declared = getattr(family_module(config), "row_state", None)
     return () if declared is None else declared(config, dtype)
 

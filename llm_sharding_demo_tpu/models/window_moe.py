@@ -20,9 +20,9 @@ module dispatches on (``init_params`` / ``forward`` /
   others). A sliding layer keeps, for each row, a RING of its last
   ``sliding_window`` positions (``ops.sliding_window``) and nothing
   else: ``row_state`` declares it, it rides in ``KVCache.state`` as one
-  array ``[Lw, B, Hkv, window, 2 hd]``, and the state slab
-  (``runtime.state_slab``) keeps it between segments and with a stored
-  prefix. Whatever a row's depth, a sliding layer holds and reads
+  array ``[Lw, B, Hkv, window, 2 hd]`` (a live row's ring is its lane
+  of the batch's working cache), and the state slab
+  (``runtime.state_slab``) keeps it with a stored prefix. Whatever a row's depth, a sliding layer holds and reads
   ``window`` positions of it.
 - **The first period differs from the others in its feed-forward**:
   the first ``first_k_dense`` layers have a dense SwiGLU, every later
@@ -202,8 +202,8 @@ def window_positions(state, depths) -> Tuple[int, int]:
     """``(held, seen)``: the positions the sliding layers hold for rows
     at ``depths``, and the positions those rows have reached, summed
     (what the scheduler samples as ``window.positions_*``). ``state``
-    is where the rows' records live (the state slab's leaves, or a
-    working cache's ``KVCache.state``): a row's room is the ring axis
+    is where the rows' records live (a working cache's
+    ``KVCache.state``): a row's room is the ring axis
     of what is ALLOCATED there, so records sized to a depth would read
     ``held == seen``."""
     room = state[0].shape[-2]

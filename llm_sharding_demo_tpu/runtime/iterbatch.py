@@ -106,9 +106,12 @@ once a GROW: the wider cache is gathered from the pool, so that two
 widths of it never stand side by side (``_grow``). Two kinds of batch keep a whole gather in front of every call and a
 whole scatter behind it: one on a QUANTIZED pool (its served tokens
 depend on every position being read back through its block's scale),
-and a speculative one (its segment rolls whole rows). The state slab's
-rows are still fetched for a call and handed back after it. The pool is
-also the ADMISSION authority:
+and a speculative one (its segment rolls whole rows). What a row holds
+beside its positions (``models.row_state``) is a lane of the same
+working cache from the seed or the join to the row's end: the state
+slab beside the pool keeps the prefix store's snapshots and no live
+row's record, and no call moves one. The pool is also the ADMISSION
+authority:
 
 - admission of a policy-compatible request defers (without closing the
   batch) while the allocator's watermark says its blocks don't fit —
@@ -263,6 +266,8 @@ GUARDED_STATE = {
     "attn_positions_rect": "_stats_lock",
     "state_lanes_streamed": "_stats_lock",
     "state_lanes_compiled": "_stats_lock",
+    "state_calls_resident": "_stats_lock", "_state_joined": "_stats_lock",
+    "_state_rows": "_stats_lock", "_state_peak": "_stats_lock",
     "grows": "_stats_lock", "preemptions": "_stats_lock",
     "resumes": "_stats_lock", "fault_parks": "_stats_lock",
     "batches_closed": "_stats_lock", "_turned": "_stats_lock",
@@ -403,10 +408,6 @@ class _Slot:
     # the trash block
     blk_lo: int = 0
     blk_ids: List[int] = dataclasses.field(default_factory=list)
-    # pool mode, a family whose rows hold a state beside their
-    # positions: this row's slot in the state slab (held exactly as
-    # long as blk_ids; None otherwise)
-    state_slot: Optional[int] = None
     # tokens emitted before a preemption (host copy); delivery prepends
     # them in place of first_ref
     resumed_prefix: Optional[np.ndarray] = None
@@ -463,6 +464,18 @@ def _admit_cache_scope_key(cache, solo, slot, roll):
 _admit_cache = graftscope.instrument(
     jax.jit(_admit_cache_impl, donate_argnums=(0,)),
     "iterbatch._admit_cache", key_fn=_admit_cache_scope_key)
+
+
+def _widen_state(state, rows: int):
+    """``rows`` more lanes behind a working cache's row state (``None``
+    where the family has none). The new lanes are EMPTY: a lane without
+    a request has an empty span, and the state kernels copy its record
+    neither in nor out (``_empty_span``); what else reads it computes
+    on zeros, for nobody."""
+    if state is None:
+        return None
+    return tuple(jnp.pad(x, [(0, 0), (0, rows)] + [(0, 0)] * (x.ndim - 2))
+                 for x in state)
 
 
 @dataclasses.dataclass
@@ -588,7 +601,9 @@ class IterBatchingEngine:
         self.prefix = prefix
         self.pool = pool
         # the state slab beside the pool (runtime.state_slab), for a
-        # family whose rows hold a state beside their positions
+        # family whose rows hold a state beside their positions: the
+        # store's snapshots; a live row's record is its lane of the
+        # batch's working cache
         self._slab = getattr(pool, "slab", None)
         from ..models import row_state
         if (pool is not None and self._slab is None
@@ -631,6 +646,14 @@ class IterBatchingEngine:
         self.calls_resident = 0
         self.cache_gathers = 0
         self.blocks_written_back = 0
+        # batches whose rows hold a state: decode calls that ran on the
+        # resident row state (every one), the records joiners' merges
+        # wrote into a lane, and the records the live batch holds now
+        # and held at most together with the slab's snapshots
+        self.state_calls_resident = 0
+        self._state_joined = 0
+        self._state_rows = 0
+        self._state_peak = 0
         # cache positions the decode kernel's stream reads for the live
         # rows of the plain calls (each row's own span ``[pad, depth)`` in
         # whole blocks, summed over a call's steps), and the positions of
@@ -803,8 +826,18 @@ class IterBatchingEngine:
                    "batches_closed": self.batches_closed,
                    **self._turned, **self._moe, **self._window,
                    "parked": len(self._parked)}
+            resident, joined, rows, peak = (
+                self.state_calls_resident, self._state_joined,
+                self._state_rows, self._state_peak)
         if self._slab is not None:
-            out.update(self._slab.stats())
+            # the records the device has room for and those it holds:
+            # the widest working state's lanes beside the slab's slots
+            st = self._slab.stats()
+            st["state.slots"] += self.max_batch
+            st["state.in_use"] += rows
+            st["state.peak"] = max(st["state.peak"], peak)
+            st["state.rows_scattered"] += joined
+            out.update(st, state_calls_resident=resident)
         out.update({f"t_{k}_s": v for k, v in self.states.totals().items()})
         return out
 
@@ -1024,6 +1057,21 @@ class IterBatchingEngine:
             # batch's cache/buffer bytes)
             graftmem.release(state.mem_cache)
             graftmem.release(state.mem_buf)
+            self._note_state_rows(None)
+
+    def _note_state_rows(self, more: Optional[int]) -> None:
+        """``more`` records more (fewer) in the live batch's working
+        cache, one a live row; ``None``: the batch has ended and holds
+        none. With the slab's snapshots, what the device holds of row
+        state (``stats()``'s ``state.in_use`` and ``state.peak``)."""
+        if self._slab is None:
+            return
+        snapshots = self._slab.stats()["state.in_use"]
+        with self._stats_lock:
+            self._state_rows = (0 if more is None
+                                else self._state_rows + more)
+            self._state_peak = max(self._state_peak,
+                                   self._state_rows + snapshots)
 
     def _hold_lead(self) -> None:
         """Wait until at most ONE decode call is in flight: the one the
@@ -1238,6 +1286,7 @@ class IterBatchingEngine:
                                        order=self._order, t0=t0)
         if self.pool is not None:
             self._init_tables(state)
+        self._note_state_rows(len(seed))
         with self._stats_lock:
             state.batch = self.batches_run
             self.batches_run += 1
@@ -1265,8 +1314,7 @@ class IterBatchingEngine:
                 alloc.blocks_for(s_max)
                 - (s_max - len(self._ent_ids(e))) // self.pool.block_size
                 for e in ents)
-            ok = need <= alloc.available() and (
-                self._slab is None or len(ents) <= self._slab.available())
+            ok = need <= alloc.available()
         return ok
 
     def _reserve(self, ent) -> int:
@@ -1319,49 +1367,22 @@ class IterBatchingEngine:
         a deferrable admission into a ``PoolExhausted`` request failure
         — or, raced the other way, an over-watermark grant (the
         graftsched check-then-act fixture pins both shapes). Returns
-        ``(p_lo, granted ids, state slot or None)`` or None to defer
-        (blocks free up as rows retire)."""
+        ``(p_lo, granted ids)`` or None to defer (blocks free up as
+        rows retire). A row's state takes no room of its own: its place
+        is its lane (``_slot_possible``)."""
         if self.pool is None:
-            return 0, [], None
+            return 0, []
         alloc = self.pool.allocator
         plen_eff = len(self._ent_ids(ent))
         p_lo = (state.depth - plen_eff) // self.pool.block_size
         p_hi = -(-state.depth // self.pool.block_size)
         ids = alloc.admit_alloc(p_hi - p_lo)
-        if ids is None:
-            return None
-        if self._slab is None:
-            return p_lo, ids, None
-        # a row that has blocks but no state slot does not fit
-        slot = self._take_state_slot()
-        if slot is None:
-            alloc.free(ids)
-            return None
-        return p_lo, ids, slot
-
-    def _take_state_slot(self) -> Optional[int]:
-        """One slab slot for a live row, evicting prefix entries (their
-        snapshots hold slots) oldest first if none is free."""
-        alloc = self.pool.allocator
-        slot = self._slab.alloc()
-        while slot is None and alloc.prefix_len():
-            alloc.evict_lru()
-            slot = self._slab.alloc()
-        return slot
+        return None if ids is None else (p_lo, ids)
 
     def _free_reserved(self, reserved) -> None:
         """Hand back what ``_reserve_blocks`` granted."""
         if self.pool is not None and reserved is not None:
             self.pool.allocator.free(reserved[1])
-            if reserved[2] is not None:
-                self._slab.free(reserved[2])
-
-    def _state_ids(self, state: _BatchState) -> np.ndarray:
-        """The slab slot of every lane: a live row's own, the trash slot
-        for free and ghost lanes."""
-        return np.asarray(
-            [self._slab.trash if s is None or s.state_slot is None
-             else s.state_slot for s in state.slots], dtype=np.int32)
 
     def _admit(self, state: _BatchState):
         """Drain parked rows (oldest first — they outrank the queue),
@@ -1484,7 +1505,11 @@ class IterBatchingEngine:
         widths then never stand on the device side by side (widening
         16 rows of ``chat`` by concatenation held 4.3 GB where the
         cache is 2.15: PERF.md 6, PR 41). The one gather a live batch
-        still makes: four in a batch's life at most."""
+        still makes: four in a batch's life at most. What rows hold
+        beside their positions is in no pool and is widened in either
+        kind of batch, by empty lanes (``_widen_state``): for those
+        leaves alone a pooled batch's two widths stand side by side
+        while the grow runs."""
         old = len(state.slots)
         new = min(_next_pow2(old + 1), self.max_batch)
         pad_rows = new - old
@@ -1497,10 +1522,8 @@ class IterBatchingEngine:
         def grow_cache(c):
             def one(kc: KVCache) -> KVCache:
                 v = kc.v if getattr(kc.v, "ndim", 0) <= 1 else rep(kc.v, 1)
-                state = (None if kc.state is None
-                         else tuple(rep(x, 1) for x in kc.state))
                 return KVCache(k=rep(kc.k, 1), v=v, length=kc.length,
-                               state=state)
+                               state=_widen_state(kc.state, pad_rows))
             if isinstance(c, list):
                 return [one(x) for x in c]
             return one(c)
@@ -1514,18 +1537,20 @@ class IterBatchingEngine:
                                                   jnp.int32)]))
         resident = state.cache is not None
         if resident:
+            row_state = state.cache.state
             state.cache = (None if state.tables is not None
                            else grow_cache(state.cache))
         if state.tables is not None:
             # a ghost lane reads the trash block here and its
             # write-back lands there, like a retired row's stale lane
-            # (and its state in the slab's trash slot: ``_state_ids``)
             state.tables = np.concatenate(
                 [state.tables,
                  np.full((pad_rows, self.pool.nbm), self.pool.trash,
                          dtype=np.int32)], axis=0)
             if resident:
-                state.cache = self.pool.gather(state.tables, state.depth)
+                state.cache = self.pool.gather(
+                    state.tables, state.depth)._replace(
+                        state=_widen_state(row_state, pad_rows))
                 with self._stats_lock:
                     self.cache_gathers += 1
                 REGISTRY.inc("iter_cache_gathers_total")
@@ -1676,12 +1701,14 @@ class IterBatchingEngine:
         if self.pool is not None:
             state.slots[slot].blk_lo = blk_lo
             state.slots[slot].blk_ids = blk_ids
-            state.slots[slot].state_slot = reserved[2]
         with self._stats_lock:
             if resume is not None:
                 self.resumes += 1
             else:
                 self.joins += 1
+            # the merge above wrote the joiner's record into its lane
+            self._state_joined += self._slab is not None
+        self._note_state_rows(1)
         if resume is not None:
             REGISTRY.inc("kv_pool_resumes_total")
         else:
@@ -1696,11 +1723,12 @@ class IterBatchingEngine:
         """Seed-time placement: allocate each live row's content blocks
         (pad-prefix positions stay on trash) and scatter the seed
         prefill into them, whole. The contiguous cache STAYS with the
-        batch as its resident working cache (its K/V planes: what a row
-        holds beside its positions lives in the slab); from here on the
+        batch as its resident working cache, with what its rows hold
+        beside their positions (which is in no pool); from here on the
         pool is written behind every call and read only where the batch
         grows (``_grow``). A quantized pool and a speculative batch give
-        the cache up here and gather it anew for every call."""
+        the cache up here and gather it anew for every call: no family
+        with a row state is served by either (``serving.app``)."""
         bs = self.pool.block_size
         state.tables = np.full((len(state.slots), self.pool.nbm),
                                self.pool.trash, dtype=np.int32)
@@ -1714,16 +1742,7 @@ class IterBatchingEngine:
                 s.blk_lo = p_lo
                 s.blk_ids = self.pool.allocator.alloc(p_hi - p_lo)
                 state.tables[i, p_lo:p_hi] = s.blk_ids
-                if self._slab is not None:
-                    s.state_slot = self._take_state_slot()
-                    if s.state_slot is None:
-                        raise RuntimeError(
-                            "no state slot for a seeded row (the slab "
-                            "is smaller than the batch)")
             self.pool.scatter(state.cache, state.tables)
-            if self._slab is not None:
-                self._slab.scatter(state.cache.state,
-                                   self._state_ids(state))
         except BaseException:
             # all-or-nothing: rows placed before the failure must not
             # leak their refs (the seed delivers the error to every
@@ -1732,14 +1751,11 @@ class IterBatchingEngine:
                 self._release_blocks(state, i)
             raise
         if self.pool.block_dtype is not None or state.spec_mode:
+            assert self._slab is None, "a row state has no pool to go to"
             state.cache = None
             # the pool alone holds the KV bytes (its own ledger entry)
             graftmem.release(state.mem_cache)
             state.mem_cache = 0
-        else:
-            # what a row holds beside its positions lives in the slab
-            state.cache = state.cache._replace(state=None)
-            graftmem.update(state.mem_cache, state.cache)
 
     def _place_admitted(self, state: _BatchState, slot: int,
                         solo, roll: int,
@@ -1751,16 +1767,14 @@ class IterBatchingEngine:
         and scatter of the rolled row (the paged form of
         ``_admit_cache``'s roll merge, which the caller makes as well
         where the batch has a resident cache: the two then hold the
-        same row). ``_admit_one`` owns freeing the reservation on
-        failure; this only resets the table row."""
-        p_lo, ids, state_slot = reserved
+        same row; the row's state goes into its lane by that merge
+        alone). ``_admit_one`` owns freeing the reservation on failure;
+        this only resets the table row."""
+        p_lo, ids = reserved
         try:
             state.tables[slot, :] = self.pool.trash
             state.tables[slot, p_lo:p_lo + len(ids)] = ids
             self.pool.scatter_row(solo, state.tables[slot], roll)
-            if state_slot is not None:
-                # the joiner's state goes into its slot with no roll
-                self._slab.scatter(solo.state, [state_slot])
         except BaseException:
             state.tables[slot, :] = self.pool.trash
             raise
@@ -1771,6 +1785,7 @@ class IterBatchingEngine:
         its span is EMPTY (``_empty_span``)."""
         self._release_blocks(state, i)
         state.slots[i] = None
+        self._note_state_rows(-1)
         self._empty_span(state, i)
 
     def _empty_span(self, state: _BatchState, i: int) -> None:
@@ -1791,12 +1806,7 @@ class IterBatchingEngine:
 
     def _release_blocks(self, state: _BatchState, i: int) -> None:
         s = state.slots[i]
-        if self.pool is None or s is None:
-            return
-        if s.state_slot is not None:
-            self._slab.free(s.state_slot)
-            s.state_slot = None
-        if not s.blk_ids:
+        if self.pool is None or s is None or not s.blk_ids:
             return
         self.pool.allocator.free(s.blk_ids)
         s.blk_ids = []
@@ -1988,10 +1998,9 @@ class IterBatchingEngine:
         REGISTRY.gauge("queue_depth", depth, scheduler="iter")
         if self._window_positions is not None:
             # a live row's cache holds its prompt and all it emitted but
-            # the token in flight; its record lies in the slab, or
-            # without a pool in the batch's own cache
+            # the token in flight
             held, seen = self._window_positions(
-                state.cache.state if self._slab is None else self._slab.data,
+                state.cache.state,
                 [s.plen + s.emitted - 1 for s in state.slots
                  if s is not None])
             with self._stats_lock:
@@ -2044,14 +2053,11 @@ class IterBatchingEngine:
             self._ensure_blocks(state, d + n)
             if not state.active():
                 return  # everyone preempted (single-row pool squeeze)
-        # the batch's own working cache; a batch that gave it up to the
-        # pool at its seed gathers the whole of it (``_init_tables``)
+        # the batch's own working cache, its rows' state in it; a batch
+        # that gave it up to the pool at its seed gathers the whole of
+        # it (``_init_tables``)
         resident = state.cache is not None
         cache = state.cache if resident else self.pool.gather(state.tables, d)
-        if self._slab is not None:
-            # the segment program carries the slab rows it runs
-            slots_j = self._state_ids(state)
-            cache = cache._replace(state=self._slab.gather(slots_j))
         # keys for ``seg_steps`` steps whatever ``n`` (a row's keys are
         # prefix-stable) and ``n`` an operand: one program a width
         step_keys = self._segment_keys(state, self.seg_steps)
@@ -2072,9 +2078,7 @@ class IterBatchingEngine:
                 wrote = self.pool.nbm
             self.pool.note_compiles()
             if self._slab is not None:
-                self._slab.scatter(cache.state, slots_j)
-                self._slab.note_compiles()
-                cache = cache._replace(state=None)
+                self._slab.note_compiles()   # the store's two movers
         if resident:
             state.cache = cache
         self._count_stream(state, d, n)
@@ -2093,6 +2097,7 @@ class IterBatchingEngine:
                 self.calls_resident += resident
                 self.cache_gathers += not resident
                 self.blocks_written_back += live * wrote
+                self.state_calls_resident += self._slab is not None
         REGISTRY.inc("iter_segments_total")
         if cut:
             REGISTRY.inc("iter_segments_cut_total")
@@ -2392,14 +2397,16 @@ class IterBatchingEngine:
             REGISTRY.inc("iter_eos_retires_total")
         s.done_t = time.monotonic()
         s.req.payload = (s, eos_at)
-        s.req.done.set()
-        self._vacate(state, i)
         gaps = (min(s.emitted, s.req.max_new_tokens) - 1 if eos_at is None
                 else eos_at)
+        # counted before the caller is told: whoever reads ``stats()``
+        # behind its answer finds the row in them
         with self._stats_lock:
             self.rows_served += 1
             self.steps_paid += s.emitted - 1
             self.gaps_answered += gaps
+        s.req.done.set()
+        self._vacate(state, i)
         REGISTRY.inc("iter_steps_paid_total", value=s.emitted - 1)
         REGISTRY.inc("iter_gaps_answered_total", value=gaps)
         if state.spec_mode:

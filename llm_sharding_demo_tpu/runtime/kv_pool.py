@@ -1215,9 +1215,8 @@ class KVBlockPool:
         cache_layers``: not every layer of every model does), each of
         the family's ``cache_entry``; and, for a family whose rows hold
         a state beside their positions (``models.row_state``), a state
-        slab of ``state_slots`` records beside it (live rows and the
-        prefix store's snapshots: ``MAX_BATCH + PREFIX_CACHE`` as
-        served). The paged
+        slab of ``state_slots`` records beside it (the prefix store's
+        snapshots: ``PREFIX_CACHE`` as served). The paged
         path drives the engine's OWN compiled programs on gathered
         views, so the engine must be the unstaged single-device one:
         no stage partitioning (per-stage cache lists), no mesh. With a
@@ -1250,7 +1249,7 @@ class KVBlockPool:
                 raise ValueError(
                     f"{type(cfg).__name__}'s rows hold a state beside "
                     "their positions: give the pool state_slots (a slot "
-                    "a live row and one a stored prefix)")
+                    "a stored prefix's snapshot)")
             from .state_slab import StateSlab
             pool.attach_slab(StateSlab(leaves, state_slots))
         return pool

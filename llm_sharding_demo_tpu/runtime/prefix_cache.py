@@ -328,9 +328,9 @@ class PrefixCachingEngine:
         With a state slab beside the pool the entry is its blocks AND a
         snapshot of the row's state at this boundary (``cache.state``):
         blocks are shared with a shallower entry, a state cannot be, so
-        the entry's cost is a slab slot on top of its new blocks. No
-        slot (live rows hold them all, even after evicting every other
-        entry) skips the insert. Returns whether an entry was made."""
+        the entry's cost is a slab slot on top of its new blocks, made
+        free by evicting the oldest entry where snapshots hold them
+        all. Returns whether an entry was made."""
         from .kv_pool import PoolExhausted
         alloc = self._pool.allocator
         slab = self._pool.slab

@@ -1,37 +1,37 @@
-"""The state slab: per-ROW state beside the paged pool.
+"""The state slab: per-ROW state snapshots beside the paged pool.
 
 The paged pool (``runtime.kv_pool``) stores what a model caches per
 POSITION, in blocks. A family whose layers (some of them) keep a state
 that belongs to a row and not to a position (``models.row_state``:
 ``gdn_moe``'s linear-attention matrices and convolution tails) has
 nothing to page: the state is one fixed-size record a row, rewritten
-whole by every step. The slab holds those records between decode
-segments, as the pool holds the blocks:
+whole by every step.
 
-- ``slots`` records (plus a trash slot ghost lanes read and write, the
-  slab's sibling of the pool's trash block), each leaf one device array
-  ``[layers, slots + 1, ...]`` with the slot on axis 1, where the
-  engine's caches have the batch;
-- a **live row** takes a slot at admission, the segment loop gathers
-  the live rows' slots into the working cache (``KVCache.state``) and
-  scatters them back after the segment, and the slot is freed at
-  retirement, cancellation and preemption (a preempted row's state is
-  rebuilt by recompute);
-- a **snapshot** is a slot that holds the state at the boundary a
-  prefix-store entry was registered for, keyed by the entry's content
-  key: taken when the entry is inserted, copied out (never handed over)
-  on a hit, freed when the entry is dropped: LRU eviction, the capacity
-  trim and pool pressure alike, through the allocator's
-  ``_on_prefix_drop`` hook. Blocks of a deeper entry are shared
-  structurally with the shallower one's; a state cannot be, so every
-  entry owns its snapshot.
+- a **live row's** record is a LANE of its batch's working cache
+  (``KVCache.state``, lanes on axis 1): there from the seed's prefill
+  or the join (``iterbatch._admit_cache``) to the row's end, as the K/V
+  planes are. It takes no slot here and no call moves it; a preempted
+  row's is rebuilt by recompute;
+- the slab holds the prefix store's **snapshots**: ``slots`` records,
+  each leaf one device array ``[layers, slots, ...]`` with the slot on
+  axis 1, where the engine's caches have the batch. A snapshot is the
+  state at the boundary a prefix-store entry was registered for, keyed
+  by the entry's content key: taken when the entry is inserted, copied
+  out (never handed over) on a hit, freed when the entry is dropped:
+  LRU eviction, the capacity trim and pool pressure alike, through the
+  allocator's ``_on_prefix_drop`` hook. Blocks of a deeper entry are
+  shared structurally with the shallower one's; a state cannot be, so
+  every entry owns its snapshot.
 
 Slot lifecycle (docs/ARCHITECTURE.md has it beside the blocks')::
 
-    free -> live      (a row admitted; rewritten every segment)
-         -> free      (retired / cancelled / preempted)
     free -> snapshot  (an entry registered; immutable)
          -> free      (the entry dropped)
+
+Both movers carry ONE record: gathering several slots at once copied
+the whole slab twice where a leaf's minor axes are ``[256, 128]``
+(0.63-0.92 GB of temporaries at 2-16 ids on a v5e;
+tests/test_tpu_compile_engine.py).
 
 Host accounting (free list, snapshot map, counters) lives under
 ``_lock``; the device arrays are rebound only under ``_dev_lock``.
@@ -53,8 +53,8 @@ from ..utils.metrics import CompileWatch
 JIT_ENTRY_POINTS = ("_gather", "_scatter")
 
 # Observability contract (tools/graftcheck scope pass): both movers'
-# dispatches are timed into the graftscope ring, keyed by the number of
-# rows moved (slot ids are traced operands and never key programs).
+# dispatches are timed into the graftscope ring (one program each: the
+# slot is a traced operand).
 PROFILED_SCOPES = ("_gather", "_scatter")
 
 # Donation contract (tools/graftcheck sanitize pass): the scatter
@@ -86,12 +86,16 @@ LOCK_ORDER = ("_dev_lock", "_lock")
 DEVICE_LOCKS = ("_dev_lock",)
 
 
-def _gather_scope_key(data, ids):
-    return (int(ids.shape[0]),)
+def _gather_state_impl(data, slot):
+    return tuple(jax.lax.dynamic_slice_in_dim(x, slot, 1, axis=1)
+                 for x in data)
 
 
-def _scatter_scope_key(data, state, ids):
-    return (int(ids.shape[0]),)
+def _scatter_state_impl(data, state, slot):
+    return tuple(
+        jax.lax.dynamic_update_slice_in_dim(x, s.astype(x.dtype), slot,
+                                            axis=1)
+        for x, s in zip(data, state))
 
 
 class StateSlab:
@@ -104,11 +108,10 @@ class StateSlab:
         if slots < 1:
             raise ValueError(f"slots={slots} must be >= 1")
         self.slots = slots
-        self.trash = slots
         self.data = tuple(
-            jnp.zeros(shape[:1] + (slots + 1,) + shape[1:], dtype)
+            jnp.zeros(shape[:1] + (slots,) + shape[1:], dtype)
             for shape, dtype in leaves)
-        self.bytes_per_slot = sum(x.nbytes for x in self.data) // (slots + 1)
+        self.bytes_per_slot = sum(x.nbytes for x in self.data) // slots
         self._lock = graftsched.lock("state_slab.StateSlab._lock")
         self._dev_lock = graftsched.rlock("state_slab.StateSlab._dev_lock")
         self._free: List[int] = list(range(slots - 1, -1, -1))
@@ -117,38 +120,17 @@ class StateSlab:
         self.peak = 0
         self.restores = 0
         self.evictions = 0
-        # records the movers carried, counted on the host from the ids
-        # they were handed (no device work, no fetch): times
-        # ``bytes_per_slot`` the bytes a batch's boundaries moved
+        # records the movers carried (a restore out of its slot, a
+        # snapshot into its slot), counted on the host: times
+        # ``bytes_per_slot`` the bytes the store's boundaries moved
         self.rows_gathered = 0
         self.rows_scattered = 0
         graftmem.track(self, "data", "state_slab", self.data)
-
-        def _gather_state_impl(data, ids):
-            return tuple(jnp.take(x, ids, axis=1) for x in data)
-
-        def _scatter_state_impl(data, state, ids):
-            # one in-place row update a lane, in lane order (duplicate
-            # targets, the trash slot, resolve as the pool's do: the
-            # last write wins); a scatter keeps a second copy of the
-            # rows it moves, 1.2 GB at 16 rows (the compiler's report
-            # for a v5e), which the chip does not have
-            out = []
-            for x, s in zip(data, state):
-                for i in range(s.shape[1]):
-                    x = jax.lax.dynamic_update_slice_in_dim(
-                        x, jax.lax.slice_in_dim(s, i, i + 1, axis=1
-                                                ).astype(x.dtype),
-                        ids[i], axis=1)
-                out.append(x)
-            return tuple(out)
-
         self._gather = graftscope.instrument(
-            jax.jit(_gather_state_impl), "state_slab._gather",
-            key_fn=_gather_scope_key)
+            jax.jit(_gather_state_impl), "state_slab._gather")
         self._scatter = graftscope.instrument(
             jax.jit(_scatter_state_impl, donate_argnums=(0,)),
-            "state_slab._scatter", key_fn=_scatter_scope_key)
+            "state_slab._scatter")
         self._compile_watches = (
             CompileWatch("state_slab", self._gather),
             CompileWatch("state_slab", self._scatter))
@@ -156,8 +138,8 @@ class StateSlab:
     # -- accounting ----------------------------------------------------------
 
     def alloc(self) -> Optional[int]:
-        """One free slot, or ``None``: the caller defers (or evicts a
-        prefix entry, whose snapshot then comes back, and asks again)."""
+        """One free slot, or ``None``: the store evicts an entry, whose
+        snapshot then comes back, and asks again."""
         with self._lock:
             if not self._free:
                 return None
@@ -174,35 +156,6 @@ class StateSlab:
             self._free.append(slot)
             self.in_use -= 1
 
-    def available(self) -> int:
-        """Slots a live row could get now: the free ones and those
-        snapshots hold (an entry can be evicted for a row)."""
-        with self._lock:
-            return len(self._free) + len(self._snap)
-
-    # -- device movers -------------------------------------------------------
-
-    @staticmethod
-    def _ids(ids) -> jnp.ndarray:
-        # a private host copy, as ``KVBlockPool._device_tables`` makes
-        return jnp.asarray(np.array(ids, dtype=np.int32))
-
-    def gather(self, ids) -> tuple:
-        """The state of the rows in slots ``ids`` [B] (the trash slot
-        for ghost lanes), as ``KVCache.state``: fresh buffers, safe to
-        donate."""
-        with self._lock:
-            self.rows_gathered += len(ids)
-        with self._dev_lock:
-            return self._gather(self.data, self._ids(ids))
-
-    def scatter(self, state: tuple, ids) -> None:
-        """Write ``state`` (batch on axis 1) back into slots ``ids``."""
-        with self._lock:
-            self.rows_scattered += len(ids)
-        with self._dev_lock:
-            self.data = self._scatter(self.data, state, self._ids(ids))
-
     def note_compiles(self) -> None:
         for w in self._compile_watches:
             w.check()
@@ -212,8 +165,10 @@ class StateSlab:
     def snapshot(self, key: bytes, slot: int, state: tuple) -> None:
         """Keep ``state`` (one row) in ``slot`` (from ``alloc``) as the
         snapshot of the store entry ``key``."""
-        self.scatter(state, [slot])
+        with self._dev_lock:
+            self.data = self._scatter(self.data, state, np.int32(slot))
         with self._lock:
+            self.rows_scattered += 1
             old = self._snap.pop(key, None)
             self._snap[key] = slot
         self.free(old)
@@ -228,7 +183,7 @@ class StateSlab:
                     return None
                 self.restores += 1
                 self.rows_gathered += 1
-            return self._gather(self.data, self._ids([slot]))
+            return self._gather(self.data, np.int32(slot))
 
     def drop(self, keys) -> None:
         """The store dropped these entries: their snapshots go with

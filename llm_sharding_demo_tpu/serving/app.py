@@ -801,9 +801,12 @@ def create_app(cfg: Optional[ServingConfig] = None,
                     block_size=cfg.kv_block_size,
                     block_dtype=cfg.kv_pool_dtype or None,
                     # a family whose rows hold a state beside their
-                    # positions: a slab slot a live row and one a
-                    # stored prefix (ignored by every other family)
-                    state_slots=cfg.max_batch + cfg.prefix_cache)
+                    # positions: a slab slot a stored prefix's snapshot
+                    # (a live row's record is a lane of its batch's
+                    # working cache; one where there is no store, for
+                    # the slab says the family has a row state; ignored
+                    # by every other family)
+                    state_slots=max(cfg.prefix_cache, 1))
         elif kv_pool is not None:
             raise ValueError("kv_pool injected but KV_POOL_BLOCKS=0 — "
                              "a silently unused pool would misreport "

@@ -85,8 +85,8 @@ MEMORY_COMPONENTS = {
                     "(KVBlockPool.scales)",
     "pool_latent":  "paged pool of a one-plane (latent) cache: one "
                     "vector a position a layer (KVBlockPool.data)",
-    "state_slab":   "per-row state records beside the paged pool: live "
-                    "rows' and prefix snapshots' (StateSlab.data)",
+    "state_slab":   "per-row state records beside the paged pool: the "
+                    "prefix store's snapshots (StateSlab.data)",
     "engine_cache": "contiguous KV caches and in-flight decode "
                     "working views (engine / iterbatch batch state)",
     "spec_buffers": "speculative-decode device token buffers",

@@ -95,7 +95,7 @@ METRIC_CATALOG: Dict[str, str] = {
     "iter_attn_positions_rect_total": "counter",
     "iter_attn_stream_share": "gauge",
     # lanes the state kernels stream a step (live rows) and lanes of the
-    # compiled width, for batches whose rows hold a state in the slab
+    # compiled width, for batches whose rows hold a state
     "iter_state_lanes_streamed_total": "counter",
     "iter_state_lanes_compiled_total": "counter",
     "iter_steps_paid_total": "counter",

@@ -403,33 +403,34 @@ def test_softmax_routing_picks_and_weighs(rows):
     assert np.abs(np.asarray(raw) - chosen).max() < 1e-6
 
 
-def test_the_slab_keeps_rows_and_snapshots():
+def test_the_slab_keeps_snapshots_one_record_a_move():
     leaves = (((2, 3, 4), jnp.float32), ((2, 5), jnp.bfloat16))
     slab = StateSlab(leaves, 3)
-    a, b = slab.alloc(), slab.alloc()
+    assert [x.shape for x in slab.data] == [(2, 3, 3, 4), (2, 3, 5)]
     state = tuple(jnp.arange(2 * 2 * np.prod(s[1:]), dtype=jnp.float32
                              ).reshape(s[:1] + (2,) + s[1:]).astype(t)
                   for s, t in leaves)
-    slab.scatter(state, [a, b])
-    back = slab.gather([b, a, slab.trash])
-    for x, y in zip(back, state):
-        assert x.dtype == y.dtype and x.shape[1] == 3
-        assert np.array_equal(np.asarray(x[:, 0]), np.asarray(y[:, 1]))
-        assert np.array_equal(np.asarray(x[:, 1]), np.asarray(y[:, 0]))
-    c = slab.alloc()
+    rows = [tuple(x[:, i:i + 1] for x in state) for i in range(2)]
+    a, b, c = slab.alloc(), slab.alloc(), slab.alloc()
     assert slab.alloc() is None and slab.stats()["state.peak"] == 3
-    one = tuple(x[:, :1] for x in state)
-    slab.snapshot(b"key", c, one)
-    assert slab.available() == 1 and slab.stats()["state.snapshots"] == 1
-    got = slab.restore(b"key")
-    assert all(np.array_equal(np.asarray(x), np.asarray(y))
-               for x, y in zip(got, one))
+    slab.snapshot(b"one", b, rows[1])
+    slab.snapshot(b"key", c, rows[0])
+    assert slab.stats()["state.snapshots"] == 2
+    for key, want in ((b"key", rows[0]), (b"one", rows[1])):
+        got = slab.restore(key)
+        assert all(x.dtype == y.dtype and x.shape == y.shape
+                   and np.array_equal(np.asarray(x), np.asarray(y))
+                   for x, y in zip(got, want))
+    # a key taken again moves to the new slot and frees the old one
+    slab.snapshot(b"key", a, rows[1])
+    assert np.array_equal(np.asarray(slab.restore(b"key")[0]),
+                          np.asarray(rows[1][0]))
     assert slab.restore(b"other") is None
     slab.drop([b"key", b"never"])
     st = slab.stats()
     assert (st["state.evictions"], st["state.restores"],
-            st["state.in_use"], st["state.snapshots"]) == (1, 1, 2, 0)
-    slab.free(a)
+            st["state.in_use"], st["state.snapshots"]) == (1, 3, 1, 1)
+    assert (st["state.rows_gathered"], st["state.rows_scattered"]) == (3, 3)
     with pytest.raises(ValueError):
         slab.free(a)
 
@@ -512,8 +513,8 @@ def test_solo_and_paged_streams_are_the_references_choice(wide, kernel):
                                            ("interpret", True)])
 def test_rows_that_join_and_retire_serve_their_solo_streams(wide, kernel,
                                                             pooled):
-    """Rows joining a live batch (their state merged with no roll, or
-    into a slab slot), growing it, and retiring, through
+    """Rows joining a live batch (their state merged into a lane
+    with no roll), growing it, and retiring, through
     ``IterBatchingEngine`` with and without the pool, the slab and the
     store: every stream equals its solo run; the spans carry the routing
     counters and the state labels, ``stats()`` the slab's."""
@@ -522,7 +523,7 @@ def test_rows_that_join_and_retire_serve_their_solo_streams(wide, kernel,
     pool = prefix = None
     if pooled:
         pool = KVBlockPool.for_engine(eng, 96, block_size=16,
-                                      state_slots=4 + 3)
+                                      state_slots=3)
         prefix = PrefixCachingEngine(eng, capacity=3, chunk=64, pool=pool)
     it = IterBatchingEngine(eng, max_batch=4, seg_steps=8, prefix=prefix,
                             pool=pool)
@@ -585,8 +586,15 @@ def test_rows_that_join_and_retire_serve_their_solo_streams(wide, kernel,
         assert sum(s.labels["state_snapshots"] for s in pre) == 1
         assert prefix.stats()["hits"] >= 1
         assert st["state.slots"] == 7 and st["state.restores"] >= 1
+        # the batch has ended: what is held is the store's snapshot
         assert st["state.in_use"] == st["state.snapshots"] == 1
         assert 4 <= st["state.peak"] <= 7
+        # no call moved a record: a restore out of its slot, a snapshot
+        # into its slot and a joiner's record into its lane are all
+        assert st["state_calls_resident"] == st["segments"]
+        assert st["state.rows_gathered"] == st["state.restores"]
+        assert st["state.rows_scattered"] == 1 + st["joins"]
+        assert pool.slab.slots == 3 and pool.slab.stats()["state.peak"] <= 2
         assert pool.allocator.stats().blocks_in_use == \
             pool.allocator.stats().blocks_evictable
     else:

@@ -438,7 +438,7 @@ def test_the_pool_and_the_slab_both_hold_every_layer(whole):
     # 3 cached layers of 3, one plane of fused [K | V] rows
     assert pool.data.shape == (3, 33, 1, 2, 16, 64) and pool.planes == 1
     assert pool.slab.slots == 5
-    assert pool.slab.data[0].shape == (3, 6, 4, 24, 16)
+    assert pool.slab.data[0].shape == (3, 5, 4, 24, 16)
     assert pool.slab.bytes_per_slot == 3 * (4 * 24 * 16 * 4 + 3 * 160 * 4)
     cache = pool.gather(np.full((1, pool.nbm), pool.trash, np.int32), 0)
     assert cache.k.shape == (3, 1, 2, 256, 64) and cache.v.shape == (0,)
@@ -497,8 +497,8 @@ def test_solo_and_paged_streams_are_the_references_choice(wide, kernel):
                                            ("interpret", True)])
 def test_rows_that_join_and_retire_serve_their_solo_streams(wide, kernel,
                                                             pooled):
-    """Rows joining a live batch (their state merged with no roll, or
-    into a slab slot), growing it, and retiring, through
+    """Rows joining a live batch (their state merged into a lane
+    with no roll), growing it, and retiring, through
     ``IterBatchingEngine`` with and without the pool, the slab and the
     store: every stream equals its solo run; the spans carry the state
     labels, ``stats()`` the slab's counters, the movers' among them."""
@@ -507,7 +507,7 @@ def test_rows_that_join_and_retire_serve_their_solo_streams(wide, kernel,
     pool = prefix = None
     if pooled:
         pool = KVBlockPool.for_engine(eng, 96, block_size=16,
-                                      state_slots=4 + 3)
+                                      state_slots=3)
         prefix = PrefixCachingEngine(eng, capacity=3, chunk=64, pool=pool)
     it = IterBatchingEngine(eng, max_batch=4, seg_steps=8, prefix=prefix,
                             pool=pool)
@@ -561,15 +561,18 @@ def test_rows_that_join_and_retire_serve_their_solo_streams(wide, kernel,
         assert sum(s.labels["state_snapshots"] for s in pre) == 1
         assert prefix.stats()["hits"] >= 1
         assert st["state.slots"] == 7 and st["state.restores"] >= 1
+        # the batch has ended: what is held is the store's snapshot
         assert st["state.in_use"] == st["state.snapshots"] == 1
         assert 4 <= st["state.peak"] <= 7
+        # no call moved a record: a restore out of its slot, a snapshot
+        # into its slot and a joiner's record into its lane are all
+        assert st["state_calls_resident"] == st["segments"]
+        assert st["state.rows_gathered"] == st["state.restores"]
+        assert st["state.rows_scattered"] == 1 + st["joins"]
+        assert pool.slab.slots == 3 and pool.slab.stats()["state.peak"] <= 2
         assert pool.allocator.stats().blocks_in_use == \
             pool.allocator.stats().blocks_evictable
-        # what the movers carried: every call gathers and scatters its
-        # width of records of bytes_per_slot
         assert st["state.row_bytes"] == pool.slab.bytes_per_slot
-        assert st["state.rows_gathered"] >= st["segments"]
-        assert st["state.rows_scattered"] >= st["segments"]
         kv = pool.stats()
         assert kv["layers"] == 3 and kv["entry_width"] == 1 * 2 * 128
     else:
@@ -725,4 +728,6 @@ def test_served_over_http_with_pool_store_and_slab():
     st = app.runner.stats()
     assert st["state.slots"] == 2 + 2 and st["state.in_use"] == 0
     assert st["state.peak"] >= 1
-    assert st["state.rows_gathered"] >= 1 and st["state.row_bytes"] > 0
+    # a lone row seeds its batch: no record is moved for it
+    assert st["state.rows_gathered"] == 0 and st["state.row_bytes"] > 0
+    assert st["state_calls_resident"] == st["segments"] >= 1

@@ -64,7 +64,7 @@ def engines():
 
 
 def _scheduler(eng, pooled, **kw):
-    pool = (KVBlockPool.for_engine(eng, 96, block_size=16, state_slots=4)
+    pool = (KVBlockPool.for_engine(eng, 96, block_size=16, state_slots=1)
             if pooled else None)
     return IterBatchingEngine(eng, max_batch=4, pool=pool, **kw)
 
@@ -326,7 +326,7 @@ def test_the_pool_mirrors_the_resident_cache(engines, family):
     columns' blocks and the trash block, nothing else."""
     eng = engines(family)
     pool = KVBlockPool.for_engine(eng, MAX_SEQ // 16, block_size=16,
-                                  state_slots=4, watermark=1.0)
+                                  state_slots=1, watermark=1.0)
     pool.data = jnp.full_like(pool.data, POISON)
     it = IterBatchingEngine(eng, max_batch=4, pool=pool, max_wait_ms=300.0)
     bad, wrote = _watch(it)
@@ -374,6 +374,15 @@ def test_the_pool_mirrors_the_resident_cache(engines, family):
     assert st["cache_gathers"] == st["grows"]
     assert st["blocks_written_back"] == sum(wrote)
     assert pool.allocator.stats().blocks_in_use == 0
+    if pool.slab is not None:
+        # the rows' state rode in the working cache through all of it:
+        # no call moved a record, a joiner's went into its lane (the
+        # resumes that joined a live batch too), none is held now
+        assert st["state_calls_resident"] == st["segments"]
+        assert st["state.rows_gathered"] == 0 == st["state.in_use"]
+        assert 1 <= st["state.rows_scattered"] <= (
+            st["joins"] + st["resumes"])
+        assert st["state.slots"] == 1 + 4 and 2 <= st["state.peak"] <= 4
     # ONE write-back program a width (1, 2 and 4), whatever the depth
     assert pool._scatter_span._cache_size() <= 3
     # a grow's gather at widths 2 and 4, and this test's own reads
@@ -392,7 +401,7 @@ def _span_case(family, engines):
                         length=jnp.zeros((), jnp.int32))
     else:
         eng = engines(family)
-        pool = KVBlockPool.for_engine(eng, 40, block_size=16, state_slots=4)
+        pool = KVBlockPool.for_engine(eng, 40, block_size=16, state_slots=1)
         cache = jax.tree.map(
             lambda x: (jnp.asarray(rs.randn(*x.shape), x.dtype)
                        if x.ndim > 1 else x), _prefilled(eng)[1])

@@ -192,7 +192,7 @@ def test_state_lane_counters_after_two_rows_retire():
     engine = DecodeEngine(
         hybrid_ssm.init_params(cfg, jax.random.PRNGKey(0)), cfg, max_seq=128)
     pool = KVBlockPool.for_engine(engine, num_blocks=40, block_size=8,
-                                  state_slots=4)
+                                  state_slots=1)
     ib = IterBatchingEngine(engine, max_batch=4, seg_steps=8,
                             max_wait_ms=300.0, pool=pool)
     marks, inner = [], ib._advance

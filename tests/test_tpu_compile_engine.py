@@ -573,6 +573,75 @@ def test_hybrid_ssm_prefill_holds_one_positions_logits(prefills):
     assert 768 * 261120 * 4 > SSM_PREFILL_TEMPORARIES * 1e9
 
 
+# A live row's record is a lane of the batch's working cache (ISSUE
+# 48): what still MOVES a record is a joiner's merge into its lane, a
+# grow's widening of the state leaves, and the store's two movers at
+# ONE record. At this family's leaves ([6, B, 32, 256, 128] float32 and
+# [6, B, 3, 5120] bfloat16, 25.35 MB a row) the mover that went, a
+# ``jnp.take`` of 2-16 slots out of a 25-slot slab, held 0.63-0.92 GB
+# beside its arguments: the compiler copied the whole slab in two halves
+# of the 256-wide axis first. The compiler's report here for what stays:
+# no temporaries in any of the four (the merge updates the cache's
+# planes and state in place at this family's, qwen3-next's and
+# kimi-linear's leaves alike).
+ROW_STATE_TEMPORARIES = 0.05
+
+
+def _row_state_bytes(tree):
+    return sum(x.size * x.dtype.itemsize for x in tree)
+
+
+def test_a_joiner_merges_into_its_lane_in_place(one_chip, built):
+    from llm_sharding_demo_tpu.runtime.iterbatch import _admit_cache_impl
+    eng, _ = built(SSM, None)
+    cache = one_chip.placed(jax.eval_shape(lambda: eng._fresh_cache(16)))
+    solo = one_chip.placed(jax.eval_shape(lambda: eng._fresh_cache(1)))
+    assert [x.shape[1:] for x in cache.state] == [(16, 32, 256, 128),
+                                                  (16, 3, 5120)]
+    mem = jax.jit(_admit_cache_impl, donate_argnums=(0,)).lower(
+        cache, solo, one_chip.shape((), jnp.int32),
+        one_chip.shape((), jnp.int32)).compile().memory_analysis()
+    assert mem.alias_size_in_bytes >= _row_state_bytes(cache.state)
+    assert mem.temp_size_in_bytes < ROW_STATE_TEMPORARIES * 1e9, (
+        f"{mem.temp_size_in_bytes / 1e9:.3f} GB of temporaries")
+
+
+def test_a_grow_holds_the_two_widths_of_the_row_state_and_no_more(
+        one_chip, built):
+    from llm_sharding_demo_tpu.runtime.iterbatch import _widen_state
+    eng, _ = built(SSM, None)
+    narrow = one_chip.placed(
+        jax.eval_shape(lambda: eng._fresh_cache(8)).state)
+    mem = jax.jit(lambda state: _widen_state(state, 8)).lower(
+        narrow).compile().memory_analysis()
+    # the wider leaves (and the tuple that names them) are all it makes
+    assert 0 <= mem.output_size_in_bytes - 2 * _row_state_bytes(narrow) < 2**12
+    assert mem.temp_size_in_bytes < ROW_STATE_TEMPORARIES * 1e9, (
+        f"{mem.temp_size_in_bytes / 1e9:.3f} GB of temporaries")
+
+
+def test_the_slabs_movers_carry_one_record(one_chip, built):
+    """A restore's copy out of the store's eight slots and a snapshot's
+    write into one: a slice and an in-place update, the slot an operand."""
+    from llm_sharding_demo_tpu.models import row_state
+    from llm_sharding_demo_tpu.runtime import state_slab
+    eng, _ = built(SSM, None)
+    slots = int(Spec().config(SSM)["serving_env"]["PREFIX_CACHE"])
+    data = tuple(one_chip.shape(s[:1] + (slots,) + s[1:], t)
+                 for s, t in row_state(eng.config, eng.dtype))
+    row = tuple(one_chip.shape(x.shape[:1] + (1,) + x.shape[2:], x.dtype)
+                for x in data)
+    slot = one_chip.shape((), jnp.int32)
+    gather = jax.jit(state_slab._gather_state_impl).lower(
+        data, slot).compile().memory_analysis()
+    assert gather.temp_size_in_bytes < 2**20, gather.temp_size_in_bytes
+    scatter = jax.jit(state_slab._scatter_state_impl,
+                      donate_argnums=(0,)).lower(
+        data, row, slot).compile().memory_analysis()
+    assert scatter.alias_size_in_bytes >= _row_state_bytes(data)
+    assert scatter.temp_size_in_bytes < 2**20, scatter.temp_size_in_bytes
+
+
 # -- the per-channel delta-rule / latent family (ISSUE 46) ---------------------
 #
 # Its depth comes from two published lists and cannot be cut: the whole

@@ -377,7 +377,7 @@ def test_rows_that_join_and_retire_serve_their_solo_streams(wide, kernel,
     pool = prefix = None
     if pooled:
         pool = KVBlockPool.for_engine(eng, 96, block_size=16,
-                                      state_slots=4 + 3)
+                                      state_slots=3)
         prefix = PrefixCachingEngine(eng, capacity=3, chunk=64, pool=pool)
     it = IterBatchingEngine(eng, max_batch=4, seg_steps=8, prefix=prefix,
                             pool=pool)
@@ -446,8 +446,15 @@ def test_rows_that_join_and_retire_serve_their_solo_streams(wide, kernel,
         assert sum(s.labels["state_snapshots"] for s in pre) == 1
         assert prefix.stats()["hits"] >= 1
         assert st["state.slots"] == 7 and st["state.restores"] >= 1
+        # the batch has ended: what is held is the store's snapshot
         assert st["state.in_use"] == st["state.snapshots"] == 1
         assert 4 <= st["state.peak"] <= 7
+        # no call moved a record: a restore out of its slot, a snapshot
+        # into its slot and a joiner's record into its lane are all
+        assert st["state_calls_resident"] == st["segments"]
+        assert st["state.rows_gathered"] == st["state.restores"]
+        assert st["state.rows_scattered"] == 1 + st["joins"]
+        assert pool.slab.slots == 3 and pool.slab.stats()["state.peak"] <= 2
         assert pool.allocator.stats().blocks_in_use == \
             pool.allocator.stats().blocks_evictable
     else:
