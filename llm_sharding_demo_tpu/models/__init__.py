@@ -2,40 +2,48 @@
 
 Every family module exposes the same pure-function surface —
 ``init_params`` / ``forward`` / ``forward_with_cache`` / ``make_cache``
-over a stacked-block param pytree — so the runtime (decode engine,
-speculative decoding, serving, quantization, checkpointing) dispatches on
-the config object alone via ``family_module``.
+over a stacked-block param pytree — and states what else the rest of
+the program may ask of it in ONE ``FAMILY = family.Family(...)``: its
+cache's shape, what the engine and the scheduler need to know, the
+serving options it refuses. ``REGISTRY`` finds that declaration from a
+config object's type, so the runtime (decode engine, speculative
+decoding, serving, quantization, checkpointing) dispatches on the config
+alone. Adding a family is its module and one entry of ``FAMILIES``.
 """
 
 from __future__ import annotations
 
+from . import (gdn_moe, gpt2, hybrid_ssm, kda_moe, latent_moe, llama, moe,
+               window_moe)
+from .family import Family
+
+FAMILIES = (gpt2.FAMILY, moe.FAMILY, llama.FAMILY, latent_moe.FAMILY,
+            gdn_moe.FAMILY, window_moe.FAMILY, hybrid_ssm.FAMILY,
+            kda_moe.FAMILY)
+REGISTRY = {f.config_class: f for f in FAMILIES}
+
+
+def family_of(config) -> Family:
+    """Config dataclass -> its family's declaration: the most derived
+    registered class of the config's type (``MoEConfig`` subclasses
+    ``GPT2Config`` and is found first)."""
+    for cls in type(config).__mro__:
+        if cls in REGISTRY:
+            return REGISTRY[cls]
+    raise TypeError(f"unknown model config type {type(config).__name__}")
+
+
+def family_named(name: str) -> Family:
+    """The checkpoint tag (``Family.name``) -> the declaration."""
+    for family in REGISTRY.values():
+        if family.name == name:
+            return family
+    raise ValueError(f"unknown checkpoint model family {name!r}")
+
 
 def family_module(config):
-    """Config dataclass -> the model module implementing it.
-
-    MoEConfig subclasses GPT2Config, so it is tested first; LlamaConfig is
-    standalone. Plain GPT2Config is the only family the dense pipeline
-    partitioner (parallel.partition) can stage.
-    """
-    from . import (gdn_moe, gpt2, hybrid_ssm, kda_moe, latent_moe, llama,
-                   moe, window_moe)
-    if isinstance(config, moe.MoEConfig):
-        return moe
-    if isinstance(config, hybrid_ssm.HybridSSMConfig):
-        return hybrid_ssm
-    if isinstance(config, window_moe.WindowMoEConfig):
-        return window_moe
-    if isinstance(config, gdn_moe.GDNMoEConfig):
-        return gdn_moe
-    if isinstance(config, kda_moe.KDAMoEConfig):
-        return kda_moe
-    if isinstance(config, latent_moe.LatentMoEConfig):
-        return latent_moe
-    if isinstance(config, llama.LlamaConfig):
-        return llama
-    if isinstance(config, gpt2.GPT2Config):
-        return gpt2
-    raise TypeError(f"unknown model config type {type(config).__name__}")
+    """Config dataclass -> the model module implementing it."""
+    return family_of(config).module
 
 
 def cache_entry(config) -> tuple:
@@ -46,10 +54,7 @@ def cache_entry(config) -> tuple:
     something else (``latent_moe`` and ``kda_moe``: one plane of one
     latent vector) says so in its own ``cache_entry``. The paged pool, its movers, the
     prefix store and the byte accounting size themselves from this."""
-    declared = getattr(family_module(config), "cache_entry", None)
-    if declared is not None:
-        return declared(config)
-    return (2, getattr(config, "n_kv_head", config.n_head), config.head_dim)
+    return family_of(config).cache_entry(config)
 
 
 def cache_layers(config) -> int:
@@ -60,8 +65,7 @@ def cache_layers(config) -> int:
     list names; their other layers hold ``row_state``;
     ``hybrid_ssm`` says all of them, and holds ``row_state`` in all of
     them too)."""
-    declared = getattr(family_module(config), "cache_layers", None)
-    return config.n_layer if declared is None else declared(config)
+    return family_of(config).cache_layers(config)
 
 
 def row_state(config, dtype) -> tuple:
@@ -74,8 +78,7 @@ def row_state(config, dtype) -> tuple:
     positions; ``hybrid_ssm``: every layer's state-space matrices and
     convolution tails, beside every layer's positions). The state slab
     (``runtime.state_slab.StateSlab``) sizes itself from this."""
-    declared = getattr(family_module(config), "row_state", None)
-    return () if declared is None else declared(config, dtype)
+    return family_of(config).row_state(config, dtype)
 
 
 def is_partitionable(config) -> bool:
@@ -83,17 +86,14 @@ def is_partitionable(config) -> bool:
     to ``config`` (/forward + /forward_b compat endpoints, remote
     dispatch, shard-pod partial restore) — the wire-parity surface stays
     GPT-2-only by design."""
-    from . import gpt2, moe
-    return (isinstance(config, gpt2.GPT2Config)
-            and not isinstance(config, moe.MoEConfig))
+    return family_of(config).wire_topology
 
 
 def is_stage_partitionable(config) -> bool:
     """True when ``parallel.partition`` can stage this family's tree —
     THE single staging predicate (engine and serving both consult it).
     Dense GPT-2 and llama stage; MoE's expert tree decodes unstaged."""
-    from . import llama
-    return is_partitionable(config) or isinstance(config, llama.LlamaConfig)
+    return family_of(config).stageable
 
 
 def is_window_independent(config) -> bool:
@@ -106,5 +106,4 @@ def is_window_independent(config) -> bool:
     families are independent (``hybrid_ssm`` is one), and so are
     ``latent_moe``, ``gdn_moe``, ``kda_moe`` and ``window_moe``, whose
     routing has no capacity and drops no token."""
-    from . import moe
-    return not isinstance(config, moe.MoEConfig)
+    return family_of(config).window_independent

@@ -53,7 +53,7 @@ with the family surface every runtime module dispatches on
   counters. A decode step on a TPU
   goes through ``ops.decode_attention``, anything else through the
   masked einsum over the same buffer; both bound or mask their reads by
-  the live depth (``BOUNDS_OWN_READS``: the engine cuts no windows).
+  the live depth (``bounds_own_reads``: the engine cuts no windows).
 - **Experts** (``ops.expert_ffn``): ``softmax`` over ALL
   ``n_routed_total`` in float32, the ``n_experts_per_tok`` largest,
   normalised over the chosen; the layer computes the terms of the
@@ -86,16 +86,14 @@ from ..ops import expert_ffn, gated_delta
 from ..ops.attention import (KVCache, cached_attention_fused,
                              causal_attention, merge_heads, split_heads)
 from ..ops.layers import linear, rms_norm_offset
-from ..ops.rope import apply_rope_leading, rope_angles
-from .latent_moe import CACHE_COUNTERS, _count, span_labels  # noqa: F401
-from .llama import _embed, pre_norm_block, swiglu
+from ..ops.rope import apply_rope_leading
+from . import stack
+from .family import Family
+from .latent_moe import CACHE_COUNTERS, INT8_REFUSED, _count, span_labels
+from .llama import pre_norm_block, swiglu
 
 Params = Dict[str, Any]
 
-# what the engine asks a family beside its cache entry (see
-# ``models.latent_moe`` for the vocabulary)
-BOUNDS_OWN_READS = True      # kernel and masked einsum bound their reads
-INT8_WEIGHTS = False         # the grouped matmul indexes plain stacks
 CONV_TAIL = 3                # carried inputs of a width-4 convolution
 
 
@@ -507,30 +505,13 @@ def apply_blocks(params: Params, h: jnp.ndarray, config: GDNMoEConfig,
     return h, KVCache(kv, counters, new_len, state)
 
 
-def _angles(config: GDNMoEConfig, seq_len: int, offset,
-            pad: Optional[jnp.ndarray]):
-    pos = offset + jnp.arange(seq_len)
-    if pad is not None:
-        pos = jnp.maximum(pos[None, :] - pad[:, None], 0)
-    return rope_angles(pos, config.rotary_dim, config.rope_theta)
-
-
-def _final(params: Params, h: jnp.ndarray, config: GDNMoEConfig):
-    h = rms_norm_offset(h, params["ln_f"]["scale"], config.rms_norm_eps)
-    return jnp.einsum("bsd,dv->bsv", h, params["lm_head"]["kernel"],
-                      preferred_element_type=jnp.float32)
-
-
 def forward(params: Params, input_ids: jnp.ndarray, config: GDNMoEConfig,
             remat: bool = False, mesh=None) -> jnp.ndarray:
     """Full no-cache forward: [B, S] -> [B, S, vocab] float32 logits
     (the chunked rule from a zero state; ``remat``/``mesh`` accepted for
     the family surface and unused: nothing trains or shards this family
     yet)."""
-    h = _embed(params, input_ids)
-    cos, sin = _angles(config, input_ids.shape[1], 0, None)
-    h, _ = apply_blocks(params, h, config, cos, sin)
-    return _final(params, h, config)
+    return stack.forward(FAMILY, params, input_ids, config)
 
 
 def forward_with_cache(params: Params, input_ids: jnp.ndarray,
@@ -540,41 +521,51 @@ def forward_with_cache(params: Params, input_ids: jnp.ndarray,
                        decode_kernel: Optional[str] = None,
                        ) -> Tuple[jnp.ndarray, KVCache]:
     """Cached forward at ``cache.length``: a single position through
-    the recurrence and the decode kernels where the engine resolved them
-    (``decode_kernel``: ``"device"`` or ``"interpret"``), several
-    through the chunked rule and the masked einsum. ``flash_prefill`` is
-    accepted for the family surface and unused."""
-    del flash_prefill
-    if decode_kernel not in (None, "device", "interpret"):
-        raise ValueError(f"decode_kernel={decode_kernel!r}: this family "
-                         "has the per-layer kernels only")
-    if cache.state is None:
-        raise ValueError("this family's cache carries the rows' state "
-                         "(KVCache.state); it was dropped on the way here")
-    h = _embed(params, input_ids)
-    cos, sin = _angles(config, input_ids.shape[1], cache.length, pad)
-    h, cache = apply_blocks(params, h, config, cos, sin, cache, pad,
-                            decode_kernel=decode_kernel)
-    return _final(params, h, config), cache
-
-
-def make_state(config: GDNMoEConfig, batch: int, dtype) -> tuple:
-    """Zeroed ``KVCache.state`` for ``batch`` rows: ``row_state``'s
-    leaves with the batch on axis 1."""
-    return tuple(jnp.zeros(shape[:1] + (batch,) + shape[1:], dt)
-                 for shape, dt in row_state(config, dtype))
+    the recurrence and the decode kernels where the engine resolved
+    them, several through the chunked rule and the masked einsum.
+    ``flash_prefill`` is accepted for the family surface and unused."""
+    return stack.forward_with_cache(FAMILY, params, input_ids, config, cache,
+                                    pad, flash_prefill, decode_kernel)
 
 
 def make_cache(config: GDNMoEConfig, batch: int, max_seq: int,
                dtype=jnp.float32) -> KVCache:
     """The softmax layers' fused ``[P, B, Hkv, max_seq, 2 hd]`` rows,
     the zeroed counters, and the rows' zeroed state."""
-    if max_seq > config.n_positions:
-        raise ValueError(
-            f"max_seq={max_seq} exceeds n_positions={config.n_positions}")
-    return KVCache(
-        k=jnp.zeros((config.n_periods, batch, config.n_kv_head, max_seq,
-                     2 * config.head_dim), dtype),
-        v=jnp.zeros((len(CACHE_COUNTERS),), jnp.int32),
-        length=jnp.zeros((), jnp.int32),
-        state=make_state(config, batch, dtype))
+    return stack.make_cache(FAMILY, config, batch, max_seq, dtype)
+
+
+# What the linear-attention / sparse-expert families refuse, one
+# sentence each (``models.kda_moe`` says the same): they serve through
+# the single-device engine (solo, the iteration scheduler, the paged
+# pool with its state slab, the prefix store) in float32 or bfloat16.
+REFUSES = (
+    ("spec_decode",
+     "SPEC_DECODE: a rejected draft cannot be rewound out of "
+     "{name}'s per-row state (it has no position axis) without "
+     "a snapshot a verify; serve it without speculation"),
+    ("kv_pool_dtype",
+     "KV_POOL_DTYPE={value}: {name}'s pool is one "
+     "plane with counters in its second leaf and its rows' state "
+     "is float32 by contract; the quantized movers have not been "
+     "fitted to it"),
+    ("kv_host_blocks",
+     "KV_HOST_BLOCKS: a demoted entry of {name} would need its "
+     "state snapshot demoted with its blocks; the host tier "
+     "moves blocks only"),
+    ("multi_chip",
+     "PP/TP/EP_DECODE: no multi-chip decoder stages or shards "
+     "{name} (runs of unlike layers, a state slab beside "
+     "the pool, experts indexed in place); it serves on one "
+     "chip, told which experts it holds"),
+    ("int8_weights", INT8_REFUSED))
+
+FAMILY = Family(
+    name="gdn_moe", config_class=GDNMoEConfig,
+    frame=stack.Frame(apply_blocks, rotary_width=lambda c: c.rotary_dim,
+                      norm=rms_norm_offset),
+    cache_entry=cache_entry, cache_layers=cache_layers, row_state=row_state,
+    cache_counters=CACHE_COUNTERS, span_labels=span_labels,
+    bounds_own_reads=True,       # kernel and masked einsum bound their reads
+    decode_kernel_eligible=decode_kernel_eligible,
+    refuses=REFUSES)

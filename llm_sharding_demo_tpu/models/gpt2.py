@@ -33,6 +33,7 @@ from ..ops.attention import (KVCache, cached_attention_inplace,
                              causal_attention, merge_heads, split_heads,
                              write_kv_layer)
 from ..ops.layers import gelu_new, layer_norm, linear
+from .family import Family
 
 Params = Dict[str, Any]
 
@@ -440,3 +441,7 @@ def make_cache(config: GPT2Config, batch: int, max_seq: int,
             "decode past the position table would silently clamp")
     return KVCache.create(config.n_layer, batch, config.n_head, max_seq,
                           config.head_dim, dtype)
+
+
+FAMILY = Family(name="gpt2", config_class=GPT2Config, wire_topology=True,
+                stageable=True)

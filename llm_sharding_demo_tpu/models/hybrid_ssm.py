@@ -35,7 +35,7 @@ the family surface every runtime module dispatches on (``init_params`` /
   (``cache_entry``). A decode step on a TPU goes through
   ``ops.decode_attention``, anything else through the masked einsum over
   the same buffer; both bound or mask their reads by the live depth
-  (``BOUNDS_OWN_READS``).
+  (``bounds_own_reads``).
 - **The multipliers are applied at run time**, in float32 where the
   value is float32 anyway (the convolution's taps carry their ranges',
   the gate ``z``, ``dt``, the residual sums, the SwiGLU gate) and on the
@@ -66,14 +66,12 @@ from ..ops import gated_delta, ssd
 from ..ops.attention import (KVCache, cached_attention_fused,
                              causal_attention, merge_heads, split_heads)
 from ..ops.layers import linear, rms_norm
-from ..ops.rope import apply_rope, rope_angles
-from .llama import _embed
+from ..ops.rope import apply_rope
+from . import stack
+from .family import Family
 
 Params = Dict[str, Any]
 
-# what the engine asks a family beside its cache entry (see
-# ``models.latent_moe`` for the vocabulary)
-BOUNDS_OWN_READS = True      # kernel and masked einsum bound their reads
 CONV_TAIL = 3                # carried inputs of a width-4 convolution
 
 
@@ -192,11 +190,6 @@ def cache_entry(config: HybridSSMConfig) -> Tuple[int, int, int]:
     and joins keys and values in every mover, cache-sized temporaries
     this chip, full of weights and state, does not have."""
     return (1, config.n_kv_head, 2 * config.head_dim)
-
-
-def cache_layers(config: HybridSSMConfig) -> int:
-    """How many layers cache positions: every one."""
-    return config.n_layer
 
 
 def row_state(config: HybridSSMConfig, dtype) -> Tuple[tuple, ...]:
@@ -425,42 +418,13 @@ def apply_blocks(params: Params, h: jnp.ndarray, config: HybridSSMConfig,
     return h, KVCache(kv, cache.v, new_len, state)
 
 
-def _angles(config: HybridSSMConfig, seq_len: int, offset,
-            pad: Optional[jnp.ndarray]):
-    pos = offset + jnp.arange(seq_len)
-    if pad is not None:
-        pos = jnp.maximum(pos[None, :] - pad[:, None], 0)
-    return rope_angles(pos, config.head_dim, config.rope_theta)
-
-
-def _start(params: Params, input_ids: jnp.ndarray, config: HybridSSMConfig):
-    h = _embed(params, input_ids)
-    return (h.astype(jnp.float32) * config.embedding_multiplier
-            ).astype(h.dtype)
-
-
-def _final(params: Params, h: jnp.ndarray, config: HybridSSMConfig):
-    h = rms_norm(h, params["ln_f"]["scale"], config.rms_norm_eps)
-    from ..ops.quant import is_quantized
-    kernel = params["lm_head"]["kernel"]
-    if is_quantized(kernel):
-        logits = linear(h, kernel).astype(jnp.float32)
-    else:
-        logits = jnp.einsum("bsd,dv->bsv", h, kernel,
-                            preferred_element_type=jnp.float32)
-    return logits * config.lm_head_multiplier
-
-
 def forward(params: Params, input_ids: jnp.ndarray, config: HybridSSMConfig,
             remat: bool = False, mesh=None) -> jnp.ndarray:
     """Full no-cache forward: [B, S] -> [B, S, vocab] float32 logits
     (the chunked rule from a zero state; ``remat``/``mesh`` accepted for
     the family surface and unused: nothing trains or shards this family
     yet)."""
-    h = _start(params, input_ids, config)
-    cos, sin = _angles(config, input_ids.shape[1], 0, None)
-    h, _ = apply_blocks(params, h, config, cos, sin)
-    return _final(params, h, config)
+    return stack.forward(FAMILY, params, input_ids, config)
 
 
 def forward_with_cache(params: Params, input_ids: jnp.ndarray,
@@ -470,43 +434,51 @@ def forward_with_cache(params: Params, input_ids: jnp.ndarray,
                        decode_kernel: Optional[str] = None,
                        ) -> Tuple[jnp.ndarray, KVCache]:
     """Cached forward at ``cache.length``: a single position through
-    the recurrence and the decode kernels where the engine resolved them
-    (``decode_kernel``: ``"device"`` or ``"interpret"``), several
-    through the chunked rule and the masked einsum. Returns the LAST
-    position's logits ``[B, 1, vocab]`` whatever the call's length
-    (module docstring). ``flash_prefill`` is accepted for the family
-    surface and unused."""
-    del flash_prefill
-    if decode_kernel not in (None, "device", "interpret"):
-        raise ValueError(f"decode_kernel={decode_kernel!r}: this family "
-                         "has the per-layer kernels only")
-    if cache.state is None:
-        raise ValueError("this family's cache carries the rows' state "
-                         "(KVCache.state); it was dropped on the way here")
-    h = _start(params, input_ids, config)
-    cos, sin = _angles(config, input_ids.shape[1], cache.length, pad)
-    h, cache = apply_blocks(params, h, config, cos, sin, cache, pad,
-                            decode_kernel=decode_kernel)
-    return _final(params, h[:, -1:], config), cache
-
-
-def make_state(config: HybridSSMConfig, batch: int, dtype) -> tuple:
-    """Zeroed ``KVCache.state`` for ``batch`` rows: ``row_state``'s
-    leaves with the batch on axis 1."""
-    return tuple(jnp.zeros(shape[:1] + (batch,) + shape[1:], dt)
-                 for shape, dt in row_state(config, dtype))
+    the recurrence and the decode kernels where the engine resolved
+    them, several through the chunked rule and the masked einsum.
+    Returns the LAST position's logits ``[B, 1, vocab]`` whatever the
+    call's length (module docstring). ``flash_prefill`` is accepted for
+    the family surface and unused."""
+    return stack.forward_with_cache(FAMILY, params, input_ids, config, cache,
+                                    pad, flash_prefill, decode_kernel)
 
 
 def make_cache(config: HybridSSMConfig, batch: int, max_seq: int,
                dtype=jnp.float32) -> KVCache:
     """Every layer's fused ``[L, B, Hkv, max_seq, 2 hd]`` rows, the
     fused layout's empty second leaf, and the rows' zeroed state."""
-    if max_seq > config.n_positions:
-        raise ValueError(
-            f"max_seq={max_seq} exceeds n_positions={config.n_positions}")
-    return KVCache(
-        k=jnp.zeros((config.n_layer, batch, config.n_kv_head, max_seq,
-                     2 * config.head_dim), dtype),
-        v=jnp.zeros((0,), dtype),
-        length=jnp.zeros((), jnp.int32),
-        state=make_state(config, batch, dtype))
+    return stack.make_cache(FAMILY, config, batch, max_seq, dtype)
+
+
+# It serves through the single-device engine (solo, the iteration
+# scheduler, the paged pool of every layer's positions with every
+# layer's row state in the state slab, the prefix store) in float32,
+# bfloat16 or with int8 weights; what it refuses, one sentence each.
+FAMILY = Family(
+    name="hybrid_ssm", config_class=HybridSSMConfig,
+    frame=stack.Frame(
+        apply_blocks, rotary_width=lambda c: c.head_dim,
+        embedding_multiplier=lambda c: c.embedding_multiplier,
+        logit_multiplier=lambda c: c.lm_head_multiplier,
+        last_position_logits=True),
+    cache_entry=cache_entry, row_state=row_state,
+    bounds_own_reads=True,       # kernel and masked einsum bound their reads
+    decode_kernel_eligible=decode_kernel_eligible,
+    refuses=(
+        ("spec_decode",
+         "SPEC_DECODE: a rejected draft cannot be rewound out of "
+         "{name}'s per-row state (it has no position axis) without "
+         "a snapshot a verify; serve it without speculation"),
+        ("kv_pool_dtype",
+         "KV_POOL_DTYPE={value}: {name}'s pool holds "
+         "fused [K | V] rows in one plane and its rows' state is "
+         "float32 by contract; the quantized movers have not been "
+         "fitted to either"),
+        ("kv_host_blocks",
+         "KV_HOST_BLOCKS: a demoted entry of {name} would need its "
+         "state snapshot demoted with its blocks; the host tier "
+         "moves blocks only"),
+        ("multi_chip",
+         "PP/TP/EP_DECODE: no multi-chip decoder stages or shards "
+         "{name} (a state slab beside the pool in every layer, two "
+         "state-space groups to divide); it serves on one chip")))

@@ -51,7 +51,7 @@ repo has, each changed, in ONE stack:
   positions (``cache_layers``): one plane, one "head", ``[c_kv | k_r]``
   in a lane-aligned row (``cache_entry``), as that family's pool holds
   it; a prefill into a fresh cache attends in the expanded form
-  (``FRESH_PREFILL_FLAG``), everything else in the absorbed one, a
+  (``fresh_prefill_flag``), everything else in the absorbed one, a
   decode step on a TPU through ``ops.latent_decode``'s kernel.
 - **Experts**: ``models.latent_moe.expert_layer`` (sigmoid scores over
   ALL ``n_routed_total``, a selection bias, renormalised and scaled;
@@ -76,17 +76,14 @@ import jax.numpy as jnp
 from ..ops import gated_delta, kda
 from ..ops.attention import KVCache
 from ..ops.layers import linear
-from .latent_moe import (CACHE_COUNTERS, _attention, _count,  # noqa: F401
-                         expert_layer, span_labels)
-from .llama import _embed, _final, pre_norm_block, swiglu
+from . import gdn_moe, stack
+from .family import Family
+from .latent_moe import (CACHE_COUNTERS, _attention, _count, expert_layer,
+                         span_labels)
+from .llama import pre_norm_block, swiglu
 
 Params = Dict[str, Any]
 
-# what the engine asks a family beside its cache entry (see
-# ``models.latent_moe`` for the vocabulary)
-BOUNDS_OWN_READS = True      # absorbed attention bounds its reads by depth
-FRESH_PREFILL_FLAG = True    # wants to know a prefill's cache is fresh
-INT8_WEIGHTS = False         # the grouped matmul indexes plain stacks
 CONV_TAIL = 3                # carried inputs of a width-4 convolution
 
 KDA, MLA = "kda", "mla"
@@ -568,8 +565,7 @@ def forward(params: Params, input_ids: jnp.ndarray, config: KDAMoEConfig,
     (the chunked rule from a zero state, expanded attention;
     ``remat``/``mesh`` accepted for the family surface and unused:
     nothing trains or shards this family yet)."""
-    h, _ = apply_blocks(params, _embed(params, input_ids), config)
-    return _final(params, h, config)
+    return stack.forward(FAMILY, params, input_ids, config)
 
 
 def forward_with_cache(params: Params, input_ids: jnp.ndarray,
@@ -578,37 +574,32 @@ def forward_with_cache(params: Params, input_ids: jnp.ndarray,
                        flash_prefill: bool = False,
                        decode_kernel: Optional[str] = None,
                        ) -> Tuple[jnp.ndarray, KVCache]:
-    """Cached forward at ``cache.length``. ``flash_prefill`` is the
-    engine's static word that the cache is fresh: the latent layers then
-    attend in the expanded form over this call's tokens alone;
-    everything else reads the cache in the absorbed form. A single
-    position goes through the recurrence and the two decode kernels
-    where the engine resolved them (``decode_kernel``: ``"device"`` or
-    ``"interpret"``), several through the chunked rule."""
-    if decode_kernel not in (None, "device", "interpret"):
-        raise ValueError(f"decode_kernel={decode_kernel!r}: this family "
-                         "has the per-layer kernels only")
-    if cache.state is None:
-        raise ValueError("this family's cache carries the rows' state "
-                         "(KVCache.state); it was dropped on the way here")
-    h, cache = apply_blocks(params, _embed(params, input_ids), config,
-                            cache, pad, fresh=flash_prefill,
-                            decode_kernel=decode_kernel)
-    return _final(params, h, config), cache
+    """Cached forward at ``cache.length``. With ``flash_prefill`` (the
+    cache is fresh) the latent layers attend in the expanded form over
+    this call's tokens alone; everything else reads the cache in the
+    absorbed form. A single position goes through the recurrence and
+    the two decode kernels where the engine resolved them, several
+    through the chunked rule."""
+    return stack.forward_with_cache(FAMILY, params, input_ids, config, cache,
+                                    pad, flash_prefill, decode_kernel)
 
 
 def make_cache(config: KDAMoEConfig, batch: int, max_seq: int,
                dtype=jnp.float32) -> KVCache:
     """The latent layers' ``[Lm, B, 1, max_seq, cache_lanes]`` rows, the
-    zeroed counters, and the rows' zeroed state (``row_state``'s leaves
-    with the batch on axis 1)."""
-    if max_seq > config.n_positions:
-        raise ValueError(
-            f"max_seq={max_seq} exceeds n_positions={config.n_positions}")
-    return KVCache(
-        k=jnp.zeros((config.n_mla, batch, 1, max_seq, config.cache_lanes),
-                    dtype),
-        v=jnp.zeros((len(CACHE_COUNTERS),), jnp.int32),
-        length=jnp.zeros((), jnp.int32),
-        state=tuple(jnp.zeros(shape[:1] + (batch,) + shape[1:], dt)
-                    for shape, dt in row_state(config, dtype)))
+    zeroed counters, and the rows' zeroed state."""
+    return stack.make_cache(FAMILY, config, batch, max_seq, dtype)
+
+
+FAMILY = Family(
+    name="kda_moe", config_class=KDAMoEConfig,
+    frame=stack.Frame(apply_blocks),    # nothing is turned by position
+    cache_entry=cache_entry, cache_layers=cache_layers, row_state=row_state,
+    cache_counters=CACHE_COUNTERS, span_labels=span_labels,
+    bounds_own_reads=True,       # absorbed attention bounds its reads by depth
+    fresh_prefill_flag=True,     # wants to know a prefill's cache is fresh
+    decode_kernel_eligible=decode_kernel_eligible,
+    prompt_bucket=prompt_bucket,
+    # per-row state without a position axis beside a one-plane pool, as
+    # ``models.gdn_moe`` has it
+    refuses=gdn_moe.REFUSES)

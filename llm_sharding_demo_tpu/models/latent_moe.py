@@ -17,7 +17,7 @@ JAX, with the family surface every runtime module dispatches on
   prefill into a fresh cache attends in the expanded form; anything
   that reads the cache (a decode step, a continuation chunk) in the
   absorbed form, whose reads are bounded by the live depth inside the
-  program (``BOUNDS_OWN_READS``: the engine cuts no windows for it); a
+  program (``bounds_own_reads``: the engine cuts no windows for it); a
   decode step on a TPU runs it as a Pallas kernel (``ops.
   latent_decode``) that streams the row's vectors block by block. The
   rotary parts are turned pair by pair where they lie (``ops.rope.
@@ -78,16 +78,16 @@ from ..ops import expert_ffn, latent_attention
 from ..ops.attention import KVCache
 from ..ops.layers import linear, rms_norm
 from ..ops.rope import pair_angles, rotate_pairs
-from .llama import _embed, _final, pre_norm_block, swiglu
+from . import stack
+from .family import Family
+from .llama import pre_norm_block, swiglu
 
 Params = Dict[str, Any]
 
-# what one position holds in one layer's cache is declared by
-# ``cache_entry``; these tell the engine what else differs from the
-# dense families
-BOUNDS_OWN_READS = True      # absorbed attention bounds its reads by depth
-FRESH_PREFILL_FLAG = True    # wants to know a prefill's cache is fresh
-INT8_WEIGHTS = False         # the grouped matmul indexes plain stacks
+# the grouped matmul (``ops.expert_ffn``) indexes plain stacks: what
+# every family built on it says to INFERENCE_DTYPE=int8
+INT8_REFUSED = ("INFERENCE_DTYPE=int8: {name} indexes its experts' plain "
+                "weight stacks; it serves float32 or bfloat16")
 
 # the cache's second leaf, in this order (all int32, summed over the
 # expert layers of every forward since the leaf was zeroed)
@@ -441,23 +441,12 @@ def apply_blocks(params: Params, h: jnp.ndarray, config: LatentMoEConfig,
     return h, KVCache(latent, counters, new_len)
 
 
-def _angles(config: LatentMoEConfig, seq_len: int, offset,
-            pad: Optional[jnp.ndarray]):
-    pos = offset + jnp.arange(seq_len)
-    if pad is not None:
-        pos = jnp.maximum(pos[None, :] - pad[:, None], 0)
-    return pair_angles(pos, config.qk_rope_head_dim, config.rope_theta)
-
-
 def forward(params: Params, input_ids: jnp.ndarray, config: LatentMoEConfig,
             remat: bool = False, mesh=None) -> jnp.ndarray:
     """Full no-cache forward: [B, S] -> [B, S, vocab] float32 logits
     (expanded attention; ``remat``/``mesh`` accepted for the family
     surface and unused: nothing trains or shards this family yet)."""
-    h = _embed(params, input_ids)
-    cos, sin = _angles(config, input_ids.shape[1], 0, None)
-    h, _ = apply_blocks(params, h, config, cos, sin)
-    return _final(params, h, config)
+    return stack.forward(FAMILY, params, input_ids, config)
 
 
 def forward_with_cache(params: Params, input_ids: jnp.ndarray,
@@ -466,22 +455,13 @@ def forward_with_cache(params: Params, input_ids: jnp.ndarray,
                        flash_prefill: bool = False,
                        decode_kernel: Optional[str] = None,
                        ) -> Tuple[jnp.ndarray, KVCache]:
-    """Cached forward at ``cache.length``. ``flash_prefill`` is the
-    engine's static word that the cache is fresh (offset 0; a left-pad
-    prefix is masked either way): the expanded form then attends over
-    this call's tokens alone.
-    Everything else attends over the cache in the absorbed form, a
-    single position through the Pallas kernel where the engine resolved
-    one (``decode_kernel``: ``"device"`` or ``"interpret"``)."""
-    if decode_kernel not in (None, "device", "interpret"):
-        raise ValueError(f"decode_kernel={decode_kernel!r}: this family "
-                         "has the per-layer kernel only")
-    h = _embed(params, input_ids)
-    cos, sin = _angles(config, input_ids.shape[1], cache.length, pad)
-    h, cache = apply_blocks(params, h, config, cos, sin, cache, pad,
-                            fresh=flash_prefill,
-                            decode_kernel=decode_kernel)
-    return _final(params, h, config), cache
+    """Cached forward at ``cache.length``. With ``flash_prefill`` (the
+    cache is fresh; a left-pad prefix is masked either way) the
+    expanded form attends over this call's tokens alone. Everything
+    else attends over the cache in the absorbed form, a single position
+    through the Pallas kernel where the engine resolved one."""
+    return stack.forward_with_cache(FAMILY, params, input_ids, config, cache,
+                                    pad, flash_prefill, decode_kernel)
 
 
 def make_cache(config: LatentMoEConfig, batch: int, max_seq: int,
@@ -489,11 +469,37 @@ def make_cache(config: LatentMoEConfig, batch: int, max_seq: int,
     """``[L, B, 1, max_seq, cache_lanes]`` latents (``kv_lora_rank +
     qk_rope_head_dim`` values a position, lane-aligned) and the zeroed
     counters."""
-    if max_seq > config.n_positions:
-        raise ValueError(
-            f"max_seq={max_seq} exceeds n_positions={config.n_positions}")
-    return KVCache(
-        k=jnp.zeros((config.n_layer, batch, 1, max_seq, config.cache_lanes),
-                    dtype),
-        v=jnp.zeros((len(CACHE_COUNTERS),), jnp.int32),
-        length=jnp.zeros((), jnp.int32))
+    return stack.make_cache(FAMILY, config, batch, max_seq, dtype)
+
+
+# It serves through the single-device engine (solo, either batcher, the
+# paged pool, the prefix store) in float32 or bfloat16; what it refuses,
+# one sentence each, instead of a wrong answer further down.
+FAMILY = Family(
+    name="latent_moe", config_class=LatentMoEConfig,
+    frame=stack.Frame(apply_blocks,
+                      rotary_width=lambda c: c.qk_rope_head_dim,
+                      angle_table=pair_angles),
+    cache_entry=cache_entry,
+    cache_counters=CACHE_COUNTERS, span_labels=span_labels,
+    bounds_own_reads=True,       # absorbed attention bounds its reads by depth
+    fresh_prefill_flag=True,     # wants to know a prefill's cache is fresh
+    decode_kernel_eligible=decode_kernel_eligible,
+    refuses=(
+        ("kv_pool_dtype",
+         "KV_POOL_DTYPE={value}: {name}'s pool holds one "
+         "latent vector a position; the quantized movers scale per "
+         "kv-head and have not been fitted to it"),
+        ("kv_host_blocks",
+         "KV_HOST_BLOCKS: the host tier has not been run over "
+         "{name}'s one-plane pool"),
+        ("spec_decode",
+         "SPEC_DECODE: the verify loop's rewind leaves {name}'s "
+         "routing counters and cached latents of rejected drafts "
+         "untested; serve it without speculation"),
+        ("multi_chip",
+         "PP/TP/EP_DECODE: no multi-chip decoder stages or shards "
+         "{name} (two stacks of unlike layers, experts indexed in "
+         "place); it serves on one chip, told which experts it "
+         "holds"),
+        ("int8_weights", INT8_REFUSED)))

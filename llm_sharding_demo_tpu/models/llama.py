@@ -6,8 +6,8 @@ surface as ``models.gpt2`` — ``init_params`` / ``forward`` /
 ``forward_with_cache`` / ``make_cache`` over stacked ``[n_layer, ...]``
 block leaves scanned by ``lax.scan`` — so the decode engine, speculative
 decoding, serving, quantization, and checkpointing all work via the
-family registry (``models.family_module``) without knowing the
-architecture. Differences from GPT-2 that matter here:
+family's declaration (``FAMILY`` below; ``models.family``) without
+knowing the architecture. Differences from GPT-2 that matter here:
 
 - **RoPE instead of a learned position table** (``ops.rope``): positions
   are computed, not gathered, so context length is bounded only by cache
@@ -37,7 +37,10 @@ from ..ops.attention import (KVCache, cached_attention_inplace,
                              causal_attention, merge_heads, split_heads,
                              write_kv_layer)
 from ..ops.layers import linear, rms_norm
-from ..ops.rope import apply_rope, rope_angles
+from ..ops.rope import apply_rope
+from . import stack
+from .family import Family
+from .stack import embed as _embed  # parallel/ and training/ call it so
 
 Params = Dict[str, Any]
 
@@ -280,34 +283,14 @@ def _block(block_params: Params, h: jnp.ndarray, config: LlamaConfig,
     return h, new_ck, new_cv
 
 
-def _embed(params: Params, input_ids: jnp.ndarray) -> jnp.ndarray:
-    wte = params["wte"]
-    from ..ops.quant import is_quantized
-    if is_quantized(wte):
-        from ..ops.quant import embed_rows
-        return embed_rows(wte, input_ids)
-    return wte[input_ids]
-
-
 def _angles(config: LlamaConfig, seq_len: int, offset,
             pad: Optional[jnp.ndarray]):
-    """(cos, sin) for positions ``offset + arange(S)`` (per-row shifted
-    down by ``pad`` for left-padded ragged batches; pad columns clip to
-    position 0 — masked as keys, never read as outputs)."""
-    pos = offset + jnp.arange(seq_len)
-    if pad is not None:
-        pos = jnp.maximum(pos[None, :] - pad[:, None], 0)   # [B, S]
-    return rope_angles(pos, config.head_dim, config.rope_theta)
+    return stack.angles(config.head_dim, config.rope_theta, seq_len, offset,
+                        pad)
 
 
 def _final(params: Params, h: jnp.ndarray, config: LlamaConfig) -> jnp.ndarray:
-    h = rms_norm(h, params["ln_f"]["scale"], config.rms_norm_eps)
-    from ..ops.quant import is_quantized
-    kernel = params["lm_head"]["kernel"]
-    if is_quantized(kernel):
-        return linear(h, kernel).astype(jnp.float32)
-    return jnp.einsum("bsd,dv->bsv", h, kernel,
-                      preferred_element_type=jnp.float32)
+    return stack.head(params, h, config.rms_norm_eps)
 
 
 def apply_blocks(blocks: Params, h: jnp.ndarray, config: LlamaConfig,
@@ -424,3 +407,12 @@ def make_cache(config: LlamaConfig, batch: int, max_seq: int,
             "(the configured serving/cache bound)")
     return KVCache.create(config.n_layer, batch, config.n_kv_head, max_seq,
                           config.head_dim, dtype)
+
+
+def _tp_pspecs(mesh):
+    from ..parallel import spmd
+    return spmd.llama_param_pspecs(mesh)
+
+
+FAMILY = Family(name="llama", config_class=LlamaConfig, stageable=True,
+                param_pspecs=_tp_pspecs)

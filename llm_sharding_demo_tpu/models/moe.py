@@ -30,6 +30,7 @@ import jax.numpy as jnp
 
 from ..ops.layers import gelu_new, linear
 from ..ops.attention import KVCache
+from .family import Family
 from .gpt2 import (GPT2Config, Params, _block as gpt2_block, embed,
                    final_logits)
 
@@ -447,3 +448,8 @@ def make_cache(config: MoEConfig, batch: int, max_seq: int,
             "decode past the position table would silently clamp")
     return KVCache.create(config.n_layer, batch, config.n_head, max_seq,
                          config.head_dim, dtype)
+
+
+# capacity-factor routing makes tokens compete for expert slots within a
+# window: the one window-DEPENDENT family
+FAMILY = Family(name="moe", config_class=MoEConfig, window_independent=False)

@@ -63,18 +63,14 @@ from ..ops import sliding_window
 from ..ops.attention import (KVCache, merge_heads, split_heads,
                              write_kv_layer_fused)
 from ..ops.layers import linear, rms_norm
-from ..ops.rope import apply_rope, rope_angles
-from .latent_moe import (CACHE_COUNTERS, _count, expert_layer,  # noqa: F401
+from ..ops.rope import apply_rope
+from . import stack
+from .family import Family
+from .latent_moe import (CACHE_COUNTERS, INT8_REFUSED, _count, expert_layer,
                          span_labels)
-from .llama import _embed, pre_norm_block, swiglu
+from .llama import pre_norm_block, swiglu
 
 Params = Dict[str, Any]
-
-# what the engine asks a family beside its cache entry (see
-# ``models.latent_moe`` for the vocabulary)
-BOUNDS_OWN_READS = True      # kernel, ring and masked einsum bound reads
-FRESH_PREFILL_FLAG = True    # a fresh prefill attends its own tokens only
-INT8_WEIGHTS = False         # the grouped matmul indexes plain stacks
 
 
 @dataclasses.dataclass(frozen=True)
@@ -401,29 +397,12 @@ def apply_blocks(params: Params, h: jnp.ndarray, config: WindowMoEConfig,
     return h, KVCache(kv, counters, new_len, (rings,))
 
 
-def _angles(config: WindowMoEConfig, seq_len: int, offset,
-            pad: Optional[jnp.ndarray]):
-    pos = offset + jnp.arange(seq_len)
-    if pad is not None:
-        pos = jnp.maximum(pos[None, :] - pad[:, None], 0)
-    return rope_angles(pos, config.head_dim, config.rope_theta)
-
-
-def _final(params: Params, h: jnp.ndarray, config: WindowMoEConfig):
-    h = rms_norm(h, params["ln_f"]["scale"], config.rms_norm_eps)
-    return jnp.einsum("bsd,dv->bsv", h, params["lm_head"]["kernel"],
-                      preferred_element_type=jnp.float32)
-
-
 def forward(params: Params, input_ids: jnp.ndarray, config: WindowMoEConfig,
             remat: bool = False, mesh=None) -> jnp.ndarray:
     """Full no-cache forward: [B, S] -> [B, S, vocab] float32 logits
     (``remat``/``mesh`` accepted for the family surface and unused:
     nothing trains or shards this family yet)."""
-    h = _embed(params, input_ids)
-    cos, sin = _angles(config, input_ids.shape[1], 0, None)
-    h, _ = apply_blocks(params, h, config, cos, sin)
-    return _final(params, h, config)
+    return stack.forward(FAMILY, params, input_ids, config)
 
 
 def forward_with_cache(params: Params, input_ids: jnp.ndarray,
@@ -432,40 +411,54 @@ def forward_with_cache(params: Params, input_ids: jnp.ndarray,
                        flash_prefill: bool = False,
                        decode_kernel: Optional[str] = None,
                        ) -> Tuple[jnp.ndarray, KVCache]:
-    """Cached forward at ``cache.length``. ``flash_prefill`` is the
-    engine's static word that the cache is fresh (offset 0; a left-pad
-    prefix is masked either way): the full layers then attend over this
-    call's tokens alone. A single position runs the full layers through
-    the decode kernel where the engine resolved one (``decode_kernel``:
-    ``"device"`` or ``"interpret"``) and the sliding layers over their
-    ring; a call of several computes the band, and the full layers a
-    block of queries at a time."""
-    if decode_kernel not in (None, "device", "interpret"):
-        raise ValueError(f"decode_kernel={decode_kernel!r}: this family "
-                         "has the per-layer kernel only")
-    if cache.state is None:
-        raise ValueError("this family's cache carries the rows' window "
-                         "records (KVCache.state); they were dropped on "
-                         "the way here")
-    h = _embed(params, input_ids)
-    cos, sin = _angles(config, input_ids.shape[1], cache.length, pad)
-    h, cache = apply_blocks(params, h, config, cos, sin, cache, pad,
-                            fresh=flash_prefill, decode_kernel=decode_kernel)
-    return _final(params, h, config), cache
+    """Cached forward at ``cache.length``. With ``flash_prefill`` (the
+    cache is fresh; a left-pad prefix is masked either way) the full
+    layers attend over this call's tokens alone. A single position runs
+    the full layers through the decode kernel where the engine resolved
+    one and the sliding layers over their ring; a call of several
+    computes the band, and the full layers a block of queries at a
+    time."""
+    return stack.forward_with_cache(FAMILY, params, input_ids, config, cache,
+                                    pad, flash_prefill, decode_kernel)
 
 
 def make_cache(config: WindowMoEConfig, batch: int, max_seq: int,
                dtype=jnp.float32) -> KVCache:
     """The full layers' fused ``[P, B, Hkv, max_seq, 2 hd]`` rows, the
-    zeroed counters, and the rows' zeroed rings (``row_state``'s leaves
-    with the batch on axis 1)."""
-    if max_seq > config.n_positions:
-        raise ValueError(
-            f"max_seq={max_seq} exceeds n_positions={config.n_positions}")
-    return KVCache(
-        k=jnp.zeros((config.n_periods, batch, config.n_kv_head, max_seq,
-                     2 * config.head_dim), dtype),
-        v=jnp.zeros((len(CACHE_COUNTERS),), jnp.int32),
-        length=jnp.zeros((), jnp.int32),
-        state=tuple(jnp.zeros(shape[:1] + (batch,) + shape[1:], dt)
-                    for shape, dt in row_state(config, dtype)))
+    zeroed counters, and the rows' zeroed rings."""
+    return stack.make_cache(FAMILY, config, batch, max_seq, dtype)
+
+
+# It serves through the single-device engine (solo, the iteration
+# scheduler, the paged pool of its full layers with the window records
+# in the state slab, the prefix store) in float32 or bfloat16; what it
+# refuses, one sentence each.
+FAMILY = Family(
+    name="window_moe", config_class=WindowMoEConfig,
+    frame=stack.Frame(apply_blocks, rotary_width=lambda c: c.head_dim),
+    cache_entry=cache_entry, cache_layers=cache_layers, row_state=row_state,
+    cache_counters=CACHE_COUNTERS, span_labels=span_labels,
+    bounds_own_reads=True,       # kernel, ring and masked einsum bound reads
+    fresh_prefill_flag=True,     # a fresh prefill attends its own tokens only
+    decode_kernel_eligible=decode_kernel_eligible,
+    prompt_bucket=prompt_bucket, window_positions=window_positions,
+    refuses=(
+        ("spec_decode",
+         "SPEC_DECODE: a rejected draft cannot be taken back out of "
+         "{name}'s window records (a ring has overwritten what the "
+         "draft displaced); serve it without speculation"),
+        ("kv_pool_dtype",
+         "KV_POOL_DTYPE={value}: {name}'s pool is fused "
+         "with counters in its second leaf and its window records "
+         "carry the served type; the quantized movers have not been "
+         "fitted to either"),
+        ("kv_host_blocks",
+         "KV_HOST_BLOCKS: a demoted entry of {name} would need its "
+         "window records demoted with its blocks; the host tier "
+         "moves blocks only"),
+        ("multi_chip",
+         "PP/TP/EP_DECODE: no multi-chip decoder stages or shards "
+         "{name} (a first period unlike the others, a state slab "
+         "beside the pool, experts indexed in place); it serves on "
+         "one chip, told which experts it holds"),
+        ("int8_weights", INT8_REFUSED)))

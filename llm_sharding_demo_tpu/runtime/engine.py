@@ -451,8 +451,7 @@ def _place_tp_params(params: Params, config, mesh) -> Params:
     split is between layers (reference server.py:63-64)."""
     from jax.sharding import NamedSharding
 
-    from ..models.llama import LlamaConfig
-    from ..parallel import spmd
+    from ..models import family_of
 
     # the spmd pspec helpers key on the literal axis name "tp"
     if "tp" not in mesh.axis_names:
@@ -464,8 +463,7 @@ def _place_tp_params(params: Params, config, mesh) -> Params:
             f"tp={tp} must divide n_head={config.n_head} and "
             f"n_kv_head={kv_heads}: the KV cache and attention shard "
             "over whole heads")
-    specs = (spmd.llama_param_pspecs(mesh) if isinstance(config, LlamaConfig)
-             else spmd.param_pspecs(mesh))
+    specs = family_of(config).param_pspecs(mesh)
 
     def place(spec, leaf):
         return jax.device_put(leaf, NamedSharding(mesh, spec))
@@ -545,11 +543,11 @@ class DecodeEngine:
         from ..utils.graftnum import engine_regime_of
         self.regime = engine_regime_of(dtype)
         quantize = self.regime == "int8"
-        from ..models import family_module as _family
-        if quantize and not getattr(_family(config), "INT8_WEIGHTS", True):
-            raise NotImplementedError(
-                f"{type(config).__name__} indexes its experts' plain "
-                "weight stacks; it serves float32 or bfloat16, not int8")
+        from ..models import family_of, is_stage_partitionable
+        self.family = family_of(config)
+        why_not = quantize and self.family.refusal("int8_weights", config)
+        if why_not:
+            raise NotImplementedError(why_not)
         if quantize and mesh is not None and not hasattr(config, "n_experts"):
             # refuse BEFORE any weight work (quantizing a real checkpoint
             # takes seconds — same convention as the prefill_chunk guard)
@@ -599,13 +597,12 @@ class DecodeEngine:
                 # (int8 x tp already refused above, before weight work)
                 self._mesh_mode = "tp"
                 self.params = _place_tp_params(self.params, config, mesh)
-        # Model dispatch: any family module exposing the
+        # Model dispatch: any family whose module exposes the
         # (forward_with_cache, make_cache) pair can be decoded
-        # (models.family_module — gpt2, moe, llama). Stage partitioning
-        # covers the dense families (GPT-2 and llama — parallel.partition
-        # dispatches structurally); MoE's expert tree decodes unstaged.
-        from ..models import family_module, is_stage_partitionable
-        self._model = family_module(config)
+        # (models.family_of). Stage partitioning covers the dense
+        # families (GPT-2 and llama — parallel.partition dispatches
+        # structurally); MoE's expert tree decodes unstaged.
+        self._model = self.family.module
         if boundaries is not None and not is_stage_partitionable(config):
             raise NotImplementedError(
                 "pipeline stage partitioning (boundaries) covers the "
@@ -653,7 +650,7 @@ class DecodeEngine:
                 f"decode_kernel={decode_kernel!r} not one of {_KERNEL_MODES}")
         self._cache_seq = max_seq
         self._decode_kernel: Optional[str] = None
-        if getattr(self._model, "BOUNDS_OWN_READS", False):
+        if self.family.bounds_own_reads:
             # the family's attention bounds its cache reads by the live
             # depth inside the program (as the decode kernels' block
             # loops do): a window bucket as wide as the cache means the
@@ -661,7 +658,7 @@ class DecodeEngine:
             self.WINDOW_BUCKET = max_seq
         # names of the counters a family's cache carries in its second,
         # one-dimensional leaf (models.latent_moe), or ()
-        self.cache_counters = getattr(self._model, "CACHE_COUNTERS", ())
+        self.cache_counters = self.family.cache_counters
         # "auto" engages only outside the f32 regime, however the dtype
         # was spelled (fp32 is BASELINE.json's byte-pinned greedy-parity
         # mode; the kernel's online softmax is allclose-not-bitwise vs
@@ -673,7 +670,7 @@ class DecodeEngine:
         # a family with a cache of its own (models.cache_entry) brings
         # its own kernel and geometry rule and keeps its own cache
         # layout under it
-        own_rule = getattr(self._model, "decode_kernel_eligible", None)
+        own_rule = self.family.decode_kernel_eligible
         if mesh is not None and explicit_kernel:
             raise ValueError(
                 f"decode_kernel={decode_kernel!r} does not compose with a "
@@ -757,7 +754,7 @@ class DecodeEngine:
         # require; the XLA mode keeps the family's separate buffers.
         heads = getattr(self.config, "n_kv_head", self.config.n_head)
         if (self._decode_kernel is not None
-                and not hasattr(self._model, "decode_kernel_eligible")):
+                and self.family.decode_kernel_eligible is None):
             from ..ops.attention import create_fused_cache
             if self.specs is None:
                 return create_fused_cache(self.config.n_layer, batch, heads,
@@ -837,7 +834,7 @@ class DecodeEngine:
         # a family with its own fresh-cache attention form (latent:
         # expanded, reading no cache; it masks pad itself) takes the
         # same static word
-        flash = flash or getattr(self._model, "FRESH_PREFILL_FLAG", False)
+        flash = flash or self.family.fresh_prefill_flag
         logits, cache = self._forward_cached(params, ids, cache, pad,
                                              flash_prefill=flash)
         return logits[:, -1], cache

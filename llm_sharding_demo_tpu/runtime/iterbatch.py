@@ -698,12 +698,11 @@ class IterBatchingEngine:
         # depths those rows have reached, as of the last scheduling
         # decision
         self._window: dict = {}
-        self._window_positions = getattr(engine._model, "window_positions",
-                                         None)
+        self._window_positions = engine.family.window_positions
         # a family that serves prompts of thousands of positions says
         # how coarsely a lone prompt is bucketed (``prompt_bucket``;
         # models.window_moe); the others take multiples of the argument
-        self._family_bucket = getattr(engine._model, "prompt_bucket", None)
+        self._family_bucket = engine.family.prompt_bucket
         # (instant, reason) transitions of what holds the head of the
         # queue (worker-thread-only): every admitted request's wait is
         # cut by it. 4096 transitions span minutes of boundaries; a wait
@@ -1536,10 +1535,12 @@ class IterBatchingEngine:
                            [state.pad_j, jnp.full((pad_rows,), self._no_span,
                                                   jnp.int32)]))
         resident = state.cache is not None
-        if resident:
+        if resident and state.tables is None:
+            state.cache = grow_cache(state.cache)
+        elif resident:
+            # (one KVCache: a staged engine's list of them has no pool)
             row_state = state.cache.state
-            state.cache = (None if state.tables is not None
-                           else grow_cache(state.cache))
+            state.cache = None
         if state.tables is not None:
             # a ghost lane reads the trash block here and its
             # write-back lands there, like a retired row's stale lane
@@ -1969,7 +1970,7 @@ class IterBatchingEngine:
         names = self.engine.cache_counters
         if not names:
             return None
-        model, config = self.engine._model, self.engine.config
+        family, config = self.engine.family, self.engine.config
 
         def label(values):
             got = dict(zip(names, values))
@@ -1977,7 +1978,7 @@ class IterBatchingEngine:
                 for k, v in got.items():
                     self._moe[f"moe.prefill_{k}" if prefill
                               else f"moe.{k}"] += v
-            return model.span_labels(got, config, prefill)
+            return family.span_labels(got, config, prefill)
         return jnp.copy(cache.v), label
 
     def _set_gauges(self, state: _BatchState) -> None:

@@ -284,7 +284,8 @@ def create_app(cfg: Optional[ServingConfig] = None,
     #   /forward_b — the reference's ShardA/ShardB contract
     #   (server.py:51-105) regardless of how many stages /generate uses;
     # - coordinator + remote dispatch: nothing (shards hold the weights).
-    from ..models import is_partitionable, is_stage_partitionable
+    from ..models import (family_of, is_partitionable,
+                          is_stage_partitionable)
     # Two distinct notions: ``partitionable`` is the reference's GPT-2
     # WIRE topology (/forward + /forward_b relay, remote dispatch) —
     # GPT-2-only by design; ``stageable`` is whether the decode engine
@@ -332,126 +333,17 @@ def create_app(cfg: Optional[ServingConfig] = None,
             if on:
                 raise ValueError(f"{why} (refused for this family)")
 
-    from ..models import latent_moe as _latent
-    if isinstance(config, _latent.LatentMoEConfig):
-        # what the latent-attention / sparse-expert family refuses, one
-        # message each, instead of a wrong answer further down: it
-        # serves through the single-device engine (solo, either batcher,
-        # the paged pool, the prefix store) in float32 or bfloat16
-        name = type(config).__name__
-        refused = (
-            (cfg.kv_pool_dtype,
-             f"KV_POOL_DTYPE={cfg.kv_pool_dtype}: {name}'s pool holds one "
-             "latent vector a position; the quantized movers scale per "
-             "kv-head and have not been fitted to it"),
-            (cfg.kv_host_blocks > 0,
-             f"KV_HOST_BLOCKS: the host tier has not been run over "
-             f"{name}'s one-plane pool"),
-            (cfg.spec_decode > 0,
-             f"SPEC_DECODE: the verify loop's rewind leaves {name}'s "
-             "routing counters and cached latents of rejected drafts "
-             "untested; serve it without speculation"),
-            (cfg.pp_decode or cfg.tp_decode or cfg.ep_decode,
-             f"PP/TP/EP_DECODE: no multi-chip decoder stages or shards "
-             f"{name} (two stacks of unlike layers, experts indexed in "
-             "place); it serves on one chip, told which experts it "
-             "holds"),
-            (cfg.inference_dtype == "int8",
-             f"INFERENCE_DTYPE=int8: {name} indexes its experts' plain "
-             "weight stacks; it serves float32 or bfloat16"),
-        )
-        _refuse(refused)
-    from ..models import gdn_moe as _gdn, kda_moe as _kda
-    if isinstance(config, (_gdn.GDNMoEConfig, _kda.KDAMoEConfig)):
-        # what the linear-attention / sparse-expert families refuse, one
-        # message each: they serve through the single-device engine
-        # (solo, the iteration scheduler, the paged pool with its state
-        # slab, the prefix store) in float32 or bfloat16
-        name = type(config).__name__
-        refused = (
-            (cfg.spec_decode > 0,
-             f"SPEC_DECODE: a rejected draft cannot be rewound out of "
-             f"{name}'s per-row state (it has no position axis) without "
-             "a snapshot a verify; serve it without speculation"),
-            (cfg.kv_pool_dtype,
-             f"KV_POOL_DTYPE={cfg.kv_pool_dtype}: {name}'s pool is one "
-             "plane with counters in its second leaf and its rows' state "
-             "is float32 by contract; the quantized movers have not been "
-             "fitted to it"),
-            (cfg.kv_host_blocks > 0,
-             f"KV_HOST_BLOCKS: a demoted entry of {name} would need its "
-             "state snapshot demoted with its blocks; the host tier "
-             "moves blocks only"),
-            (cfg.pp_decode or cfg.tp_decode or cfg.ep_decode,
-             f"PP/TP/EP_DECODE: no multi-chip decoder stages or shards "
-             f"{name} (runs of unlike layers, a state slab beside "
-             "the pool, experts indexed in place); it serves on one "
-             "chip, told which experts it holds"),
-            (cfg.inference_dtype == "int8",
-             f"INFERENCE_DTYPE=int8: {name} indexes its experts' plain "
-             "weight stacks; it serves float32 or bfloat16"),
-        )
-        _refuse(refused)
-    from ..models import window_moe as _window
-    if isinstance(config, _window.WindowMoEConfig):
-        # what the sliding-window / sparse-expert family refuses, one
-        # message each: it serves through the single-device engine
-        # (solo, the iteration scheduler, the paged pool of its full
-        # layers with the window records in the state slab, the prefix
-        # store) in float32 or bfloat16
-        name = type(config).__name__
-        refused = (
-            (cfg.spec_decode > 0,
-             f"SPEC_DECODE: a rejected draft cannot be taken back out of "
-             f"{name}'s window records (a ring has overwritten what the "
-             "draft displaced); serve it without speculation"),
-            (cfg.kv_pool_dtype,
-             f"KV_POOL_DTYPE={cfg.kv_pool_dtype}: {name}'s pool is fused "
-             "with counters in its second leaf and its window records "
-             "carry the served type; the quantized movers have not been "
-             "fitted to either"),
-            (cfg.kv_host_blocks > 0,
-             f"KV_HOST_BLOCKS: a demoted entry of {name} would need its "
-             "window records demoted with its blocks; the host tier "
-             "moves blocks only"),
-            (cfg.pp_decode or cfg.tp_decode or cfg.ep_decode,
-             f"PP/TP/EP_DECODE: no multi-chip decoder stages or shards "
-             f"{name} (a first period unlike the others, a state slab "
-             "beside the pool, experts indexed in place); it serves on "
-             "one chip, told which experts it holds"),
-            (cfg.inference_dtype == "int8",
-             f"INFERENCE_DTYPE=int8: {name} indexes its experts' plain "
-             "weight stacks; it serves float32 or bfloat16"),
-        )
-        _refuse(refused)
-    from ..models import hybrid_ssm as _hybrid
-    if isinstance(config, _hybrid.HybridSSMConfig):
-        # what the state-space / attention family refuses, one message
-        # each: it serves through the single-device engine (solo, the
-        # iteration scheduler, the paged pool of every layer's positions
-        # with every layer's row state in the state slab, the prefix
-        # store) in float32, bfloat16 or with int8 weights
-        name = type(config).__name__
-        refused = (
-            (cfg.spec_decode > 0,
-             f"SPEC_DECODE: a rejected draft cannot be rewound out of "
-             f"{name}'s per-row state (it has no position axis) without "
-             "a snapshot a verify; serve it without speculation"),
-            (cfg.kv_pool_dtype,
-             f"KV_POOL_DTYPE={cfg.kv_pool_dtype}: {name}'s pool holds "
-             "fused [K | V] rows in one plane and its rows' state is "
-             "float32 by contract; the quantized movers have not been "
-             "fitted to either"),
-            (cfg.kv_host_blocks > 0,
-             f"KV_HOST_BLOCKS: a demoted entry of {name} would need its "
-             "state snapshot demoted with its blocks; the host tier "
-             "moves blocks only"),
-            (cfg.pp_decode or cfg.tp_decode or cfg.ep_decode,
-             f"PP/TP/EP_DECODE: no multi-chip decoder stages or shards "
-             f"{name} (a state slab beside the pool in every layer, two "
-             "state-space groups to divide); it serves on one chip"),
-        )
-        _refuse(refused)
+    # what the family's declaration (models.family.Family.refuses) says
+    # it does not serve, refused here, at start-up, instead of a wrong
+    # answer further down
+    asked = {"kv_pool_dtype": cfg.kv_pool_dtype,
+             "kv_host_blocks": cfg.kv_host_blocks > 0,
+             "spec_decode": cfg.spec_decode > 0,
+             "multi_chip": cfg.pp_decode or cfg.tp_decode or cfg.ep_decode,
+             "int8_weights": cfg.inference_dtype == "int8"}
+    family = family_of(config)
+    _refuse((asked[option], family.refusal(option, config, asked[option]))
+            for option, _ in family.refuses)
     if cfg.ep_decode:
         if not (cfg.shard_role == "coordinator" and cfg.dispatch == "local"):
             raise ValueError("EP_DECODE applies to the coordinator's local "

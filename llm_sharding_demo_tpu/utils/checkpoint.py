@@ -10,7 +10,7 @@ access and each pipeline stage can load only its own parameter subset
 
 Layout on disk::
 
-    <dir>/config.json          # GPT2Config fields (+ "family" tag)
+    <dir>/config.json          # the config's fields + its "family" tag
     <dir>/params/              # Orbax PyTreeCheckpointer payload
 
 In memory the block stack is ``[n_layer, ...]`` leaves (the ``lax.scan``
@@ -38,6 +38,7 @@ import jax
 import numpy as np
 import orbax.checkpoint as ocp
 
+from ..models import family_named, family_of
 from ..models.gpt2 import GPT2Config, Params
 from ..parallel import partition as P_
 
@@ -86,33 +87,23 @@ def _is_per_layer(blocks) -> bool:
             and all(k.isdigit() for k in blocks))
 
 
-def _config_family(config: GPT2Config) -> str:
-    """Model-family tag written next to the config fields.
-
-    ``dataclasses.asdict`` flattens every family to a plain dict; without a
-    tag an MoE or llama checkpoint would restore as a GPT2Config crash
-    (unknown fields) or — worse, if fields ever overlapped — as the wrong
-    model.
-    """
-    from ..models.llama import LlamaConfig
-    from ..models.moe import MoEConfig
-    if isinstance(config, MoEConfig):
-        return "moe"
-    if isinstance(config, LlamaConfig):
-        return "llama"
-    return "gpt2"
-
-
 def save(directory: str, params: Params, config: GPT2Config) -> None:
     """Write config + params (per-layer block layout — see module doc).
     Overwrites an existing checkpoint."""
     directory = os.path.abspath(directory)
     os.makedirs(directory, exist_ok=True)
-    payload = {"family": _config_family(config), **dataclasses.asdict(config)}
+    # ``dataclasses.asdict`` flattens every family to a plain dict; the
+    # family's tag beside the fields says which config class they are
+    payload = {"family": family_of(config).name,
+               **dataclasses.asdict(config)}
     with open(os.path.join(directory, CONFIG_FILE), "w") as f:
         json.dump(payload, f, indent=2)
-    on_disk = {k: v for k, v in params.items() if k != "blocks"}
-    on_disk["blocks"] = _split_blocks(params["blocks"])
+    # one stack under ``blocks`` goes to disk a layer a subtree (what a
+    # stage restore reads); a family that lays its layers out otherwise
+    # (periods, groups) is written as it stands
+    on_disk = dict(params)
+    if "blocks" in params:
+        on_disk["blocks"] = _split_blocks(params["blocks"])
     ckptr = ocp.PyTreeCheckpointer()
     ckptr.save(os.path.join(directory, PARAMS_DIR), on_disk, force=True)
 
@@ -121,15 +112,15 @@ def load_config(directory: str) -> GPT2Config:
     with open(os.path.join(os.path.abspath(directory), CONFIG_FILE)) as f:
         fields = json.load(f)
     family = fields.pop("family", "gpt2")  # pre-tag checkpoints are dense
-    if family == "moe":
-        from ..models.moe import MoEConfig
-        return MoEConfig(**fields)
-    if family == "llama":
-        from ..models.llama import LlamaConfig
-        return LlamaConfig(**fields)
-    if family != "gpt2":
-        raise ValueError(f"unknown checkpoint model family {family!r}")
-    return GPT2Config(**fields)
+    # JSON has no tuples: a list among the fields was one
+    fields = {k: _tuples(v) for k, v in fields.items()}
+    return family_named(family).config_class(**fields)
+
+
+def _tuples(value):
+    if isinstance(value, list):
+        return tuple(_tuples(v) for v in value)
+    return value
 
 
 def load(directory: str) -> Tuple[GPT2Config, Params]:

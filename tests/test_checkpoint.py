@@ -132,3 +132,60 @@ def test_legacy_stacked_checkpoint_still_loads(model, tmp_path):
     for a, b in zip(jax.tree_util.tree_leaves(stage),
                     jax.tree_util.tree_leaves(want)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+# -- every family's checkpoint restores as its own config class -----------
+# (before there was a family registry the tag knew ``moe`` and ``llama``
+# and called everything else ``gpt2``: a latent, linear-attention,
+# window, state-space or delta-rule checkpoint then crashed in
+# ``GPT2Config(**fields)``)
+
+def _tiny_configs():
+    from test_family import TINY
+    return sorted(TINY.items())
+
+
+@pytest.mark.parametrize("name,config", _tiny_configs(),
+                         ids=[n for n, _ in _tiny_configs()])
+def test_a_checkpoint_of_every_family_restores_its_config(name, config,
+                                                          tmp_path):
+    from llm_sharding_demo_tpu.models import family_of
+    import json
+    family = family_of(config)
+    params = family.module.init_params(config, jax.random.PRNGKey(0))
+    d = str(tmp_path / name)
+    ckpt.save(d, params, config)
+    with open(tmp_path / name / ckpt.CONFIG_FILE) as f:
+        assert json.load(f)["family"] == family.name == name
+    restored = ckpt.load_config(d)
+    assert type(restored) is type(config) and restored == config
+    assert hash(restored) == hash(config)      # tuples came back tuples
+    # and the weights, whether the family stacks ``blocks`` or lays its
+    # layers out in periods or groups
+    _, params2 = ckpt.load(d)
+    assert jax.tree.structure(params2) == jax.tree.structure(params)
+    for a, b in zip(jax.tree.leaves(params), jax.tree.leaves(params2)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("tag,wants", [
+    (None, "GPT2Config"), ("gpt2", "GPT2Config"), ("gpt3", ValueError)])
+def test_what_a_tag_loads_as(model, tmp_path, tag, wants):
+    """A directory without a tag is a pre-tag, dense GPT-2 checkpoint;
+    an unknown tag is refused by name."""
+    import dataclasses
+    import json
+    import os
+    config, _ = model
+    fields = dataclasses.asdict(config)
+    if tag is not None:
+        fields["family"] = tag
+    with open(os.path.join(tmp_path, ckpt.CONFIG_FILE), "w") as f:
+        json.dump(fields, f)
+    if wants is ValueError:
+        with pytest.raises(ValueError,
+                           match="unknown checkpoint model family 'gpt3'"):
+            ckpt.load_config(str(tmp_path))
+    else:
+        restored = ckpt.load_config(str(tmp_path))
+        assert type(restored).__name__ == wants and restored == config

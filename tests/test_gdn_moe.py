@@ -679,23 +679,11 @@ def test_a_preempted_row_resumes_inside_the_declared_tolerance(whole):
                       < budget), i
 
 
-def test_what_the_family_refuses():
+def test_what_the_engines_refuse():
+    # (what the SERVER refuses for every family: tests/test_family.py)
     from llm_sharding_demo_tpu.runtime.spec_decode import SpecDecodeEngine
-    from llm_sharding_demo_tpu.serving.app import create_app
-    from llm_sharding_demo_tpu.utils.config import ServingConfig
     cfg = gdn_moe.CONFIGS["gdn-moe-tiny"]
     params = gdn_moe.init_params(cfg, jax.random.PRNGKey(0))
-    base = dict(model_id="test", max_seq=64, batch_mode="iter",
-                max_batch=2, kv_pool_blocks=16)
-    for extra, word in ((dict(spec_decode=2), "SPEC_DECODE"),
-                        (dict(kv_pool_dtype="int8"), "KV_POOL_DTYPE"),
-                        (dict(kv_host_blocks=8), "KV_HOST_BLOCKS"),
-                        (dict(inference_dtype="int8"), "int8")):
-        with pytest.raises(ValueError, match=word):
-            create_app(ServingConfig(**base, **extra), model=(cfg, params))
-    with pytest.raises(ValueError, match="PP/TP/EP_DECODE"):
-        create_app(ServingConfig(model_id="test", max_seq=64,
-                                tp_decode=True), model=(cfg, params))
     with pytest.raises(NotImplementedError, match="int8"):
         DecodeEngine(params, cfg, max_seq=64, dtype="int8")
     with pytest.raises(NotImplementedError, match="rewound"):

@@ -217,11 +217,22 @@ def test_composes_with_staged_engine():
     rng = np.random.default_rng(9)
     pA = rng.integers(0, 211, size=(5,))
     pB = rng.integers(0, 211, size=(6,))
-    wantA = engine.generate(pA[None, :], 30).tokens[0]
+    wantA = engine.generate(pA[None, :], 96).tokens[0]
     wantB = engine.generate(pB[None, :], 20).tokens[0]
-    resA, resB = _staggered(ib, [(pA, 30, 0.0, {}), (pB, 20, 0.5, {})])
+    # B arrives while A is demonstrably mid-flight (not after a fixed
+    # delay, which a warm compile cache turns into "after A is done"):
+    # the batch of one GROWS around the staged engine's list of caches,
+    # which the grow once took for one cache (`'list' object has no
+    # attribute 'state'`, a 500 under load)
+    before = ib.stats()
+    resA, resB = _staggered(ib, [
+        (pA, 96, 0.0, {}),
+        (pB, 20, _after_segments(ib, before["segments"], 1), {})])
+    after = ib.stats()
     np.testing.assert_array_equal(resA.tokens[0], wantA)
     np.testing.assert_array_equal(resB.tokens[0], wantB)
+    assert after["joins"] - before["joins"] >= 1
+    assert after["grows"] - before["grows"] >= 1
 
 
 def _spec_setup(max_seq=200, draft_len=5, seg_steps=12, max_batch=4):
@@ -426,6 +437,7 @@ def test_serving_batch_mode_iter():
     reports the scheduler stats, misconfigurations refuse."""
     import json
     import threading as th
+    import urllib.error
     import urllib.request
 
     from llm_sharding_demo_tpu.serving.app import create_app
@@ -461,8 +473,13 @@ def test_serving_batch_mode_iter():
                 json.dumps({"prompt": p, "max_new_tokens": 6,
                             "mode": "greedy"}).encode(),
                 {"content-type": "application/json"})
-            results[p] = json.loads(
-                urllib.request.urlopen(req, timeout=300).read())["generated"]
+            try:
+                results[p] = json.loads(urllib.request.urlopen(
+                    req, timeout=300).read())["generated"]
+            except urllib.error.HTTPError as e:
+                # a thread that dies leaves ``results`` short and says
+                # nothing: keep what the server said
+                results[p] = f"{e.code}: {e.read()[:300]!r}"
 
         threads = [th.Thread(target=post, args=(p,)) for p in prompts]
         for t in threads:

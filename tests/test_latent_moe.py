@@ -501,21 +501,9 @@ def test_a_joiner_on_all_three_strides_serves_the_solo_stream(whole):
     assert np.all(ref.max(-1) - ref[np.arange(len(served)), served] < TOL)
 
 
-def test_what_the_family_refuses():
-    from llm_sharding_demo_tpu.serving.app import create_app
-    from llm_sharding_demo_tpu.utils.config import ServingConfig
+def test_what_the_engine_refuses():
+    # (what the SERVER refuses for every family: tests/test_family.py)
     cfg = latent_moe.CONFIGS["latent-moe-tiny"]
     params = latent_moe.init_params(cfg, jax.random.PRNGKey(0))
-    base = dict(model_id="test", max_seq=64, batch_mode="iter",
-                max_batch=2, kv_pool_blocks=16)
-    for extra, word in ((dict(kv_pool_dtype="int8"), "KV_POOL_DTYPE"),
-                        (dict(kv_host_blocks=8), "KV_HOST_BLOCKS"),
-                        (dict(spec_decode=2), "SPEC_DECODE"),
-                        (dict(inference_dtype="int8"), "int8")):
-        with pytest.raises(ValueError, match=word):
-            create_app(ServingConfig(**base, **extra), model=(cfg, params))
-    with pytest.raises(ValueError, match="PP/TP/EP_DECODE"):
-        create_app(ServingConfig(model_id="test", max_seq=64,
-                                tp_decode=True), model=(cfg, params))
     with pytest.raises(NotImplementedError, match="int8"):
         DecodeEngine(params, cfg, max_seq=64, dtype="int8")

@@ -596,30 +596,7 @@ def test_a_preempted_row_resumes_inside_the_declared_tolerance(whole):
                       < budget), i
 
 
-def app_config(**extra):
-    from llm_sharding_demo_tpu.utils.config import ServingConfig
-    base = dict(model_id="test", max_seq=64, batch_mode="iter",
-                max_batch=2, kv_pool_blocks=16)
-    return ServingConfig(**{**base, **extra})
-
-
-@pytest.mark.parametrize("extra,word", [
-    (dict(spec_decode=2), "SPEC_DECODE"),
-    (dict(kv_pool_dtype="int8"), "KV_POOL_DTYPE"),
-    (dict(kv_host_blocks=8), "KV_HOST_BLOCKS"),
-    (dict(batch_mode="admission", max_batch=1, kv_pool_blocks=0,
-          tp_decode=True), "PP/TP/EP_DECODE"),
-    (dict(inference_dtype="int8"), "INFERENCE_DTYPE=int8")],
-    ids=["spec", "pool-dtype", "host-tier", "mesh", "int8"])
-def test_what_the_served_family_refuses(extra, word):
-    from llm_sharding_demo_tpu.serving.app import create_app
-    cfg = window_moe.CONFIGS["window-moe-tiny"]
-    params = window_moe.init_params(cfg, jax.random.PRNGKey(0))
-    with pytest.raises(ValueError, match=word) as e:
-        create_app(app_config(**extra), model=(cfg, params))
-    assert "refused for this family" in str(e.value)
-
-
+# (what the SERVER refuses for every family: tests/test_family.py)
 def test_what_the_engines_refuse():
     from llm_sharding_demo_tpu.runtime.spec_decode import SpecDecodeEngine
     cfg = window_moe.CONFIGS["window-moe-tiny"]
