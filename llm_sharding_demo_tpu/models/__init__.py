@@ -14,12 +14,12 @@ alone. Adding a family is its module and one entry of ``FAMILIES``.
 from __future__ import annotations
 
 from . import (gdn_moe, gpt2, hybrid_ssm, kda_moe, latent_moe, llama, moe,
-               window_moe)
+               sdar_moe, window_moe)
 from .family import Family
 
 FAMILIES = (gpt2.FAMILY, moe.FAMILY, llama.FAMILY, latent_moe.FAMILY,
             gdn_moe.FAMILY, window_moe.FAMILY, hybrid_ssm.FAMILY,
-            kda_moe.FAMILY)
+            kda_moe.FAMILY, sdar_moe.FAMILY)
 REGISTRY = {f.config_class: f for f in FAMILIES}
 
 
@@ -104,6 +104,8 @@ def is_window_independent(config) -> bool:
     continuations). MoE capacity-factor routing makes tokens compete for
     expert slots within a window, so it is window-DEPENDENT; the dense
     families are independent (``hybrid_ssm`` is one), and so are
-    ``latent_moe``, ``gdn_moe``, ``kda_moe`` and ``window_moe``, whose
-    routing has no capacity and drops no token."""
+    ``latent_moe``, ``gdn_moe``, ``kda_moe``, ``window_moe`` and
+    ``sdar_moe``, whose routing has no capacity and drops no token
+    (``sdar_moe``'s windows are whole blocks: a position sees its
+    block-mates, and every caller forwards whole blocks)."""
     return family_of(config).window_independent

@@ -93,6 +93,11 @@ class Family:
     # ``(state, depths) -> (held, seen)``: positions the rows' window
     # records hold, and positions those rows have reached
     window_positions: Optional[Callable[[Any, Any], Tuple[int, int]]] = None
+    # ``config -> ops.block_diffusion.Options``: the family generates by
+    # ROUNDS over blocks of ``block_length`` positions (a round is some
+    # denoise forwards and a commit, and yields a whole block a row);
+    # ``None``: a step yields one token a row
+    block_options: Optional[Callable[[Any], Any]] = None
 
     # -- topology and exactness -------------------------------------------
     # the reference's GPT-2 stage-shard WIRE topology applies

@@ -391,6 +391,11 @@ class GenerateResult:
     # forwards actually run; zero acceptance costs new_tokens - 1 verifies
     # (the first token comes from prefill), fewer means drafts landed.
     verify_steps: Optional[int] = None
+    # A family that generates by blocks only (``Family.block_options``):
+    # for each new token the index, inside its round, of the denoise
+    # forward that fixed it, ``[B, new_tokens]``; ``decode_steps`` is
+    # then the forwards the rounds ran (denoise and commit).
+    fixed_at: Optional[np.ndarray] = None
 
     def row_tokens(self, i: int) -> np.ndarray:
         """Row i's tokens with its left-pad prefix stripped."""
@@ -659,6 +664,17 @@ class DecodeEngine:
         # names of the counters a family's cache carries in its second,
         # one-dimensional leaf (models.latent_moe), or ()
         self.cache_counters = self.family.cache_counters
+        # how a family that generates by ROUNDS over blocks does so
+        # (ops.block_diffusion.Options), or None: a step yields a token
+        self.block = (None if self.family.block_options is None
+                      else self.family.block_options(config))
+        if self.block is not None and (mesh is not None
+                                       or boundaries is not None
+                                       or prefill_chunk):
+            raise NotImplementedError(
+                f"{type(config).__name__} generates by rounds over blocks "
+                "on the single-device engine; no mesh, stages or "
+                "prefill_chunk")
         # "auto" engages only outside the f32 regime, however the dtype
         # was spelled (fp32 is BASELINE.json's byte-pinned greedy-parity
         # mode; the kernel's online softmax is allclose-not-bitwise vs
@@ -989,7 +1005,15 @@ class DecodeEngine:
         len(step_keys)], cache, last [B])``: columns from ``steps`` on
         are never written and never read, ``last`` is the token of the
         last step run. The length is then no part of the program's key:
-        one program serves every length up to ``len(step_keys)``."""
+        one program serves every length up to ``len(step_keys)``.
+
+        A family that generates by blocks runs ROUNDS in place of steps
+        (``_decode_rounds``): ``token`` is then each row's block ``[B,
+        L]``, ``steps`` the rounds, and the tokens come back with the
+        forward that fixed each."""
+        if self.block is not None:
+            return self._decode_rounds(params, token, cache, pad,
+                                       step_keys.shape[0], steps, sampling)
         sub = self._slice_cache(cache, window) if window else cache
         if self.cache_counters:
             # what comes back beside this segment's tokens is this
@@ -1022,6 +1046,154 @@ class DecodeEngine:
         cache = self._merge_window(cache, sub) if window else sub
         return (out, cache) if steps is None else (out, cache, token)
 
+    def _decode_rounds(self, params: Params, block: jnp.ndarray, cache,
+                       pad: Optional[jnp.ndarray], room: int,
+                       rounds: jnp.ndarray, sampling: SamplingConfig):
+        """``rounds`` ROUNDS of generation by blocks from the batch's
+        depth ``cache.length`` (a block boundary of every row).
+
+        ``block`` [B, L] int32 is each row's block as the first round
+        finds it: token ids where a position is given (the tail of a
+        prompt that does not end on a boundary), ``MASKED`` elsewhere;
+        every later round starts all masked. A round runs DENOISE
+        forwards of the block's ``L`` positions against the cache, each
+        writing the block's keys and values at ``[d, d + L)`` and
+        leaving the depth where it was, choosing a candidate and a
+        confidence for every masked position and fixing some by the
+        transfer rule, until no LIVE row has a masked position (a lane
+        without a request, ``pad >= d``, has none to begin with); then
+        one COMMIT forward on the finished block, whose keys and values
+        the cache keeps, and the depth moves by ``L``. Every row commits
+        in the same forward: the batch keeps one depth, and a round
+        yields ``L`` positions a row whatever the weights. Only the
+        FORWARDS are data.
+
+        Returns ``(out [B, 2, room], cache, next block [B, L])``:
+        ``out[:, 0]`` the positions' tokens (given ones included, at
+        their places), ``out[:, 1]`` for each the index inside its round
+        of the forward that fixed it (-1: given); columns from ``rounds
+        x L`` on are never written. The cache's counter leaf holds this
+        call's sums: the routing of every forward and, behind it, what
+        the rounds counted (``ops.block_diffusion.COUNTERS``)."""
+        from ..ops import block_diffusion as BD
+        if sampling.mode != "greedy":
+            raise NotImplementedError(
+                "generation by blocks chooses candidates greedily; "
+                "sampled candidates are not implemented")
+        opt = self.block
+        length = opt.block_length
+        b = block.shape[0]
+        at = self.cache_counters.index(BD.COUNTERS[0])
+        live = (jnp.ones((b,), bool) if pad is None
+                else pad < cache.length)
+        sub = cache._replace(v=jnp.zeros_like(cache.v))
+
+        def one_round(i, carry):
+            blk, c, out, tally = carry
+            blk = jnp.where(live[:, None], blk, 0)
+            masked0 = jnp.sum(blk == BD.MASKED, axis=-1, dtype=jnp.int32)
+            floor = BD.floor_of(masked0, opt.denoising_steps)
+            depth = c.length
+
+            def denoise(state):
+                blk, fixed_at, c, f, over_n = state
+                masked = blk == BD.MASKED
+                logits, c = self._forward_cached(
+                    params, jnp.where(masked, opt.mask_token_id, blk), c,
+                    pad)
+                cand, conf = BD.choose(logits, opt.mask_token_id)
+                fix, over = BD.transfer(masked, conf, floor, opt.remasking,
+                                        opt.confidence_threshold)
+                return (jnp.where(fix, cand, blk),
+                        jnp.where(fix, f, fixed_at),
+                        c._replace(length=depth), f + 1,
+                        over_n + jnp.sum(over, dtype=jnp.int32))
+
+            blk, fixed_at, c, forwards, over_n = jax.lax.while_loop(
+                lambda state: jnp.any(state[0] == BD.MASKED), denoise,
+                (blk, jnp.full_like(blk, -1), c, jnp.int32(0),
+                 jnp.int32(0)))
+            # the commit: its logits are read by nobody, so the compiled
+            # program runs no head for it
+            _, c = self._forward_cached(params, blk, c, pad)
+            out = jax.lax.dynamic_update_slice(
+                out, jnp.stack([blk, fixed_at], axis=1),
+                (jnp.uint32(0), jnp.uint32(0),
+                 (i * length).astype(jnp.uint32)))
+            tally = tally + jnp.stack([
+                forwards + 1, jnp.int32(1), jnp.int32(1), jnp.sum(masked0),
+                over_n, (forwards + 1) * jnp.sum(live, dtype=jnp.int32)])
+            return jnp.full_like(blk, BD.MASKED), c, out, tally
+
+        block, sub, out, tally = jax.lax.fori_loop(
+            0, rounds, one_round,
+            (block, sub, jnp.zeros((b, 2, room), jnp.int32),
+             jnp.zeros((len(BD.COUNTERS),), jnp.int32)))
+        sub = sub._replace(v=sub.v.at[at:at + len(BD.COUNTERS)].add(tally))
+        return out, sub, block
+
+    def _generate_blocks(self, prompt_ids, max_new_tokens: int,
+                         sampling: SamplingConfig) -> GenerateResult:
+        """``generate`` for a family that generates by blocks: each
+        row's whole blocks are prefilled, the rest of its prompt is
+        given to the first round, and as many rounds run as the longest
+        answer needs. Rows may differ in length (their pads are whole
+        blocks, so every row's block grid meets the batch's depth)."""
+        from ..ops import block_diffusion as BD
+        length = self.block.block_length
+        rows = ([np.asarray(prompt_ids, np.int32)]
+                if np.ndim(prompt_ids[0]) == 0 else
+                [np.asarray(r, np.int32).reshape(-1) for r in prompt_ids])
+        if max_new_tokens < 1:
+            raise ValueError("max_new_tokens must be >= 1")
+        given = [len(r) % length for r in rows]
+        kept = [len(r) - g for r, g in zip(rows, given)]
+        if min(kept) < length:
+            raise ValueError(f"a prompt needs at least one whole block of "
+                             f"{length} tokens")
+        depth = max(kept)
+        rounds = -(-(max(given) + max_new_tokens) // length)
+        if depth + rounds * length > self.max_seq:
+            raise ValueError(
+                f"prompt blocks {depth} + {rounds} rounds of {length} "
+                f"exceed max_seq={self.max_seq}; cache writes would "
+                "silently clamp")
+        ids = np.zeros((len(rows), depth), np.int32)
+        block = np.full((len(rows), length), BD.MASKED, np.int32)
+        for i, (r, k, g) in enumerate(zip(rows, kept, given)):
+            ids[i, depth - k:] = r[:k]
+            block[i, :g] = r[k:]
+        pad = np.asarray([depth - k for k in kept], np.int32)
+        pad_j = jnp.asarray(pad) if pad.any() else None
+        t0 = time.perf_counter()
+        run_params = self._run_params()
+        _, cache = self._prefill(run_params, jnp.asarray(ids), pad_j)
+        jax.block_until_ready(cache.k)
+        t1 = time.perf_counter()
+        tracing.record("prefill", t0, t1, batch=len(rows), prompt_len=depth,
+                       chunked=False)
+        out, cache, _ = self._decode_seg(
+            run_params, jnp.asarray(block), cache, pad_j,
+            jnp.zeros((rounds * length, 2), jnp.uint32), np.int32(rounds),
+            sampling=sampling, window=None)
+        counters = dict(zip(self.cache_counters, np.asarray(cache.v)))
+        del cache
+        out = np.asarray(out)
+        t2 = time.perf_counter()
+        forwards = int(counters["block_forwards"])
+        tracing.record("decode", t1, t2, batch=len(rows), steps=forwards,
+                       rounds=rounds, tokens=max_new_tokens)
+        self._note_compiles()
+        new = np.stack([out[i, :, g:g + max_new_tokens]
+                        for i, g in enumerate(given)])
+        prompts, lead = left_pad(rows)
+        return GenerateResult(
+            tokens=np.concatenate([prompts, new[:, 0]], axis=1),
+            prompt_len=prompts.shape[1], prefill_seconds=t1 - t0,
+            decode_seconds=t2 - t1, new_tokens=max_new_tokens,
+            decode_steps=forwards, pad=lead if lead.any() else None,
+            fixed_at=new[:, 1])
+
     # -- public API ----------------------------------------------------------
 
     def generate(self, prompt_ids, max_new_tokens: int,
@@ -1051,6 +1223,12 @@ class DecodeEngine:
         for ``stop_at_eos`` requests. May return fewer than
         ``max_new_tokens`` tokens (``GenerateResult.new_tokens``).
         """
+        if self.block is not None:
+            if pad is not None or eos_id is not None:
+                raise NotImplementedError(
+                    "generation by blocks takes ragged prompts as a list "
+                    "and stops at max_new_tokens")
+            return self._generate_blocks(prompt_ids, max_new_tokens, sampling)
         ids, batch, prompt_len, key, pad = prepare_generate(
             prompt_ids, max_new_tokens, self.max_seq, sampling, key, pad=pad)
 

@@ -271,7 +271,8 @@ GUARDED_STATE = {
     "grows": "_stats_lock", "preemptions": "_stats_lock",
     "resumes": "_stats_lock", "fault_parks": "_stats_lock",
     "batches_closed": "_stats_lock", "_turned": "_stats_lock",
-    "_moe": "_stats_lock", "_window": "_stats_lock",
+    "_moe": "_stats_lock", "_rounds": "_stats_lock",
+    "_window": "_stats_lock",
     "_parked": "_stats_lock",
     "_pending": "_stats_lock",
     "_np": "_lock",
@@ -383,6 +384,12 @@ class _SegOut:
                 self._np = np.array(self.arr, copy=True)
             return self._np
 
+    def row(self, row: int, plane: int = 0) -> np.ndarray:
+        """One row's columns: its tokens, or (``plane`` 1, a call of
+        rounds: ``engine._decode_rounds``) the forward that fixed each."""
+        a = self.np[row]
+        return a if a.ndim == 1 else a[plane]
+
 
 @dataclasses.dataclass
 class _Slot:
@@ -394,7 +401,12 @@ class _Slot:
                                   # rows: resumed_prefix replaces it)
     dk: Optional[jax.Array]       # per-row decode key (sample mode)
     emitted: int = 1              # tokens generated so far (incl. first)
-    segs: List = dataclasses.field(default_factory=list)  # (_SegOut, n)
+    # (_SegOut, lo, n): the row's n tokens of that call, from column lo
+    segs: List = dataclasses.field(default_factory=list)
+    # generation by blocks: positions of the row's NEXT block that its
+    # prompt already fills (its first round yields that many tokens
+    # fewer; 0 from then on, and for every other family)
+    given: int = 0
     # admission order: THE preemption priority (higher = admitted later
     # = preempted first). Monotonic across the scheduler's lifetime.
     order: int = 0
@@ -596,6 +608,27 @@ class IterBatchingEngine:
                 f"pool rows span {pool.max_seq} slots, engine cache is "
                 f"{engine._cache_seq}; gathered segments must match the "
                 "compiled programs' cache width")
+        # a family that generates by ROUNDS over blocks of ``_unit``
+        # positions (engine.block; ops.block_diffusion): a call is
+        # rounds, a row yields a whole block a round, and every depth,
+        # pad and stored chunk is whole blocks. 1: a step, a token.
+        self._blocks = engine.block is not None
+        self._unit = engine.block.block_length if self._blocks else 1
+        if self._blocks:
+            if spec is not None:
+                raise NotImplementedError(
+                    "a family that generates by blocks does not speculate")
+            for what, n in (("seg_steps", seg_steps),
+                            ("prompt_bucket", prompt_bucket),
+                            ("the prefix store's chunk",
+                             prefix.chunk if prefix is not None else 0),
+                            ("the pool's block_size",
+                             pool.block_size if pool is not None else 0)):
+                if n % self._unit:
+                    raise ValueError(
+                        f"{what}={n} is not whole blocks of {self._unit}: "
+                        "a depth, a pad or a stored chunk would end "
+                        "inside a block")
         self.engine = engine
         self.spec = spec
         self.prefix = prefix
@@ -689,9 +722,17 @@ class IterBatchingEngine:
         # (engine.cache_counters; models.latent_moe): what the decode
         # segments and the prefills handed back, added up as each
         # becomes ready. Empty for the dense families.
+        routing = [k for k in engine.cache_counters
+                   if not k.startswith("block_")]
         self._moe = dict.fromkeys(
-            [f"moe.{k}" for k in engine.cache_counters]
-            + [f"moe.prefill_{k}" for k in engine.cache_counters], 0)
+            [f"moe.{k}" for k in routing]
+            + [f"moe.prefill_{k}" for k in routing], 0)
+        # what the rounds of a family that generates by blocks counted
+        # (``block_*`` among the cache's counters;
+        # ``ops.block_diffusion.COUNTERS`` says what each is)
+        self._rounds = dict.fromkeys(
+            [f"block.{k[6:]}" for k in engine.cache_counters
+             if k.startswith("block_")], 0)
         # a family whose sliding-window layers hold a window of a row
         # and not its depth (``window_positions``; models.window_moe):
         # what the records allocated for the live rows hold and the
@@ -730,10 +771,19 @@ class IterBatchingEngine:
         prompt = np.asarray(prompt_ids, dtype=np.int32).reshape(-1)
         if prompt.size == 0:
             raise ValueError("prompt must be non-empty")
-        if len(prompt) + max_new_tokens > self.engine.max_seq:
+        # (a family that generates by blocks writes whole blocks: what
+        # the prompt leaves over and the answer, rounded up to one)
+        rest = len(prompt) % self._unit
+        if (len(prompt) - rest + _round_up(max_new_tokens + rest, self._unit)
+                > self.engine.max_seq):
             raise ValueError(
                 f"prompt_len={len(prompt)} + max_new_tokens="
                 f"{max_new_tokens} exceeds max_seq={self.engine.max_seq}")
+        if self._blocks and (len(prompt) < self._unit
+                             or sampling.mode != "greedy"):
+            raise ValueError(
+                f"generation by blocks of {self._unit} takes a prompt of "
+                "at least one block and chooses greedily")
         if sampling.mode != "greedy" and key is None:
             raise ValueError(
                 "sample-mode requests must carry a per-request PRNG key")
@@ -797,10 +847,16 @@ class IterBatchingEngine:
         # retirement (prefill + shared segments + scheduling), not a
         # pure decode window — an honest end-to-end number, but do not
         # read tokens_per_second as a device decode rate.
+        fixed_at = None
+        if self._blocks and s.resumed_prefix is None:
+            fixed_at = np.concatenate(
+                [seg.row(s.row, 1)[lo:lo + n] for seg, lo, n in s.segs]
+            )[None, :len(new)]
         return GenerateResult(
             tokens=tokens, prompt_len=s.plen,
             prefill_seconds=0.0, decode_seconds=s.done_t - s.t0,
-            new_tokens=len(new), decode_steps=len(new) - 1)
+            new_tokens=len(new), decode_steps=len(new) - 1,
+            fixed_at=fixed_at)
 
     def stats(self) -> dict:
         with self._stats_lock:
@@ -823,7 +879,8 @@ class IterBatchingEngine:
                    "resumes": self.resumes,
                    "fault_parks": self.fault_parks,
                    "batches_closed": self.batches_closed,
-                   **self._turned, **self._moe, **self._window,
+                   **self._turned, **self._moe, **self._rounds,
+                   **self._window,
                    "parked": len(self._parked)}
             resident, joined, rows, peak = (
                 self.state_calls_resident, self._state_joined,
@@ -1096,22 +1153,46 @@ class IterBatchingEngine:
     def _ent_req(e) -> _Req:
         return e.req if isinstance(e, _Parked) else e
 
-    @staticmethod
-    def _ent_ids(e) -> np.ndarray:
+    def _ent_ids(self, e) -> np.ndarray:
         """The tokens a seed/admission prefill forwards for this entry:
         the prompt, or — resuming a parked row — prompt + all emitted
         tokens but the last (the last is the live, not-yet-forwarded
-        token the segment loop carries)."""
+        token the segment loop carries). Generation by blocks: the
+        WHOLE BLOCKS of everything known (the prompt and what a parked
+        row emitted); the rest is given to the row's first round
+        (``_ent_block``)."""
+        if self._blocks:
+            known = self._ent_known(e)
+            return known[:len(known) - len(known) % self._unit]
         if isinstance(e, _Parked):
             return np.concatenate([e.req.prompt, e.tokens[:-1]])
         return e.prompt
 
     @staticmethod
-    def _ent_need(e) -> int:
-        """Cache slots the entry still needs past its prefill."""
+    def _ent_known(e) -> np.ndarray:
         if isinstance(e, _Parked):
-            return e.req.max_new_tokens - e.emitted + 1
-        return e.max_new_tokens
+            return np.concatenate([e.req.prompt, e.tokens])
+        return e.prompt
+
+    def _ent_block(self, e) -> np.ndarray:
+        """Generation by blocks: the entry's block as its first round
+        finds it, ``[L]``: what is known past its whole blocks, then
+        masked positions."""
+        from ..ops.block_diffusion import MASKED
+        known = self._ent_known(e)
+        block = np.full((self._unit,), MASKED, np.int32)
+        given = len(known) % self._unit
+        block[:given] = known[len(known) - given:]
+        return block
+
+    def _ent_need(self, e) -> int:
+        """Cache slots the entry still needs past its prefill."""
+        left = self._ent_req(e).max_new_tokens - (
+            e.emitted if isinstance(e, _Parked) else 0)
+        if self._blocks:
+            return _round_up(left + len(self._ent_known(e)) % self._unit,
+                             self._unit)
+        return left + 1 if isinstance(e, _Parked) else left
 
     def _seed(self, head) -> _BatchState:
         """Start a batch: gather same-policy parked rows first (they
@@ -1200,15 +1281,25 @@ class IterBatchingEngine:
         with grafttime.correlate([_rid_of(self._ent_req(e))
                                   for e in seed]):
             last_logits, cache = eng._prefill(run_params, ids_j, pad_j)
-        first, pks, dks = self._first_tokens(
-            last_logits, sampling, [self._ent_req(e).key for e in seed], b)
+        if self._blocks:
+            # the prefill yields no token: a row's first comes from its
+            # first round, which starts from what its prompt leaves over
+            # (a lane without a request has nothing masked)
+            dks = None
+            first = jnp.asarray(np.stack(
+                [self._ent_block(e) for e in seed]
+                + [np.zeros((self._unit,), np.int32)] * (b - len(seed))))
+        else:
+            first, pks, dks = self._first_tokens(
+                last_logits, sampling,
+                [self._ent_req(e).key for e in seed], b)
         # Resumed rows: the "first" token is the parked row's last
         # emitted token — KNOWN, never re-selected (greedy would
         # reproduce it from the recomputed logits; a sampled row's draw
         # came from an earlier step key, so the override is what makes
         # the resumed stream byte-identical).
         for i, e in enumerate(seed):
-            if isinstance(e, _Parked):
+            if isinstance(e, _Parked) and not self._blocks:
                 first = first.at[i].set(int(e.tokens[-1]))
         sp1 = time.perf_counter()
         covered = []                  # (trace, its prefill span)
@@ -1239,7 +1330,8 @@ class IterBatchingEngine:
         # the span's window is the dispatch; the shared first-token
         # array says when the prefill had run
         state.ready = tracing.READY.hand(
-            first, covered, counters=self._routing_counters(cache, True))
+            last_logits if self._blocks else first, covered,
+            counters=self._routing_counters(cache, True))
         if spec_mode:
             # verify-loop entry state (spec_decode._seg_b invariant): the
             # token buffer holds prompt + the unforwarded first token per
@@ -1262,7 +1354,8 @@ class IterBatchingEngine:
                 if isinstance(e, _Parked) and e.spec_key is not None:
                     keys = keys.at[i].set(jnp.asarray(e.spec_key))
             state.keys = keys
-        first_ref = _SegOut(first)          # one shared [B] fetch
+        # one shared [B] fetch (a prefill by blocks yields no token)
+        first_ref = None if self._blocks else _SegOut(first)
         state.slots = [None] * b
         n_res = 0
         for i, e in enumerate(seed):
@@ -1282,7 +1375,9 @@ class IterBatchingEngine:
                                        pad=int(pad[i]),
                                        first_ref=first_ref, first_idx=i,
                                        dk=None if dks is None else dks[i],
+                                       emitted=0 if self._blocks else 1,
                                        order=self._order, t0=t0)
+            state.slots[i].given = self._given(e)
         if self.pool is not None:
             self._init_tables(state)
         self._note_state_rows(len(seed))
@@ -1297,6 +1392,11 @@ class IterBatchingEngine:
         self._retire_finished(state)      # max_new_tokens == 1 rows
         self._set_gauges(state)
         return state
+
+    def _given(self, e) -> int:
+        """Positions of the entry's next block that are known already
+        (generation by blocks; 0 otherwise)."""
+        return len(self._ent_known(e)) % self._unit if self._blocks else 0
 
     def _fits(self, ents: List) -> bool:
         s_max = self._seed_smax(ents)
@@ -1333,8 +1433,10 @@ class IterBatchingEngine:
     def _seed_smax(self, ents: List) -> int:
         raw = max(len(self._ent_ids(e)) for e in ents)
         need = max(self._ent_need(e) for e in ents)
+        # (whole blocks, where the family generates by blocks)
         return min(self._bucketed(raw),
-                   self.engine.max_seq - need - self._reserve(ents[0]))
+                   (self.engine.max_seq - need - self._reserve(ents[0]))
+                   // self._unit * self._unit)
 
     def _first_tokens(self, last_logits, sampling, keys, b):
         """First-token selection + per-row (prefill, decode) key split.
@@ -1589,7 +1691,8 @@ class IterBatchingEngine:
                          resume: Optional[_Parked],
                          reserved: Optional[Tuple[int, List[int]]]):
         eng = self.engine
-        stream = self._ent_ids(resume) if resume is not None else req.prompt
+        ent = resume if resume is not None else req
+        stream = self._ent_ids(ent)
         plen_eff = len(stream)            # tokens the prefill forwards
         # timeline: the join/resume DECISION happens here — before the
         # admit prefill dispatch it causes
@@ -1646,18 +1749,21 @@ class IterBatchingEngine:
                     kind="resume" if resume is not None else "admit",
                     depth=state.depth, prompt_len=plen_eff, live=live)
         sampling = state.sampling
-        if sampling.mode == "greedy":
+        if self._blocks:
+            first, dk = jnp.asarray(self._ent_block(ent)), None
+        elif sampling.mode == "greedy":
             first = jnp.argmax(logits, axis=-1).astype(jnp.int32)[0]
             dk = None
         else:
             pk, dk = jax.random.split(jnp.asarray(req.key))
             first = select_token(logits, sampling, pk[None, :])[0]
-        if resume is not None:
+        if resume is not None and not self._blocks:
             # the live token is the parked row's last emitted one —
             # known, never re-selected (see _seed_batch)
             first = jnp.asarray(int(resume.tokens[-1]), jnp.int32)
         if pre is not None:
-            tracing.READY.hand(first, [(req.trace, pre)],
+            tracing.READY.hand(logits if self._blocks else first,
+                               [(req.trace, pre)],
                                counters=self._routing_counters(solo, True))
         if self.pool is not None:
             blk_lo, blk_ids = self._place_admitted(
@@ -1692,9 +1798,11 @@ class IterBatchingEngine:
         self._order += 1
         state.slots[slot] = _Slot(
             req=req, plen=plen, row=slot, pad=state.depth - plen_eff,
-            first_ref=None if resume is not None else _SegOut(first[None]),
-            first_idx=0, dk=dk, t0=t0,
-            emitted=resume.emitted if resume is not None else 1,
+            first_ref=(None if resume is not None or self._blocks
+                       else _SegOut(first[None])),
+            first_idx=0, dk=dk, t0=t0, given=self._given(ent),
+            emitted=(resume.emitted if resume is not None
+                     else 0 if self._blocks else 1),
             resumed_prefix=resume.tokens if resume is not None else None,
             order=resume.order if resume is not None else self._order,
             fault_budget_used=(resume.fault_budget_used
@@ -1714,8 +1822,7 @@ class IterBatchingEngine:
             REGISTRY.inc("kv_pool_resumes_total")
         else:
             REGISTRY.inc("iter_joins_total")
-        if req.max_new_tokens <= (resume.emitted if resume is not None
-                                  else 1):
+        if req.max_new_tokens <= state.slots[slot].emitted:
             self._retire_finished(state)
 
     # -- paged storage (pool mode) -------------------------------------------
@@ -1976,8 +2083,11 @@ class IterBatchingEngine:
             got = dict(zip(names, values))
             with self._stats_lock:
                 for k, v in got.items():
-                    self._moe[f"moe.prefill_{k}" if prefill
-                              else f"moe.{k}"] += v
+                    if k.startswith("block_"):
+                        self._rounds[f"block.{k[6:]}"] += v
+                    else:
+                        self._moe[f"moe.prefill_{k}" if prefill
+                                  else f"moe.{k}"] += v
             return family.span_labels(got, config, prefill)
         return jnp.copy(cache.v), label
 
@@ -2041,17 +2151,23 @@ class IterBatchingEngine:
         # on the host a call ahead of the device, with no fetch): that
         # row is answered at its last token and its slot and blocks
         # come back at this call's end
-        longest = min(self.seg_steps, eng.max_seq - d)
-        n = min(longest, *(s.req.max_new_tokens - s.emitted
+        # (a family that generates by blocks: ``n`` ROUNDS, each a
+        # whole block of ``unit`` positions a row, which the host knows
+        # a call ahead just the same; only the forwards are data)
+        unit = self._unit
+        longest = min(self.seg_steps, eng.max_seq - d) // unit
+        n = min(longest, *(-(-(s.req.max_new_tokens - s.emitted + s.given)
+                             // unit)
                            for s in state.slots if s is not None))
         assert n >= 1, "active rows past max_seq or budget (admission bug)"
-        window = eng._decode_window(d + n)   # shared bucket policy
+        span = n * unit                      # positions the call writes
+        window = eng._decode_window(d + span)   # shared bucket policy
         pooled = self.pool is not None
         if pooled:
             # grow every live row's block range to cover this segment's
             # writes — THE preemption point (youngest row parks when
             # even LRU eviction cannot free enough blocks)
-            self._ensure_blocks(state, d + n)
+            self._ensure_blocks(state, d + span)
             if not state.active():
                 return  # everyone preempted (single-row pool squeeze)
         # the batch's own working cache, its rows' state in it; a batch
@@ -2070,10 +2186,10 @@ class IterBatchingEngine:
         if pooled:
             bs = self.pool.block_size
             if resident:
-                # only the columns that hold positions [d, d + n)
+                # only the columns that hold positions [d, d + span)
                 self.pool.scatter_span(cache, state.tables, d // bs,
                                        self._span)
-                wrote = (d + n - 1) // bs - d // bs + 1
+                wrote = (d + span - 1) // bs - d // bs + 1
             else:
                 self.pool.scatter(cache, state.tables)
                 wrote = self.pool.nbm
@@ -2082,8 +2198,9 @@ class IterBatchingEngine:
                 self._slab.note_compiles()   # the store's two movers
         if resident:
             state.cache = cache
-        self._count_stream(state, d, n)
-        state.depth = d + n
+        if not self._blocks:         # the decode kernel's stream
+            self._count_stream(state, d, n)
+        state.depth = d + span
         self._in_flight.append(out)
         seg = _SegOut(out)
         t1 = time.perf_counter()
@@ -2107,19 +2224,43 @@ class IterBatchingEngine:
                          else "iter_cache_gathers_total")
             REGISTRY.inc("kv_pool_blocks_written_back_total",
                          value=live * wrote)
-        covered = []
+        covered, took = [], []
         for s in state.slots:
             if s is not None:
-                s.segs.append((seg, n))
-                s.emitted += n
+                # the row's own yield: a first round's block holds
+                # ``given`` positions of the prompt, a last one may reach
+                # past the budget
+                lo, s.given = s.given, 0
+                take = min(span - lo, s.req.max_new_tokens - s.emitted)
+                s.segs.append((seg, lo, take))
+                s.emitted += take
                 if s.req.trace is not None:
                     # the window is the DISPATCH (segments queue
                     # asynchronously on the device — the serving-thread
-                    # view); the waiter stamps when the segment had run
+                    # view); the waiter stamps when the segment had run.
+                    # A call of rounds says its rounds and the row's
+                    # tokens now; ``steps`` (the FORWARDS it ran) and
+                    # ``fixed_at`` are the device's to say (below)
                     covered.append((s.req.trace, s.req.trace.add_span(
                         "decode", t0, t1, seg=seg_no, batch=state.batch,
-                        steps=n, width=len(state.slots), depth=state.depth,
+                        **({"rounds": n, "tokens": take} if self._blocks
+                           else {"steps": n}),
+                        width=len(state.slots), depth=state.depth,
                         **({"blocks": len(s.blk_ids)} if pooled else {}))))
+                    took.append((s.row, lo, take))
+        if self._blocks:
+            counted = routing[1]
+            ran = eng.cache_counters.index("block_forwards")
+
+            def forwards_and_fixes(values):
+                # every row's span: the forwards the call ran (a pass
+                # over the weights each: what ``steps`` means to every
+                # reader) and, for each token the row got from it, the
+                # forward inside its round that fixed it
+                said = dict(counted(values), steps=values[ran])
+                return [dict(said, fixed_at=seg.row(row, 1)[lo:lo + take]
+                             .tolist()) for row, lo, take in took]
+            routing = (routing[0], forwards_and_fixes)
         prev = state.ready
 
         def observe(at):
@@ -2383,9 +2524,12 @@ class IterBatchingEngine:
             # a resumed row's pre-preemption tokens were fetched at the
             # park; segments since the resume append after them
             parts = [s.resumed_prefix]
-        else:
+        elif s.first_ref is not None:
             parts = [s.first_ref.np[s.first_idx:s.first_idx + 1]]
-        parts += [seg.np[s.row][:n] for seg, n in s.segs]
+        else:
+            # generation by blocks: a prefill yields no token
+            parts = [np.zeros((0,), np.int32)]
+        parts += [seg.row(s.row)[lo:lo + n] for seg, lo, n in s.segs]
         return np.concatenate(parts)[:s.req.max_new_tokens]
 
     def _deliver(self, state: _BatchState, i: int, s: _Slot, eos_at):

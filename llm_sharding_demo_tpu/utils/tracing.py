@@ -232,7 +232,8 @@ class _Handover:
         self.at: Optional[float] = None
         # ``(device vector, label(values) -> dict)``: numbers the device
         # computed beside ``array`` (a segment's routing counters), read
-        # once the array exists and written onto the covered spans
+        # once the array exists and written onto the covered spans (a
+        # list of dicts: one a covered span, in their order)
         self.counters = counters
         self.values = None
 
@@ -262,8 +263,10 @@ class _Handover:
                     # computed by the program that made ``array``
                     self.values = counters[0].tolist()
                     labels = counters[1](self.values)
-                    for s in covers:
-                        s.labels.update(labels)
+                    if isinstance(labels, dict):     # the same on each
+                        labels = [labels] * len(covers)
+                    for s, said in zip(covers, labels):
+                        s.labels.update(said)
                 except Exception:  # noqa: BLE001 — as above: the
                     pass           # request fails on its own path
         for s in covers:

@@ -19,8 +19,8 @@ import pytest
 
 from llm_sharding_demo_tpu import models
 from llm_sharding_demo_tpu.models import (gdn_moe, gpt2, hybrid_ssm, kda_moe,
-                                          latent_moe, llama, moe, stack,
-                                          window_moe)
+                                          latent_moe, llama, moe, sdar_moe,
+                                          stack, window_moe)
 from llm_sharding_demo_tpu.models.family import REFUSABLE, Family
 from llm_sharding_demo_tpu.runtime.engine import DecodeEngine
 from llm_sharding_demo_tpu.utils.config import ServingConfig
@@ -38,11 +38,12 @@ TINY = {
     "window_moe": window_moe.CONFIGS["window-moe-tiny"],
     "hybrid_ssm": hybrid_ssm.CONFIGS["hybrid-ssm-tiny"],
     "kda_moe": kda_moe.CONFIGS["kda-moe-tiny"],
+    "sdar_moe": sdar_moe.CONFIGS["sdar-moe-tiny"],
 }
 MODULES = {"gpt2": gpt2, "moe": moe, "llama": llama,
            "latent_moe": latent_moe, "gdn_moe": gdn_moe,
            "window_moe": window_moe, "hybrid_ssm": hybrid_ssm,
-           "kda_moe": kda_moe}
+           "kda_moe": kda_moe, "sdar_moe": sdar_moe}
 
 # what each served family says to each option it refuses: the parent's
 # sentences (PR 48's ``serving/app.py``), word for word, before the
@@ -143,6 +144,27 @@ REFUSED = {
     ("kda_moe", "int8_weights"):
         "INFERENCE_DTYPE=int8: KDAMoEConfig indexes its experts' plain "
         "weight stacks; it serves float32 or bfloat16",
+    # (new with the family, PR 50: a step that yields a block)
+    ("sdar_moe", "spec_decode"):
+        "SPEC_DECODE: SDARMoEConfig generates by rounds of a whole block; "
+        "a draft-verify loop over single tokens has no place in a round; "
+        "serve it without speculation",
+    ("sdar_moe", "kv_pool_dtype"):
+        "KV_POOL_DTYPE=int8: SDARMoEConfig's pool is one plane with "
+        "counters in its second leaf, and a round reads the block it is "
+        "writing; the quantized movers have not been fitted to it",
+    ("sdar_moe", "kv_host_blocks"):
+        "KV_HOST_BLOCKS: no deployment of SDARMoEConfig has needed the "
+        "host tier yet and none has been checked against a demoted block "
+        "boundary; serve it from the device pool",
+    ("sdar_moe", "multi_chip"):
+        "PP/TP/EP_DECODE: no multi-chip decoder stages or shards "
+        "SDARMoEConfig (a round's forwards are one program's loop, experts "
+        "indexed in place); it serves on one chip, told which experts it "
+        "holds",
+    ("sdar_moe", "int8_weights"):
+        "INFERENCE_DTYPE=int8: SDARMoEConfig indexes its experts' plain "
+        "weight stacks; it serves float32 or bfloat16",
 }
 
 
@@ -200,6 +222,16 @@ SAID = {
         state=(((7, 4, 16, 16), F32), ((7, 3, 192), BF16)),
         counters=COUNTERS, bounds=True, fresh=True, own_kernel=True,
         bucket=True, refuses=STATEFUL + ("int8_weights",)),
+    # (new with the family, PR 50; behind the routing counters, what its
+    # rounds count)
+    "sdar_moe": says(
+        (1, 2, 32), 3, [((3, 3, 2, 32, 32), BF16), ((11,), I32), ((), I32)],
+        counters=COUNTERS + ("block_forwards", "block_rounds",
+                             "block_commits", "block_tokens_fixed",
+                             "block_fixed_over_threshold",
+                             "block_row_forwards"),
+        bounds=True, fresh=True, own_kernel=True,
+        refuses=STATEFUL + ("int8_weights",)),
 }
 
 
