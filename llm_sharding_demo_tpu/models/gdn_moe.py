@@ -421,11 +421,13 @@ def _gated_attention(attn: Params, a: jnp.ndarray, config: GDNMoEConfig,
 
 def expert_layer(moe: Params, experts: Params, m: jnp.ndarray,
                  config: GDNMoEConfig, layer_idx,
+                 kernel: Optional[str] = None,
                  ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """The feed-forward of every layer on ``m`` [B, T, d] normed: the
     held experts' weighted terms plus the gated shared expert. ``moe``
     holds this layer's router, shared expert and its gate, ``experts``
-    the WHOLE ``[n_layer, E, ...]`` stacks. Returns ``(out, counts
+    the WHOLE ``[n_layer, E, ...]`` stacks, ``kernel`` what the engine
+    resolved (``ops.expert_ffn``). Returns ``(out, counts
     [n_routed_experts])``."""
     c = config
     b, t, d = m.shape
@@ -437,7 +439,7 @@ def expert_layer(moe: Params, experts: Params, m: jnp.ndarray,
     with jax.named_scope("moe_experts"):
         y, counts = expert_ffn.held_experts_ffn(
             x, ids, w, experts["gate"]["kernel"], experts["up"]["kernel"],
-            experts["down"]["kernel"], layer_idx, c.first_expert)
+            experts["down"]["kernel"], layer_idx, c.first_expert, kernel)
     with jax.named_scope("moe_shared"):
         share = jax.nn.sigmoid(
             linear(x, moe["shared_gate"]["kernel"]).astype(jnp.float32))
@@ -474,7 +476,8 @@ def apply_blocks(params: Params, h: jnp.ndarray, config: GDNMoEConfig,
 
         def feed(moe, layer):
             def ffn(m):
-                out, counts = expert_layer(moe, experts, m, c, layer)
+                out, counts = expert_layer(moe, experts, m, c, layer,
+                                           decode_kernel)
                 seen.append(counts)
                 return out
             return ffn

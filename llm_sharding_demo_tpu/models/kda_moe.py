@@ -491,7 +491,7 @@ def _layer(p: Params, experts: Params, h, held, li, expert_layer_idx,
         if "mlp" in p:
             return swiglu(p["mlp"], m)
         out, counts = expert_layer(p["moe"], experts, m, config,
-                                   expert_layer_idx)
+                                   expert_layer_idx, kernel)
         seen.append(counts)
         return out
 

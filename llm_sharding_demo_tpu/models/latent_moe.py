@@ -349,12 +349,15 @@ def _attention(attn: Params, a: jnp.ndarray, config: LatentMoEConfig,
 
 def expert_layer(moe: Params, experts: Params, m: jnp.ndarray,
                  config: LatentMoEConfig, layer_idx,
+                 kernel: Optional[str] = None,
                  ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """The expert feed-forward on ``m`` [B, S, d] normed: the held
     experts' weighted terms plus the shared expert. ``moe`` holds this
     layer's router and shared expert, ``experts`` the WHOLE ``[layers,
     experts, ...]`` stacks (indexed inside, so that only chosen experts
-    are read). Returns ``(out, counts [n_routed_experts])``."""
+    are read), ``kernel`` what the engine resolved (the held experts'
+    tiles as ``ops.expert_ffn``'s kernel, or its loop for ``None``).
+    Returns ``(out, counts [n_routed_experts])``."""
     c = config
     b, s, d = m.shape
     x = m.reshape(b * s, d)
@@ -365,7 +368,7 @@ def expert_layer(moe: Params, experts: Params, m: jnp.ndarray,
     with jax.named_scope("moe_experts"):
         y, counts = expert_ffn.held_experts_ffn(
             x, ids, w, experts["gate"]["kernel"], experts["up"]["kernel"],
-            experts["down"]["kernel"], layer_idx, c.first_expert)
+            experts["down"]["kernel"], layer_idx, c.first_expert, kernel)
     with jax.named_scope("moe_shared"):
         y = y + swiglu(moe["shared"], x)
     return y.reshape(b, s, d), counts
@@ -422,7 +425,7 @@ def apply_blocks(params: Params, h: jnp.ndarray, config: LatentMoEConfig,
         return swiglu(p["mlp"], m), None
 
     def expert_ffn_(p, m, li):
-        return expert_layer(p["moe"], experts, m, c, li)
+        return expert_layer(p["moe"], experts, m, c, li, decode_kernel)
 
     carry = (h, latent)
     for stack, first_layer, ffn in (

@@ -209,11 +209,13 @@ def _attention(attn: Params, a: jnp.ndarray, config: SDARMoEConfig,
 
 def expert_layer(router: jnp.ndarray, experts: Params, m: jnp.ndarray,
                  config: SDARMoEConfig, layer_idx,
+                 kernel: Optional[str] = None,
                  ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """The feed-forward on ``m`` [B, T, d] normed: the held experts'
     weighted terms and nothing else. ``experts`` holds the WHOLE
     ``[n_layer, held, ...]`` stacks (indexed inside, so that only chosen
-    experts are read). Returns ``(out, counts [held])``."""
+    experts are read), ``kernel`` what the engine resolved
+    (``ops.expert_ffn``). Returns ``(out, counts [held])``."""
     c = config
     b, t, d = m.shape
     x = m.reshape(b * t, d)
@@ -223,7 +225,7 @@ def expert_layer(router: jnp.ndarray, experts: Params, m: jnp.ndarray,
     with jax.named_scope("moe_experts"):
         y, counts = expert_ffn.held_experts_ffn(
             x, ids, w, experts["gate"]["kernel"], experts["up"]["kernel"],
-            experts["down"]["kernel"], layer_idx, c.first_expert)
+            experts["down"]["kernel"], layer_idx, c.first_expert, kernel)
     return y.reshape(b, t, d), counts
 
 
@@ -234,8 +236,10 @@ def apply_blocks(params: Params, h: jnp.ndarray, config: SDARMoEConfig,
                  ) -> Tuple[jnp.ndarray, Optional[KVCache]]:
     """All the layers, one ``lax.scan``; the cache rides the carry, the
     experts' stacks stay outside the scanned leaves, as loop constants.
-    ``decode_kernel`` is accepted for the family surface: a block's
-    forward is several positions and takes the masked einsum."""
+    ``decode_kernel`` is what the engine resolved: the held experts'
+    tiles run as ``ops.expert_ffn``'s kernel under it (a block's
+    forward is several positions and its attention takes the masked
+    einsum either way)."""
     c = config
     offset = 0 if cache is None else cache.length
     kv = None if cache is None else cache.k
@@ -248,7 +252,7 @@ def apply_blocks(params: Params, h: jnp.ndarray, config: SDARMoEConfig,
 
         def ffn(m):
             out, counts = expert_layer(p["moe"]["router"]["kernel"], experts,
-                                       m, c, li)
+                                       m, c, li, decode_kernel)
             seen.append(counts)
             return out
 
@@ -289,16 +293,20 @@ def forward_with_cache(params: Params, input_ids: jnp.ndarray,
     each of its positions (a round's forward); a longer one (a prefill,
     a stride of the prefix store) its last position's alone.
     ``flash_prefill`` is the engine's static word that the cache is
-    fresh: nothing cached is read then."""
+    fresh: nothing cached is read then; ``decode_kernel`` what it
+    resolved (``"device"``, ``"interpret"`` or ``None``)."""
     c = config
     t = input_ids.shape[1]
+    if decode_kernel not in (None, "device", "interpret"):
+        raise ValueError(f"decode_kernel={decode_kernel!r}: this family "
+                         "has the per-layer kernels only")
     if t % c.block_length:
         raise ValueError(f"a cached call forwards whole blocks of "
                          f"{c.block_length}, got {t} positions")
     h = stack.embed(params, input_ids)
     cos, sin = stack.angles(c.head_dim, c.rope_theta, t, cache.length, pad)
     h, cache = apply_blocks(params, h, c, cos, sin, cache, pad,
-                            fresh=flash_prefill)
+                            fresh=flash_prefill, decode_kernel=decode_kernel)
     if t != c.block_length:
         h = h[:, -1:]
     return stack.head(params, h, c.rms_norm_eps), cache

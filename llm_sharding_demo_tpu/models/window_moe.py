@@ -367,7 +367,8 @@ def apply_blocks(params: Params, h: jnp.ndarray, config: WindowMoEConfig,
                     with jax.named_scope("dense_ffn"):
                         return swiglu(p["mlp"], m)
                 out, counts = expert_layer(p["moe"], experts, m, c,
-                                           layer - c.first_k_dense)
+                                           layer - c.first_k_dense,
+                                           decode_kernel)
                 seen.append(counts)
                 return out
 

@@ -183,10 +183,12 @@ def test_routing_bias_chooses_and_scores_weigh():
     np.testing.assert_allclose(np.asarray(raw), picked, rtol=1e-6)
 
 
+@pytest.mark.parametrize("kernel", [None, "interpret"],
+                         ids=["loop", "kernel"])
 @pytest.mark.parametrize("b,s", [(4, 40), (8, 1), (16, 1)],
                          ids=["general", "one-tile-an-expert", "widest-step"])
 def test_a_tokens_output_is_bit_equal_alone_in_a_batch_and_in_a_chunk(
-        whole, b, s):
+        whole, b, s, kernel):
     """Window independence: no capacity, no dropped token, tiles of one
     shape summed in expert order. Bit-equal wherever the token sits and
     whatever shares its forward: alone among zeros, among other mates,
@@ -197,7 +199,10 @@ def test_a_tokens_output_is_bit_equal_alone_in_a_batch_and_in_a_chunk(
     general grouped matmul (160 tokens) and through the form for at most
     ``TILE`` pairs that a decode step takes (8 and 16 rows of one
     position), whose routed part is one ``[TILE, d]`` program at every
-    width: THAT is bit-equal across widths too."""
+    width: THAT is bit-equal across widths too. With the tiles as the
+    loop and as the one kernel (``ops.expert_ffn.held_expert_tiles``,
+    interpreted), whose tile shape, tile order and chunk order follow
+    the shapes and the hit list alone."""
     _, cfg, params = whole
     moe = dict(jax.tree.map(lambda a: a, params["blocks"]["moe"]))
     experts = moe.pop("experts")
@@ -209,7 +214,8 @@ def test_a_tokens_output_is_bit_equal_alone_in_a_batch_and_in_a_chunk(
 
     def run(tokens, shape=(b, s)):
         out, counts = latent_moe.expert_layer(
-            layer, experts, jnp.asarray(tokens.reshape(*shape, 64)), cfg, 2)
+            layer, experts, jnp.asarray(tokens.reshape(*shape, 64)), cfg, 2,
+            kernel)
         assert int(counts.sum()) == len(tokens) * 4              # none dropped
         return np.asarray(out).reshape(len(tokens), 64)
 
@@ -234,7 +240,7 @@ def test_a_tokens_output_is_bit_equal_alone_in_a_batch_and_in_a_chunk(
             y, _ = expert_ffn.held_experts_ffn(
                 jnp.asarray(x[rows]), ids[rows], w[rows],
                 experts["gate"]["kernel"], experts["up"]["kernel"],
-                experts["down"]["kernel"], 2, 0)
+                experts["down"]["kernel"], 2, 0, kernel)
             return np.asarray(y)
 
         every = routed(slice(0, t))

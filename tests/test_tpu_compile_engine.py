@@ -21,7 +21,7 @@ from benchmark.harness import server
 from benchmark.harness.spec import Spec, resolve
 from benchmark.rehearse import _Abstract
 from llm_sharding_demo_tpu.models import gpt2
-from llm_sharding_demo_tpu.ops import gated_delta
+from llm_sharding_demo_tpu.ops import expert_ffn, gated_delta
 from llm_sharding_demo_tpu.runtime.engine import DecodeEngine, SamplingConfig
 from llm_sharding_demo_tpu.runtime.prefix_cache import PrefixCachingEngine
 
@@ -60,24 +60,30 @@ PREFILL_TEMPORARIES = {"mistral-7b-l16": 1.0, "joyai-llm-flash-ep16": 2.0,
 
 
 # Device operations in ONE iteration of a layer loop of the compiled
-# decode segment at the cells' whole depth (the loop's body, and for the
-# latent family the body of the loop over the experts that were hit
-# inside it): every instruction the compiler schedules there but the
-# ones below, which move or name data without a launch. A budget is the
+# decode segment at the cells' whole depth (the loop's body): every
+# instruction the compiler schedules there but the ones below, which
+# move or name data without a launch. A budget is the
 # number reached, not looser, so that a change which grows a layer
 # back shows here; Mistral's is pinned, so that a shared function's
 # change that reaches it shows whichever way it goes.
 # Before the single-position forms (ISSUE 31) the expert layer stood at
-# 103 + 24 a tile at width 1 and 88, 96, 96, 92 + 24 at widths 2 to 16.
+# 103 + 24 a tile at width 1 and 88, 96, 96, 92 + 24 at widths 2 to 16;
+# with them, and the held experts' tiles a loop over the experts that
+# were hit, at (67, 61, 65, 65, 60) + 7 a hit expert. Since ISSUE 51 the
+# tiles are ONE ``held_expert_tiles`` kernel a layer and no loop: the
+# second number is how many such kernels a layer holds, and the first is
+# no looser than the loop's layer with ONE expert hit (74, 68, 72, 72,
+# 67): the hit list and the weighted sum around the kernel cost 6, 2,
+# -2, -2 and 2 operations a LAYER where the loop cost 7 a hit expert.
 NOT_OPERATIONS = {"parameter", "tuple", "get-tuple-element", "bitcast",
                   "reshape", "constant", "copy-done"}
 LAYER_OPERATIONS = {
     ("mistral-7b-l16", 8): (33, None),
-    ("joyai-llm-flash-ep16", 1): (67, 7),
-    ("joyai-llm-flash-ep16", 2): (61, 7),
-    ("joyai-llm-flash-ep16", 4): (65, 7),
-    ("joyai-llm-flash-ep16", 8): (65, 7),
-    ("joyai-llm-flash-ep16", 16): (60, 7),
+    ("joyai-llm-flash-ep16", 1): (73, 1),
+    ("joyai-llm-flash-ep16", 2): (63, 1),
+    ("joyai-llm-flash-ep16", 4): (63, 1),
+    ("joyai-llm-flash-ep16", 8): (63, 1),
+    ("joyai-llm-flash-ep16", 16): (62, 1),
 }
 # what the single-position forms took out of a latent layer and must
 # not come back: the up-projections' copy into a head-major layout and
@@ -141,10 +147,14 @@ def _decode_segment(chip, eng, params, batch, counted=True):
     shape = chip.shape
     cache = chip.placed(jax.eval_shape(lambda: eng._fresh_cache(batch)))
     steps = (shape((), jnp.int32),) if counted else ()
+    # a family that generates by blocks takes each row's block and runs
+    # ROUNDS (``engine._decode_rounds``)
+    token = (batch,) if eng.block is None else (batch,
+                                                eng.block.block_length)
     return jax.jit(
         eng._decode_seg_impl, donate_argnums=(2,),
         static_argnames=("sampling", "window")).lower(
-            params, shape((batch,), jnp.int32), cache,
+            params, shape(token, jnp.int32), cache,
             shape((batch,), jnp.int32),
             shape((SEG_STEPS, batch, 2), jnp.uint32), *steps,
             sampling=SamplingConfig(mode="greedy"), window=None
@@ -175,6 +185,15 @@ def _loops(text):
             loop = re.search(r" while\(.*body=%?([\w.\-]+)", line)
             if loop:
                 found[loop.group(1)] = holder, lines[loop.group(1)]
+    return found
+
+
+def _expert_kernels(lines):
+    """The ``held_expert_tiles`` kernels among a computation's
+    instructions (ISSUE 51: one an expert layer)."""
+    found = [x for x in lines
+             if re.search(rf"%{expert_ffn.KERNEL_NAME}[.\d]* = ", x)]
+    assert all("tpu_custom_call" in x for x in found), found
     return found
 
 
@@ -313,7 +332,7 @@ def test_decode_segment_layer_operations(one_chip, built, name, batch):
     cell's WHOLE depth (at two layers the compiler unrolls the layer
     loops into the step's body and there is nothing to count), its
     steps' loop holding the layer loop and that, for the latent family,
-    the loop over the experts that were hit."""
+    ONE kernel over the experts that were hit and no loop (ISSUE 51)."""
     eng, params = built(name, None)
     compiled, _ = _decode_segment(one_chip, eng, params, batch)
     loops = _loops(compiled.as_text())
@@ -322,21 +341,19 @@ def test_decode_segment_layer_operations(one_chip, built, name, batch):
     layers = [b for b, (holder, _) in loops.items() if holder == steps[0]]
     assert len(layers) == 1, sorted(loops)
     layer = _operations(loops[layers[0]][1])
-    tiles = [_operations(lines) for holder, lines in loops.values()
-             if holder == layers[0]]
-    want_layer, want_tile = LAYER_OPERATIONS[name, batch]
+    inside = [b for b, (holder, _) in loops.items() if holder == layers[0]]
+    want_layer, want_kernels = LAYER_OPERATIONS[name, batch]
     listed = "\n".join([f"{len(layer)} operations in a layer:"] + layer)
-    if want_tile is None:           # pinned, not a budget
-        assert len(layer) == want_layer and not tiles, listed
+    assert not inside, (inside, listed)    # no loop over experts, or any
+    if want_kernels is None:        # pinned, not a budget
+        assert len(layer) == want_layer, listed
         return
     assert len(layer) <= want_layer, listed
-    assert len(tiles) == 1 and len(tiles[0]) <= want_tile, "\n".join(
-        [f"{[len(t) for t in tiles]} operations a tile:"]
-        + [x for t in tiles for x in t])
+    assert len(_expert_kernels(loops[layers[0]][1])) == want_kernels, listed
     # at 512 rows the fold's own [512, 4096] product has W_uv's shape
     own_product = batch * eng.config.n_head == 512
     for gone in (GONE[1:] if own_product else GONE):
-        back = [x for x in layer + tiles[0] if re.search(gone, x)]
+        back = [x for x in layer if re.search(gone, x)]
         assert not back, back
 
 
@@ -344,33 +361,34 @@ def test_decode_segment_layer_operations(one_chip, built, name, batch):
 # loop runs over PERIODS: one iteration is three linear-attention layers
 # (two projections, the convolution, the state kernel, the gated norm,
 # the output projection) and one gated softmax layer, each with its
-# router, shared expert and loop over the held experts that were hit:
-# (operations in a period outside those loops, in one expert loop's
-# body), the numbers reached (ISSUE 35): 74 and 79 a layer beside the
-# latent layer's 67 and 60. At 16 rows the 160 (row, choice) pairs are
-# more than a tile, so routing and the experts take the general forms
-# (a sort, 29 operations a tile); up to 12 rows the single-position
-# ones (7 a tile, no sort).
+# router, shared expert and, since ISSUE 51, ONE kernel over the held
+# experts that were hit: (operations in a period, such kernels in it).
+# With a loop over those experts a layer the numbers reached were (297,
+# 7 a hit expert) and (314, 29) (ISSUE 35): at 16 rows the 160 (row,
+# choice) pairs are more than a tile, so routing takes its general form
+# (a sort) and the loop took its own (29 operations a tile); the kernel
+# takes one tile an expert wherever the TOKENS fit a tile, so the
+# experts' sort, gathers and scatters are gone there too. The numbers
+# reached, under the loop's period ALONE (289 and 277 against 297 and
+# 314, before its 7 and 29 operations a hit expert).
 PERIOD_OPERATIONS = {
-    (GDN, 1): (297, 7),
-    (GDN, 16): (314, 29),
+    (GDN, 1): (289, 4),
+    (GDN, 16): (277, 4),
 }
 # The window / expert family at its whole depth is two periods: the
 # first on its own leaves in the step's body, the second the one
 # iteration of its loop over periods, which the compiler unrolls into
-# the step's body too. So a STEP is counted: (operations in a step
-# outside the loops over the experts that were hit, in one such loop's
-# body), the numbers reached (ISSUE 37). Eight layers, of which six read
-# a ring (a select, two dots and a softmax in XLA) and two run the
-# two-plane decode kernel; seven loops over experts.
-# Since ISSUE 39 the program counted is the one whose steps are counted
-# by an operand: at one row its step reads 612 where the scan of 32
-# reads 610 (the count's conversion for the token buffer's row and
-# two prefetches the compiler places otherwise, against a comparison and
-# a fusion of the scan's: none of them a layer's); at 16 rows the same.
+# the step's body too. So a STEP is counted: (operations in a step,
+# ``held_expert_tiles`` kernels in it), the numbers reached. Eight
+# layers, of which six read a ring (a select, two dots and a softmax in
+# XLA) and two run the two-plane decode kernel; seven expert layers.
+# With a loop over the hit experts a layer they were (612, 7 a hit
+# expert) and (547, 7) (ISSUEs 37, 39: the program whose steps are
+# counted by an operand): 549 is under the loop's step alone, 578 under
+# the loop's step with one expert hit a layer (547 + 7 x 7 = 596).
 STEP_OPERATIONS = {
-    (SWA, 1): (612, 7),
-    (SWA, 16): (547, 7),
+    (SWA, 1): (549, 7),
+    (SWA, 16): (578, 7),
 }
 
 
@@ -384,19 +402,22 @@ def test_decode_segment_period_operations(one_chip, built, name, batch):
     periods = [b for b, (holder, _) in loops.items() if holder == steps[0]]
     assert len(periods) == 1, sorted(loops)
     period = _operations(loops[periods[0]][1])
-    tiles = [_operations(lines) for holder, lines in loops.values()
-             if holder == periods[0]]
-    want_period, want_tile = PERIOD_OPERATIONS[name, batch]
+    want_period, want_kernels = PERIOD_OPERATIONS[name, batch]
+    experts = _expert_kernels(loops[periods[0]][1])
     kernels = [x for x in period if "custom-call" in x]
-    # three state kernels and one two-plane decode kernel a period
-    assert len(kernels) == 4, kernels
+    # three state kernels and one two-plane decode kernel a period,
+    # and the held experts' tiles of each of its four layers
+    assert len(experts) == want_kernels, experts
+    assert len(kernels) == 4 + want_kernels, kernels
     assert len(period) <= want_period, "\n".join(
         [f"{len(period)} operations in a period:"] + period)
-    # one loop over the experts that were hit a layer
-    assert len(tiles) == 4 and max(map(len, tiles)) <= want_tile, "\n".join(
-        [f"{[len(t) for t in tiles]} operations a tile:"]
-        + [x for t in tiles for x in t])
-    assert not [x for x in period if re.search(r" sort\(", x)] or batch > 12
+    # no loop over the experts that were hit, or any other, in a period
+    assert not [b for b, (holder, _) in loops.items()
+                if holder == periods[0]], sorted(loops)
+    # the router's top 10 of 512 by a sort past a tile of pairs; the
+    # experts sort nothing at either width
+    sorts = [x for x in period if re.search(r" sort\(", x)]
+    assert len(sorts) == (4 if batch > 12 else 0), sorts
 
 
 @pytest.mark.parametrize("name,batch", sorted(STEP_OPERATIONS))
@@ -407,18 +428,18 @@ def test_decode_segment_step_operations(one_chip, built, name, batch):
     steps = [b for b, (holder, _) in loops.items() if holder not in loops]
     assert len(steps) == 1, sorted(loops)
     step = _operations(loops[steps[0]][1])
-    tiles = [_operations(lines) for holder, lines in loops.values()
-             if holder == steps[0]]
-    want_step, want_tile = STEP_OPERATIONS[name, batch]
+    want_step, want_kernels = STEP_OPERATIONS[name, batch]
+    experts = _expert_kernels(loops[steps[0]][1])
     kernels = [x for x in loops[steps[0]][1] if "tpu_custom_call" in x]
-    # one two-plane decode kernel a full-attention layer, none for a ring
-    assert len(kernels) == eng.config.n_periods, kernels
+    # one two-plane decode kernel a full-attention layer, none for a
+    # ring, and the held experts' tiles of each expert layer
+    assert len(experts) == want_kernels, experts
+    assert len(kernels) == eng.config.n_periods + want_kernels, kernels
     assert len(step) <= want_step, "\n".join(
         [f"{len(step)} operations in a step:"] + step)
-    # one loop over the experts that were hit an expert layer
-    assert len(tiles) == 7 and max(map(len, tiles)) <= want_tile, "\n".join(
-        [f"{[len(t) for t in tiles]} operations a tile:"]
-        + [x for t in tiles for x in t])
+    # no loop over the experts that were hit, or any other, in a step
+    assert not [b for b, (holder, _) in loops.items()
+                if holder == steps[0]], sorted(loops)
     assert not [x for x in step if re.search(r" sort\(", x)]
 
 
@@ -675,23 +696,25 @@ def test_kda_moe_decode_segment_compiles(one_chip, built, batch):
     loops = _loops(text)
     steps = [b for b, (holder, _) in loops.items() if holder not in loops]
     assert len(steps) == 1, sorted(loops)
-    inner = [b for b, (holder, _) in loops.items() if holder == steps[0]]
-    # two loops over the experts hit (the tail's two expert layers; the
-    # first layer's feed-forward is dense) and the loop over the six
-    # like runs, which holds four more
-    runs = [b for b in inner
-            if sum(h == b for h, _ in loops.values()) == 4]
-    assert len(inner) == 3 and len(runs) == 1, sorted(loops)
+    runs = [b for b, (holder, _) in loops.items() if holder == steps[0]]
+    # ONE loop in a step, over the six like runs, and none inside it:
+    # the held experts' tiles are a kernel a layer (ISSUE 51; before it
+    # a loop over the experts hit: two in the step's body for the tail's
+    # two expert layers, four in a run's)
+    assert len(runs) == 1 and len(loops) == 2, sorted(loops)
     assert 6 in _trip_bounds(text)
 
     def kernels(lines):
         found = [x for x in lines if "tpu_custom_call" in x]
         return (sum("kda_state_update" in x for x in found),
-                sum("latent_decode_attention" in x for x in found))
+                sum("latent_decode_attention" in x for x in found),
+                len(_expert_kernels(lines)))
 
-    # 2 + 3 x 6 = 20 state kernels and 1 + 6 = 7 latent ones a step
-    assert kernels(loops[steps[0]][1]) == (2, 1)
-    assert kernels(loops[runs[0]][1]) == (3, 1)
+    # 2 + 3 x 6 = 20 state kernels, 1 + 6 = 7 latent ones and 2 + 4 x 6
+    # = 26 of the experts' (the first layer's feed-forward is dense) a
+    # step
+    assert kernels(loops[steps[0]][1]) == (2, 1, 2)
+    assert kernels(loops[runs[0]][1]) == (3, 1, 4)
 
 
 @pytest.mark.parametrize("ids", [64, 256, "longest prompt"])
@@ -719,3 +742,45 @@ def test_kda_moe_walks_and_prefill_compile(one_chip, built, ids):
     for gone in SERIAL_SOLVE:
         assert not re.search(gone, text), gone
     assert gated_delta.CHUNK not in _trip_bounds(text)
+
+
+# -- the held experts' tiles as one kernel (ISSUE 51) --------------------------
+#
+# The largest expert of the cells (k-exaone-236b-ep8: 75.5 MB, streamed
+# through VMEM in chunks of 128 columns of ``f``) and the claimed cell's
+# (sdar-30b-a3b-ep8: 9.4 MB, chunks of 256) in the decode segment at the
+# whole depth and at the widths the census above does not compile, so
+# that a chip never meets a kernel the compiler refuses for its VMEM.
+# sdar's decode call runs ROUNDS over blocks of 4 positions a row: a
+# forward is ``batch x 4`` tokens, which fit a tile at every width, so
+# every forward takes the kernel in the form of one tile an expert.
+SDAR = "sdar-30b-a3b-ep8"
+SDAR_TEMPORARIES = 1.2      # GB; the compiler's own report: 1.01
+
+
+@pytest.mark.parametrize("name,batch", [
+    (SWA, 2), (SWA, 4), (SWA, 8), (SDAR, 1), (SDAR, 2), (SDAR, 4), (SDAR, 8)])
+def test_the_expert_kernel_fits_the_chip_at_the_cells_widths(
+        one_chip, built, name, batch):
+    eng, params = built(name, None)
+    compiled, _ = _decode_segment(one_chip, eng, params, batch)
+    mem = one_chip.check(compiled)
+    if name == SDAR:
+        assert mem.temp_size_in_bytes < SDAR_TEMPORARIES * 1e9, (
+            f"{mem.temp_size_in_bytes / 1e9:.3f} GB of temporaries")
+    text = compiled.as_text()
+    kernels = _expert_kernels(text.splitlines())
+    # one a layer of a layer loop's body (sdar: the denoise forward's and
+    # the commit forward's), or one an expert layer written out
+    # (k-exaone's seven)
+    assert len(kernels) == (2 if name == SDAR else 7), kernels
+    d = eng.config.n_embd
+    rows = -(-batch * (eng.block.block_length if name == SDAR else 1) // 8) * 8
+    assert all(f"f32[16,{rows},{d}]" in x for x in kernels), kernels
+    # no layer's stack of experts copied out in front of the kernel, nor
+    # one expert's matrix (k-exaone's shared expert has an expert's
+    # shape, so only sdar's program can say the second)
+    held = eng.config.n_routed_experts
+    f = eng.config.moe_intermediate_size
+    one = "|(1,1,)?" if name == SDAR else ""
+    assert not re.search(rf"= bf16\[((1,)?{held},{one}){d},{f}\]", text)
