@@ -51,7 +51,7 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..ops import block_diffusion, expert_ffn
+from ..ops import block_decode, block_diffusion, expert_ffn
 from ..ops.attention import KVCache, merge_heads, split_heads
 from ..ops.layers import linear, rms_norm
 from ..ops.rope import apply_rope
@@ -140,12 +140,12 @@ def cache_entry(config: SDARMoEConfig) -> Tuple[int, int, int]:
 
 
 def decode_kernel_eligible(config: SDARMoEConfig, cache_seq: int) -> bool:
-    """The two-plane decode kernel's geometry rule: the cache in whole
-    blocks of fused 128-lane rows. (A block's forward is ``L`` positions
-    and takes the masked einsum of ``ops.block_diffusion.attend``; the
-    rule keeps the cache in the layout a kernel over it would read.)"""
-    from ..ops import decode_attention
-    return decode_attention.eligible(cache_seq, config.head_dim, 1)
+    """The two-plane decode kernels' geometry rule: the cache in whole
+    blocks of fused 128-lane rows. A round's forward (``L`` positions
+    over the cached ones) is ``ops.block_decode``'s kernel under it;
+    prefills and the store's strides keep the masked einsum of
+    ``ops.block_diffusion.attend``."""
+    return block_decode.eligible(cache_seq, config.head_dim)
 
 
 def init_params(config: SDARMoEConfig, key: jax.Array,
@@ -191,8 +191,10 @@ def init_params(config: SDARMoEConfig, key: jax.Array,
 
 def _attention(attn: Params, a: jnp.ndarray, config: SDARMoEConfig,
                cos, sin, kv: Optional[jnp.ndarray], li, offset,
-               pad: Optional[jnp.ndarray], fresh: bool):
-    """``a`` [B, T, d] normed -> ``(out, kv)`` over the fused cache."""
+               pad: Optional[jnp.ndarray], fresh: bool,
+               kernel: Optional[str] = None):
+    """``a`` [B, T, d] normed -> ``(out, kv)`` over the fused cache;
+    ``kernel`` what the engine resolved (``block_diffusion.attend``)."""
     c = config
     with jax.named_scope("block_attn"):
         q = split_heads(linear(a, attn["wq"]["kernel"]), c.n_head)
@@ -203,7 +205,7 @@ def _attention(attn: Params, a: jnp.ndarray, config: SDARMoEConfig,
         k = apply_rope(rms_norm(k, attn["k_norm"]["scale"], c.rms_norm_eps),
                        cos, sin)
         o, kv = block_diffusion.attend(q, k, v, c.block_length, kv, li,
-                                       offset, pad, fresh)
+                                       offset, pad, fresh, kernel)
         return linear(merge_heads(o), attn["wo"]["kernel"]), kv
 
 
@@ -236,10 +238,11 @@ def apply_blocks(params: Params, h: jnp.ndarray, config: SDARMoEConfig,
                  ) -> Tuple[jnp.ndarray, Optional[KVCache]]:
     """All the layers, one ``lax.scan``; the cache rides the carry, the
     experts' stacks stay outside the scanned leaves, as loop constants.
-    ``decode_kernel`` is what the engine resolved: the held experts'
-    tiles run as ``ops.expert_ffn``'s kernel under it (a block's
-    forward is several positions and its attention takes the masked
-    einsum either way)."""
+    ``decode_kernel`` is what the engine resolved: under it the held
+    experts' tiles run as ``ops.expert_ffn``'s kernel, and the attention
+    of a forward of ONE block over the cached ones as
+    ``ops.block_decode``'s (``block_diffusion.attend`` says which calls
+    those are, from their shapes)."""
     c = config
     offset = 0 if cache is None else cache.length
     kv = None if cache is None else cache.k
@@ -259,7 +262,7 @@ def apply_blocks(params: Params, h: jnp.ndarray, config: SDARMoEConfig,
         h, kv = pre_norm_block(
             p, h, c.rms_norm_eps,
             lambda a: _attention(p["attn"], a, c, cos, sin, kv, li, offset,
-                                 pad, fresh), ffn)
+                                 pad, fresh, decode_kernel), ffn)
         return (h, kv), seen[0]
 
     (h, kv), counts = jax.lax.scan(

@@ -18,7 +18,12 @@ Three pieces live here, as pure functions the engine's round loop
   visible, and over each other under the block mask. The cached part is
   READ BEFORE the new rows are written, so the write is not consumed by
   a matmul of the same layer and the compiler updates the cache in
-  place (``ops.decode_attention`` says what happens otherwise);
+  place (``ops.decode_attention`` says what happens otherwise). A
+  round's forward (ONE block over a cache that holds something) is
+  ``ops.block_decode``'s kernel where the engine resolved a decode
+  kernel: it streams each row's span and not the layer's whole slice.
+  The XLA form below is every other call's, and the oracle the kernel
+  is tested against;
 - ``choose``: greedy candidates and their confidences, in float32, the
   mask token's own logit left out of the choice;
 - ``transfer``: which masked positions a forward fixes, by rule.
@@ -31,6 +36,7 @@ from typing import NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from . import block_decode
 from .attention import NEG_INF, write_kv_layer_fused
 
 # Numerics contract (tools/graftcheck numerics pass): scores, softmax
@@ -102,6 +108,7 @@ def _weighted(p, v):
 def attend(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, length: int,
            kv: Optional[jnp.ndarray] = None, layer_idx=None, offset=0,
            pad: Optional[jnp.ndarray] = None, fresh: bool = False,
+           kernel: Optional[str] = None,
            ) -> Tuple[jnp.ndarray, Optional[jnp.ndarray]]:
     """q [B, H, T, hd], k/v [B, Hkv, T, hd]: the new positions ``offset
     + arange(T)``. Without ``kv`` they attend to each other under the
@@ -109,8 +116,20 @@ def attend(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, length: int,
     cache) they also see every cached position of ``[pad, offset)``
     (``offset`` is a block boundary of every row, so all of them are
     earlier blocks), unless the cache is ``fresh`` and holds none; the
-    new rows are then written at ``offset``. Returns ``(out, kv)``."""
+    new rows are then written at ``offset``. Returns ``(out, kv)``.
+
+    ``kernel`` is the decode kernel the engine resolved (``"device"``,
+    ``"interpret"`` or ``None``). Which calls take it follows from the
+    shapes: ONE block (``T == length``) over a cache that is not fresh
+    and has the kernel's geometry; a prefill or a stride of the store
+    (``T > length``) reads the cache once a request, not once a forward,
+    and keeps the form below."""
     t, hd = q.shape[2], q.shape[3]
+    if (kernel is not None and kv is not None and not fresh and t == length
+            and block_decode.eligible(kv.shape[3], hd)):
+        return block_decode.block_decode_attention(
+            q, k, v, kv, layer_idx, offset, pad,
+            interpret=kernel == "interpret")
     scale = 1.0 / jnp.sqrt(jnp.asarray(hd, dtype=jnp.float32))
     pos = offset + jnp.arange(t)
     own = block_mask(pos, pos, length, pad)

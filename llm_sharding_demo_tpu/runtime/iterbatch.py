@@ -45,9 +45,12 @@ EMPTY span: its entry of ``pad_j`` is the cache's length, a pad no depth
 reaches, so the decode kernel, which streams each row's own ``[pad,
 depth)``, reads nothing for it (``_empty_span``), and the state kernels
 of a family whose rows hold a state stream the other lanes alone; a lane
-WITH a request keeps its pad to the digit. ``attn_positions_streamed``
-over ``attn_positions_rect`` (``stats()``) says what share of the
-rectangle width x depth the live rows' spans are, and
+WITH a request keeps its pad to the digit. A family that generates by
+blocks streams spans too: every forward of a round is
+``ops.block_decode``'s kernel a layer, over the same ``[pad, depth)``.
+``attn_positions_streamed`` over ``attn_positions_rect`` (``stats()``)
+says what share of the rectangle width x depth the live rows' spans are
+(a forward of a round counted like a step), and
 ``state_lanes_streamed`` over ``state_lanes_compiled`` what share of
 the compiled lanes the state kernels stream.
 
@@ -2198,8 +2201,11 @@ class IterBatchingEngine:
                 self._slab.note_compiles()   # the store's two movers
         if resident:
             state.cache = cache
-        if not self._blocks:         # the decode kernel's stream
-            self._count_stream(state, d, n)
+        # the decode kernel's stream: a step is a forward; how many a
+        # call of rounds ran is the device's to say (below)
+        count_stream = self._count_stream(state, d, n)
+        if not self._blocks:
+            count_stream(n)
         state.depth = d + span
         self._in_flight.append(out)
         seg = _SegOut(out)
@@ -2257,6 +2263,7 @@ class IterBatchingEngine:
                 # over the weights each: what ``steps`` means to every
                 # reader) and, for each token the row got from it, the
                 # forward inside its round that fixed it
+                count_stream(values[ran])
                 said = dict(counted(values), steps=values[ran])
                 return [dict(said, fixed_at=seg.row(row, 1)[lo:lo + take]
                              .tolist()) for row, lo, take in took]
@@ -2276,7 +2283,7 @@ class IterBatchingEngine:
         self._retire_finished(state)
         self._set_gauges(state)
 
-    def _count_stream(self, state: _BatchState, d: int, n: int) -> None:
+    def _count_stream(self, state: _BatchState, d: int, n: int):
         """What the decode kernel's stream reads in a call of ``n`` steps
         from depth ``d``, reckoned on the host with the kernel's own
         arithmetic (``ops.decode_attention.streamed_blocks``) in blocks
@@ -2288,30 +2295,46 @@ class IterBatchingEngine:
         empty or pad. Beside it, for a batch whose rows hold a state in
         the slab: the lanes the state kernels stream a step (the live
         rows', ``ops.gated_delta.live_lanes``) and the lanes of the
-        compiled width."""
-        offs = np.arange(d, d + n)
-        streamed = BLOCK_S * int(streamed_blocks(
+        compiled width.
+
+        Returns the function that counts it, given the FORWARDS the call
+        ran: ``n`` for a call of steps (a step is a forward, each a
+        position deeper). A call of ROUNDS runs its forwards
+        (``ops.block_decode``'s kernel a layer of each) at its rounds'
+        depths ``d, d + unit, ...`` and says how many when it is ready;
+        they are counted spread evenly over the rounds (rounds of one
+        call differ by a block at most, where one starts on a block's
+        edge). The rows' pads are taken NOW: a lane may be vacated
+        before the call is ready."""
+        offs = d + self._unit * np.arange(n)
+        streamed = int(streamed_blocks(
             [s.pad for s in state.slots if s is not None], offs,
             BLOCK_S).sum())
-        rect = BLOCK_S * len(state.slots) * int(
+        rect = len(state.slots) * int(
             streamed_blocks([0], offs, BLOCK_S).sum())
-        lanes = compiled = 0
-        if self._slab is not None:
-            lanes = n * sum(s is not None for s in state.slots)
-            compiled = n * len(state.slots)
-        with self._stats_lock:
-            self.attn_positions_streamed += streamed
-            self.attn_positions_rect += rect
-            self.state_lanes_streamed += lanes
-            self.state_lanes_compiled += compiled
-            share = self.attn_positions_streamed / max(
-                self.attn_positions_rect, 1)
-        REGISTRY.inc("iter_attn_positions_streamed_total", value=streamed)
-        REGISTRY.inc("iter_attn_positions_rect_total", value=rect)
-        REGISTRY.gauge("iter_attn_stream_share", round(share, 4))
-        if compiled:
-            REGISTRY.inc("iter_state_lanes_streamed_total", value=lanes)
-            REGISTRY.inc("iter_state_lanes_compiled_total", value=compiled)
+        live = sum(s is not None for s in state.slots)
+        width = len(state.slots) if self._slab is not None else 0
+
+        def count(forwards: int) -> None:
+            mine = BLOCK_S * (streamed * forwards // n)
+            whole = BLOCK_S * (rect * forwards // n)
+            lanes, compiled = forwards * live, forwards * width
+            with self._stats_lock:
+                self.attn_positions_streamed += mine
+                self.attn_positions_rect += whole
+                if compiled:
+                    self.state_lanes_streamed += lanes
+                    self.state_lanes_compiled += compiled
+                share = self.attn_positions_streamed / max(
+                    self.attn_positions_rect, 1)
+            REGISTRY.inc("iter_attn_positions_streamed_total", value=mine)
+            REGISTRY.inc("iter_attn_positions_rect_total", value=whole)
+            REGISTRY.gauge("iter_attn_stream_share", round(share, 4))
+            if compiled:
+                REGISTRY.inc("iter_state_lanes_streamed_total", value=lanes)
+                REGISTRY.inc("iter_state_lanes_compiled_total",
+                             value=compiled)
+        return count
 
     def _advance_spec(self, state: _BatchState):
         """One draft-verify SEGMENT (spec batches): up to

@@ -26,6 +26,7 @@ import pytest
 
 from benchmark.reference import sdar_moe as ref_mod
 from llm_sharding_demo_tpu.models import latent_moe, llama, sdar_moe
+from llm_sharding_demo_tpu.ops.decode_attention import BLOCK_S
 from llm_sharding_demo_tpu.runtime.engine import DecodeEngine, SamplingConfig
 from llm_sharding_demo_tpu.runtime.iterbatch import IterBatchingEngine
 from llm_sharding_demo_tpu.runtime.kv_pool import KVBlockPool
@@ -181,6 +182,83 @@ def test_a_budget_that_ends_inside_a_block_retires_with_the_right_count(
         assert res.tokens[0, 10:].tolist() == want["tokens"]
         assert sum(d["tokens"] for d in decodes) == n
         assert sum(d["rounds"] for d in decodes) == -(-(2 + n) // 4)
+
+
+# -- under the decode kernels: a round's forwards stream the rows' spans ----
+
+KERNEL_SIZES = dict(SIZES, head_dim=64)    # fused rows of 128 lanes
+
+
+def test_a_joiner_under_the_decode_kernels_still_equals_its_solo_run():
+    """``decode_kernel="interpret"``: every forward of a round is
+    ``ops.block_decode``'s kernel a layer. A joiner meets a batch
+    mid-answer (another pad, a grown width, empty lanes), every row is
+    the published loop's, and the stream counter has counted the call's
+    FORWARDS: the cache is one streamed block, so a live row streams one
+    block a forward (the device's own ``block_row_forwards``) and the
+    rectangle is the width's."""
+    cfg = dataclasses.replace(sdar_moe.CONFIGS["sdar-moe-tiny"],
+                              denoising_steps=2, head_dim=64)
+    params = REF.init(KERNEL_SIZES, 7, jnp.float32)
+    engine = DecodeEngine(params, cfg, max_seq=256,
+                          decode_kernel="interpret")
+    assert engine._decode_kernel == "interpret"
+    pool = KVBlockPool.for_engine(engine, num_blocks=64, block_size=16)
+    ib = IterBatchingEngine(engine, max_batch=4, seg_steps=8, max_wait_ms=0,
+                            pool=pool)
+    widths, inner = [], ib._advance
+
+    def advance(state):
+        widths.append(len(state.slots))
+        return inner(state)
+    ib._advance = advance
+    jobs = [(prompt_of(8, 1), 40), (prompt_of(13, 2), 9),
+            (prompt_of(22, 3), 6)]
+    got = ask(ib, jobs, gap_s=1.0)
+    for (prompt, n), (res, decodes, prefills) in zip(jobs, got):
+        want = REF.generate(params, KERNEL_SIZES, prompt, n)
+        assert res.tokens[0, len(prompt):].tolist() == want["tokens"]
+        assert res.fixed_at[0].tolist() == want["fixed_at"]
+    assert sum(p[0].get("kind") != "seed" for _, _, p in got) >= 1
+    st = ib.stats()
+    assert st["attn_positions_streamed"] == BLOCK_S * st["block.row_forwards"]
+    assert 0 < st["attn_positions_streamed"] < st["attn_positions_rect"]
+    assert st["attn_positions_rect"] % BLOCK_S == 0
+    # every call's forwards times its width: between the narrowest and
+    # the widest width over all forwards
+    assert (min(widths) * st["block.forwards"]
+            <= st["attn_positions_rect"] // BLOCK_S
+            <= max(widths) * st["block.forwards"])
+    assert max(widths) > 1
+
+
+@pytest.mark.parametrize("depth,rounds,forwards,mine,whole", [
+    # depth 508: both rounds (508, 512) stream two blocks of 256; the
+    # row padded to 300 starts in the second
+    (508, 2, 6, 6 * (2 + 1), 6 * 4 * 2),
+    # depth 512: the second round, at 516, streams a third block
+    (512, 2, 6, 3 * (2 + 1) + 3 * (3 + 2), 3 * 4 * 2 + 3 * 4 * 3),
+    # a lone round, both rows inside the first block
+    (40, 1, 3, 3 * (1 + 1), 3 * 4 * 1)],
+    ids=["inside-a-block", "across-a-blocks-edge", "one-round"])
+def test_the_stream_counter_counts_a_calls_forwards(served, depth, rounds,
+                                                    forwards, mine, whole):
+    """Two rows of known pads in a width of four: streamed and
+    rectangle to the digit, from the forwards the call reports; the
+    empty lanes add to the rectangle alone."""
+    ib = served.ib
+    state = types_ns(slots=[types_ns(pad=0), None,
+                            types_ns(pad=300 if depth > 300 else 8), None])
+    before = ib.stats()
+    count = ib._count_stream(state, depth, rounds)
+    assert ib.stats()["attn_positions_rect"] == before["attn_positions_rect"]
+    count(forwards)
+    after = ib.stats()
+    assert (after["attn_positions_streamed"]
+            - before["attn_positions_streamed"]) == BLOCK_S * mine
+    assert (after["attn_positions_rect"]
+            - before["attn_positions_rect"]) == BLOCK_S * whole
+    assert after["state_lanes_compiled"] == before["state_lanes_compiled"]
 
 
 def test_what_the_scheduler_refuses(served):

@@ -235,6 +235,32 @@ def test_each_transfer_rule_is_the_published_loops(rule):
         assert first == [0, 0, 1, 1]
 
 
+# fused rows of 128 lanes and a cache of whole blocks: the geometry the
+# decode kernels take (``sdar_moe.decode_kernel_eligible``)
+KERNEL_SIZES = dict(SIZES, head_dim=64)
+
+
+@pytest.mark.parametrize("rest,rows", [(0, 1), (1, 1), (3, 3)],
+                         ids=["on-a-boundary", "one-past", "a-batch-of-three"])
+def test_rounds_under_the_decode_kernels_are_the_published_loop(rest, rows):
+    """``decode_kernel="interpret"``: every round's forwards take
+    ``ops.block_decode``'s kernel (and the experts' tiles theirs), the
+    prefill keeps the XLA form; rows of different lengths ride one
+    batch, so the kernel meets pads, and each row is still the loop's."""
+    sizes = dict(KERNEL_SIZES, denoising_steps=2)
+    params = REF.init(sizes, 11, jnp.float32)
+    engine = DecodeEngine(params, config_of(sizes, head_dim=64), max_seq=200,
+                          decode_kernel="interpret")
+    assert engine._decode_kernel == "interpret" and engine._cache_seq == 256
+    prompts = [prompt_of(12 + rest + 8 * i, seed=rest + i)
+               for i in range(rows)]
+    together = engine.generate(prompts, 10)
+    for i, prompt in enumerate(prompts):
+        want = REF.generate(params, sizes, prompt, 10)
+        assert together.row_tokens(i)[len(prompt):].tolist() == want["tokens"]
+        assert together.fixed_at[i].tolist() == want["fixed_at"]
+
+
 def sharpened(params, by=6.0):
     """A head that is sure of itself: some confidences pass 0.9."""
     head = params["lm_head"]["kernel"] * by

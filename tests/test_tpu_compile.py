@@ -81,6 +81,35 @@ def test_decode_attention_with_spans_compiles(one_chip, geometry, batch):
         2 * 2 + 4 * decode_attention_mod._F32_TEMPORARIES) <= room
 
 
+# a block's four positions over the same two-plane cache
+# (``ops.block_decode``): SDAR-30B-A3B's geometry (32 query heads over 4
+# of 128, 48 layers, a 2,048-slot cache) at its narrowest and widest
+# width, where the halves of a fused row are whole lane tiles; and rows
+# of 128 lanes (GPT-2's 64-wide heads), where the kernel cuts the
+# halves inside a tile, which only this compiler can refuse
+@pytest.mark.parametrize("geometry,batch", [
+    *[pytest.param((32, 4, 128, 48, 2048), b, id=f"sdar-30b-a3b-ep8-{b}")
+      for b in (1, 8)],
+    pytest.param((12, 12, 64, 12, 1024), 4, id="heads-of-64-4")])
+def test_block_decode_attention_compiles(one_chip, geometry, batch):
+    from llm_sharding_demo_tpu.ops.block_decode import block_decode_attention
+    h, hkv, hd, layers, depth = geometry
+    shape = one_chip.shape
+    compiled = jax.jit(
+        lambda q, k, v, kv, li, off, pad: block_decode_attention(
+            q, k, v, kv, li, off, pad), donate_argnums=(3,)).lower(
+        shape((batch, h, 4, hd)), shape((batch, hkv, 4, hd)),
+        shape((batch, hkv, 4, hd)),
+        shape((layers, batch, hkv, depth, 2 * hd)),
+        shape((), jnp.int32), shape((), jnp.int32),
+        shape((batch,), jnp.int32)).compile()
+    mem = one_chip.check(compiled)
+    # the cache goes in and comes out as one buffer, and nothing else
+    # is held on the device beside the operands
+    assert mem.alias_size_in_bytes == layers * batch * hkv * depth * 2 * hd * 2
+    assert mem.temp_size_in_bytes < 1e6
+
+
 @pytest.mark.parametrize("batch,lanes", [(1, 640), (16, 640)])
 def test_latent_decode_attention_compiles(one_chip, batch, lanes):
     """The absorbed decode step over a latent cache at the published

@@ -11,6 +11,7 @@ not a chip run.
 """
 
 import functools
+import hashlib
 import re
 
 import jax
@@ -21,7 +22,7 @@ from benchmark.harness import server
 from benchmark.harness.spec import Spec, resolve
 from benchmark.rehearse import _Abstract
 from llm_sharding_demo_tpu.models import gpt2
-from llm_sharding_demo_tpu.ops import expert_ffn, gated_delta
+from llm_sharding_demo_tpu.ops import block_decode, expert_ffn, gated_delta
 from llm_sharding_demo_tpu.runtime.engine import DecodeEngine, SamplingConfig
 from llm_sharding_demo_tpu.runtime.prefix_cache import PrefixCachingEngine
 
@@ -144,6 +145,11 @@ def _decode_segment(chip, eng, params, batch, counted=True):
     the cache it donates: the COUNTED form (the call's length an int32
     operand, at most ``SEG_STEPS``), or the ``lax.scan`` of ``SEG_STEPS``
     steps that a caller with no count keeps."""
+    lowered, cache = _lowered_segment(chip, eng, params, batch, counted)
+    return lowered.compile(), cache
+
+
+def _lowered_segment(chip, eng, params, batch, counted=True):
     shape = chip.shape
     cache = chip.placed(jax.eval_shape(lambda: eng._fresh_cache(batch)))
     steps = (shape((), jnp.int32),) if counted else ()
@@ -157,8 +163,7 @@ def _decode_segment(chip, eng, params, batch, counted=True):
             params, shape(token, jnp.int32), cache,
             shape((batch,), jnp.int32),
             shape((SEG_STEPS, batch, 2), jnp.uint32), *steps,
-            sampling=SamplingConfig(mode="greedy"), window=None
-    ).compile(), cache
+            sampling=SamplingConfig(mode="greedy"), window=None), cache
 
 
 def _computations(text):
@@ -784,3 +789,78 @@ def test_the_expert_kernel_fits_the_chip_at_the_cells_widths(
     f = eng.config.moe_intermediate_size
     one = "|(1,1,)?" if name == SDAR else ""
     assert not re.search(rf"= bf16\[((1,)?{held},{one}){d},{f}\]", text)
+
+
+# -- a block's attention as one kernel (ISSUE 52) ------------------------------
+#
+# sdar's decode segment at the cell's four widths: a forward of a round
+# (the denoise loop's and the commit's: two layer loops) holds ONE
+# ``block_decode_attention`` a layer in place of ``attend``'s XLA form,
+# whose marks are gone from the program: the layer's whole slice of the
+# cache (``bf16[B,4,2048,256]``, 33.5 MB at 8 rows) and the float32
+# scores over every key of every lane (``f32[B,32,4,2052]``). The cache
+# is aliased through both loops and both kernels, never copied.
+
+@pytest.mark.parametrize("batch", [1, 2, 4, 8])
+def test_a_blocks_attention_is_one_kernel_a_layer(one_chip, built, batch):
+    eng, params = built(SDAR, None)
+    compiled, cache = _decode_segment(one_chip, eng, params, batch)
+    mem = one_chip.check(compiled)
+    # the compiler's own report: 1.008 GB at every width, the three
+    # stacked weights' copies (D8); the kernel's buffers are VMEM's
+    assert mem.temp_size_in_bytes < SDAR_TEMPORARIES * 1e9, (
+        f"{mem.temp_size_in_bytes / 1e9:.3f} GB of temporaries")
+    text = compiled.as_text()
+    name = rf"%{block_decode.KERNEL_NAME}[.\d]* = "
+    # one call in the body of each of two loops, and none elsewhere
+    calls = [[x for x in lines if re.search(name, x)]
+             for _, lines in _loops(text).values()]
+    calls = [found for found in calls if found]
+    assert [len(found) for found in calls] == [1, 1], calls
+    assert all("tpu_custom_call" in found[0] for found in calls), calls
+    assert len(re.findall(name, text)) == 2
+    c = eng.config
+    layer = f"{batch},{c.n_kv_head},{eng._cache_seq},{2 * c.head_dim}"
+    assert not re.search(rf"= bf16\[{layer}\]", text)
+    assert not re.search(
+        rf"f32\[{batch},{c.n_head},{eng.block.block_length},"
+        rf"{eng._cache_seq + eng.block.block_length}\]", text)
+    # the cache: donated, aliased to the result, and no copy of it made
+    assert not re.search(rf"= bf16\[{c.n_layer},{layer}\]\S* copy\(", text)
+    held = cache.k.size * cache.k.dtype.itemsize
+    assert held <= mem.alias_size_in_bytes < held + 1e6
+
+
+# The cells that run ``ops/decode_attention.py``'s kernel, and the
+# sliding-window family beside them, lower their decode segments to the
+# PARENT's text (1db1e4a; ``_segment_digest`` with the parent's package
+# on the path). A Mosaic kernel's payload carries the source locations
+# of its callers, the checkout's path among them, so it is left out of
+# the digest: what is compared is every XLA operation, every kernel's
+# operands, aliases and shapes. (The kernels' own source,
+# ``ops/decode_attention.py`` among it, is not touched by ISSUE 52.)
+PARENT_SEGMENTS = {
+    ("mistral-7b-l16", 8):
+        "fe749cf027c1e570996f1ab183e5946c4210983bb22dd211d284fc46aa0b6754",
+    ("falcon-h1-34b-l6", 8):
+        "1468ac8620e635431eea0cfae84188ca88b9e202808b3774ed6e0e4965146015",
+    (SWA, 4):
+        "8a56a0901e767e125abd8a46985ec64fac92cb026f9906f684dbfbde3d79a22f",
+}
+_PAYLOAD = re.compile(r'(\\22body\\22: \\22)[^\\]*(\\22)')
+
+
+def _segment_digest(chip, eng, params, batch):
+    text = _lowered_segment(chip, eng, params, batch)[0].as_text()
+    text, kernels = _PAYLOAD.subn(r"\1\2", text)
+    assert kernels == text.count("@tpu_custom_call") > 0
+    return hashlib.sha256(text.encode()).hexdigest()
+
+
+@pytest.mark.parametrize("name,batch", sorted(PARENT_SEGMENTS))
+def test_a_family_without_blocks_lowers_to_the_parents_segment(
+        one_chip, built, name, batch):
+    eng, params = built(name)
+    assert eng.block is None and eng._decode_kernel == "device"
+    assert (_segment_digest(one_chip, eng, params, batch)
+            == PARENT_SEGMENTS[name, batch])
